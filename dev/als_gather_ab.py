@@ -1,8 +1,8 @@
 """A/B the ALS gather levers on the real chip.
 
-Results recorded in BASELINE.md "Round-5 lever A/B" — both levers
-rejected with data (the gather bound is per-index, not per-byte).
-Re-run to reproduce; protocol follows the kernel-table slope method.
+When last run on a v5e (before PR 1) both levers were rejected: the
+gather bound was per-index, not per-byte.  Re-run to reproduce;
+protocol follows the kernel-table slope method.
 
 Levers, measured at the ML-1M attribution shape (6040x3706, nnz=1M,
 r=10, P=256 grouped layout, user side):

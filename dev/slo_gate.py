@@ -61,8 +61,7 @@ def main() -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    if hasattr(jax.config, "jax_num_cpu_devices"):
-        jax.config.update("jax_num_cpu_devices", 8)
+    jax.config.update("jax_num_cpu_devices", 8)
 
     from oap_mllib_tpu import serving
     from oap_mllib_tpu.config import set_config

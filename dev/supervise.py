@@ -67,8 +67,7 @@ def _worker(rank: int, world: int, coord: str) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    if hasattr(jax.config, "jax_num_cpu_devices"):
-        jax.config.update("jax_num_cpu_devices", local_dev)
+    jax.config.update("jax_num_cpu_devices", local_dev)
 
     import numpy as np
 

@@ -73,24 +73,24 @@ class Config:
     # MXU precision tier for the K-Means hot loop AND the PCA covariance
     # Gram.  "highest" = full f32 (multi-pass) — the 1e-4 numerical-parity
     # contract.  "high" = bf16_3x: K-Means runs bf16_3x centroid sums +
-    # bf16 assignment (within 1e-5 of highest; ~3x kernel steady-state,
-    # ~2.6x end-to-end — BASELINE.md; see kmeans_ops._assign_prec), PCA
+    # bf16 assignment (within 1e-5 of highest at a fraction of the MXU
+    # passes; see kmeans_ops._assign_prec), PCA
     # holds <=1e-4 on the centered Gram.
     # "default" = bf16 everywhere (K-Means ~1e-2, PCA ~1e-3); opt-in for
     # throughput-first workloads.  The x64 lane pins PCA to highest.
     # Per-tier bounds pinned on tests_tpu/; docs/configuration.md has the
     # full table.
     matmul_precision: str = "highest"
-    # K-Means hot-loop kernel: "auto" picks the fastest measured path per
-    # shape/tier (BASELINE.md kernel table, v5e): the fused Pallas kernel
-    # at the f32-accurate tiers (it won every profiled shape once the
-    # loop-mode assignment landed), the chunked XLA Lloyd at "default" or
-    # when (k, d) overflows the kernel's VMEM blocks.  "xla"/"pallas"
+    # K-Means hot-loop kernel: "auto" follows kmeans_ops.pallas_preferred
+    # — the fused Pallas kernel (one pass over X per iteration, the
+    # distance and one-hot blocks never leaving VMEM) at every tier while
+    # (k, d) fits the kernel's VMEM blocks, the chunked XLA Lloyd past
+    # them.  "xla"/"pallas"
     # force a path; "pallas" requires TPU + single-device + f32 and falls
     # back otherwise.
     kmeans_kernel: str = "auto"
     # ALS normal-equation layout: "auto" uses the scatter-free grouped-edge
-    # programs (12x the COO path at MovieLens-1M scale on v5e, BASELINE.md)
+    # programs (batched MXU matmuls where COO pays a segment-sum scatter)
     # unless the degree distribution's padding blowup exceeds the guard, in
     # which case the COO segment-sum programs run; "grouped"/"coo" force a
     # layout.  Applies to both the single-device and the block-parallel
@@ -146,7 +146,7 @@ class Config:
     # the full d x d factorization — the parity contract, exact for any
     # spectrum.  "randomized" = top-k subspace iteration
     # (ops/pca_ops.topk_eigh_randomized): replaces the O(d^3) eigh that
-    # owns 66% of the large-d wall (BASELINE.md row 5) with a few
+    # owns most of the large-d wall with a few
     # (d, d) x (d, k+16) MXU matmuls — opt-in because accuracy is
     # spectral-gap-dependent (decaying spectra ~1e-4 vs eigh; a flat
     # spectrum biases values ~5% low and its eigenvectors are
@@ -155,8 +155,8 @@ class Config:
     # Randomized-solver tuning: probe width = k + pca_rand_oversample,
     # subspace iterations = pca_rand_iters.  The defaults hold ~1e-4 on
     # decaying spectra; weakly-gapped spectra tighten with more of both
-    # (measured d=2048 Wishart edge: ~5% value bias at 8/16, ~0.3% at
-    # 16/64 — BASELINE.md row 5).  Ignored unless pca_solver="randomized".
+    # (a d=2048 Wishart edge: ~5% value bias at 8/16, ~0.3% at 16/64).
+    # Ignored unless pca_solver="randomized".
     pca_rand_oversample: int = 16
     pca_rand_iters: int = 8
     # Shape bucketing (data/bucketing.py): round padded row counts up to

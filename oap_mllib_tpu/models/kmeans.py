@@ -558,6 +558,7 @@ class KMeans:
             cluster_sizes=np.asarray(counts),
         )
         summary.streamed = True
+        summary.kernel = "xla"  # the streamed passes run the chunked XLA programs
         summary.progcache = progcache.delta(cache_before)
         summary.tuning = autotune.delta(tune_before)
         psn.record(summary, timings, pol)
@@ -660,6 +661,7 @@ class KMeans:
         )
         summary.progcache = progcache.delta(cache_before)
         summary.tuning = autotune.delta(tune_before)
+        summary.kernel = timings.root.attrs["kernel"]
         psn.record(summary, timings, pol)
         if ckpt is not None:
             ckpt.record(summary)
@@ -670,13 +672,11 @@ class KMeans:
                    resume=None, d_orig=None):
         """Dispatch the hot loop to the configured kernel.
 
-        ``auto`` picks the fastest measured path for the shape/tier
-        (BASELINE.md kernel table, v5e; rule in
-        kmeans_ops.pallas_preferred): the fused Pallas kernel at the
-        f32-accurate tiers when (k, d) fits its VMEM blocks — its
+        ``auto`` follows kmeans_ops.pallas_preferred: the fused Pallas
+        kernel at every tier when (k, d) fits its VMEM blocks — its
         loop-mode assignment + exact-split cluster sums cut the
-        per-iteration MXU/VPU passes — else the chunked XLA Lloyd
-        (which wins the all-bf16 "default" tier).  ``xla``/``pallas`` force a path;
+        per-iteration MXU/VPU passes — else the chunked XLA Lloyd.
+        ``xla``/``pallas`` force a path;
         ``pallas`` requires TPU + single device + f32 and falls back
         otherwise.  Chunking only applies on a single device: the scan
         reshape conflicts with GSPMD row sharding.  A mesh with a model
@@ -713,7 +713,16 @@ class KMeans:
             # checkpoint at, so route onto the chunked XLA Lloyd
             # (docs/distributed.md "Elastic worlds")
             use_pallas = False
-        if mesh.shape[cfg.model_axis] > 1 and cfg.kmeans_kernel != "xla":
+        model_sharded = (
+            mesh.shape[cfg.model_axis] > 1 and cfg.kmeans_kernel != "xla"
+        )
+        if timings is not None:
+            # which Lloyd program the dispatch chose, for the summary
+            timings.root.attrs["kernel"] = (
+                "model_sharded" if model_sharded
+                else "pallas" if use_pallas else "xla"
+            )
+        if model_sharded:
             # segmented-start ring epilogue geometry: pure function of
             # (config, cache, bucket) so every rank resolves identically
             ring_segments = autotune.resolve(

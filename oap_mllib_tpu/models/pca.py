@@ -118,6 +118,15 @@ class PCAModel:
         return cls(comps, var)
 
 
+def _gram_kernel(cfg, d: int, tier: str, dtype) -> str:
+    """The single-device Gram program the dispatch chooses, as the fit
+    summaries name it."""
+    return (
+        "pallas" if pca_ops.use_pallas_gram(cfg.pca_kernel, d, tier, dtype)
+        else "xla"
+    )
+
+
 def _pca_solver_cfg() -> str:
     """Validated Config.pca_solver — a typo must raise, not silently run
     eigh (the als_kernel/als_item_layout contract).  The randomized
@@ -364,6 +373,7 @@ class PCA:
                 source, dtype, tier, timings=timings, policy=pol.name,
                 checkpoint=ckpt,
             )
+            kernel = _gram_kernel(cfg, d, tier, dtype)
         # cov is exactly (d, d) here — no model-sharding feature pad
         vals, vecs, total, solver = self._solve_spectrum(cov, d, timings)
         ratio = vals / total if total > 0 else np.zeros(self.k)
@@ -373,6 +383,7 @@ class PCA:
             "streamed": True,
             "n_rows": n,
             "pca_solver": solver,
+            "kernel": kernel,
             "progcache": progcache.delta(cache_before),
             "tuning": autotune.delta(tune_before),
         }
@@ -450,6 +461,11 @@ class PCA:
                     "highest" if cfg.enable_x64
                     else psn.kernel_tier(pol.name, cfg.matmul_precision)
                 )
+                # which Gram program the dispatch chooses, for the summary
+                timings.root.attrs["kernel"] = (
+                    "model_sharded" if mp > 1
+                    else _gram_kernel(cfg, table.data.shape[1], tier, dtype)
+                )
                 if mp > 1:
                     cov, _ = pca_ops.covariance_model_sharded(
                         table.data, table.mask, n_rows, mesh, tier,
@@ -473,6 +489,8 @@ class PCA:
             "accelerated": True,
             "mesh_shape": dict(mesh.shape),
             "pca_solver": solver,
+            # absent when the covariance was restored from a checkpoint
+            "kernel": timings.root.attrs.get("kernel"),
             "progcache": progcache.delta(cache_before),
             "tuning": autotune.delta(tune_before),
         }

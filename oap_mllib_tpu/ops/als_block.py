@@ -523,7 +523,7 @@ def als_block_run_grouped(
 
     Identical math and collective structure to :func:`als_block_run` (one
     psum per item update) with the scatter-free partials — the multi-device
-    form of the 12x single-device win (BASELINE.md round 3)."""
+    form of the single-device grouped layout."""
     cfg = get_config()
     axis = cfg.data_axis
     world = mesh.shape[axis]

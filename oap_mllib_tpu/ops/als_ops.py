@@ -212,9 +212,9 @@ def masked_solve(a: jax.Array, b: jax.Array, deg: jax.Array) -> jax.Array:
 #     [Ys | 1]^T @ [a_w*Ys | b_w | n_w]   ->  (r+1, r+2)
 #
 # whose blocks are A (r x r), b (col r), and the reg count (at [r, r+1]),
-# plus a group->destination segment-sum of tiny (r+1, r+2) tiles.  Measured
-# 2.7 ms vs the scatter path's 94 ms for the same half-iteration partials
-# (BASELINE.md round 3).  This is the reference's blocked-CSR idea
+# plus a group->destination segment-sum of tiny (r+1, r+2) tiles — no
+# scatter anywhere in the half-iteration partials, which the TPU executes
+# far slower than batched matmuls.  This is the reference's blocked-CSR idea
 # (ALSDALImpl.scala:184-230 builds per-rank CSR precisely so oneDAL can
 # batch row solves) rebuilt for the MXU.
 
@@ -346,11 +346,10 @@ def grouped_padded_edges(dst, n_dst: int, group_size: int = 0) -> int:
 def auto_group_size(nnz: int, n_dst: int) -> int:
     """Group size adapted to the mean degree so padding stays bounded:
     the next power of two ABOVE the mean degree keeps total padded edges
-    <= nnz + n_dst*P < 3*nnz, and larger P is measurably faster — fewer
-    groups shrink the (G, r+1, r+2) segment-sum and deepen the per-group
-    (P)-contraction on the MXU (ML-1M on v5e: 13.7 ms/iter at P=64 vs
-    10.3 at P=256, BASELINE.md ALS table).  Capped at 256: P=512 loses
-    the padding it adds (14.1 ms/iter), P=1024 doubles the iteration.
+    <= nnz + n_dst*P < 3*nnz, and larger P is faster — fewer groups
+    shrink the (G, r+1, r+2) segment-sum and deepen the per-group
+    (P)-contraction on the MXU.  Capped at 256: past it the padding a
+    larger P adds costs more than the deeper contraction returns.
     Long-tail distributions (millions of destinations with ~2 ratings
     each) still get small P; the caller's COO fallback guard handles the
     blowup cases anyway."""
@@ -624,8 +623,8 @@ def als_run_grouped(
 ) -> Tuple[jax.Array, jax.Array]:
     """Full ALS loop on the grouped-edge layout (both feedback modes).
 
-    ~15x the COO path at MovieLens-1M scale on v5e: scatter-free partials
-    + Cholesky solves (BASELINE.md round 3).  The launch registers with
+    Scatter-free partials + Cholesky solves, where the COO path pays a
+    segment-sum scatter per half-iteration.  The launch registers with
     the program-cache registry (utils/progcache); ``timings`` receives
     the ``<phase>/compile`` / ``<phase>/execute`` wall split.  ``policy``
     is the compute-precision policy (utils/precision.py) for the moment

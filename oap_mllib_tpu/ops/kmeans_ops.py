@@ -63,31 +63,35 @@ def _prec(precision: str):
         ) from None
 
 
+# Resident-block bounds of the fused kernel (padded elements): the full
+# (k, d) centers AND sums blocks plus the (tile, k) distance/one-hot
+# temporaries live in VMEM for the whole walk.  Fitted against the
+# chip's compiler under the plane's scoped-VMEM ceiling
+# (ops/pallas/_tiers.VMEM_LIMIT_BYTES): every (k_pad, d_pad) inside
+# these bounds compiles at all three tiers, walk and grid kernel alike
+# (tests/test_tpu_compile.py holds the edge); just outside, k=16384 at
+# d=256 needs 185 MB of the core's 128 and k=d=2048 at "high" 124.
+PALLAS_MAX_KD = 1 << 21
+PALLAS_MAX_K = 4096
+PALLAS_MAX_D = 4096
+
+
 def pallas_preferred(d: int, k: int, precision: str) -> bool:
-    """Shape/tier rule for kmeans_kernel="auto" (BASELINE.md kernel table,
-    measured on v5e): the fused Pallas kernel wins the profiled shapes at
-    the f32-accurate tiers (its loop-mode half-score assignment + exact
-    -split sums pay 1+2 bf16 passes where XLA "high" pays 3+3, "highest"
-    6+6) with one known exception — small n*k at "high" (64k x 64, k=64:
-    XLA 0.08 vs Pallas 0.19 ms/iter), accepted as a ~0.1 ms/iter auto-rule
-    miss in BASELINE.md rather than special-cased here.
+    """Shape/tier rule for kmeans_kernel="auto": the fused Pallas kernel
+    at every tier whose resident blocks fit VMEM.  Its loop-mode
+    half-score assignment + exact-split sums pay 1+2 bf16 passes where
+    XLA "high" pays 3+3 and "highest" 6+6; "default" (= the bf16 compute
+    policy via precision.kernel_tier) prices ON Pallas too since the
+    counts run as bf16 matmuls (kmeans_kernel._tile_update) —
+    dev/profile_kernels.py's fused-vs-unfused sweep regenerates the
+    evidence per backend.
 
-    "default" (= the bf16 compute policy via precision.kernel_tier) now
-    prices ON Pallas too — the ISSUE 9 workaround retirement: the old
-    rule routed it to XLA's all-bf16 single-pass pipeline, measured
-    faster when the kernel's counts still ran as two f32 VPU passes over
-    (bn, k); with the counts-as-bf16-matmul rework (see
-    kmeans_kernel._make_kernel) the fused kernel's halved HBM traffic
-    carries the tier, and dev/profile_kernels.py's fused-vs-unfused
-    sweep regenerates the evidence per backend.
-
-    Large k is excluded: the kernel holds the full (k, d) centers AND sums
-    blocks in VMEM, so past ~4M padded elements apiece (2 x 16 MB f32)
-    Mosaic would fail to place them — those fits stay on the chunked XLA
-    path."""
+    Large k·d is excluded (bounds above): those fits stay on the chunked
+    XLA path."""
     k_pad = -(-k // 128) * 128
     d_pad = -(-d // 128) * 128
-    if k_pad * d_pad > (1 << 22):  # 16 MB per f32 VMEM block
+    if (k_pad * d_pad > PALLAS_MAX_KD or k_pad > PALLAS_MAX_K
+            or d_pad > PALLAS_MAX_D):
         return False
     return precision in ("highest", "high", "default")
 
@@ -170,9 +174,43 @@ def pairwise_sq_dists(
     return jnp.maximum(d2, 0.0)
 
 
+def argmin_rows(score: jax.Array, row_min=None, exact: bool = True):
+    """``jnp.argmin(score, axis=1)`` — lowest index on ties, first NaN
+    wins — as two plain reductions: the row minimum, then the first
+    column that attains it.  ``row_min`` takes a ``jnp.min(score,
+    axis=1)`` the caller already has.
+
+    Why not ``jnp.argmin``: XLA:TPU types the value output of its
+    (value, index) reduction ``bf16[rows]`` (the compiled text shows it,
+    tests/test_tpu_compile.py::TestAssignment), and on a v5e candidates
+    within bfloat16's step of each other then tie and the lower id wins
+    whatever the f32 distances say.  Here the minimum is an f32
+    reduction with an f32 consumer, and the index reduction is over
+    integers.  ``exact=False`` is ``jnp.argmin`` itself, for a sheet
+    that is not f32-exact to begin with (:func:`_exact_assign`)."""
+    if not exact:
+        return jnp.argmin(score, axis=1)
+    m = jnp.min(score, axis=1) if row_min is None else row_min
+    k = score.shape[1]
+    ids = lax.broadcasted_iota(jnp.int32, score.shape, 1)
+    hit = (score <= m[:, None]) | (score != score)
+    first = jnp.min(jnp.where(hit, ids, k - 1), axis=1)
+    return first.astype(jax.dtypes.canonicalize_dtype(np.int64))
+
+
+def _exact_assign(assign_prec: str, policy: str) -> bool:
+    """Whether a Lloyd assignment sheet is f32-exact and so gets the f32
+    comparison of :func:`argmin_rows`: the strict-parity tier only.  The
+    fast tiers' sheet comes from a bf16 (or bf16_3x) matmul and keeps
+    ``jnp.argmin`` — the second pass over the sheet cost the XLA
+    accumulate 7% at ``highest`` and 76% at ``high`` on a v5e (1M x 256,
+    k=1000; PERF.md, PR 21), and those tiers exist for their speed."""
+    return policy == "f32" and assign_prec == "highest"
+
+
 def assign_clusters(x: jax.Array, centers: jax.Array) -> jax.Array:
     """(n,) argmin cluster ids."""
-    return jnp.argmin(pairwise_sq_dists(x, centers), axis=1)
+    return argmin_rows(pairwise_sq_dists(x, centers))
 
 
 def _accumulate(x, weights, centers, precision: str = "highest",
@@ -196,18 +234,20 @@ def _accumulate(x, weights, centers, precision: str = "highest",
     with the pre-policy code.
     """
     k = centers.shape[0]
+    aprec = _assign_prec(precision)
+    exact = _exact_assign(aprec, policy)
     if need_cost:
-        d2 = pairwise_sq_dists(
-            x, centers, _assign_prec(precision), policy
-        )  # (n, k)
-        assign = jnp.argmin(d2, axis=1)  # (n,)
+        d2 = pairwise_sq_dists(x, centers, aprec, policy)  # (n, k)
         min_d2 = jnp.min(d2, axis=1)  # (n,)
+        assign = argmin_rows(d2, min_d2, exact)  # (n,)
         cost = jnp.sum(min_d2 * weights)
     else:
         cf = psn.upcast(centers)
         c_sq = jnp.sum(cf * cf, axis=1)  # (k,)
-        cross = psn.pdot(x, centers.T, policy, _assign_prec(precision))
-        assign = jnp.argmin(0.5 * c_sq[None, :] - cross, axis=1)  # (n,)
+        cross = psn.pdot(x, centers.T, policy, aprec)
+        assign = argmin_rows(
+            0.5 * c_sq[None, :] - cross, exact=exact
+        )  # (n,)
         cost = jnp.asarray(0.0, weights.dtype)
     one_hot = (
         jax.nn.one_hot(assign, k, dtype=weights.dtype)
@@ -458,6 +498,7 @@ def _build_lloyd_model_sharded(mesh, dax: str, max_: str, max_iter: int,
 
     def accum(x_blk, w_blk, c_blk, aprec, sprec, pol, need_cost):
         k = c_blk.shape[0]
+        exact = _exact_assign(aprec, pol)
         cf = psn.upcast(c_blk)
         c_sq = jnp.sum(cf * cf, axis=1)  # (k,)
         cross = psn.pdot(x_blk, c_blk.T, pol, aprec)  # <- MXU
@@ -467,13 +508,13 @@ def _build_lloyd_model_sharded(mesh, dax: str, max_: str, max_iter: int,
             # one psum carries all three feature-block partials at once
             d2 = collective.psum(x_sq + c_sq[None, :] - 2.0 * cross, max_)
             d2 = jnp.maximum(d2, 0.0)
-            assign = jnp.argmin(d2, axis=1)
             min_d2 = jnp.min(d2, axis=1)
+            assign = argmin_rows(d2, min_d2, exact)
         else:
             # loop-body mode: rank on the half-score (argmin-invariant to
             # |x|^2); still ONE psum over the model axis, no d2/min passes
             score = collective.psum(0.5 * c_sq[None, :] - cross, max_)
-            assign = jnp.argmin(score, axis=1)
+            assign = argmin_rows(score, exact=exact)
         one_hot = (
             jax.nn.one_hot(assign, k, dtype=w_blk.dtype) * w_blk[:, None]
         )
@@ -740,7 +781,7 @@ def _pll_round(x, w, dmin, amin, base_id, key, l, cap, chunk):
         d2 = pairwise_sq_dists(x, s)
         d2 = jnp.where(v[None, :] > 0, d2, jnp.inf)
         cm = jnp.min(d2, axis=1)
-        ca = jnp.argmin(d2, axis=1).astype(jnp.int32) + b
+        ca = argmin_rows(d2, cm).astype(jnp.int32) + b
         better = cm < dm
         return (jnp.where(better, cm, dm), jnp.where(better, ca, am)), None
 

@@ -37,6 +37,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from oap_mllib_tpu.ops.pallas._tiers import LANE
+
 DEPTHS = (2, 3, 4)  # supported rotation depths (1 means "use the grid kernel")
 
 
@@ -47,6 +49,42 @@ def check_depth(depth: int) -> int:
             f"rotation depth must be one of {DEPTHS}, got {depth!r}"
         )
     return depth
+
+
+def check_tile_rows(tile_rows: int) -> int:
+    """Row-tile extent of a walk that carries a per-row column (weights,
+    mask): the column travels lane-dense (:func:`lane_dense`), so the
+    tile must be whole 128-row lane groups."""
+    tile_rows = int(tile_rows)
+    if tile_rows < LANE or tile_rows % LANE:
+        raise ValueError(
+            f"walk tile_rows must be a positive multiple of {LANE}, got "
+            f"{tile_rows!r}"
+        )
+    return tile_rows
+
+
+def lane_dense(col, tile_rows: int):
+    """Per-row column ``(n, 1)`` -> ``(n // tile_rows, tile_rows // 128,
+    128)``: row ``i`` of tile ``t`` sits at ``[t, i // 128, i % 128]``.
+    Mosaic cannot slice a ``(tile_rows, 1)`` window out of an ``(n, 1)``
+    HBM operand (the minor dim is tiled to 128 lanes), and a lane-padded
+    column would stream 128x its bytes; this layout DMAs one whole
+    ``(tile_rows // 128, 128)`` slab per tile by leading index."""
+    return col.reshape(-1, tile_rows // LANE, LANE)
+
+
+def column(dense):
+    """In-kernel inverse of :func:`lane_dense` for one resident tile:
+    ``(tile_rows // 128, 128)`` -> the ``(tile_rows, 1)`` column the tile
+    bodies broadcast against.  Pure data movement (one small transpose,
+    static lane picks, a sublane concat), so every value — and therefore
+    every downstream bit — equals the column the grid kernels and the
+    XLA twins see."""
+    t = dense.T  # (128, groups)
+    return jnp.concatenate(
+        [t[:, g : g + 1] for g in range(dense.shape[0])], axis=0
+    )
 
 
 def rotation_scratch(depth: int, tile_shapes):
@@ -67,7 +105,9 @@ def tile_walk(inputs, bufs, sems, tile, num_tiles, depth, body, axes=None):
     ``inputs`` are HBM (``ANY``) refs, ``bufs``/``sems`` the matching
     rotation scratch from :func:`rotation_scratch`, ``tile`` the static
     tile extent along each input's walk axis (``axes``, default 0 —
-    the ALS solve walks axis 1), ``num_tiles`` the static tile count.
+    the ALS solve walks axis 1; ``None`` marks a pre-tiled operand such
+    as a :func:`lane_dense` column, indexed whole by its leading axis),
+    ``num_tiles`` the static tile count.
     ``body(t, views)`` receives the tile index and the resident
     ``(tile, ...)`` views; it mutates the kernel's accumulator refs.
 
@@ -78,7 +118,9 @@ def tile_walk(inputs, bufs, sems, tile, num_tiles, depth, body, axes=None):
         axes = (0,) * len(inputs)
 
     def _dma(ref, buf, sem, ax, slot, t):
-        if ax == 0:
+        if ax is None:
+            src = ref.at[t]
+        elif ax == 0:
             src = ref.at[pl.ds(t * tile, tile)]
         else:
             src = ref.at[:, pl.ds(t * tile, tile)]

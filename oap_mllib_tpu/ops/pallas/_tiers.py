@@ -40,6 +40,30 @@ MODES = ("highest", "high", "default")
 MODE_ALIASES = {"f32": "highest", "tf32": "high", "bf16": "default"}
 
 LANE = 128  # TPU minor-axis tile (f32 lane multiple)
+SUBLANE = 8  # f32 second-minor tile: DMA windows come in whole eights
+
+# Scoped-VMEM ceiling handed to Mosaic for the kernels whose resident
+# blocks grow with the problem (K-Means centers+sums, the PCA Gram).  The
+# compiler's default is 16 MiB of the v5e core's 128 MiB; the (k, d) and
+# (d, d) accumulators plus their matmul temporaries pass that from
+# k=2048, d=256 and d=2048 on.  The cap is not an allocation — a kernel
+# is given what it uses — and it is what the dispatch bounds
+# (kmeans_ops.pallas_preferred, pca_kernel.pallas_gram_preferred) were
+# fitted against: tests/test_tpu_compile.py compiles their edges.
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+
+
+def compiled_kwargs(interpret: bool, **params):
+    """The ``compiler_params`` keyword of a compiled ``pallas_call``
+    launch; nothing under the interpreter, which takes none.  The
+    kernels whose resident blocks grow with the problem pass
+    ``vmem_limit_bytes=VMEM_LIMIT_BYTES``; the ALS walks, whose blocks
+    are fixed by rank and batch, stay inside Mosaic's default."""
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(**params)}
 
 
 def check_mode(mode: str) -> str:

@@ -46,7 +46,9 @@ from jax.experimental.pallas import tpu as pltpu
 from oap_mllib_tpu.ops.pallas import _dbuf
 from oap_mllib_tpu.ops.pallas._tiers import (
     LANE,
+    SUBLANE,
     check_mode,
+    compiled_kwargs,
     kernel_launch,
     note_emitted,
     pad_to,
@@ -180,15 +182,10 @@ def _pallas_solve_dbuf(m_t, gram, reg, r, use_gram, interpret, batch,
                        depth):
     w_rows, n = m_t.shape
     num_tiles = n // batch
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            has_side_effects=True
-        )
     return pl.pallas_call(
         _make_dbuf_solve_kernel(r, use_gram, batch, depth, num_tiles),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
@@ -196,7 +193,7 @@ def _pallas_solve_dbuf(m_t, gram, reg, r, use_gram, interpret, batch,
         out_shape=jax.ShapeDtypeStruct((r, n), jnp.float32),
         scratch_shapes=_dbuf.rotation_scratch(depth, [(w_rows, batch)]),
         interpret=interpret,
-        **kwargs,
+        **compiled_kwargs(interpret, has_side_effects=True),
     )(m_t, gram, reg)
 
 
@@ -258,7 +255,14 @@ def solve_traced(a, b, n_reg, reg, gram=None, interpret=False, batch=None,
         ],
         axis=1,
     )
-    m_t = jnp.zeros((r * r + r + 1, n_pad), jnp.float32).at[:, :n].set(m.T)
+    # sheet rows pad to the f32 sublane tile: the column walk DMAs
+    # whole-height (rows, batch) windows, and Mosaic refuses a window
+    # whose row extent (111 at r=10) is not a multiple of 8; the zero
+    # rows sit past every offset _solve_tile reads
+    w_rows = r * r + r + 1
+    m_t = jnp.zeros((pad_to(w_rows, SUBLANE), n_pad), jnp.float32).at[
+        :w_rows, :n
+    ].set(m.T)
     use_gram = gram is not None
     g = (
         gram.astype(jnp.float32)
@@ -365,19 +369,14 @@ def _make_dbuf_gram_kernel(mode, tile_rows, depth, num_tiles):
 def _pallas_factor_gram_dbuf(f_p, mode, interpret, tile_rows, depth):
     n, r_pad = f_p.shape
     num_tiles = n // tile_rows
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            has_side_effects=True
-        )
     return pl.pallas_call(
         _make_dbuf_gram_kernel(mode, tile_rows, depth, num_tiles),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((r_pad, r_pad), jnp.float32),
         scratch_shapes=_dbuf.rotation_scratch(depth, [(tile_rows, r_pad)]),
         interpret=interpret,
-        **kwargs,
+        **compiled_kwargs(interpret, has_side_effects=True),
     )(f_p)
 
 

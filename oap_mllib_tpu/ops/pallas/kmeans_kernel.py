@@ -45,7 +45,9 @@ from jax.experimental.pallas import tpu as pltpu
 from oap_mllib_tpu.ops.pallas import _dbuf
 from oap_mllib_tpu.ops.pallas._tiers import (
     LANE,
+    VMEM_LIMIT_BYTES,
     check_mode,
+    compiled_kwargs,
     dot_bf16,
     dot_f32,
     kernel_launch,
@@ -188,6 +190,7 @@ def _pallas_accumulate(x, w, centers, mode="highest", interpret=False,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        **compiled_kwargs(interpret, vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(x, w, centers)
     return sums, counts, cost
 
@@ -210,7 +213,7 @@ def _make_dbuf_kernel(mode, need_cost, tile_rows, depth, num_tiles):
         def body(t, views):
             x, w = views
             sums_inc, counts_inc, cost_inc = _tile_update(
-                x, w, c, mode, need_cost
+                x, _dbuf.column(w), c, mode, need_cost
             )
             sums_ref[:] += sums_inc
             counts_ref[:] += counts_inc
@@ -219,7 +222,7 @@ def _make_dbuf_kernel(mode, need_cost, tile_rows, depth, num_tiles):
 
         _dbuf.tile_walk(
             [x_hbm, w_hbm], [xbuf, wbuf], [xsem, wsem],
-            tile_rows, num_tiles, depth, body,
+            tile_rows, num_tiles, depth, body, axes=(0, None),
         )
 
     return _kernel
@@ -228,20 +231,18 @@ def _make_dbuf_kernel(mode, need_cost, tile_rows, depth, num_tiles):
 def _pallas_accumulate_dbuf(x, w, centers, mode, interpret, need_cost,
                             tile_rows, depth):
     """Raw double-buffered pallas_call on pre-padded operands (rows a
-    multiple of ``tile_rows``)."""
+    multiple of ``tile_rows``).  The weight column rides lane-dense
+    (``_dbuf.lane_dense``): Mosaic refuses a ``(tile_rows, 1)`` DMA
+    window on an ``(n, 1)`` HBM operand."""
+    _dbuf.check_tile_rows(tile_rows)
     n, d = x.shape
     k = centers.shape[0]
     num_tiles = n // tile_rows
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            has_side_effects=True
-        )
     sums, counts, cost = pl.pallas_call(
         _make_dbuf_kernel(mode, need_cost, tile_rows, depth, num_tiles),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=[
@@ -255,11 +256,14 @@ def _pallas_accumulate_dbuf(x, w, centers, mode, interpret, need_cost,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         scratch_shapes=_dbuf.rotation_scratch(
-            depth, [(tile_rows, d), (tile_rows, 1)]
+            depth, [(tile_rows, d), (tile_rows // LANE, LANE)]
         ),
         interpret=interpret,
-        **kwargs,
-    )(x, w, centers)
+        **compiled_kwargs(
+            interpret, vmem_limit_bytes=VMEM_LIMIT_BYTES,
+            has_side_effects=True,
+        ),
+    )(x, _dbuf.lane_dense(w, tile_rows), centers)
     return sums, counts, cost
 
 

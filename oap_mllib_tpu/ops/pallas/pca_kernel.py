@@ -45,7 +45,9 @@ from jax.experimental.pallas import tpu as pltpu
 from oap_mllib_tpu.ops.pallas import _dbuf
 from oap_mllib_tpu.ops.pallas._tiers import (
     LANE,
+    VMEM_LIMIT_BYTES,
     check_mode,
+    compiled_kwargs,
     kernel_launch,
     pad_to,
     tiered_dot,
@@ -119,6 +121,7 @@ def _pallas_moments(x, m, mean, mode, interpret, need_gram,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        **compiled_kwargs(interpret, vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(x, m, mean)
     return gram, colsum, count
 
@@ -137,7 +140,7 @@ def _make_dbuf_kernel(mode, need_gram, tile_rows, depth, num_tiles):
         def body(t, views):
             x, m = views
             gram_inc, colsum_inc, count_inc = _tile_moments(
-                x, m, mean, mode, need_gram
+                x, _dbuf.column(m), mean, mode, need_gram
             )
             colsum_ref[:] += colsum_inc
             count_ref[0, 0] += count_inc
@@ -146,7 +149,7 @@ def _make_dbuf_kernel(mode, need_gram, tile_rows, depth, num_tiles):
 
         _dbuf.tile_walk(
             [x_hbm, m_hbm], [xbuf, mbuf], [xsem, msem],
-            tile_rows, num_tiles, depth, body,
+            tile_rows, num_tiles, depth, body, axes=(0, None),
         )
 
     return _kernel
@@ -154,18 +157,17 @@ def _make_dbuf_kernel(mode, need_gram, tile_rows, depth, num_tiles):
 
 def _pallas_moments_dbuf(x, m, mean, mode, interpret, need_gram,
                          tile_rows, depth):
+    """Raw double-buffered pallas_call on pre-padded operands; the mask
+    column rides lane-dense (``_dbuf.lane_dense`` — see the K-Means
+    walk)."""
+    _dbuf.check_tile_rows(tile_rows)
     n, d = x.shape
     num_tiles = n // tile_rows
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            has_side_effects=True
-        )
     gram, colsum, count = pl.pallas_call(
         _make_dbuf_kernel(mode, need_gram, tile_rows, depth, num_tiles),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=[
@@ -179,11 +181,14 @@ def _pallas_moments_dbuf(x, m, mean, mode, interpret, need_gram,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         scratch_shapes=_dbuf.rotation_scratch(
-            depth, [(tile_rows, d), (tile_rows, 1)]
+            depth, [(tile_rows, d), (tile_rows // LANE, LANE)]
         ),
         interpret=interpret,
-        **kwargs,
-    )(x, m, mean)
+        **compiled_kwargs(
+            interpret, vmem_limit_bytes=VMEM_LIMIT_BYTES,
+            has_side_effects=True,
+        ),
+    )(x, _dbuf.lane_dense(m, tile_rows), mean)
     return gram, colsum, count
 
 
@@ -370,12 +375,15 @@ def covariance_pallas(
 
 def pallas_gram_preferred(d: int, precision: str) -> bool:
     """Shape/tier rule for pca_kernel="auto": the fused kernel holds the
-    full (d, d) Gram block in VMEM, so past ~4M padded elements (16 MB
-    f32) Mosaic cannot place it — those fits stay on the XLA pass.  All
-    three tiers qualify (the kernel ships the same hand-rolled hi/lo
-    split tiers as the K-Means kernel, so the bf16 policy prices ON
-    Pallas — the ISSUE 9 workaround retirement)."""
+    full (d, d) Gram block in VMEM next to its matmul temporaries, so it
+    runs up to d_pad = 2048 (a 16 MB Gram; the "high" tier's three split
+    passes bring the kernel to 94 MB under the plane's scoped-VMEM
+    ceiling, ops/pallas/_tiers.VMEM_LIMIT_BYTES —
+    tests/test_tpu_compile.py holds this edge) and wider fits stay on
+    the XLA pass.  All three tiers qualify (the kernel ships the same
+    hand-rolled hi/lo split tiers as the K-Means kernel, so the bf16
+    policy prices ON Pallas)."""
     d_pad = pad_to(d, LANE)
-    if d_pad * d_pad > (1 << 22):  # 16 MB per f32 VMEM block
+    if d_pad * d_pad > (1 << 22):
         return False
     return precision in ("highest", "high", "default")

@@ -46,7 +46,13 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from oap_mllib_tpu.ops.pallas._tiers import LANE, note_emitted, pad_to
+from oap_mllib_tpu.ops.pallas._tiers import (
+    LANE,
+    SUBLANE,
+    compiled_kwargs,
+    note_emitted,
+    pad_to,
+)
 from oap_mllib_tpu.parallel import collective
 
 
@@ -108,6 +114,9 @@ def _make_ring_kernel(axis_name: str, world: int, seg: int, cols: int):
     def _kernel(x_ref, out_ref, comm, send_sem, recv_sem, copy_sem):
         # x_ref/out_ref live in ANY (HBM); comm is the (2 dirs, 2 slots,
         # seg, half) VMEM rotation buffer; semaphores index [dir, slot].
+        # neighbours are named by their coordinate on the ring's own
+        # mesh axis; every other axis keeps this device's coordinate, so
+        # a (data, model) mesh runs one ring per model column
         me = lax.axis_index(axis_name)
         right = lax.rem(me + 1, world)
         left = lax.rem(me + world - 1, world)
@@ -122,8 +131,8 @@ def _make_ring_kernel(axis_name: str, world: int, seg: int, cols: int):
         barrier = pltpu.get_barrier_semaphore()
         for nb in (left, right):
             pltpu.semaphore_signal(
-                barrier, inc=1, device_id=(nb,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                barrier, inc=1, device_id={axis_name: nb},
+                device_id_type=pltpu.DeviceIdType.MESH,
             )
         pltpu.semaphore_wait(barrier, 2)
 
@@ -171,16 +180,16 @@ def _make_ring_kernel(axis_name: str, world: int, seg: int, cols: int):
                 dst_ref=comm.at[0, 1],
                 send_sem=send_sem.at[0],
                 recv_sem=recv_sem.at[0],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id={axis_name: right},
+                device_id_type=pltpu.DeviceIdType.MESH,
             )
             rdma_ccw = pltpu.make_async_remote_copy(
                 src_ref=comm.at[1, 0],
                 dst_ref=comm.at[1, 1],
                 send_sem=send_sem.at[1],
                 recv_sem=recv_sem.at[1],
-                device_id=(left,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id={axis_name: left},
+                device_id_type=pltpu.DeviceIdType.MESH,
             )
             rdma_cw.start()
             rdma_ccw.start()
@@ -193,8 +202,8 @@ def _make_ring_kernel(axis_name: str, world: int, seg: int, cols: int):
             # overlap win is within a step, across the two directions)
             for nb in (left, right):
                 pltpu.semaphore_signal(
-                    barrier, inc=1, device_id=(nb,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
+                    barrier, inc=1, device_id={axis_name: nb},
+                    device_id_type=pltpu.DeviceIdType.MESH,
                 )
             pltpu.semaphore_wait(barrier, 2)
 
@@ -223,8 +232,8 @@ def _ring_pallas(x, axis_name: str, world: int):
     cols = x.shape[1]
     return pl.pallas_call(
         _make_ring_kernel(axis_name, world, seg, cols),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((2, 2, seg, cols // 2), jnp.float32),
@@ -232,9 +241,8 @@ def _ring_pallas(x, axis_name: str, world: int):
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            collective_id=7, has_side_effects=True,
-        ),
+        # no interpreter branch: off the TPU the ppermute twin runs
+        **compiled_kwargs(False, collective_id=7, has_side_effects=True),
     )(x)
 
 
@@ -263,13 +271,17 @@ def ring_allreduce(x, axis_name: str, world: int, interpret: bool = False,
     unchanged; only the rotation's starting owner moves, so results
     stay within the ring parity envelope (<= 1e-5) and the trace-time
     census is unchanged (one ``ring.allreduce`` per call, zero
-    standalone psums)."""
+    standalone psums).  Rows pad to ``8 * world * segments``."""
     note_emitted("ring.allreduce")
     if world < 2:
         return collective.psum(x, axis_name)
     segments = max(1, int(segments))
     rows, cols = x.shape
-    rows_pad = pad_to(max(rows, world * segments), world * segments)
+    # every ring segment a whole number of f32 sublane tiles (Mosaic
+    # refuses a DMA window of 500 rows), on BOTH paths so the segment
+    # boundaries — and with them each row's addition order — agree
+    seg_mult = SUBLANE * world * segments
+    rows_pad = pad_to(max(rows, seg_mult), seg_mult)
     use_pallas = jax.default_backend() == "tpu" and not interpret
     # even lane-multiple columns on BOTH paths so the bi-directional
     # halves split at the same column — cross-backend bit identity

@@ -291,8 +291,7 @@ def topk_eigh_randomized(
     iteration (Halko/Martinsson/Tropp) — the large-d fast path behind
     ``Config.pca_solver="randomized"``.
 
-    Round-4 kernel attribution showed eigh owns 66% of the large-d PCA
-    wall (BASELINE.md row 5: 125 ms of 189 at d=2048) while k is
+    At large d the O(d^3) eigh owns most of the PCA wall while k is
     typically tens; subspace iteration replaces the O(d^3)
     factorization with (2*iters + 2) MXU matmuls of (d, d) x (d, p),
     p = k + oversample, plus a (d, p) QR per iteration and one tiny

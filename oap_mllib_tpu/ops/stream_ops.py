@@ -694,7 +694,7 @@ def _chunk_min_d2(chunk, dmin, cands, precision="highest"):
 def _chunk_ownership(chunk, w, cands):
     """(n_cand,) row weight owned by each candidate (segment-sum)."""
     d2 = kmeans_ops.pairwise_sq_dists(chunk, cands)
-    owner = jnp.argmin(d2, axis=1)
+    owner = kmeans_ops.argmin_rows(d2)
     return jnp.zeros((cands.shape[0],), w.dtype).at[owner].add(w)
 
 

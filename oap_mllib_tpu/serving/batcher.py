@@ -165,7 +165,7 @@ def _build_assign(tier: str, policy: str):
 
     def kernel(xb, centers):
         d2 = kmeans_ops.pairwise_sq_dists(xb, centers, tier, policy)
-        return jnp.argmin(d2, axis=1).astype(jnp.int32)
+        return kmeans_ops.argmin_rows(d2).astype(jnp.int32)
 
     return jax.jit(kernel, donate_argnums=_donate_args())
 

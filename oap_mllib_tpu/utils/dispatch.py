@@ -71,10 +71,9 @@ def should_accelerate(algo: str, guard_ok: bool, reason: str = "") -> bool:
     a Config.tuning typo raises HERE, at fit entry, not deep inside a
     kernel launch)."""
     cfg = get_config()
-    if cfg.compilation_cache_dir:
-        from oap_mllib_tpu.utils.progcache import ensure_persistent_cache
+    from oap_mllib_tpu.utils.progcache import ensure_persistent_cache
 
-        ensure_persistent_cache(cfg.compilation_cache_dir)
+    ensure_persistent_cache(cfg.compilation_cache_dir)
     from oap_mllib_tpu.ops.pallas.autotune import parse_mode
 
     parse_mode(cfg.tuning)

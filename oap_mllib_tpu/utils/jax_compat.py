@@ -1,11 +1,8 @@
-"""Version tolerance for the jax APIs the framework leans on.
+"""The single import point for ``shard_map``.
 
-The sharded programs target the stable ``jax.shard_map`` entry point
-(newer jax lines); older installed lines only ship
-``jax.experimental.shard_map.shard_map`` and spell the replication
-checker ``check_rep`` instead of ``check_vma``.  One shim keeps every
-call site on the new spelling so the package runs on both without a
-version pin (the container's jax is whatever the image baked in).
+Every sharded program in the package builds through this name, so the
+SPMD dataflow rules of ``dev/oaplint`` have one spelling to resolve and
+a future change of entry point touches one file.
 """
 
 from __future__ import annotations
@@ -14,25 +11,8 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` where available, else the experimental entry
-    with ``check_vma`` mapped onto its ``check_rep`` spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with this package's keyword-only call shape."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )
-
-
-def set_cpu_device_count(n: int) -> None:
-    """Ask for ``n`` virtual CPU devices: the config option on jax lines
-    that have it, the XLA_FLAGS env (must be set before the backend
-    initializes) otherwise.  Callers set the env var themselves before
-    importing jax; this only applies the config-option half."""
-    if hasattr(jax.config, "jax_num_cpu_devices"):
-        jax.config.update("jax_num_cpu_devices", n)

@@ -7,7 +7,7 @@ heuristics: an ndarray always ran the fully-resident in-memory path
 (however large), a ChunkSource always streamed (however small), and the
 ALS streamed entry silently MATERIALIZED its source back to in-memory
 layouts on exactly the long-tail degree distributions most likely to
-need streaming — the standing round-5 VERDICT criticism.  The map-reduce
+need streaming.  The map-reduce
 primitive decomposition (DrJAX, arXiv:2403.07128) and the simplified-
 MapReduce K-Means architecture (arXiv:1610.05601) both argue the
 streamed pass is a first-class representation, not a fallback: route

@@ -4,8 +4,7 @@ with f32 accumulation.
 The matmul-dominated hot paths (the K-Means Lloyd cross-distances, the
 PCA Gram/colsum, the ALS normal-equation moments) all ran at full
 f32/``matmul_precision`` while the TPU's native bf16 MXU throughput
-(~2x FLOPs, half the HBM bytes per operand) sat idle — BENCH_r05 pins
-the Pallas K-Means kernel at MFU 0.333 with ``precision: "high"``.  The
+(~2x FLOPs, half the HBM bytes per operand) sat idle.  The
 linear-algebraic formulation of these kernels (cf. arXiv:2601.17136's
 communication-avoiding kernel K-Means) is exactly the shape where
 reduced-precision INPUTS with f32 ACCUMULATION is a bounded-error win,

@@ -296,31 +296,28 @@ def _install_xla_listener() -> None:
     global _xla_listener_installed
     if _xla_listener_installed:
         return
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        def _on_event(event, duration_secs, **kwargs):
-            if event == _BACKEND_COMPILE_EVENT:
-                with _XLA_EVENTS_LOCK:
-                    _XLA_EVENTS["count"] += 1
-                    _XLA_EVENTS["secs"] += float(duration_secs)
-                _tm.counter(
-                    "oap_xla_compiles_total",
-                    help="Real XLA backend compiles (jax monitoring event)",
-                ).inc()
-                _tm.counter(
-                    "oap_xla_compile_seconds_total",
-                    help="Wall spent in XLA backend compilation",
-                ).inc(float(duration_secs))
-                _tm.histogram(
-                    "oap_xla_compile_seconds",
-                    help="Per-program XLA backend compile wall",
-                ).observe(float(duration_secs))
+    def _on_event(event, duration_secs, **kwargs):
+        if event == _BACKEND_COMPILE_EVENT:
+            with _XLA_EVENTS_LOCK:
+                _XLA_EVENTS["count"] += 1
+                _XLA_EVENTS["secs"] += float(duration_secs)
+            _tm.counter(
+                "oap_xla_compiles_total",
+                help="Real XLA backend compiles (jax monitoring event)",
+            ).inc()
+            _tm.counter(
+                "oap_xla_compile_seconds_total",
+                help="Wall spent in XLA backend compilation",
+            ).inc(float(duration_secs))
+            _tm.histogram(
+                "oap_xla_compile_seconds",
+                help="Per-program XLA backend compile wall",
+            ).observe(float(duration_secs))
 
-        monitoring.register_event_duration_secs_listener(_on_event)
-        _xla_listener_installed = True
-    except Exception:  # monitoring API absent on this jax: counter stays 0
-        pass
+    monitoring.register_event_duration_secs_listener(_on_event)
+    _xla_listener_installed = True
 
 
 def xla_compile_count() -> int:
@@ -351,6 +348,8 @@ _install_xla_listener()
 
 _persist_applied: Optional[str] = None
 
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
 
 def ensure_persistent_cache(cache_dir: str) -> None:
     """Wire ``Config.compilation_cache_dir`` into jax's persistent
@@ -360,31 +359,56 @@ def ensure_persistent_cache(cache_dir: str) -> None:
     backend compilation entirely, which is the cross-process half of
     compile amortization (shape bucketing is the within-process half).
 
-    The min-size/min-time thresholds are zeroed so the small per-chunk
-    streamed programs persist too — jax's defaults only persist
-    programs that took >1s to compile, which would exclude most of this
-    framework's kernels on a warm CPU tier."""
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, the process already has
+    its cache and jax's own handling of that variable stands: this
+    function then sets no directory, and a ``Config`` value naming a
+    different one is an error, not an override (the path is part of the
+    cache key, so a second directory would never hit the first).
+
+    The min-time threshold is zeroed so the small per-chunk streamed
+    programs persist too — jax's default only persists programs that
+    took >1s to compile, which would exclude most of this framework's
+    kernels on a warm CPU tier."""
     global _persist_applied
     if not cache_dir or _persist_applied == cache_dir:
         return
+    import os
+
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for opt, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(opt, val)
-        except Exception:  # older jax lines lack the knob; dir alone works
-            pass
-    # jax pins its cache object to the first dir it initialized with;
-    # drop it so the (possibly changed) dir takes effect — it re-creates
-    # lazily on the next compile
-    try:
-        from jax._src import compilation_cache as _cc
+    env_dir = os.environ.get(CACHE_ENV, "")
+    if env_dir and os.path.abspath(cache_dir) != os.path.abspath(env_dir):
+        raise ValueError(
+            f"Config.compilation_cache_dir={cache_dir!r} differs from "
+            f"{CACHE_ENV}={env_dir!r}: the environment owns the compile "
+            "cache of this process; unset one of the two"
+        )
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if not env_dir:
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _cc,
+        )
 
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # jax pins its cache object to the first dir it initialized
+        # with; drop it so the (possibly changed) dir takes effect — it
+        # re-creates lazily on the next compile
         _cc.reset_cache()
-    except Exception:
-        pass
     _persist_applied = cache_dir
+
+
+def use_checkout_cache(fixed_dir: str) -> str:
+    """Launch scripts (``chip_smoke.py``, ``bench.py``): the compile
+    cache lives where ``JAX_COMPILATION_CACHE_DIR`` says and, only where
+    that is unset, at ``fixed_dir`` — one fixed path inside the checkout
+    (the path is part of the cache key: a directory that moves never
+    hits).  Returns the directory in effect."""
+    import os
+
+    from oap_mllib_tpu.config import set_config
+
+    cache_dir = os.environ.get(CACHE_ENV, "") or os.path.abspath(fixed_dir)
+    set_config(compilation_cache_dir=cache_dir)
+    ensure_persistent_cache(cache_dir)
+    return cache_dir

@@ -317,7 +317,11 @@ def guarded_dispatch(op: str, axis: str, fn):
     if cfg.collective_timeout == 0 or _world() <= 1:
         if cfg.collective_timeout:  # validate only when armed at all
             collective_timeout_cfg(cfg)
-        return fn()
+        try:
+            return fn()
+        except Exception as e:  # noqa: BLE001 — re-raised unless a peer died
+            _raise_if_peer_lost(e, op, axis, 0.0)
+            raise
     timeout = collective_timeout_cfg(cfg)
     crash_dir = cfg.crash_dir
     my_rank = _rank()
@@ -343,11 +347,7 @@ def guarded_dispatch(op: str, axis: str, fn):
         if crash_dir:
             peer = check_poison(crash_dir, my_rank)
             if peer is not None:
-                _tm.counter(
-                    "oap_recovery_peer_aborts_total",
-                    help="Dispatches aborted because a peer's crash "
-                         "record appeared in the sideband",
-                ).inc()
+                _count_peer_abort()
                 write_crash_record(
                     "collective.dispatch", FAULT_PEER_ABORT,
                     f"peer rank {peer.get('rank')} aborted: "
@@ -393,6 +393,69 @@ def guarded_dispatch(op: str, axis: str, fn):
                 op=op, axis=axis, elapsed_s=elapsed, last_completed=last,
             )
     if box["exc"] is not None:
+        _raise_if_peer_lost(box["exc"], op, axis, time.monotonic() - t0)
         raise box["exc"]
     _note_completed(f"{op}|{axis}")
     return box["out"]
+
+
+def _count_peer_abort() -> None:
+    _tm.counter(
+        "oap_recovery_peer_aborts_total",
+        help="Dispatches aborted because a peer's crash record appeared "
+             "in the sideband or the transport reported the peer gone",
+    ).inc()
+
+
+# a dead peer as the collective transports report it: Gloo names the
+# closed TCP pair ("Gloo AllGather failed ... Connection reset by peer" /
+# "Connection closed by peer"), the coordination service the stopped
+# heartbeat.  A bare "connection reset" with no collective transport
+# named is a client socket and none of this plane's business.
+_PEER_TRANSPORT_MARKER = "gloo"
+_PEER_SOCKET_MARKERS = (
+    "connection reset",
+    "connection closed",
+    "broken pipe",
+    "socket closed",
+    "read error",
+    "write error",
+)
+_PEER_HEARTBEAT_MARKER = "heartbeat timeout"
+
+
+def _peer_lost(exc: BaseException) -> bool:
+    msg = str(exc).lower()
+    if _PEER_HEARTBEAT_MARKER in msg:
+        return True
+    return _PEER_TRANSPORT_MARKER in msg and any(
+        m in msg for m in _PEER_SOCKET_MARKERS
+    )
+
+
+def _raise_if_peer_lost(exc: BaseException, op: str, axis: str,
+                        elapsed: float) -> None:
+    """A collective that FAILED because its peer is gone — the transport
+    saw the socket close (``Gloo ... Connection reset by peer``) or the
+    coordination service the heartbeat stop — is the same event as one
+    that hangs until the deadline, reported sooner: write this rank's
+    crash record and raise :class:`PeerAbortError`, so survivors leave
+    through the recovery plane whichever way the loss surfaced.  Any
+    other failure returns and is re-raised unchanged by the caller."""
+    if _world() <= 1 or not _peer_lost(exc):
+        return
+    _count_peer_abort()
+    detail = str(exc)[:500]
+    write_crash_record(
+        "collective.dispatch", FAULT_PEER_ABORT,
+        f"peer lost during {op}: {detail}", op=op, elapsed_s=elapsed,
+    )
+    raise PeerAbortError(
+        f"collective '{op}' over axis '{axis}' failed after "
+        f"{elapsed:.1f}s because a peer is gone (rank {_rank()} of "
+        f"{_world()}): {detail}.  Recovery: relaunch under "
+        "utils/supervisor (resume=auto restores the last durable "
+        "checkpoint — docs/distributed.md 'Recovery runbook').",
+        record={"fault_class": FAULT_PEER_ABORT,
+                "site": "collective.dispatch", "error": detail},
+    ) from exc

@@ -17,21 +17,10 @@ import sys
 rank, nproc = int(sys.argv[1]), int(sys.argv[2])
 coord, local_dev = sys.argv[3], int(sys.argv[4])
 
-import os
-
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    # older jax lines have no jax_num_cpu_devices config option; the env
-    # flag must be in place before the backend initializes
-    os.environ["XLA_FLAGS"] = (
-        _flags + f" --xla_force_host_platform_device_count={local_dev}"
-    ).strip()
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-if hasattr(jax.config, "jax_num_cpu_devices"):
-    jax.config.update("jax_num_cpu_devices", local_dev)
+jax.config.update("jax_num_cpu_devices", local_dev)
 
 import numpy as np
 

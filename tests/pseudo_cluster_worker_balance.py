@@ -35,17 +35,10 @@ coord, local_dev = sys.argv[3], int(sys.argv[4])
 mode = os.environ["BALANCE_WORKER_MODE"]
 sleep_s = float(os.environ.get("BALANCE_CHUNK_SLEEP", "0.05"))
 
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + f" --xla_force_host_platform_device_count={local_dev}"
-    ).strip()
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-if hasattr(jax.config, "jax_num_cpu_devices"):
-    jax.config.update("jax_num_cpu_devices", local_dev)
+jax.config.update("jax_num_cpu_devices", local_dev)
 
 import numpy as np
 
@@ -102,6 +95,16 @@ elif mode == "rebalance":
 else:
     print(f"WORKER_ERROR rank={rank} unknown mode {mode}", flush=True)
     os._exit(4)
+
+# A start that does not depend on the layout: the streamed random init
+# reservoirs each rank's OWN extent (ops/stream_ops.reservoir_sample), so
+# the equal and the weighted layout of one table draw different rows —
+# and the parity this worker reports is about the passes, not the draw.
+from oap_mllib_tpu.ops import stream_ops  # noqa: E402
+
+stream_ops.reservoir_sample = (
+    lambda source, k, seed, timings=None: x[:: ROWS // k][:k].copy()
+)
 
 try:
     src = balance.local_sources(data, chunk_rows=CHUNK)

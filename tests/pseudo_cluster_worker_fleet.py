@@ -14,9 +14,12 @@ plane (telemetry/fleet.py + telemetry/flightrec.py).  Modes (env
   asserts the windows agree across ranks, the hand-fold matches, and
   the block names rank 1 with skew > 1.5.
 - ``kill`` — flight recorder + collective deadline + crash sideband
-  armed; rank 1 SIGKILLs itself mid-read of Lloyd pass 2.  Rank 0 must
-  raise CollectiveTimeoutError within the deadline, leaving a v2 crash
-  record whose ``flight_recorder`` tail carries >= 32 events.
+  armed; rank 1 SIGKILLs itself mid-read of Lloyd pass 3.  Rank 0 must
+  raise a recovery error within the deadline (at once where the
+  transport reports the closed socket, so the ring holds only what the
+  fit did until then — three walks of ten chunks are >= 32 events),
+  leaving a v2 crash record whose ``flight_recorder`` tail carries
+  >= 32 events.
 
 Invoked as:  python pseudo_cluster_worker_fleet.py RANK NPROC COORD LOCAL_DEV
 """
@@ -31,17 +34,10 @@ rank, nproc = int(sys.argv[1]), int(sys.argv[2])
 coord, local_dev = sys.argv[3], int(sys.argv[4])
 mode = os.environ["FLEET_WORKER_MODE"]
 
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + f" --xla_force_host_platform_device_count={local_dev}"
-    ).strip()
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-if hasattr(jax.config, "jax_num_cpu_devices"):
-    jax.config.update("jax_num_cpu_devices", local_dev)
+jax.config.update("jax_num_cpu_devices", local_dev)
 
 import numpy as np
 
@@ -69,7 +65,7 @@ def gen():
     for lo in range(0, rows, chunk):
         if mode == "skew" and rank == 1:
             time.sleep(0.03)  # the deliberately slowed rank
-        if (mode == "kill" and rank == 1 and walks["n"] == 3
+        if (mode == "kill" and rank == 1 and walks["n"] == 4
                 and lo >= chunk * 4):
             import signal
 

@@ -27,17 +27,10 @@ coord, local_dev = sys.argv[3], int(sys.argv[4])
 mode = os.environ["RECOVERY_WORKER_MODE"]
 crash_dir = os.environ["RECOVERY_CRASH_DIR"]
 
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + f" --xla_force_host_platform_device_count={local_dev}"
-    ).strip()
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-if hasattr(jax.config, "jax_num_cpu_devices"):
-    jax.config.update("jax_num_cpu_devices", local_dev)
+jax.config.update("jax_num_cpu_devices", local_dev)
 
 import numpy as np
 

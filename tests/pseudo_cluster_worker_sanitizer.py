@@ -31,17 +31,10 @@ import os
 
 mode = os.environ.get("SANITIZER_WORKER_MODE", "probe")
 
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + f" --xla_force_host_platform_device_count={local_dev}"
-    ).strip()
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-if hasattr(jax.config, "jax_num_cpu_devices"):
-    jax.config.update("jax_num_cpu_devices", local_dev)
+jax.config.update("jax_num_cpu_devices", local_dev)
 
 import numpy as np
 

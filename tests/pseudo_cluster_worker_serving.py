@@ -14,9 +14,11 @@ host collective plane (serving/ha.py).  Modes (env
   and after the eviction (printed as per-leg digests the parent
   cross-checks).  Exit 0 with ``EVICTED`` + ``SERVE_OK`` markers.
 - ``relaunched`` — the supervisor's replacement replica: a 1-process
-  world (nproc=1) that serves the same request legs and prints the
-  same digests, so the parent can assert the relaunch answers exactly
-  what the survivor does.
+  world (nproc=1) that LOADS the model the fleet saved beside the
+  sideband (a fit inside the 2-process world is a collective over both
+  ranks' rows, so refitting alone would give another model), serves
+  the same request legs and prints the same digests, so the parent can
+  assert the relaunch answers exactly what the survivor does.
 
 Invoked as:  python pseudo_cluster_worker_serving.py RANK NPROC COORD LOCAL_DEV
 (the standard worker argv — the shared _launch_world plumbing spawns it).
@@ -31,17 +33,10 @@ coord, local_dev = sys.argv[3], int(sys.argv[4])
 mode = os.environ["SERVING_WORKER_MODE"]
 crash_dir = os.environ["SERVING_CRASH_DIR"]
 
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + f" --xla_force_host_platform_device_count={local_dev}"
-    ).strip()
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-if hasattr(jax.config, "jax_num_cpu_devices"):
-    jax.config.update("jax_num_cpu_devices", local_dev)
+jax.config.update("jax_num_cpu_devices", local_dev)
 
 import numpy as np
 
@@ -63,7 +58,15 @@ set_config(collective_timeout=10.0, crash_dir=crash_dir)
 # weights — the serving fleet contract) and serves the same requests
 rng = np.random.default_rng(77)
 x = rng.normal(size=(600, 8)).astype(np.float32)
-model = KMeans(k=4, seed=5, init_mode="random", max_iter=4).fit(x)
+model_dir = os.path.join(crash_dir, "served-model")
+if mode == "relaunched":
+    from oap_mllib_tpu.models.kmeans import KMeansModel
+
+    model = KMeansModel.load(model_dir)
+else:
+    model = KMeans(k=4, seed=5, init_mode="random", max_iter=4).fit(x)
+    if rank == 0:
+        model.save(model_dir)  # what a relaunched replica serves from
 handle = serving.serve(model)
 handle.warmup(128)
 

@@ -1,5 +1,5 @@
 """Config-surface coverage: every field is read somewhere, documented,
-and env-overridable — the audit VERDICT r3 item 6 asked for (the round-3
+and env-overridable — what the round-3 audit asked for (the round-3
 `Config.seed` was documented but read by nothing)."""
 
 import dataclasses
@@ -404,10 +404,14 @@ class TestConfigCoverage:
         set_config(telemetry_log=str(tmp_path / "t.jsonl"))
         assert sink_path() == str(tmp_path / "t.jsonl")
 
-    def test_compilation_cache_dir_wires_jax_config(self, tmp_path):
+    def test_compilation_cache_dir_wires_jax_config(self, tmp_path,
+                                                    monkeypatch):
         """Config.compilation_cache_dir reaches jax's persistent cache
-        at dispatch time (the every-fit chokepoint)."""
+        at dispatch time (the every-fit chokepoint) — where the
+        environment does not already own the cache."""
         import jax
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
 
         from oap_mllib_tpu.utils import progcache
         from oap_mllib_tpu.utils.dispatch import should_accelerate

@@ -207,6 +207,80 @@ class TestChunkedScoring:
         np.testing.assert_allclose(model.compute_cost(x), full_cost, rtol=1e-6)
 
 
+class TestArgminRows:
+    """``kmeans_ops.argmin_rows`` is ``jnp.argmin(axis=1)`` in every case
+    the assignment sites can meet; what it is for — an f32 comparison on
+    the chip — is held by tests/test_tpu_compile.py::TestAssignment."""
+
+    @pytest.mark.parametrize("given_min", [False, True],
+                             ids=["own-min", "callers-min"])
+    @pytest.mark.parametrize("case", ["random", "ties", "nan", "all-inf"])
+    def test_equals_jnp_argmin(self, rng, case, given_min):
+        import jax
+        import jax.numpy as jnp
+
+        from oap_mllib_tpu.ops.kmeans_ops import argmin_rows
+
+        a = rng.standard_normal((64, 37)).astype(np.float32)
+        if case == "ties":
+            a[:, 5] = a[:, 30] = a.min(axis=1) - 1.0  # lowest id wins
+        elif case == "nan":
+            a[::2, 7] = a[::2, 20] = np.nan  # first NaN wins
+            a[1, :] = np.nan
+        elif case == "all-inf":
+            a[::3, :] = np.inf  # masked-out candidate blocks (k-means||)
+            a[1, 0] = -np.inf
+        a = jnp.asarray(a)
+        got = jax.jit(argmin_rows)(
+            a, jnp.min(a, axis=1) if given_min else None
+        )
+        want = jnp.argmin(a, axis=1)
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(argmin_rows(a, exact=False), want)
+
+    @pytest.mark.parametrize("tier,policy,exact", [
+        ("highest", "f32", True),
+        ("high", "f32", False),      # assignment matmul runs at bf16
+        ("default", "f32", False),
+        ("highest", "tf32", False),  # bf16_3x whatever the tier
+        ("highest", "bf16", False),
+    ])
+    def test_only_the_strict_parity_tier_pays_for_it(self, tier, policy,
+                                                     exact):
+        from oap_mllib_tpu.ops import kmeans_ops
+
+        assert kmeans_ops._exact_assign(
+            kmeans_ops._assign_prec(tier), policy
+        ) is exact
+
+
+class TestSummaryKernel:
+    """The fit records which Lloyd program the dispatch chose."""
+
+    @pytest.mark.parametrize("cfg,want", [
+        ({}, "xla"),                       # no TPU here: never pallas
+        ({"kmeans_kernel": "pallas"}, "xla"),   # falls back off-TPU
+        ({"model_parallel": 2}, "model_sharded"),
+        ({"model_parallel": 2, "kmeans_kernel": "xla"}, "xla"),
+    ], ids=["auto", "pallas-off-tpu", "model-axis", "model-axis-xla"])
+    def test_in_memory_fit(self, rng, cfg, want):
+        x, _, _ = _blobs(rng, n=256, d=8, k=3)
+        set_config(**cfg)
+        m = KMeans(k=3, max_iter=3, seed=1, init_mode="random").fit(x)
+        assert m.summary.accelerated and m.summary.kernel == want
+        assert m.summary.timings.root.attrs["kernel"] == want
+
+    def test_streamed_fit(self, rng):
+        from oap_mllib_tpu.data.stream import ChunkSource
+
+        x, _, _ = _blobs(rng, n=256, d=8, k=3)
+        m = KMeans(k=3, max_iter=2, seed=1, init_mode="random").fit(
+            ChunkSource.from_array(x, chunk_rows=128)
+        )
+        assert m.summary.streamed and m.summary.kernel == "xla"
+
+
 class TestModelParallel:
     """Mesh-sharded linalg for K-Means: centroids feature-sharded over the
     MODEL axis of a (data=4, model=2) mesh (survey §5 scope; the shard_map

@@ -324,3 +324,112 @@ class TestSingleShotPaddingJitted:
         np.asarray(s2)
         assert progcache.xla_compile_count() - before == 0
         assert np.array_equal(np.asarray(s1), np.asarray(s2))
+
+
+class TestWalkColumnLayout:
+    """The walks' per-row column (K-Means weights, PCA mask) rides
+    lane-dense, because the chip's compiler refuses a ``(tile_rows, 1)``
+    DMA window on an ``(n, 1)`` operand (tests/test_tpu_compile.py
+    compiles the real thing; this pins the layout's arithmetic)."""
+
+    @pytest.mark.parametrize("tile_rows", [128, 256, 512, 1024])
+    def test_lane_dense_then_column_is_the_identity(self, rng, tile_rows):
+        from oap_mllib_tpu.ops.pallas import _dbuf
+
+        n = 3 * tile_rows
+        col = jnp.asarray(rng.random((n, 1)).astype(np.float32))
+        dense = _dbuf.lane_dense(col, tile_rows)
+        assert dense.shape == (3, tile_rows // 128, 128)
+        # row i of tile t sits at [t, i // 128, i % 128]
+        assert float(dense[2, 1 % (tile_rows // 128), 5]) == float(
+            col[2 * tile_rows + (1 % (tile_rows // 128)) * 128 + 5, 0]
+        )
+        for t in range(3):
+            back = _dbuf.column(dense[t])
+            assert back.shape == (tile_rows, 1)
+            assert np.array_equal(
+                np.asarray(back),
+                np.asarray(col[t * tile_rows : (t + 1) * tile_rows]),
+            )
+
+    @pytest.mark.parametrize("bad", [0, 64, 100, 513, -128])
+    def test_tile_rows_must_be_whole_lane_groups(self, bad):
+        from oap_mllib_tpu.ops.pallas import _dbuf
+
+        with pytest.raises(ValueError, match="multiple of 128"):
+            _dbuf.check_tile_rows(bad)
+
+    def test_walk_rejects_a_misaligned_tile_before_tracing(self, rng):
+        from oap_mllib_tpu.ops.pallas.kmeans_kernel import (
+            lloyd_accumulate_walk,
+        )
+
+        x = jnp.asarray(rng.normal(size=(300, 8)).astype(np.float32))
+        with pytest.raises(ValueError, match="multiple of 128"):
+            lloyd_accumulate_walk(
+                x, jnp.ones((300,), jnp.float32), x[:3], interpret=True,
+                tile_rows=200, depth=2,
+            )
+
+    def test_als_moment_sheet_pads_to_the_sublane_tile(self, rng):
+        """111 rows at rank 10 -> 112: the column walk DMAs whole-height
+        windows and Mosaic wants their row extent in eights; the solve
+        must not notice."""
+        n, r = 300, 10
+        mm = rng.normal(size=(n, r, r)).astype(np.float32)
+        a = jnp.asarray(np.einsum("nij,nkj->nik", mm, mm) + 0.5 * np.eye(r))
+        b = jnp.asarray(rng.normal(size=(n, r)).astype(np.float32))
+        n_reg = jnp.asarray(np.full((n,), 3.0, np.float32))
+        ref = als_ops.regularized_solve(
+            a, b, n_reg, 0.1, jnp.eye(r, dtype=jnp.float32)
+        )
+        for depth in (0, 2):
+            out = solve_normal_eq_pallas(
+                a, b, n_reg, 0.1, interpret=True, depth=depth
+            )
+            np.testing.assert_allclose(
+                np.asarray(out), np.asarray(ref), atol=1e-4
+            )
+
+
+class TestDispatchBounds:
+    """The shape rules admit exactly what the chip's compiler accepted
+    under the plane's scoped-VMEM ceiling (tests/test_tpu_compile.py
+    compiles the edges)."""
+
+    @pytest.mark.parametrize("k,d,ok", [
+        (1000, 256, True),     # the headline shape
+        (4096, 512, True),     # on the k*d bound
+        (2048, 1024, True),
+        (512, 4096, True),
+        (4096, 513, False),    # d pads to 640: past the k*d bound
+        (4097, 128, False),    # k pads to 4224: past the k bound
+        (16384, 128, False),   # within k*d, but (tile, k) temporaries
+        (128, 4224, False),    # past the d bound
+    ])
+    def test_kmeans_rule(self, k, d, ok):
+        from oap_mllib_tpu.ops.kmeans_ops import pallas_preferred
+
+        for tier in ("highest", "high", "default"):
+            assert pallas_preferred(d, k, tier) is ok
+
+    def test_pca_rule_and_typo(self):
+        from oap_mllib_tpu.ops.pallas.pca_kernel import pallas_gram_preferred
+
+        assert pallas_gram_preferred(128, "highest")
+        assert pallas_gram_preferred(2048, "high")
+        assert not pallas_gram_preferred(2049, "high")
+        assert not pallas_gram_preferred(128, "hi")  # not a tier
+
+    def test_compiled_launches_carry_the_vmem_ceiling(self):
+        from oap_mllib_tpu.ops.pallas import _tiers
+
+        params = _tiers.compiled_kwargs(
+            False, vmem_limit_bytes=_tiers.VMEM_LIMIT_BYTES,
+            has_side_effects=True,
+        )["compiler_params"]
+        assert params.vmem_limit_bytes == _tiers.VMEM_LIMIT_BYTES
+        assert params.has_side_effects
+        assert _tiers.compiled_kwargs(True, has_side_effects=True) == {}
+        # under the v5e core's 128 MiB, well over the 16 MiB default
+        assert 16 << 20 < _tiers.VMEM_LIMIT_BYTES < 128 << 20

@@ -180,6 +180,28 @@ class TestModelParallel:
         )
 
 
+class TestSummaryKernel:
+    """The fit records which Gram program the dispatch chose."""
+
+    @pytest.mark.parametrize("cfg,want", [
+        ({}, "xla"),                          # no TPU here: never pallas
+        ({"pca_kernel": "pallas"}, "xla"),    # falls back off-TPU
+        ({"model_parallel": 2}, "model_sharded"),
+    ], ids=["auto", "pallas-off-tpu", "model-axis"])
+    def test_in_memory_fit(self, rng, cfg, want):
+        set_config(**cfg)
+        m = PCA(k=3).fit(_data(rng, n=200, d=8))
+        assert m.summary["accelerated"] and m.summary["kernel"] == want
+
+    def test_streamed_fit(self, rng):
+        from oap_mllib_tpu.data.stream import ChunkSource
+
+        m = PCA(k=3).fit(
+            ChunkSource.from_array(_data(rng, n=200, d=8), chunk_rows=64)
+        )
+        assert m.summary["streamed"] and m.summary["kernel"] == "xla"
+
+
 class TestBehavior:
     def test_shapes(self, rng):
         x = _data(rng, n=100, d=7)

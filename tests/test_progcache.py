@@ -162,12 +162,65 @@ def jax_cache_restore():
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_applied = progcache._persist_applied
     yield
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
     jax.config.update("jax_compilation_cache_dir", prev_dir)
+    cc.reset_cache()  # jax pins its cache object to the first dir it saw
     progcache._persist_applied = prev_applied
 
 
 class TestPersistentCache:
-    def test_dispatch_wires_cache_dir(self, tmp_path, jax_cache_restore):
+    def test_env_dir_stands_and_a_differing_config_dir_raises(
+            self, tmp_path, jax_cache_restore, monkeypatch):
+        """Where JAX_COMPILATION_CACHE_DIR is set, the environment owns
+        the process's cache: no directory is set from here, and a Config
+        value naming another one is an error, not an override."""
+        import jax
+
+        from oap_mllib_tpu.utils.dispatch import should_accelerate
+
+        env_dir = str(tmp_path / "env-cache")
+        monkeypatch.setenv(progcache.CACHE_ENV, env_dir)
+        monkeypatch.setattr(progcache, "_persist_applied", None)
+        before = jax.config.jax_compilation_cache_dir
+        # empty Config: nothing at all is touched
+        assert should_accelerate("KMeans", True)
+        assert jax.config.jax_compilation_cache_dir == before
+        # the same directory: accepted, still no directory update
+        set_config(compilation_cache_dir=env_dir)
+        assert should_accelerate("KMeans", True)
+        assert jax.config.jax_compilation_cache_dir == before
+        assert progcache._persist_applied == env_dir
+        # another directory: refused at fit entry
+        set_config(compilation_cache_dir=str(tmp_path / "other"))
+        with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+            should_accelerate("KMeans", True)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_use_checkout_cache_prefers_env_then_fixed_dir(
+            self, tmp_path, jax_cache_restore, monkeypatch):
+        import jax
+
+        from oap_mllib_tpu.config import get_config
+
+        fixed = str(tmp_path / "checkout" / ".jax_cache")
+        monkeypatch.setattr(progcache, "_persist_applied", None)
+        monkeypatch.delenv(progcache.CACHE_ENV, raising=False)
+        assert progcache.use_checkout_cache(fixed) == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert get_config().compilation_cache_dir == fixed
+        env_dir = str(tmp_path / "env-cache")
+        monkeypatch.setenv(progcache.CACHE_ENV, env_dir)
+        assert progcache.use_checkout_cache(fixed) == env_dir
+        assert jax.config.jax_compilation_cache_dir == fixed  # not re-set
+        assert get_config().compilation_cache_dir == env_dir
+
+    def test_dispatch_wires_cache_dir(self, tmp_path, jax_cache_restore,
+                                      monkeypatch):
+        monkeypatch.delenv(progcache.CACHE_ENV, raising=False)
+        self._dispatch_wires_cache_dir(tmp_path)
+
+    def _dispatch_wires_cache_dir(self, tmp_path):
         import jax
 
         from oap_mllib_tpu.utils.dispatch import should_accelerate
@@ -179,10 +232,12 @@ class TestPersistentCache:
         assert jax.config.jax_compilation_cache_dir == cache_dir
 
     def test_fresh_program_persists_to_disk(self, tmp_path, rng,
-                                            jax_cache_restore):
+                                            jax_cache_restore, monkeypatch):
         """A fit with the cache dir set serializes its executables —
         the artifact a warm process reloads instead of recompiling."""
         from oap_mllib_tpu.models.kmeans import KMeans
+
+        monkeypatch.delenv(progcache.CACHE_ENV, raising=False)
 
         cache_dir = str(tmp_path / "xla-cache")
         os.makedirs(cache_dir, exist_ok=True)

@@ -614,6 +614,19 @@ _RECOVERY_WORKER = os.path.join(
     os.path.dirname(__file__), "pseudo_cluster_worker_recovery.py"
 )
 
+# How a survivor learns that its peer was SIGKILLed depends on the
+# collective transport.  The installed jax's Gloo reports the closed
+# socket at once ("Gloo AllGather failed ... Connection reset by peer"),
+# which the recovery plane raises as PeerAbortError / fault class
+# peer_abort; a transport that blocks instead runs into
+# Config.collective_timeout (CollectiveTimeoutError / collective_timeout).
+# Which one fires is the transport's choice, not the plane's: the kill
+# legs below hold the plane to a PROMPT RecoveryError, a crash record
+# and a self-exit, and accept either diagnosis.
+_PEER_LOSS_MARKERS = ("TIMEOUT_CAUGHT", "PEER_ABORT")
+_PEER_LOSS_ERRORS = ("CollectiveTimeoutError", "PeerAbortError")
+_PEER_LOSS_CLASSES = ("collective_timeout", "peer_abort")
+
 
 class TestLiveWorldRecovery:
     """ISSUE 10 acceptance: the recovery plane across a REAL 2-process
@@ -657,22 +670,22 @@ class TestLiveWorldRecovery:
 
     def test_rank_kill_raises_timeout_on_survivors(self, tmp_path):
         """Satellite leg: rank 1 is SIGKILLed mid-collective; rank 0
-        must raise CollectiveTimeoutError within collective_timeout —
-        exiting BY ITSELF, well inside the 120 s watchdog — with its
-        crash record (fault class, last-completed fingerprint) in the
-        sideband for the supervisor to classify."""
+        must raise a recovery error (see ``_PEER_LOSS_ERRORS``) within
+        collective_timeout — exiting BY ITSELF, well inside the 120 s
+        watchdog — with its crash record (fault class, last-completed
+        fingerprint) in the sideband for the supervisor to classify."""
         crash_dir = str(tmp_path / "sideband")
         procs, outs, elapsed = self._launch_recovery_world(
             "hang", crash_dir
         )
         assert procs[1].returncode == -9, outs[1]  # genuinely SIGKILLed
         assert procs[0].returncode == 0, f"survivor did not self-exit:\n{outs[0]}"
-        assert "TIMEOUT_CAUGHT" in outs[0], outs[0]
+        assert any(m in outs[0] for m in _PEER_LOSS_MARKERS), outs[0]
         # the survivor's diagnosis landed in the sideband, machine-readable
         rec_path = os.path.join(crash_dir, "crash.rank0.json")
         assert os.path.exists(rec_path), os.listdir(crash_dir)
         rec = json.load(open(rec_path))
-        assert rec["fault_class"] == "collective_timeout"
+        assert rec["fault_class"] in _PEER_LOSS_CLASSES
         assert rec["rank"] == 0 and rec["world"] == 2
         assert rec["last_checkpoint_step"] == -1  # no checkpointing armed
         assert "telemetry" in rec
@@ -857,7 +870,7 @@ class TestFleetObservability:
         )
         assert procs[1].returncode == -9, outs[1]
         assert procs[0].returncode == 0, outs[0]
-        assert "TIMEOUT_CAUGHT" in outs[0], outs[0]
+        assert any(m in outs[0] for m in _PEER_LOSS_MARKERS), outs[0]
         rec = json.load(
             open(os.path.join(crash_dir, "crash.rank0.json"))
         )
@@ -908,7 +921,7 @@ class TestServingPlane:
         assert procs[1].returncode == -9, outs[1]
         assert procs[0].returncode == 0, f"survivor failed:\n{outs[0]}"
         assert "EVICTED rank=0" in outs[0], outs[0]
-        assert "CollectiveTimeoutError" in outs[0], outs[0]
+        assert any(e in outs[0] for e in _PEER_LOSS_ERRORS), outs[0]
         assert "SERVE_OK rank=0 legs=6 local_only=True" in outs[0], outs[0]
         assert "FLEET rank=0 world=2" in outs[0], outs[0]
         survivor = _answer_digests(outs[0])
@@ -922,7 +935,7 @@ class TestServingPlane:
         rec = json.load(
             open(os.path.join(crash_dir, "crash.rank0.json"))
         )
-        assert rec["fault_class"] == "collective_timeout"
+        assert rec["fault_class"] in _PEER_LOSS_CLASSES
         assert elapsed < 90, f"fleet took {elapsed:.0f}s to evict"
 
         # the supervisor's relaunch: a replacement replica (fresh
@@ -955,8 +968,9 @@ def _traffic_fields(out, tag):
 
 class TestTrafficPlane:
     """ISSUE 16 acceptance: the async traffic plane across a REAL
-    2-replica serving fleet — the factor-sharded sweep is bit-identical
-    to the single-process reference on a live multi-process mesh, a
+    2-replica serving fleet — the factor-sharded sweep names the same
+    ids as the single-process reference (scores to 1e-6) on a live
+    multi-process mesh, a
     jittered storm through the TrafficQueue holds the zero-steady-
     compile and p99-vs-p50 contracts, sheds stay loud, and a SIGKILLed
     replica is evicted while the survivor keeps the same contracts in
@@ -1010,7 +1024,7 @@ class TestTrafficPlane:
         assert procs[1].returncode == -9, outs[1]
         assert procs[0].returncode == 0, f"survivor failed:\n{outs[0]}"
         assert "EVICTED rank=0" in outs[0], outs[0]
-        assert "err=CollectiveTimeoutError" in outs[0], outs[0]
+        assert any(f"err={e}" in outs[0] for e in _PEER_LOSS_ERRORS), outs[0]
         self._check_storm(outs[0], 0, expect_local_only=True)
         assert "SHED_OK rank=0 sheds=3" in outs[0], outs[0]
         # the survivor's diagnosis is in the sideband for the
@@ -1018,7 +1032,7 @@ class TestTrafficPlane:
         rec = json.load(
             open(os.path.join(crash_dir, "crash.rank0.json"))
         )
-        assert rec["fault_class"] == "collective_timeout"
+        assert rec["fault_class"] in _PEER_LOSS_CLASSES
         assert elapsed < 150, f"fleet took {elapsed:.0f}s to evict"
 
 

@@ -97,7 +97,7 @@ def session(request):
     if spark is None:
         if os.environ.get("CI") in ("true", "1"):
             # the hosted workflow installs pyspark; a silent skip there
-            # would un-prove the drop-in claim (VERDICT r4 missing #1)
+            # would un-prove the drop-in claim
             pytest.fail("pyspark is required in CI but not importable")
         pytest.skip("pyspark not installed — real-Spark plane runs in CI")
     return spark

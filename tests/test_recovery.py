@@ -244,6 +244,78 @@ class TestWatchdog:
         rec = json.load(open(recovery.crash_record_path(crash, 0)))
         assert rec["fault_class"] == recovery.FAULT_PEER_ABORT
 
+    # what the installed jax's Gloo transport raises from a collective
+    # whose peer was SIGKILLed (tests/test_pseudo_cluster.py kill legs)
+    _GLOO_RESET = (
+        "UNKNOWN: Gloo AllGather failed: [external/gloo/gloo/transport/"
+        "tcp/pair.cc:538] Read error [127.0.0.1]:30084: Connection reset "
+        "by peer"
+    )
+    _GLOO_CLOSED = (
+        "UNKNOWN: Gloo AllGather failed: [external/gloo/gloo/transport/"
+        "tcp/pair.cc:547] Connection closed by peer [127.0.0.1]:37009"
+    )
+    _HEARTBEAT = (
+        "UNAVAILABLE: Task /job:jax_worker/replica:0/task:1 heartbeat "
+        "timeout. This indicates that the remote task has failed"
+    )
+
+    @pytest.mark.parametrize("armed", [True, False],
+                             ids=["deadline-armed", "disarmed"])
+    @pytest.mark.parametrize("text", [_GLOO_RESET, _GLOO_CLOSED, _HEARTBEAT],
+                             ids=["reset", "closed", "heartbeat"])
+    def test_lost_peer_socket_is_a_peer_abort(self, monkeypatch, tmp_path,
+                                              armed, text):
+        """A collective that FAILS because its peer is gone is the same
+        event as one that hangs to the deadline, reported sooner: a
+        PeerAbortError (a RecoveryError) with this rank's crash record,
+        whether or not a deadline is armed."""
+        _two_process(monkeypatch)
+        crash = str(tmp_path / "sideband")
+        set_config(collective_timeout=5.0 if armed else 0.0,
+                   crash_dir=crash)
+
+        def gloo_fails():
+            raise ValueError(text)
+
+        with pytest.raises(recovery.PeerAbortError) as ei:
+            recovery.guarded_dispatch("process_allgather", "host",
+                                      gloo_fails)
+        assert isinstance(ei.value, recovery.RecoveryError)
+        assert isinstance(ei.value.__cause__, ValueError)
+        assert "process_allgather" in str(ei.value)
+        assert text[-20:] in str(ei.value)
+        assert ei.value.record["fault_class"] == recovery.FAULT_PEER_ABORT
+        rec = json.load(open(recovery.crash_record_path(crash, 0)))
+        assert rec["fault_class"] == recovery.FAULT_PEER_ABORT
+        assert rec["op"] == "process_allgather"
+
+    def test_a_client_socket_reset_is_not_a_lost_peer(self, monkeypatch):
+        """No collective transport named: a reset of a client socket (a
+        coordinator connect, a file server) is not this plane's to
+        claim, even in a two-process world — it propagates raw and
+        ``resilience.classify_fault`` still reads it as TRANSIENT."""
+        from oap_mllib_tpu.utils import resilience
+
+        _two_process(monkeypatch)
+        exc = RuntimeError("Connection reset by peer")
+
+        def fails():
+            raise exc
+
+        with pytest.raises(RuntimeError) as ei:
+            recovery.guarded_dispatch("psum", "data", fails)
+        assert ei.value is exc
+        assert resilience.classify_fault(exc) == resilience.TRANSIENT
+
+    def test_lost_peer_is_not_claimed_single_process(self):
+        """world==1 has no peer to lose: the error propagates raw."""
+        with pytest.raises(ValueError, match="Gloo"):
+            recovery.guarded_dispatch(
+                "psum", "data",
+                lambda: (_ for _ in ()).throw(ValueError(self._GLOO_RESET)),
+            )
+
     def test_single_process_never_watches(self):
         """world==1: armed or not, the dispatch runs inline (there is no
         peer to wait for)."""

@@ -359,10 +359,18 @@ class TestSweep:
         assert np.array_equal(ids[sample], expect)
 
 
+# a sharded sweep's scores vs the one-device reference: the same dot
+# products compiled for different device counts agree to the last ulp
+# or so (observed 1.9e-7 relative), not bit for bit
+_SCORE_RTOL = 1e-6
+
+
 class TestShardedSweep:
     """Factor-sharded ring sweep on the 8-device pseudo-mesh: the live
     block layout serves without a host gather, and the ring-merged
-    top-k matches the single-device reference exactly."""
+    top-k matches the single-device reference — ids exactly, scores to
+    ``_SCORE_RTOL`` (the per-block score matmuls of an 8-device program
+    and the one-device program may round their last bit differently)."""
 
     def _sharded_als(self, rng, layout, nu=200, ni=96):
         set_config(als_item_layout=layout)
@@ -386,7 +394,7 @@ class TestShardedSweep:
             ref.user_factors_, ref.item_factors_, 7
         )
         assert np.array_equal(ids, ids_ref)
-        np.testing.assert_array_equal(scores, s_ref)
+        np.testing.assert_allclose(scores, s_ref, rtol=_SCORE_RTOL)
 
     def test_replicated_item_sharded_user_sweep(self, rng):
         m = self._sharded_als(rng, "replicated")
@@ -460,6 +468,8 @@ class TestEvictionReform:
         )
 
     def test_shard_factors_local_serves_bit_identical(self, rng):
+        """Identical ids; scores to ``_SCORE_RTOL`` of the one-device
+        reference (the survivors' layout is an 8-device program)."""
         uf, itf = self._host_tables(rng)
         ids, scores = sweep.recommend_for_all_users(
             self._local_model(uf, itf), 6, with_scores=True
@@ -467,7 +477,7 @@ class TestEvictionReform:
         ref = ALSModel(uf, itf)
         ids_ref, s_ref = ref._top_k_scores(uf, itf, 6)
         assert np.array_equal(ids, ids_ref)
-        np.testing.assert_array_equal(scores, s_ref)
+        np.testing.assert_allclose(scores, s_ref, rtol=_SCORE_RTOL)
 
     def test_reform_hook_reforms_once_and_answers(self, rng, monkeypatch):
         from oap_mllib_tpu.utils import recovery
@@ -499,7 +509,7 @@ class TestEvictionReform:
         ref = ALSModel(uf, itf)
         ids_ref, s_ref = ref._top_k_scores(uf, itf, 6)
         assert np.array_equal(ids, ids_ref)
-        np.testing.assert_array_equal(scores, s_ref)
+        np.testing.assert_allclose(scores, s_ref, rtol=_SCORE_RTOL)
         assert len(reformed) == 1
         assert isinstance(
             reformed[0], recovery.CollectiveTimeoutError

@@ -1,0 +1,281 @@
+"""Compile the kernels a default fit launches on one v5e chip — without
+the chip.
+
+The TPU compiler is installed wherever jax[tpu] is; it compiles for a
+chip that is described and not attached.  Interpret mode cannot see what
+it refuses: these kernels passed every interpret-mode test while Mosaic
+rejected their ``(tile_rows, 1)`` column windows, a 111-row DMA window,
+500-row ring segments, and Gram / centre blocks past the default
+scoped-VMEM limit.  Each case compiles one raw kernel at the size
+``chip_smoke.py`` (or ``bench.py --all``) runs it at, plus the edges the
+dispatch rules admit.  Nothing runs, so nothing here says a result is
+right — ``tests_tpu/`` and ``chip_smoke.py`` do that on the chip.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and under xdist
+every worker imports this file.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (  # noqa: E402
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+F32 = jnp.float32
+N_KMEANS = 1 << 20  # chip_smoke's K-Means / PCA row count
+TILE, DEPTH = 512, 2  # autotune.DEFAULTS geometry
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to jax's persistent
+    cache but cannot be read back without the chip (the next one warns
+    and recompiles): keep the cache off around this file."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    """Lower + compile ``fn`` for the shapes' (described) devices; a
+    refusal by Mosaic or XLA:TPU raises here."""
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel is in there
+    return compiled
+
+
+def _s(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, F32, sharding=sharding)
+
+
+def _kmeans_shapes(sharding, n=N_KMEANS, k=1024, d=256):
+    """(x, weight column, centres) of one padded K-Means launch."""
+    return _s((n, d), sharding), _s((n, 1), sharding), _s((k, d), sharding)
+
+
+class TestKMeansKernels:
+    """k=1000 pads to 1024 lanes; d=256; the walk is what the default
+    geometry (depth 2) launches, the grid kernel what ``depth`` < 2
+    pins."""
+
+    @pytest.mark.parametrize("mode", ["highest", "high", "default"])
+    def test_walk_loop_mode(self, one_chip, mode):
+        from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
+
+        _compile(
+            lambda x, w, c: kk._pallas_accumulate_dbuf(
+                x, w, c, mode, False, False, TILE, DEPTH),
+            *_kmeans_shapes(one_chip),
+        )
+
+    def test_walk_final_cost_pass(self, one_chip):
+        from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
+
+        _compile(
+            lambda x, w, c: kk._pallas_accumulate_dbuf(
+                x, w, c, "highest", False, True, TILE, DEPTH),
+            *_kmeans_shapes(one_chip),
+        )
+
+    def test_grid_kernel(self, one_chip):
+        from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
+
+        _compile(
+            lambda x, w, c: kk._pallas_accumulate(
+                x, w, c, "highest", False, True, TILE),
+            *_kmeans_shapes(one_chip),
+        )
+
+    def test_walk_at_the_dispatch_rule_edge(self, one_chip):
+        """The largest resident blocks ``pallas_preferred`` admits: a
+        shape just inside the bound must compile (here at the tier
+        whose split passes hold the most temporaries per element)."""
+        from oap_mllib_tpu.ops import kmeans_ops
+        from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
+
+        k, d = kmeans_ops.PALLAS_MAX_K, (
+            kmeans_ops.PALLAS_MAX_KD // kmeans_ops.PALLAS_MAX_K
+        )
+        assert kmeans_ops.pallas_preferred(d, k, "high")
+        assert not kmeans_ops.pallas_preferred(d, 2 * k, "high")
+        assert not kmeans_ops.pallas_preferred(2 * d, k, "high")
+        _compile(
+            lambda x, w, c: kk._pallas_accumulate_dbuf(
+                x, w, c, "high", False, False, TILE, DEPTH),
+            *_kmeans_shapes(one_chip, n=1 << 16, k=k, d=d),
+        )
+
+
+class TestAssignment:
+    """The XLA assignment every non-Pallas route shares (serving, the
+    sharded Lloyd, k-means||): at ``highest`` it promises the f32
+    nearest centre.  ``jnp.argmin`` does not keep that promise on this
+    compiler — the value output of its (value, index) reduction is
+    typed bfloat16, and on the chip two centres within 2^-8 relative
+    then tie (1 row in 4096 of ``chip_smoke.py``'s served batch named a
+    centre 6.7e-4 farther than the nearest) — so the programs go through
+    ``kmeans_ops.argmin_rows``.  The narrowing shows in the compiled
+    text, which is what this case holds: no bfloat16 anywhere in an f32
+    assignment program."""
+
+    @pytest.mark.parametrize("rows", [1024, 4096])
+    def test_serving_assign_keeps_f32(self, one_chip, rows):
+        from oap_mllib_tpu.serving import batcher
+
+        program = batcher._build_assign("highest", "f32")
+        text = program.lower(
+            _s((rows, 256), one_chip), _s((1000, 256), one_chip)
+        ).compile().as_text()
+        assert "operand_precision={highest,highest}" in text
+        assert "bf16" not in text
+
+    def test_lloyd_loop_body_keeps_f32(self, one_chip):
+        from oap_mllib_tpu.ops import kmeans_ops
+
+        text = jax.jit(
+            lambda x, w, c: kmeans_ops._accumulate(
+                x, w, c, "highest", False)
+        ).lower(
+            _s((8192, 256), one_chip), _s((8192,), one_chip),
+            _s((1000, 256), one_chip),
+        ).compile().as_text()
+        assert "bf16" not in text
+
+
+class TestPCAKernels:
+    @pytest.mark.parametrize("need_gram", [True, False],
+                             ids=["gram", "colsum"])
+    def test_walk_d128(self, one_chip, need_gram):
+        from oap_mllib_tpu.ops.pallas import pca_kernel as pk
+
+        _compile(
+            lambda x, m, mu: pk._pallas_moments_dbuf(
+                x, m, mu, "highest", False, need_gram, TILE, DEPTH),
+            _s((N_KMEANS, 128), one_chip), _s((N_KMEANS, 1), one_chip),
+            _s((1, 128), one_chip),
+        )
+
+    def test_grid_kernel_d128(self, one_chip):
+        from oap_mllib_tpu.ops.pallas import pca_kernel as pk
+
+        _compile(
+            lambda x, m, mu: pk._pallas_moments(
+                x, m, mu, "highest", False, True, TILE),
+            _s((N_KMEANS, 128), one_chip), _s((N_KMEANS, 1), one_chip),
+            _s((1, 128), one_chip),
+        )
+
+    def test_walk_at_the_dispatch_rule_edge_d2048(self, one_chip):
+        """``bench.py --all``'s 128k x 2048 shape sits exactly on the
+        bound of ``pallas_gram_preferred`` (a 16 MB Gram block); the
+        "high" tier's three split passes are its hungriest form."""
+        from oap_mllib_tpu.ops.pallas import pca_kernel as pk
+
+        assert pk.pallas_gram_preferred(2048, "high")
+        assert not pk.pallas_gram_preferred(2049, "high")
+        _compile(
+            lambda x, m, mu: pk._pallas_moments_dbuf(
+                x, m, mu, "high", False, True, TILE, DEPTH),
+            _s((1 << 17, 2048), one_chip), _s((1 << 17, 1), one_chip),
+            _s((1, 2048), one_chip),
+        )
+
+    def test_streamed_colsum_chunk(self, one_chip, monkeypatch):
+        """``stream_ops._colsum_chunk_pallas`` picks its kernel from
+        ``jax.default_backend()``; steer that here, in the test, to the
+        branch the chip takes."""
+        from oap_mllib_tpu.ops import stream_ops
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        rows = 1 << 16  # data/stream.DEFAULT_CHUNK_ROWS
+        compiled = stream_ops._colsum_chunk_pallas.lower(
+            _s((128,), one_chip), _s((rows, 128), one_chip),
+            _s((rows,), one_chip), tile_rows=TILE, depth=DEPTH,
+        ).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+class TestALSKernels:
+    """Rank 10 at MovieLens-1M scale: 6040 users pad to 6144 columns of
+    the moment sheet, whose 111 rows pad to 112."""
+
+    RANK = 10
+
+    @pytest.mark.parametrize("walk", [True, False], ids=["walk", "grid"])
+    def test_solve(self, one_chip, walk):
+        from oap_mllib_tpu.ops.pallas import als_kernel as ak
+
+        r = self.RANK
+        rows = ak.pad_to(r * r + r + 1, ak.SUBLANE)
+
+        def fn(m, g, reg):
+            if walk:
+                return ak._pallas_solve_dbuf(m, g, reg, r, True, False,
+                                             ak._BATCH, DEPTH)
+            return ak._pallas_solve(m, g, reg, r, True, False, ak._BATCH)
+
+        _compile(fn, _s((rows, 6144), one_chip), _s((r, r), one_chip),
+                 _s((1, 1), one_chip))
+
+    @pytest.mark.parametrize("walk", [True, False], ids=["walk", "grid"])
+    def test_factor_gram(self, one_chip, walk):
+        from oap_mllib_tpu.ops.pallas import als_kernel as ak
+
+        def fn(f):
+            if walk:
+                return ak._pallas_factor_gram_dbuf(f, "highest", False,
+                                                   TILE, DEPTH)
+            return ak._pallas_factor_gram(f, "highest", False, TILE)
+
+        _compile(fn, _s((6144, 128), one_chip))
+
+
+class TestRingKernel:
+    def test_remote_dma_ring_on_four_chips(self, topo):
+        """The packed K-Means moments of k=1000, d=256 over a 4-device
+        ring: (1000, 258) pads to (1024, 512)."""
+        from oap_mllib_tpu.ops.pallas import ring_reduce as rr
+        from oap_mllib_tpu.utils.jax_compat import shard_map
+
+        mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+        spec = P("data", None, None)
+        _compile(
+            shard_map(
+                lambda blk: rr._ring_pallas(blk[0], "data", 4)[None],
+                mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False,
+            ),
+            _s((4, 1024, 512), NamedSharding(mesh, spec)),
+        )

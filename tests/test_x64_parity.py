@@ -22,8 +22,7 @@ _SCRIPT = textwrap.dedent("""
             flags + " --xla_force_host_platform_device_count=8").strip()
     import jax
     jax.config.update("jax_platforms", "cpu")
-    if hasattr(jax.config, "jax_num_cpu_devices"):
-        jax.config.update("jax_num_cpu_devices", 8)
+    jax.config.update("jax_num_cpu_devices", 8)
     jax.config.update("jax_enable_x64", True)
     import numpy as np
     from oap_mllib_tpu.config import set_config

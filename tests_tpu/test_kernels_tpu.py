@@ -1,9 +1,10 @@
-"""Compiled-mode TPU legs for the ISSUE 9 kernel plane: Mosaic-lowered
-PCA moments + ALS solve parity, the remote-DMA ring kernel vs the psum
-reference on the real mesh, and the ring's overlap-efficiency bound.
+"""Compiled-mode TPU legs for the kernel plane: Mosaic-lowered PCA
+moments + ALS solve parity, the double-buffered walks every default fit
+launches against the grid kernels they share their tile bodies with,
+the remote-DMA ring kernel vs the psum reference on the real mesh, and
+the ring's overlap-efficiency bound.
 
-Skipped (whole module) unless the session backend is a TPU — see
-conftest.py; dev/ci.sh runs this suite whenever one is present.
+Every test skips unless the session backend is a TPU — see conftest.py.
 """
 
 import numpy as np
@@ -65,6 +66,94 @@ class TestAlsSolveCompiled:
         out = solve_normal_eq_pallas(a, b, n_reg, 0.1, gram)
         np.testing.assert_allclose(
             np.asarray(ref), np.asarray(out), atol=1e-4
+        )
+
+
+class TestWalkKernelsCompiled:
+    """The rotating-buffer walks (autotune.DEFAULTS: depth 2) against
+    the grid kernels at the same row partition.  Both compile the same
+    tile body, so on one chip the results agree bit for bit; per-row
+    weights that all differ make any slip in the walk's lane-dense
+    column layout (``_dbuf.lane_dense`` / ``_dbuf.column``) show."""
+
+    @pytest.mark.parametrize("mode", ["highest", "high", "default"])
+    def test_kmeans_walk_matches_grid_kernel(self, rng, mode):
+        from oap_mllib_tpu.ops.kmeans_ops import _accumulate
+        from oap_mllib_tpu.ops.pallas.kmeans_kernel import (
+            lloyd_accumulate_pallas,
+            lloyd_accumulate_walk,
+        )
+
+        n, d, k = 5000, 100, 37  # 10 tiles of 512, the last one padded
+        x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+        w = jnp.asarray((rng.random(n) + 0.5).astype(np.float32))
+        c = jnp.asarray(rng.normal(size=(k, d)).astype(np.float32))
+        grid = lloyd_accumulate_pallas(x, w, c, mode=mode)
+        for tile_rows, depth in ((512, 2), (512, 3)):
+            walk = lloyd_accumulate_walk(
+                x, w, c, mode=mode, tile_rows=tile_rows, depth=depth
+            )
+            for a, b in zip(walk, grid):
+                assert np.array_equal(np.asarray(a), np.asarray(b)), (
+                    mode, tile_rows, depth,
+                )
+        # another partition reorders the f32 tile reduction only
+        s1, c1, t1 = _accumulate(x, w, c)
+        s2, c2, t2 = lloyd_accumulate_walk(
+            x, w, c, mode="highest", tile_rows=256, depth=2
+        )
+        np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-3)
+        np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=1e-3)
+        np.testing.assert_allclose(float(t1), float(t2), rtol=1e-5)
+
+    @pytest.mark.parametrize("need_gram", [True, False])
+    def test_pca_walk_matches_grid_kernel(self, rng, need_gram):
+        from oap_mllib_tpu.ops.pallas.pca_kernel import pca_moments_pallas
+
+        n, d = 5000, 96
+        x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32) + 3.0)
+        m = jnp.asarray((rng.random(n) + 0.25).astype(np.float32))
+        mean = jnp.asarray(rng.normal(size=(d,)).astype(np.float32))
+        grid = pca_moments_pallas(x, m, mean, need_gram=need_gram)
+        walk = pca_moments_pallas(
+            x, m, mean, need_gram=need_gram, tile_rows=512, depth=2
+        )
+        for a, b in zip(walk, grid):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+
+    def test_pca_covariance_walk_matches_xla(self, rng):
+        n, d = 4096, 96
+        x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32) + 3.0)
+        m = jnp.asarray((rng.random(n) < 0.9).astype(np.float32))
+        nv = jnp.asarray(float(np.asarray(m).sum()))
+        cov_p, mean_p = covariance_pallas(x, m, nv, tile_rows=512, depth=2)
+        cov_r, mean_r = _covariance_jit(x, m, nv)
+        np.testing.assert_allclose(
+            np.asarray(mean_p), np.asarray(mean_r), atol=1e-4
+        )
+        np.testing.assert_allclose(
+            np.asarray(cov_p), np.asarray(cov_r), atol=1e-4
+        )
+
+    def test_als_walks_match_grid_kernels(self, rng):
+        from oap_mllib_tpu.ops.pallas.als_kernel import factor_gram_pallas
+
+        n, r = 4096, 10
+        mm = rng.normal(size=(n, r, r)).astype(np.float32)
+        a = jnp.asarray(np.einsum("nij,nkj->nik", mm, mm) + 0.5 * np.eye(r))
+        b = jnp.asarray(rng.normal(size=(n, r)).astype(np.float32))
+        n_reg = jnp.asarray(rng.integers(0, 40, n).astype(np.float32))
+        g = rng.normal(size=(64, r)).astype(np.float32)
+        gram = jnp.asarray(g.T @ g * 0.01)
+        grid = solve_normal_eq_pallas(a, b, n_reg, 0.1, gram)
+        walk = solve_normal_eq_pallas(
+            a, b, n_reg, 0.1, gram, batch=256, depth=2
+        )
+        assert np.array_equal(np.asarray(walk), np.asarray(grid))
+        f = jnp.asarray(rng.normal(size=(3706, r)).astype(np.float32))
+        assert np.array_equal(
+            np.asarray(factor_gram_pallas(f, tile_rows=512, depth=2)),
+            np.asarray(factor_gram_pallas(f)),
         )
 
 

@@ -1,6 +1,6 @@
 """Compiled-mode TPU tests: Mosaic-lowered Pallas kernels + precision tiers.
 
-Round-1 gap (VERDICT weak #2): every Pallas assertion ran interpret-only, so
+Round-1 gap: every Pallas assertion ran interpret-only, so
 a Mosaic lowering regression would ship green.  These tests compile the
 fused kernel for the real chip and hold it to the XLA path's results, and
 pin the "high" (bf16_3x) tier inside the 1e-4 parity envelope.
@@ -85,10 +85,9 @@ class TestXlaPrecisionTiers:
         assert abs(float(t1) - float(t2)) / float(t1) < 1e-4
 
     def test_auto_picks_pallas_for_deep_features(self, rng, monkeypatch):
-        """kmeans_kernel=auto routes the f32-accurate tiers to the fused
-        kernel (BASELINE.md kernel-table rule: pallas wins every profiled
-        shape at highest/high) — verified by counting calls, not
-        inferred."""
+        """kmeans_kernel=auto routes every tier whose blocks fit VMEM to
+        the fused kernel (kmeans_ops.pallas_preferred) — verified by
+        counting calls, not inferred."""
         if len(jax.devices()) != 1:
             pytest.skip("pallas estimator path requires a single device")
         import oap_mllib_tpu.ops.pallas.kmeans_kernel as pk
@@ -131,14 +130,68 @@ class TestXlaPrecisionTiers:
             m = KMeans(k=4, max_iter=10, seed=1).fit(x)
             assert m.summary.accelerated
             assert calls, "pallas kernel was configured but never invoked"
-            # auto at the "default" tier routes to XLA (kernel-table rule:
-            # XLA's all-bf16 pipeline wins that tier) — no new pallas call
+            assert m.summary.kernel == "pallas"
+            # auto prices the "default" tier ON Pallas too
+            # (kmeans_ops.pallas_preferred): one more pallas call, and a
+            # cost inside that tier's envelope of the f32 fit
             n_before = len(calls)
             set_config(kmeans_kernel="auto", matmul_precision="default")
             m2 = KMeans(k=4, max_iter=10, seed=1).fit(x)
-            assert len(calls) == n_before
+            assert len(calls) == n_before + 1
             np.testing.assert_allclose(
                 m.summary.training_cost, m2.summary.training_cost, rtol=1e-2
             )
+            # xla forces the chunked XLA Lloyd — no new pallas call
+            set_config(kmeans_kernel="xla", matmul_precision="highest")
+            m3 = KMeans(k=4, max_iter=10, seed=1).fit(x)
+            assert len(calls) == n_before + 1 and m3.summary.kernel == "xla"
         finally:
             set_config(kmeans_kernel="auto", matmul_precision="highest")
+
+
+class TestAssignmentIsF32:
+    """At ``highest`` the XLA assignment names the f32 nearest centre.
+    ``jnp.argmin`` did not on this compiler (its reduction's value output
+    is typed bfloat16; 1 served row in 4096 went to a centre 1.5e-2
+    farther), which is why the programs use ``kmeans_ops.argmin_rows``.
+    Centres come in pairs 0.02 apart, so each row has two candidates
+    whose d2 differ by ~0.008 near 23 — far inside bfloat16's step of
+    0.125, far outside f32 rounding.  A pair sits either side by side
+    (one 128-lane tile) or 500 ids apart (always two tiles)."""
+
+    @pytest.mark.parametrize("rows", [1024, 4096])
+    @pytest.mark.parametrize("pairs", ["adjacent", "split"])
+    @pytest.mark.parametrize("sheet", ["d2", "half-score"])
+    def test_argmin_rows_is_the_float64_nearest(self, rng, sheet, pairs,
+                                                rows):
+        from oap_mllib_tpu.ops import kmeans_ops
+        from oap_mllib_tpu.utils import precision as psn
+
+        d, k = 256, 1000
+        proto = rng.standard_normal((k // 2, d), dtype=np.float32)
+        delta = rng.standard_normal((k // 2, d), dtype=np.float32)
+        delta *= 0.02 / np.linalg.norm(delta, axis=1, keepdims=True)
+        c = np.empty((k, d), np.float32)
+        if pairs == "adjacent":
+            c[0::2], c[1::2] = proto, proto + delta
+        else:
+            c[: k // 2], c[k // 2:] = proto, proto + delta
+        x = proto[rng.integers(k // 2, size=rows)] + 0.3 * rng.standard_normal(
+            (rows, d), dtype=np.float32
+        )
+
+        def ids_of(xb, cb):
+            if sheet == "d2":
+                s = kmeans_ops.pairwise_sq_dists(xb, cb)
+            else:  # the Lloyd loop body's ranking sheet
+                s = 0.5 * jnp.sum(cb * cb, axis=1)[None, :] - psn.pdot(xb, cb.T)
+            return kmeans_ops.argmin_rows(s)
+
+        ids = np.asarray(jax.jit(ids_of)(jnp.asarray(x), jnp.asarray(c)))
+        x64, c64 = x.astype(np.float64), c.astype(np.float64)
+        x_sq, c_sq = (x64 * x64).sum(1), (c64 * c64).sum(1)
+        d2 = x_sq[:, None] + c_sq[None, :] - 2.0 * x64 @ c64.T
+        excess = d2[np.arange(rows), ids] - d2.min(axis=1)
+        # what f32 rounding of |x|^2 + |c|^2 - 2 x.c can explain
+        bound = 8 * np.finfo(np.float32).eps * (x_sq.max() + c_sq.max())
+        assert excess.max() <= bound, (excess.max(), bound)
