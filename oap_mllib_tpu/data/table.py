@@ -27,6 +27,7 @@ import numpy as np
 
 from oap_mllib_tpu.data.bucketing import bucket_rows
 from oap_mllib_tpu.parallel.mesh import data_sharding, pad_rows
+from oap_mllib_tpu.telemetry import spans
 
 # rows are padded per shard to this multiple (cheap: padding is masked)
 _ROW_MULTIPLE = 256
@@ -42,6 +43,20 @@ def _padded_row_target(n: int, multiple: int) -> int:
     return bucket_rows(n, multiple)
 
 
+def _upload(put, padded: np.ndarray, mask: np.ndarray, mesh):
+    """The ``upload`` sub-span of both constructors: ``put(host array,
+    sharding)`` for the table and its mask, then the wait for both —
+    ``device_put`` returns before the bytes land, and without the wait
+    the rest of the upload is booked to whichever phase first blocks on
+    the table.  ``attrs["bytes"]`` is what this process sent."""
+    with spans.child("upload") as span:
+        data = put(padded, data_sharding(mesh, 2))
+        mask_dev = put(mask, data_sharding(mesh, 1))
+        jax.block_until_ready((data, mask_dev))
+        span.attrs["bytes"] = padded.nbytes + mask.nbytes
+    return data, mask_dev
+
+
 @dataclasses.dataclass
 class DenseTable:
     """A row-sharded dense matrix with padded rows.
@@ -49,6 +64,14 @@ class DenseTable:
     ``data`` is (n_padded, d) sharded P(data, None) over the mesh;
     ``mask`` is (n_padded,) float (1.0 valid / 0.0 pad), sharded the same
     way so masked reductions stay local + psum.
+
+    The constructors split the phase that calls them (``table_convert``)
+    into two sub-spans (telemetry/spans.child): ``host_copy`` — dtype
+    copy, padding/densify, the mask: pure host — and ``upload``, which
+    ends when the bytes have LANDED (``block_until_ready``), not when
+    ``device_put`` returns, and carries their count in ``attrs["bytes"]``.
+    The wait costs no wall where the caller's next statement depends on
+    the table anyway (every in-memory fit's does).
     """
 
     data: jax.Array
@@ -74,44 +97,45 @@ class DenseTable:
         from oap_mllib_tpu.data import sparse as _sparse
 
         n_data = mesh.shape[mesh.axis_names[0]]
-        if _sparse.is_sparse(x):
-            # SciPy input: densify per row block straight into the
-            # padded table (data/sparse.densify_into) — peak host extra
-            # is the padded table + one block, never CSR + a second
-            # full dense copy
-            if x.ndim != 2:
-                raise ValueError(f"expected 2-D data, got shape {x.shape}")
-            n_valid = int(x.shape[0])
-            target = _padded_row_target(n_valid, n_data * _ROW_MULTIPLE)
-            out_dtype = np.dtype(
-                dtype if dtype is not None
-                else (x.dtype if x.dtype.kind == "f" else np.float64)
-            )
-            padded = np.zeros((target, int(x.shape[1])), out_dtype)
-            _sparse.densify_into(padded, x, n_valid)
-        else:
-            x = np.asarray(x)
-            if x.ndim != 2:
-                raise ValueError(f"expected 2-D data, got shape {x.shape}")
-            if dtype is not None:
-                x = x.astype(dtype)
-            # pad so every data-axis shard has equal rows AND, with
-            # bucketing on (the default), so the padded count lands on a
-            # geometric bucket — every fit whose rows share a bucket
-            # reuses one compiled program, and the bucketed count's
-            # power-of-two chunk factors feed the chunked Lloyd cleanly
-            padded, n_valid = pad_rows(
-                x, _padded_row_target(x.shape[0], n_data * _ROW_MULTIPLE)
-            )
-        mask = np.zeros((padded.shape[0],), dtype=padded.dtype)
-        mask[:n_valid] = 1.0
-        sharding2 = data_sharding(mesh, 2)
-        sharding1 = data_sharding(mesh, 1)
-        return cls(
-            data=jax.device_put(padded, sharding2),
-            mask=jax.device_put(mask, sharding1),
-            n_rows=n_valid,
-        )
+        with spans.child("host_copy"):
+            if _sparse.is_sparse(x):
+                # SciPy input: densify per row block straight into the
+                # padded table (data/sparse.densify_into) — peak host
+                # extra is the padded table + one block, never CSR + a
+                # second full dense copy
+                if x.ndim != 2:
+                    raise ValueError(
+                        f"expected 2-D data, got shape {x.shape}"
+                    )
+                n_valid = int(x.shape[0])
+                target = _padded_row_target(n_valid, n_data * _ROW_MULTIPLE)
+                out_dtype = np.dtype(
+                    dtype if dtype is not None
+                    else (x.dtype if x.dtype.kind == "f" else np.float64)
+                )
+                padded = np.zeros((target, int(x.shape[1])), out_dtype)
+                _sparse.densify_into(padded, x, n_valid)
+            else:
+                x = np.asarray(x)
+                if x.ndim != 2:
+                    raise ValueError(
+                        f"expected 2-D data, got shape {x.shape}"
+                    )
+                if dtype is not None:
+                    x = x.astype(dtype)
+                # pad so every data-axis shard has equal rows AND, with
+                # bucketing on (the default), so the padded count lands
+                # on a geometric bucket — every fit whose rows share a
+                # bucket reuses one compiled program, and the bucketed
+                # count's power-of-two chunk factors feed the chunked
+                # Lloyd cleanly
+                padded, n_valid = pad_rows(
+                    x, _padded_row_target(x.shape[0], n_data * _ROW_MULTIPLE)
+                )
+            mask = np.zeros((padded.shape[0],), dtype=padded.dtype)
+            mask[:n_valid] = 1.0
+        data, mask = _upload(jax.device_put, padded, mask, mesh)
+        return cls(data=data, mask=mask, n_rows=n_valid)
 
     @classmethod
     def from_process_local(cls, x_local: np.ndarray, mesh, dtype=None) -> "DenseTable":
@@ -127,27 +151,24 @@ class DenseTable:
         weight rows).  In a single-process world it's identical to
         ``from_numpy``.
         """
-        import jax
-
-        x_local = np.asarray(x_local)
-        if dtype is not None:
-            x_local = x_local.astype(dtype)
-        n_proc = getattr(jax, "process_count", lambda: 1)()
-        if n_proc == 1:
+        if jax.process_count() == 1:
             return cls.from_numpy(x_local, mesh, dtype)
         n_data = mesh.shape[mesh.axis_names[0]]
-        from oap_mllib_tpu.parallel.mesh import data_sharding
-
-        local_devices = max(1, n_data // n_proc)
-        # bucket per-process shards too: the allgathered max below then
-        # lands on a bucket, so multi-host tables amortize exactly like
-        # single-host ones (every process re-pads to the common max)
-        padded, n_valid_local = pad_rows(
-            x_local,
-            _padded_row_target(
-                x_local.shape[0], local_devices * _ROW_MULTIPLE
-            ),
-        )
+        local_devices = max(1, n_data // jax.process_count())
+        with spans.child("host_copy"):
+            x_local = np.asarray(x_local)
+            if dtype is not None:
+                x_local = x_local.astype(dtype)
+            # bucket per-process shards too: the allgathered max below
+            # then lands on a bucket, so multi-host tables amortize
+            # exactly like single-host ones (every process re-pads to
+            # the common max)
+            padded, n_valid_local = pad_rows(
+                x_local,
+                _padded_row_target(
+                    x_local.shape[0], local_devices * _ROW_MULTIPLE
+                ),
+            )
         # Per-process shards pad independently, so valid-row counts landing
         # in different padding buckets (e.g. 100 vs 1100 rows) would yield
         # UNEQUAL local shapes — breaking both the global-shape inference of
@@ -155,7 +176,8 @@ class DenseTable:
         # layout math in valid_to_padded/align_weights.  Allgather the
         # actual padded sizes (alongside the exact valid counts — summing
         # the f32 mask on device loses integers past 2^24) and re-pad every
-        # shard to the common max.
+        # shard to the common max.  The allgather waits on the peers and
+        # belongs to neither sub-span.
         from jax.experimental import multihost_utils
 
         gathered = np.asarray(
@@ -165,19 +187,20 @@ class DenseTable:
         ).reshape(-1, 2)
         counts = gathered[:, 0]
         target = int(gathered[:, 1].max())
-        if padded.shape[0] < target:
-            padded = np.concatenate(
-                [padded,
-                 np.zeros((target - padded.shape[0], padded.shape[1]),
-                          padded.dtype)]
-            )
-        mask_local = np.zeros((padded.shape[0],), dtype=padded.dtype)
-        mask_local[:n_valid_local] = 1.0
-        data = jax.make_array_from_process_local_data(
-            data_sharding(mesh, 2), padded
-        )
-        mask = jax.make_array_from_process_local_data(
-            data_sharding(mesh, 1), mask_local
+        with spans.child("host_copy"):
+            if padded.shape[0] < target:
+                padded = np.concatenate(
+                    [padded,
+                     np.zeros((target - padded.shape[0], padded.shape[1]),
+                              padded.dtype)]
+                )
+            mask_local = np.zeros((padded.shape[0],), dtype=padded.dtype)
+            mask_local[:n_valid_local] = 1.0
+        data, mask = _upload(
+            lambda host, sharding: jax.make_array_from_process_local_data(
+                sharding, host
+            ),
+            padded, mask_local, mesh,
         )
         return cls(
             data=data,

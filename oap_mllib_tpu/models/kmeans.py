@@ -617,7 +617,9 @@ class KMeans:
                 if jax.process_count() > 1
                 else DenseTable.from_numpy
             )
-            table = make(x.astype(dtype), mesh)
+            # the dtype copy is the constructor's, inside its host_copy
+            # sub-span (data/table.py)
+            table = make(x, mesh, dtype)
             weights = table.mask
             if sample_weight is not None:
                 # collective path: multi-host shards pad per process, so the

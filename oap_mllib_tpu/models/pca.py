@@ -451,7 +451,9 @@ class PCA:
                     if jax.process_count() > 1
                     else DenseTable.from_numpy
                 )
-                table = make(x.astype(dtype), mesh)
+                # the dtype copy is the constructor's, inside its host_copy
+                # sub-span (data/table.py)
+                table = make(x, mesh, dtype)
             with phase_timer(timings, "covariance"):
                 n_rows = jnp.asarray(float(table.n_rows), dtype)
                 # x64 lane pins the Gram to HIGHEST regardless of tier

@@ -37,6 +37,7 @@ import numpy as np
 from jax import lax
 from oap_mllib_tpu.utils import precision as psn
 from oap_mllib_tpu.parallel import collective
+from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import progcache
 from oap_mllib_tpu.utils.jax_compat import shard_map
 
@@ -739,6 +740,7 @@ def _slot_chunk_size(cap: int, target: int = 1024) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "chunk"))
+@jax.named_scope("kmeans.pll_round")
 def _pll_round(x, w, dmin, amin, base_id, key, l, cap, chunk):
     """One k-means|| sampling round, entirely on device.
 
@@ -790,6 +792,7 @@ def _pll_round(x, w, dmin, amin, base_id, key, l, cap, chunk):
 
 
 @functools.partial(jax.jit, static_argnames=("n_cand",))
+@jax.named_scope("kmeans.candidate_weights")
 def _candidate_weights(w, amin, n_cand: int):
     """Total row weight owned by each candidate (global segment-sum)."""
     return jnp.zeros((n_cand,), w.dtype).at[amin].add(w)
@@ -819,57 +822,65 @@ def init_kmeans_parallel(
     rng = np.random.default_rng(seed)
     n, d = x_dev.shape
 
-    # first center: uniform valid row (index_map: valid -> padded layout)
-    first = np.asarray([rng.integers(n_valid)])
-    if index_map is not None:
-        first = np.asarray(index_map(first))
-    c0 = _gather_rows(x_dev, first)  # (1, d)
+    # everything that waits on the device: the seed-row gather, the
+    # rounds with their phi and slot fetches, the candidate weights
+    with spans.child("rounds") as span:
+        # first center: uniform valid row (index_map: valid -> padded layout)
+        first = np.asarray([rng.integers(n_valid)])
+        if index_map is not None:
+            first = np.asarray(index_map(first))
+        c0 = _gather_rows(x_dev, first)  # (1, d)
 
-    l = jnp.asarray(2.0 * k, jnp.float32)  # Spark's oversampling factor
-    cap = 4 * k  # per-round slot buffer
-    chunk = _slot_chunk_size(cap)
-    key = jax.random.PRNGKey(seed)
+        l = jnp.asarray(2.0 * k, jnp.float32)  # Spark's oversampling factor
+        cap = 4 * k  # per-round slot buffer
+        chunk = _slot_chunk_size(cap)
+        key = jax.random.PRNGKey(seed)
 
-    # running state: distances/assignments vs candidate 0
-    d2_0 = pairwise_sq_dists(x_dev, jnp.asarray(c0))[:, 0]
-    dmin = d2_0
-    amin = jnp.zeros((n,), jnp.int32)
+        # running state: distances/assignments vs candidate 0
+        d2_0 = pairwise_sq_dists(x_dev, jnp.asarray(c0))[:, 0]
+        dmin = d2_0
+        amin = jnp.zeros((n,), jnp.int32)
 
-    all_slots = [np.asarray(c0)]
-    all_valid = [np.ones((1,), np.float32)]
-    base = 1
-    for step in range(init_steps):
-        slots, slot_valid, dmin, amin, phi = _pll_round(
-            x_dev, weights_dev, dmin, amin,
-            jnp.asarray(base, jnp.int32),
-            jax.random.fold_in(key, step), l, cap, chunk,
-        )
-        if float(phi) <= 0.0:
-            break
-        # small host fetch, re-replicated if GSPMD left the output sharded
-        all_slots.append(_to_host(slots))
-        all_valid.append(_to_host(slot_valid))
-        base += cap
+        all_slots = [np.asarray(c0)]
+        all_valid = [np.ones((1,), np.float32)]
+        base = 1
+        for step in range(init_steps):
+            slots, slot_valid, dmin, amin, phi = _pll_round(
+                x_dev, weights_dev, dmin, amin,
+                jnp.asarray(base, jnp.int32),
+                jax.random.fold_in(key, step), l, cap, chunk,
+            )
+            if float(phi) <= 0.0:
+                break
+            # small host fetch, re-replicated if GSPMD left the output sharded
+            all_slots.append(_to_host(slots))
+            all_valid.append(_to_host(slot_valid))
+            base += cap
 
-    cand = np.concatenate(all_slots, axis=0)
-    valid = np.concatenate(all_valid, axis=0) > 0
-    cand_w = _to_host(_candidate_weights(weights_dev, amin, base))[: len(cand)]
-    cand, cand_w = cand[valid], cand_w[valid]
+        cand = np.concatenate(all_slots, axis=0)
+        valid = np.concatenate(all_valid, axis=0) > 0
+        cand_w = _to_host(
+            _candidate_weights(weights_dev, amin, base)
+        )[: len(cand)]
+        cand, cand_w = cand[valid], cand_w[valid]
+        span.attrs["rounds"] = len(all_slots) - 1
 
-    if cand.shape[0] <= k:
-        # not enough candidates: top up with random rows
-        extra = init_random(
-            x_dev, n_valid, k - cand.shape[0] + 1, seed + 1, index_map
-        )
-        cand = np.concatenate([cand, extra], axis=0)[: max(k, 1)]
-        return (
-            cand[:k]
-            if cand.shape[0] >= k
-            else np.resize(cand, (k, cand.shape[1]))
-        )
-
-    # weight candidates by how much row weight they own, k-means++ reduce
-    return _weighted_kmeans_pp(cand, cand_w, k, rng)
+    # the host's reduction of the candidates to k centers
+    with spans.child("kmeanspp_host") as span:
+        span.attrs["candidates"] = int(cand.shape[0])
+        if cand.shape[0] <= k:
+            # not enough candidates: top up with random rows
+            extra = init_random(
+                x_dev, n_valid, k - cand.shape[0] + 1, seed + 1, index_map
+            )
+            cand = np.concatenate([cand, extra], axis=0)[: max(k, 1)]
+            return (
+                cand[:k]
+                if cand.shape[0] >= k
+                else np.resize(cand, (k, cand.shape[1]))
+            )
+        # weight candidates by how much row weight they own, k-means++ reduce
+        return _weighted_kmeans_pp(cand, cand_w, k, rng)
 
 
 def _weighted_kmeans_pp(points: np.ndarray, weights: np.ndarray, k: int, rng) -> np.ndarray:

@@ -259,6 +259,7 @@ def _pallas_accumulate_dbuf(x, w, centers, mode, interpret, need_cost,
             depth, [(tile_rows, d), (tile_rows // LANE, LANE)]
         ),
         interpret=interpret,
+        name="kmeans_accumulate_walk",
         **compiled_kwargs(
             interpret, vmem_limit_bytes=VMEM_LIMIT_BYTES,
             has_side_effects=True,
@@ -493,13 +494,15 @@ def _lloyd_loop_padded(x_p, w_p, c_p, max_iter, tol, mode="highest",
         return new_centers, it + 1, converged
 
     state = (c_p, jnp.asarray(0, jnp.int32), jnp.asarray(False))
-    centers, n_iter, _ = jax.lax.while_loop(cond, body, state)
+    with jax.named_scope("kmeans.lloyd_walk"):
+        centers, n_iter, _ = jax.lax.while_loop(cond, body, state)
     # final cost + counts w.r.t. the returned centers, always at full
     # precision — the user-facing objective should not carry the fast
     # tiers' distance error
-    _, counts, cost = _accum_any(
-        x_p, w_p, centers, "highest", interpret, True, tile_rows, depth
-    )
+    with jax.named_scope("kmeans.lloyd_cost"):
+        _, counts, cost = _accum_any(
+            x_p, w_p, centers, "highest", interpret, True, tile_rows, depth
+        )
     return centers, n_iter, cost[0, 0], counts[0]
 
 

@@ -22,11 +22,14 @@ orderings are deterministic accounting, never wall-clock timestamps.
 A thread-local *active span* stack lets deeper layers attach to whatever
 phase is running without threading a handle through every signature —
 the collective facade (parallel/collective.py) books its per-op bytes
-and dispatch wall onto ``current_span()``.  When a ``jax.profiler``
-trace is active (utils/profiling.py), entering a span also emits a
-``jax.profiler.TraceAnnotation`` so the same names line up in
-TensorBoard/XProf; with no trace running the annotation is skipped
-behind one module-level bool — the telemetry-off cheap-guard contract.
+and dispatch wall onto ``current_span()``, and ``data/`` and ``ops/``
+open sub-spans of the running phase through :func:`child`
+(``table_convert/upload``, ``init_centers/rounds``).  When a
+``jax.profiler`` trace is active (utils/profiling.py), entering a span
+also emits a ``jax.profiler.TraceAnnotation`` named by the span's path
+below the fit root, so the same names line up in TensorBoard/XProf;
+with no trace running the annotation is skipped behind one module-level
+bool — the telemetry-off cheap-guard contract.
 """
 
 from __future__ import annotations
@@ -48,13 +51,16 @@ class Span:
     the node, same totals).  ``count`` is the number of explicit
     recordings; implicitly-created path containers keep ``count == 0``
     and are excluded from the flat views, matching the old record list
-    (which only ever held explicitly-added phases).
+    (which only ever held explicitly-added phases).  ``path`` is the
+    ``a/b`` path below the tree's root (empty on a root): the key of the
+    flat views and the name of the span's trace annotation.
     """
 
-    __slots__ = ("name", "duration_s", "count", "attrs", "children")
+    __slots__ = ("name", "path", "duration_s", "count", "attrs", "children")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, path: str = ""):
         self.name = name
+        self.path = path
         self.duration_s = 0.0
         self.count = 0
         self.attrs: Dict[str, Any] = {}
@@ -66,7 +72,7 @@ class Span:
         for c in self.children:
             if c.name == name:
                 return c
-        c = Span(name)
+        c = Span(name, self.path + _SEP + name if self.path else name)
         self.children.append(c)
         return c
 
@@ -153,7 +159,9 @@ def enter(span: Span, annotate: bool = True):
     """Time one entry of ``span``: push it as the thread's active span,
     record the monotonic wall on exit, and — only when a jax.profiler
     trace is running (one bool check) — emit a TraceAnnotation so the
-    span shows up on the XProf timeline under the same name.  With the
+    span shows up on the XProf timeline under its path below the root
+    (``table_convert``, ``table_convert/upload``: a top-level phase
+    keeps its bare name, a sub-span says whose it is).  With the
     flight recorder armed (telemetry/flightrec.py — one config check
     when off), span open/close land in the event ring so post-mortems
     and merged timelines see which phases were in flight."""
@@ -170,7 +178,7 @@ def enter(span: Span, annotate: bool = True):
         if profiling.trace_active():
             import jax
 
-            ann = jax.profiler.TraceAnnotation(span.name)
+            ann = jax.profiler.TraceAnnotation(span.path or span.name)
             ann.__enter__()
     if flightrec.enabled():
         flightrec.record("span_open", span.name)
@@ -185,3 +193,19 @@ def enter(span: Span, annotate: bool = True):
         if ann is not None:
             ann.__exit__(None, None, None)
         stack.pop()
+
+
+@contextlib.contextmanager
+def child(name: str):
+    """Time ``name`` as a sub-span of the thread's active span: how
+    ``data/`` and ``ops/`` split the phase that called them
+    (``table_convert/upload``, ``init_centers/rounds``) without a
+    ``timings`` handle in their signatures and without reading a clock.
+    Outside any fit nothing is timed or pushed; the span yielded then is
+    a detached one, so a call site sets ``attrs`` unconditionally."""
+    parent = current_span()
+    if parent is None:
+        yield Span(name)
+        return
+    with enter(parent.child(name)) as span:
+        yield span

@@ -64,10 +64,3 @@ def maybe_trace():
         return
     with trace(log_dir):
         yield
-
-
-def annotate(name: str):
-    """Named sub-span inside a trace (jax.profiler.TraceAnnotation)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

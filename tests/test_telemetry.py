@@ -431,6 +431,136 @@ class TestFitSummaryTelemetry:
         assert "kmeans.fit/lloyd_loop/compute" in paths
 
 
+SUB_SPANS = (
+    "table_convert/host_copy", "table_convert/upload",
+    "init_centers/rounds", "init_centers/kmeanspp_host",
+)
+
+
+class TestSubSpans:
+    """The host-bound phases of a fit are split where the work happens
+    (ISSUE 25): data/table.py and ops/kmeans_ops.py open sub-spans of
+    the running phase through ``spans.child``."""
+
+    @pytest.fixture
+    def kmeans_fit(self, rng, monkeypatch):
+        """(model, the DenseTable the fit built)."""
+        from oap_mllib_tpu import KMeans
+        from oap_mllib_tpu.data.table import DenseTable
+
+        built = []
+        make = DenseTable.from_numpy.__func__
+
+        def spy(cls, *a, **kw):
+            built.append(make(cls, *a, **kw))
+            return built[-1]
+
+        monkeypatch.setattr(DenseTable, "from_numpy", classmethod(spy))
+        x = rng.normal(size=(700, 6)).astype(np.float32)
+        model = KMeans(k=4, max_iter=3, seed=0).fit(x)
+        assert model.summary.accelerated and len(built) == 1
+        return model, built[0]
+
+    @pytest.mark.parametrize("path", SUB_SPANS)
+    def test_kmeans_fit_records_sub_span(self, kmeans_fit, path):
+        flat = kmeans_fit[0].summary.timings.as_dict()
+        assert flat[path] > 0
+
+    @pytest.mark.parametrize("parent", ["table_convert", "init_centers"])
+    def test_children_cover_their_parent(self, kmeans_fit, parent):
+        timings = kmeans_fit[0].summary.timings
+        whole = timings.as_dict()[parent]
+        parts = sum(
+            sec for sub, sec in timings.subphases(parent).items()
+            if parent + "/" + sub in SUB_SPANS
+        )
+        assert parts <= whole
+        assert whole - parts <= max(0.05 * whole, 2e-3)
+
+    def test_counters_at_the_boundaries(self, kmeans_fit):
+        model, table = kmeans_fit
+        root = model.summary.timings.root
+        assert root.node("table_convert/upload").attrs["bytes"] == (
+            table.data.nbytes + table.mask.nbytes
+        )
+        assert root.node("init_centers/rounds").attrs["rounds"] == 2
+        cand = root.node("init_centers/kmeanspp_host").attrs["candidates"]
+        assert 4 < cand <= 1 + 2 * 16  # one seed row + 4k slots a round
+        # the tree the exporters serialize carries them too
+        tree = dict(_tree_paths(model.summary.telemetry["spans"]))
+        up = tree["kmeans.fit/table_convert/upload"]
+        assert up["attrs"]["bytes"] == table.data.nbytes + table.mask.nbytes
+
+    def test_pca_fit_records_the_staging_pair(self, rng):
+        from oap_mllib_tpu import PCA
+
+        x = rng.normal(size=(400, 6)).astype(np.float32)
+        flat = PCA(k=2).fit(x).summary["timings"].as_dict()
+        assert flat["table_convert/host_copy"] > 0
+        assert flat["table_convert/upload"] > 0
+        assert (
+            flat["table_convert/host_copy"] + flat["table_convert/upload"]
+            <= flat["table_convert"]
+        )
+
+    def test_child_outside_a_fit_is_a_no_op(self):
+        from oap_mllib_tpu.telemetry import spans
+
+        assert current_span() is None
+        with spans.child("upload") as sp:
+            assert current_span() is None
+            sp.attrs["bytes"] = 1  # call sites never ask whether it is live
+        assert current_span() is None
+        assert sp.count == 0 and sp.duration_s == 0.0
+
+    def test_child_nests_under_the_active_span(self):
+        from oap_mllib_tpu.telemetry import spans
+
+        t = Timings("kmeans.fit")
+        with phase_timer(t, "table_convert"):
+            with spans.child("upload") as sp:
+                assert current_span() is sp
+                with spans.child("dma") as inner:
+                    pass
+        assert sp.path == "table_convert/upload"
+        assert inner.path == "table_convert/upload/dma"
+        assert set(t.as_dict()) == {
+            "table_convert", "table_convert/upload",
+            "table_convert/upload/dma",
+        }
+
+    def test_trace_annotations_are_named_by_path(self, monkeypatch):
+        """Under a live trace a sub-span's annotation says whose it is;
+        a top-level phase keeps its bare name (the benchmark's trace
+        reduction finds the phases by it)."""
+        import contextlib
+
+        import jax
+
+        from oap_mllib_tpu.telemetry import spans
+        from oap_mllib_tpu.utils import profiling
+
+        seen = []
+        monkeypatch.setattr(
+            jax.profiler, "TraceAnnotation",
+            lambda name: seen.append(name) or contextlib.nullcontext(),
+        )
+        t = Timings("kmeans.fit")
+        with phase_timer(t, "table_convert"), spans.child("upload"):
+            pass
+        assert seen == []  # no trace running: no annotation at all
+        monkeypatch.setattr(profiling, "_active", 1)
+        with phase_timer(t, "table_convert"), spans.child("upload"):
+            pass
+        with phase_timer(t, "lloyd_loop"):
+            pass
+        with enter(Span("bare")):
+            pass
+        assert seen == [
+            "table_convert", "table_convert/upload", "lloyd_loop", "bare",
+        ]
+
+
 class TestCompatSurfaces:
     def test_drop_in_summary_exposes_telemetry(self, rng):
         """The compat layers proxy the inner summaries, so the span tree
