@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from oap_mllib_tpu.data.bucketing import bucket_rows
-from oap_mllib_tpu.parallel.mesh import data_sharding, pad_rows
+from oap_mllib_tpu.parallel.mesh import data_sharding
 from oap_mllib_tpu.telemetry import spans
 
 # rows are padded per shard to this multiple (cheap: padding is masked)
@@ -41,6 +41,33 @@ def _padded_row_target(n: int, multiple: int) -> int:
     multiple * 2^j, i.e. highly divisible — which is exactly what
     auto_row_chunks / _accumulate_chunked want to see."""
     return bucket_rows(n, multiple)
+
+
+def _stage_rows(x, multiple: int, dtype):
+    """The host side of both ndarray constructors: the array to upload,
+    its valid-row count, and the bytes copied to make it.  Decided from
+    the input alone: ``x`` itself (no allocation, no pass over the
+    table) when its dtype matches, it is C-contiguous and its rows sit
+    on their bucket; otherwise ONE pass into a fresh array of the
+    bucket's shape — the assignment casts and un-strides, and only the
+    tail rows are zeroed."""
+    x = np.asarray(x)
+    if x.ndim != 2:
+        raise ValueError(f"expected 2-D data, got shape {x.shape}")
+    dtype = np.dtype(dtype if dtype is not None else x.dtype)
+    n = x.shape[0]
+    # pad so every data-axis shard has equal rows AND, with bucketing on
+    # (the default), so the padded count lands on a geometric bucket —
+    # every fit whose rows share a bucket reuses one compiled program,
+    # and the bucketed count's power-of-two chunk factors feed the
+    # chunked Lloyd cleanly
+    target = _padded_row_target(n, multiple)
+    if x.dtype == dtype and x.flags.c_contiguous and n == target:
+        return x, n, 0
+    padded = np.empty((target, x.shape[1]), dtype)
+    padded[:n] = x
+    padded[n:] = 0
+    return padded, n, padded.nbytes
 
 
 def _upload(put, padded: np.ndarray, mask: np.ndarray, mesh):
@@ -66,12 +93,24 @@ class DenseTable:
     way so masked reductions stay local + psum.
 
     The constructors split the phase that calls them (``table_convert``)
-    into two sub-spans (telemetry/spans.child): ``host_copy`` — dtype
-    copy, padding/densify, the mask: pure host — and ``upload``, which
-    ends when the bytes have LANDED (``block_until_ready``), not when
-    ``device_put`` returns, and carries their count in ``attrs["bytes"]``.
-    The wait costs no wall where the caller's next statement depends on
-    the table anyway (every in-memory fit's does).
+    into two sub-spans (telemetry/spans.child): ``host_copy`` — the one
+    pass that casts, un-strides and pads (``_stage_rows``) or densifies,
+    and the mask: pure host, with what the pass wrote in
+    ``attrs["copied_bytes"]`` — and ``upload``, which ends when the
+    bytes have LANDED (``block_until_ready``), not when ``device_put``
+    returns, and carries their count in ``attrs["bytes"]``.  The wait
+    costs no wall where the caller's next statement depends on the table
+    anyway (every in-memory fit's does).
+
+    The caller's array is uploaded AS IS, with no host copy
+    (``copied_bytes`` 0), when it already has the table's dtype, is
+    C-contiguous and has a row count on its bucket; it must not be
+    mutated until the constructor returns.  The program never writes
+    into it.  Off the CPU ``data`` is a device buffer of its own from
+    then on.  On the CPU backend ``device_put`` SHARES a host buffer
+    that is 64-byte aligned instead of copying it (jax 0.9.0), so there
+    an array uploaded as is must stay unchanged while the table lives —
+    inside ``fit`` it does: the table dies with the fit.
     """
 
     data: jax.Array
@@ -94,10 +133,14 @@ class DenseTable:
 
     @classmethod
     def from_numpy(cls, x: np.ndarray, mesh, dtype=None) -> "DenseTable":
+        """The table of a host array (or SciPy matrix) ``x`` on ``mesh``,
+        as ``dtype`` (``x``'s own when None).  At most one host pass
+        over the rows, and none when ``x`` can go up as it is (class
+        docstring): do not mutate ``x`` until this returns."""
         from oap_mllib_tpu.data import sparse as _sparse
 
         n_data = mesh.shape[mesh.axis_names[0]]
-        with spans.child("host_copy"):
+        with spans.child("host_copy") as span:
             if _sparse.is_sparse(x):
                 # SciPy input: densify per row block straight into the
                 # padded table (data/sparse.densify_into) — peak host
@@ -115,23 +158,12 @@ class DenseTable:
                 )
                 padded = np.zeros((target, int(x.shape[1])), out_dtype)
                 _sparse.densify_into(padded, x, n_valid)
+                copied = padded.nbytes
             else:
-                x = np.asarray(x)
-                if x.ndim != 2:
-                    raise ValueError(
-                        f"expected 2-D data, got shape {x.shape}"
-                    )
-                if dtype is not None:
-                    x = x.astype(dtype)
-                # pad so every data-axis shard has equal rows AND, with
-                # bucketing on (the default), so the padded count lands
-                # on a geometric bucket — every fit whose rows share a
-                # bucket reuses one compiled program, and the bucketed
-                # count's power-of-two chunk factors feed the chunked
-                # Lloyd cleanly
-                padded, n_valid = pad_rows(
-                    x, _padded_row_target(x.shape[0], n_data * _ROW_MULTIPLE)
+                padded, n_valid, copied = _stage_rows(
+                    x, n_data * _ROW_MULTIPLE, dtype
                 )
+            span.attrs["copied_bytes"] = copied
             mask = np.zeros((padded.shape[0],), dtype=padded.dtype)
             mask[:n_valid] = 1.0
         data, mask = _upload(jax.device_put, padded, mask, mesh)
@@ -156,18 +188,12 @@ class DenseTable:
         n_data = mesh.shape[mesh.axis_names[0]]
         local_devices = max(1, n_data // jax.process_count())
         with spans.child("host_copy"):
-            x_local = np.asarray(x_local)
-            if dtype is not None:
-                x_local = x_local.astype(dtype)
             # bucket per-process shards too: the allgathered max below
             # then lands on a bucket, so multi-host tables amortize
             # exactly like single-host ones (every process re-pads to
             # the common max)
-            padded, n_valid_local = pad_rows(
-                x_local,
-                _padded_row_target(
-                    x_local.shape[0], local_devices * _ROW_MULTIPLE
-                ),
+            padded, n_valid_local, copied = _stage_rows(
+                x_local, local_devices * _ROW_MULTIPLE, dtype
             )
         # Per-process shards pad independently, so valid-row counts landing
         # in different padding buckets (e.g. 100 vs 1100 rows) would yield
@@ -187,13 +213,15 @@ class DenseTable:
         ).reshape(-1, 2)
         counts = gathered[:, 0]
         target = int(gathered[:, 1].max())
-        with spans.child("host_copy"):
+        with spans.child("host_copy") as span:
             if padded.shape[0] < target:
                 padded = np.concatenate(
                     [padded,
                      np.zeros((target - padded.shape[0], padded.shape[1]),
                               padded.dtype)]
                 )
+                copied += padded.nbytes
+            span.attrs["copied_bytes"] = copied
             mask_local = np.zeros((padded.shape[0],), dtype=padded.dtype)
             mask_local[:n_valid_local] = 1.0
         data, mask = _upload(

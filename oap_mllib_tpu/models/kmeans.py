@@ -617,8 +617,8 @@ class KMeans:
                 if jax.process_count() > 1
                 else DenseTable.from_numpy
             )
-            # the dtype copy is the constructor's, inside its host_copy
-            # sub-span (data/table.py)
+            # a cast or pad, where x needs one, is the constructor's,
+            # inside its host_copy sub-span (data/table.py)
             table = make(x, mesh, dtype)
             weights = table.mask
             if sample_weight is not None:

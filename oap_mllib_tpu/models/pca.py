@@ -451,8 +451,8 @@ class PCA:
                     if jax.process_count() > 1
                     else DenseTable.from_numpy
                 )
-                # the dtype copy is the constructor's, inside its host_copy
-                # sub-span (data/table.py)
+                # a cast or pad, where x needs one, is the constructor's,
+                # inside its host_copy sub-span (data/table.py)
                 table = make(x, mesh, dtype)
             with phase_timer(timings, "covariance"):
                 n_rows = jnp.asarray(float(table.n_rows), dtype)

@@ -1,0 +1,225 @@
+"""The staging rule of ``DenseTable``'s ndarray constructors (ISSUE 26):
+at most ONE host pass over the table before the upload, and none when
+dtype, layout and row bucket already match — the caller's array itself is
+what ``_upload`` receives then."""
+
+import tracemalloc
+
+import jax
+import numpy as np
+import pytest
+
+from oap_mllib_tpu.data import table as table_mod
+from oap_mllib_tpu.data.table import DenseTable
+from oap_mllib_tpu.parallel.mesh import get_mesh
+from oap_mllib_tpu.utils.timing import Timings, phase_timer
+
+D = 6
+# (dtype of x, dtype asked of the table)
+DTYPES = {
+    "match": (np.float32, np.float32),
+    "f64_to_f32": (np.float64, np.float32),
+    "int": (np.int32, np.float32),
+}
+LAYOUTS = ("c", "fortran", "strided", "readonly")
+CONSTRUCTORS = ("from_numpy", "from_process_local")
+
+
+def _bucket(mesh):
+    """The smallest row bucket of the suite's mesh."""
+    return mesh.shape[mesh.axis_names[0]] * table_mod._ROW_MULTIPLE
+
+
+def _make(rng, n, src_dtype, layout):
+    base = (rng.normal(size=(2 * n, 2 * D)) * 100).astype(src_dtype)
+    if layout == "strided":
+        x = base[::2, ::2]
+        assert not x.flags.c_contiguous and not x.flags.f_contiguous
+        return x
+    x = np.ascontiguousarray(base[:n, :D])
+    if layout == "fortran":
+        x = np.asfortranarray(x)
+        assert not x.flags.c_contiguous
+    elif layout == "readonly":
+        x.flags.writeable = False
+    return x
+
+
+def _aligned(rng, shape, offset):
+    """A C-contiguous float32 array whose first byte sits ``offset`` past
+    a 64-byte boundary."""
+    nbytes = int(np.prod(shape)) * 4
+    buf = np.empty(nbytes + 128, np.uint8)
+    start = (-buf.ctypes.data) % 64 + offset
+    x = buf[start:start + nbytes].view(np.float32).reshape(shape)
+    x[:] = rng.normal(size=shape)
+    assert x.flags.c_contiguous and x.ctypes.data % 64 == offset
+    return x
+
+
+@pytest.fixture
+def staged(monkeypatch):
+    """Build a table inside a ``table_convert`` phase; returns
+    ``build(constructor, x, dtype) -> (table, the host array _upload
+    received, the host_copy span)``."""
+    uploads = []
+    upload = table_mod._upload
+
+    def spy(put, padded, mask, mesh):
+        uploads.append(padded)
+        return upload(put, padded, mask, mesh)
+
+    monkeypatch.setattr(table_mod, "_upload", spy)
+
+    def build(constructor, x, dtype):
+        timings = Timings("test.fit")
+        with phase_timer(timings, "table_convert"):
+            table = getattr(DenseTable, constructor)(x, get_mesh(), dtype)
+        assert len(uploads) == 1
+        return (
+            table, uploads.pop(),
+            timings.root.node("table_convert/host_copy"),
+        )
+
+    return build
+
+
+@pytest.mark.parametrize("constructor", CONSTRUCTORS)
+@pytest.mark.parametrize("rows", ["on_bucket", "off_bucket"])
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("dtypes", sorted(DTYPES))
+def test_staging_makes_at_most_one_pass(
+    rng, staged, dtypes, layout, rows, constructor
+):
+    src_dtype, dtype = DTYPES[dtypes]
+    target = _bucket(get_mesh())
+    n = target if rows == "on_bucket" else target - 548
+    x = _make(rng, n, src_dtype, layout)
+    before = x.copy()
+
+    table, sent, span = staged(constructor, x, dtype)
+
+    zero_pass = (
+        dtypes == "match" and layout in ("c", "readonly")
+        and rows == "on_bucket"
+    )
+    if zero_pass:
+        assert sent is x
+        assert span.attrs["copied_bytes"] == 0
+    else:
+        # one new array of the bucket's shape, cast, un-strided, padded
+        assert not np.shares_memory(sent, x)
+        assert sent.shape == (target, D) and sent.dtype == dtype
+        assert sent.flags.c_contiguous
+        np.testing.assert_array_equal(sent[:n], before.astype(dtype))
+        assert not sent[n:].any()
+        assert span.attrs["copied_bytes"] == sent.nbytes
+    assert span.duration_s > 0 and span.count == 1
+    # the same bytes reach the device either way, and the caller's array
+    # is as it was
+    assert table.n_rows == n and table.n_padded == target
+    assert table.data.dtype == dtype
+    np.testing.assert_array_equal(table.to_numpy(), before.astype(dtype))
+    mask = np.asarray(table.mask)
+    assert mask[:n].all() and not mask[n:].any()
+    assert x.tobytes() == before.tobytes()
+
+
+@pytest.mark.parametrize("dtypes", ["f64_to_f32", "int", "match"])
+def test_one_pass_allocates_one_table(rng, dtypes):
+    """A cast AND a pad are one allocation of the padded table, not a
+    cast copy followed by a padded copy."""
+    src_dtype, dtype = DTYPES[dtypes]
+    x = (rng.normal(size=(1500, 64)) * 100).astype(src_dtype)[:, ::2]
+    tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        padded, n, copied = table_mod._stage_rows(x, 2048, dtype)
+        peak = tracemalloc.get_traced_memory()[1] - base
+    finally:
+        tracemalloc.stop()
+    assert n == 1500 and copied == padded.nbytes == 2048 * 32 * 4
+    assert padded.nbytes <= peak < 1.25 * padded.nbytes
+
+
+def test_no_dtype_asked_keeps_the_callers(rng, staged):
+    x = rng.normal(size=(_bucket(get_mesh()), D))  # float64
+    table, sent, span = staged("from_numpy", x, None)
+    assert sent is x and span.attrs["copied_bytes"] == 0
+    assert table.n_rows == x.shape[0]
+
+
+@pytest.mark.parametrize("constructor", CONSTRUCTORS)
+def test_not_two_dimensional_raises(constructor):
+    with pytest.raises(ValueError, match="2-D"):
+        getattr(DenseTable, constructor)(
+            np.zeros(8, np.float32), get_mesh(), np.float32
+        )
+
+
+@pytest.mark.parametrize("offset", [0, 16], ids=["aligned64", "unaligned"])
+def test_mutating_x_after_the_constructor_returns(rng, staged, offset):
+    """Off the CPU the table is a device buffer of its own.  On the CPU
+    backend (jax 0.9.0) ``device_put`` copies a host buffer unless it is
+    64-byte aligned, and SHARES an aligned one — which the old
+    unconditional ``astype`` copy made irrelevant.  The table is a
+    snapshot wherever jax copied; the class docstring says what holds
+    where it did not."""
+    x = _aligned(rng, (_bucket(get_mesh()), D), offset)
+    want = x.copy()
+    table, sent, _ = staged("from_numpy", x, np.float32)
+    assert sent is x  # the zero-pass route: nothing of ours in between
+    shared = any(
+        np.shares_memory(np.asarray(s.data), x)
+        for s in table.data.addressable_shards
+    )
+    x += 1.0
+    if offset:
+        assert not shared
+    if not shared:
+        np.testing.assert_array_equal(table.to_numpy(), want)
+    else:
+        assert jax.default_backend() == "cpu"
+
+
+@pytest.fixture
+def blobs(rng):
+    """On the bucket, float32, C-contiguous: the zero-pass route."""
+    n = _bucket(get_mesh())
+    centres = rng.normal(size=(4, D)) * 10
+    x = centres[rng.integers(4, size=n)] + rng.normal(size=(n, D))
+    return x.astype(np.float32)
+
+
+def _fit(estimator, x):
+    from oap_mllib_tpu import KMeans, PCA
+
+    if estimator == "kmeans":
+        model = KMeans(k=4, max_iter=3, seed=0).fit(x)
+        assert model.summary.accelerated
+        return model.cluster_centers_, model.summary.timings
+    model = PCA(k=2).fit(x)
+    return model.components_, model.summary["timings"]
+
+
+@pytest.mark.parametrize("estimator", ["kmeans", "pca"])
+def test_fit_leaves_the_callers_array_as_it_was(blobs, estimator):
+    """The caller's array is what is uploaded, so nothing in a fit may
+    write into it: it goes in read-only and comes out bit-identical."""
+    before = blobs.tobytes()
+    blobs.flags.writeable = False
+    _, timings = _fit(estimator, blobs)
+    copy = timings.root.node("table_convert/host_copy")
+    assert copy.attrs["copied_bytes"] == 0 and copy.duration_s > 0
+    assert blobs.tobytes() == before
+
+
+@pytest.mark.parametrize("estimator", ["kmeans", "pca"])
+def test_fit_result_does_not_depend_on_the_layout(blobs, estimator):
+    """Zero passes (C order) and one pass (Fortran order) put the same
+    bytes on the device: bit-equal models."""
+    got_c, _ = _fit(estimator, blobs)
+    got_f, timings = _fit(estimator, np.asfortranarray(blobs))
+    copy = timings.root.node("table_convert/host_copy")
+    assert copy.attrs["copied_bytes"] == blobs.nbytes
+    assert np.asarray(got_c).tobytes() == np.asarray(got_f).tobytes()

@@ -483,6 +483,10 @@ class TestSubSpans:
         assert root.node("table_convert/upload").attrs["bytes"] == (
             table.data.nbytes + table.mask.nbytes
         )
+        # 700 rows are off their bucket: ONE host pass wrote the padded
+        # table (the zero-pass route reads 0: tests/test_table_staging.py)
+        copy = root.node("table_convert/host_copy")
+        assert copy.attrs["copied_bytes"] == table.data.nbytes
         assert root.node("init_centers/rounds").attrs["rounds"] == 2
         cand = root.node("init_centers/kmeanspp_host").attrs["candidates"]
         assert 4 < cand <= 1 + 2 * 16  # one seed row + 4k slots a round
@@ -490,12 +494,17 @@ class TestSubSpans:
         tree = dict(_tree_paths(model.summary.telemetry["spans"]))
         up = tree["kmeans.fit/table_convert/upload"]
         assert up["attrs"]["bytes"] == table.data.nbytes + table.mask.nbytes
+        copy = tree["kmeans.fit/table_convert/host_copy"]
+        assert copy["attrs"]["copied_bytes"] == table.data.nbytes
 
     def test_pca_fit_records_the_staging_pair(self, rng):
         from oap_mllib_tpu import PCA
 
         x = rng.normal(size=(400, 6)).astype(np.float32)
-        flat = PCA(k=2).fit(x).summary["timings"].as_dict()
+        timings = PCA(k=2).fit(x).summary["timings"]
+        flat = timings.as_dict()
+        copy = timings.root.node("table_convert/host_copy")
+        assert copy.attrs["copied_bytes"] > x.nbytes  # padded to the bucket
         assert flat["table_convert/host_copy"] > 0
         assert flat["table_convert/upload"] > 0
         assert (
