@@ -28,6 +28,8 @@ import numpy as np
 from oap_mllib_tpu.data.bucketing import bucket_rows
 from oap_mllib_tpu.parallel.mesh import data_sharding
 from oap_mllib_tpu.telemetry import spans
+from oap_mllib_tpu.utils import progcache
+from oap_mllib_tpu.utils.jax_compat import shard_map
 
 # rows are padded per shard to this multiple (cheap: padding is masked)
 _ROW_MULTIPLE = 256
@@ -70,17 +72,73 @@ def _stage_rows(x, multiple: int, dtype):
     return padded, n, padded.nbytes
 
 
+# Host-to-device bytes ONE device has in flight at a time.  Measured on a
+# v5e host (PERF.md section 6, PR 27): the four 2.15 GB row shards of an
+# 8.6 GB table put all at once — by one ``device_put`` under the row
+# sharding or by four — land at 1.8-2.1 GB/s, their time spent mapping
+# DMA buffers; the same shards in pieces of 1.07 GB, one a device in
+# flight, at 25 GB/s, and in pieces of 268 MB at 24.
+_UPLOAD_PIECE_BYTES = 1 << 30
+
+
+def _join_pieces(sharding):
+    """The program that makes every device's row shard of the pieces it
+    holds (one ``concatenate`` a device, no traffic), kept in the program
+    registry: a fresh jit(shard_map) closure a table would recompile."""
+    return progcache.get_or_build(
+        "table.join_pieces",
+        (progcache.mesh_fingerprint(sharding.mesh), tuple(sharding.spec)),
+        lambda: jax.jit(
+            shard_map(
+                lambda pieces: jnp.concatenate(pieces, axis=0),
+                mesh=sharding.mesh,
+                in_specs=(sharding.spec,),
+                out_specs=sharding.spec,
+            )
+        ),
+    )
+
+
+def _put_rows(host: np.ndarray, sharding):
+    """``jax.device_put(host, sharding)`` of a table every row of which
+    this process holds, and on one device, or in a world of several
+    processes, just that.  Else every device's row slice goes to it
+    in pieces of at most ``_UPLOAD_PIECE_BYTES`` (views of ``host``:
+    nothing is copied on the host), one piece a device in flight, so
+    that what is in flight does not grow with the table; a shard of
+    several pieces is joined on its device, where it is held twice
+    until the pieces are dropped."""
+    index = sharding.addressable_devices_indices_map(host.shape)
+    if len(index) == 1 or jax.process_count() > 1:
+        return jax.device_put(host, sharding)
+    slices = [(dev, host[idx]) for dev, idx in index.items()]
+    shard_rows = slices[0][1].shape[0]
+    step = max(1, _UPLOAD_PIECE_BYTES * host.shape[0] // max(host.nbytes, 1))
+    pieces = []  # one global array a wave: that piece of every shard
+    for lo in range(0, shard_rows, step):
+        parts = [jax.device_put(rows[lo:lo + step], dev) for dev, rows in slices]
+        jax.block_until_ready(parts)
+        shape = (host.shape[0] // shard_rows * parts[0].shape[0], *host.shape[1:])
+        pieces.append(
+            jax.make_array_from_single_device_arrays(shape, sharding, parts)
+        )
+    return pieces[0] if len(pieces) == 1 else _join_pieces(sharding)(pieces)
+
+
 def _upload(put, padded: np.ndarray, mask: np.ndarray, mesh):
     """The ``upload`` sub-span of both constructors: ``put(host array,
     sharding)`` for the table and its mask, then the wait for both —
     ``device_put`` returns before the bytes land, and without the wait
     the rest of the upload is booked to whichever phase first blocks on
-    the table.  ``attrs["bytes"]`` is what this process sent."""
+    the table.  ``attrs["bytes"]`` is what this process sent, and
+    ``attrs["shards"]`` the row shards the table was cut into (one a
+    device of the data axis)."""
     with spans.child("upload") as span:
         data = put(padded, data_sharding(mesh, 2))
         mask_dev = put(mask, data_sharding(mesh, 1))
         jax.block_until_ready((data, mask_dev))
         span.attrs["bytes"] = padded.nbytes + mask.nbytes
+        span.attrs["shards"] = mesh.shape[mesh.axis_names[0]]
     return data, mask_dev
 
 
@@ -166,7 +224,7 @@ class DenseTable:
             span.attrs["copied_bytes"] = copied
             mask = np.zeros((padded.shape[0],), dtype=padded.dtype)
             mask[:n_valid] = 1.0
-        data, mask = _upload(jax.device_put, padded, mask, mesh)
+        data, mask = _upload(_put_rows, padded, mask, mesh)
         return cls(data=data, mask=mask, n_rows=n_valid)
 
     @classmethod

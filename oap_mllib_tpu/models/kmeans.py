@@ -29,7 +29,9 @@ from oap_mllib_tpu.data.table import DenseTable
 from oap_mllib_tpu.fallback.kmeans_np import lloyd_np, predict_np
 from oap_mllib_tpu.ops import kmeans_ops
 from oap_mllib_tpu.ops.pallas import autotune
+from oap_mllib_tpu.parallel import collective
 from oap_mllib_tpu.parallel.mesh import get_mesh
+from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import checkpoint as ckpt_mod
 from oap_mllib_tpu.utils import precision as psn
 from oap_mllib_tpu.utils import progcache
@@ -327,11 +329,14 @@ class KMeans:
             # memory-budget route plan (utils/membudget.py): an ndarray
             # whose working set exceeds the HBM budget streams through
             # the prefetch pipeline instead of silently assuming it fits
+            # priced per device: on a mesh each holds one row shard
+            shards = get_mesh().shape[get_config().data_axis]
             plan = membudget.plan_kmeans(
                 x.shape[0], x.shape[1], self.k,
                 row_chunks_hint=kmeans_ops.auto_row_chunks(
-                    x.shape[0], self.k
+                    -(-x.shape[0] // shards), self.k
                 ),
+                shards=shards,
             )
             if plan.route == membudget.ROUTE_STREAMED:
                 src = ChunkSource.from_array(
@@ -679,12 +684,17 @@ class KMeans:
         loop-mode assignment + exact-split cluster sums cut the
         per-iteration MXU/VPU passes — else the chunked XLA Lloyd.
         ``xla``/``pallas`` force a path;
-        ``pallas`` requires TPU + single device + f32 and falls back
-        otherwise.  Chunking only applies on a single device: the scan
-        reshape conflicts with GSPMD row sharding.  A mesh with a model
-        axis > 1 routes to the feature-sharded shard_map Lloyd — unless
-        ``xla`` is forced, which keeps the GSPMD data-parallel program
-        (centroids replicated) so the two can be A/B'd on the same mesh.
+        ``pallas`` requires TPU + one process + f32 and falls back
+        otherwise.  On one device the loop is one jitted program over
+        the table.  On a mesh of more devices it is the data-parallel
+        program (kmeans_ops.lloyd_run_data_sharded): ONE shard_map in
+        which every device runs that same accumulate — kernel or chunked
+        XLA — on its own row shard and the moments are all-reduced each
+        iteration; chunking is legal there because the shard is local.
+        A mesh with a model axis > 1 routes to the feature-sharded
+        shard_map Lloyd — unless ``xla`` is forced, which runs the
+        data-parallel program (centroids replicated, the model axis
+        holding replicas) so the two can be A/B'd on the same mesh.
         """
         # the compute-precision policy maps onto the legacy kernel tier
         # (utils/precision.kernel_tier: f32 keeps matmul_precision, tf32
@@ -755,7 +765,6 @@ class KMeans:
             return self._run_lloyd_segmented(
                 run_iters, centers0, ckpt, resume, d_orig
             )
-        single_device = len(jax.devices()) == 1 and jax.process_count() == 1
         # tuned tile geometry for the hot loop, resolved for BOTH kernel
         # routes (the XLA Lloyd derives its chunking from the same tile
         # rows, so a tuned bucket steers either program)
@@ -764,7 +773,10 @@ class KMeans:
             autotune.shape_bucket(self.k, table.data.shape[1]),
             tier,
         )
-        if use_pallas:
+        # one device: the loop is one jitted program over the table; more:
+        # the data-parallel program, the same accumulate on every shard
+        shards = mesh.shape[cfg.data_axis] if mesh.devices.size > 1 else 1
+        if use_pallas and shards == 1:
             from oap_mllib_tpu.ops.pallas.kmeans_kernel import lloyd_run_pallas
 
             key = (
@@ -786,45 +798,78 @@ class KMeans:
                     tile_rows=geo["tile_rows"],
                     depth=geo["depth"],
                 )
-        if single_device and geo != autotune.DEFAULTS["kmeans"]:
+        rows_per_shard = table.n_padded // shards
+        row_chunks = (
+            1 if use_pallas
+            else self._row_chunks(rows_per_shard, geo, degraded)
+        )
+        ran = []  # iterations of each launch (a checkpointed fit has several)
+
+        def run_iters(c0, iters):
+            tol = jnp.asarray(self.tol, dtype)
+            if shards == 1:
+                return kmeans_ops.lloyd_run(
+                    table.data, weights, jnp.asarray(c0), iters, tol,
+                    row_chunks=row_chunks, precision=tier, timings=timings,
+                    policy=pol.name,
+                )
+            out = kmeans_ops.lloyd_run_data_sharded(
+                table.data, weights, jnp.asarray(c0), iters, tol,
+                mesh, cfg.data_axis, walk=use_pallas, precision=tier,
+                policy=pol.name, tile_rows=geo["tile_rows"],
+                depth=geo["depth"], row_chunks=row_chunks, timings=timings,
+            )
+            ran.append(out[1])
+            return out
+
+        if ckpt is None:
+            out = run_iters(centers0, self.max_iter)
+        else:
+            out = self._run_lloyd_segmented(
+                run_iters, centers0, ckpt, resume, d_orig
+            )
+        if shards > 1:
+            # what was reduced is booked once the loop has returned: the
+            # program runs its iterations on the device and only then
+            # says how many there were
+            ran = [int(n) for n in ran]
+            k_rows, d_cols = np.asarray(centers0).shape
+            nbytes = sum(
+                kmeans_ops.lloyd_reduce_bytes(
+                    k_rows, d_cols, np.dtype(dtype).itemsize, n, use_pallas
+                )
+                for n in ran
+            )
+            collective.note_in_program(
+                "psum",
+                sum(n + 1 for n in ran),  # + each launch's final cost pass
+                nbytes * max(1, shards // jax.process_count()),
+            )
+            span = spans.current_span()
+            if span is not None:
+                span.attrs["shards"] = shards
+                span.attrs["rows_per_shard"] = rows_per_shard
+                span.attrs["reduce_bytes"] = nbytes
+        return out
+
+    def _row_chunks(self, rows, geo, degraded):
+        """Chunk count of the XLA Lloyd's scan over ``rows`` resident
+        rows (the table on one device, one shard on a mesh)."""
+        if geo != autotune.DEFAULTS["kmeans"]:
             # tuned bucket: chunk the scan at the tuned tile rows (the
             # default geometry keeps auto_row_chunks' occupancy rule
             # bit-for-bit, so untuned fits are unchanged)
-            row_chunks = max(
-                1, -(-table.n_padded // max(geo["tile_rows"], 1))
-            )
+            row_chunks = max(1, -(-rows // max(geo["tile_rows"], 1)))
         else:
-            row_chunks = (
-                kmeans_ops.auto_row_chunks(table.n_padded, self.k)
-                if single_device
-                else 1
-            )
-        if degraded and single_device:
+            row_chunks = kmeans_ops.auto_row_chunks(rows, self.k)
+        if degraded:
             # auto_row_chunks returns a chunk COUNT — each geometric
             # rung doubles it again, halving the rows (and the live
             # (chunk, k) buffer) per scan step
             row_chunks = min(
-                row_chunks * (2 ** int(degraded)), max(table.n_padded, 1)
+                row_chunks * (2 ** int(degraded)), max(rows, 1)
             )
-
-        def run_iters(c0, iters):
-            return kmeans_ops.lloyd_run(
-                table.data,
-                weights,
-                jnp.asarray(c0),
-                iters,
-                jnp.asarray(self.tol, dtype),
-                row_chunks=row_chunks,
-                precision=tier,
-                timings=timings,
-                policy=pol.name,
-            )
-
-        if ckpt is None:
-            return run_iters(centers0, self.max_iter)
-        return self._run_lloyd_segmented(
-            run_iters, centers0, ckpt, resume, d_orig
-        )
+        return row_chunks
 
     def _run_lloyd_segmented(self, run_iters, centers0, ckpt, resume,
                              d_orig):

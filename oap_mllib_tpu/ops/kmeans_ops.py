@@ -16,8 +16,12 @@ TPU-first redesign:
   is decided on device, no host round-trips per iteration (the reference
   pays a JNI + CCL round per phase).
 - Cross-device reduction (per-cluster sums/counts/cost over the row-sharded
-  table) is expressed as global ``jnp.sum``/matmul; GSPMD lowers it to
-  psum over the ``data`` mesh axis.  No root rank: results land replicated.
+  table): the estimator's loop on a mesh is one ``shard_map`` in which
+  every device accumulates its own row shard and ``psum`` sums the moments
+  over the ``data`` axis (:func:`lloyd_run_data_sharded`); the k-means||
+  rounds and a direct :func:`lloyd_run` on sharded arrays are global
+  ``jnp.sum``/matmul/scatter that GSPMD lowers to the same collectives.
+  No root rank: results land replicated.
 - Padded rows carry mask weight 0 so they never contribute (survey §2.6
   fixed-shape design note).
 
@@ -100,9 +104,11 @@ def pallas_preferred(d: int, k: int, precision: str) -> bool:
 def use_pallas_path(kernel_cfg: str, d: int, k: int, precision: str, dtype) -> bool:
     """Single source of truth for the kernel dispatch (estimator AND
     bench): the fused Pallas kernel runs only when configured/preferred
-    AND its preconditions hold — TPU backend, one device, one process,
-    f32.  Keeping this in one place prevents the two call sites from
-    silently diverging."""
+    AND its preconditions hold — TPU backend, one process, f32.  The
+    device count is no precondition: on a mesh every device walks its
+    own row shard with the same kernel (:func:`lloyd_run_data_sharded`).
+    Keeping this in one place prevents the two call sites from silently
+    diverging."""
     if kernel_cfg not in ("auto", "xla", "pallas"):
         raise ValueError(
             f"kmeans_kernel must be auto|xla|pallas, got {kernel_cfg!r}"
@@ -113,7 +119,6 @@ def use_pallas_path(kernel_cfg: str, d: int, k: int, precision: str, dtype) -> b
     return (
         want
         and jax.default_backend() == "tpu"
-        and len(jax.devices()) == 1
         and jax.process_count() == 1
         and np.dtype(dtype) == np.float32
     )
@@ -266,9 +271,10 @@ def _accumulate_chunked(x, weights, centers, row_chunks: int,
     buffers so n*k never materializes in HBM (needed for bench-scale runs
     like 1M x 256 with k=1000, where (n, k) f32 alone is 4 GB).
 
-    NOTE single-chip only for now: the reshape assumes the leading dim can
-    be freely split, which conflicts with row-sharding over a mesh; the
-    sharded path uses the unchunked accumulate (modest k).
+    NOTE the rows must be local: the reshape assumes the leading dim can
+    be freely split, which conflicts with GSPMD row-sharding.  On a mesh
+    the estimator calls this inside its ``shard_map``
+    (:func:`lloyd_run_data_sharded`), where ``x`` is one device's shard.
     """
     n = x.shape[0]
     if n % row_chunks != 0:
@@ -379,6 +385,21 @@ def _lloyd_run_jit(
     precision: str = "highest",
     policy: str = "f32",
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    return _lloyd_rows(
+        x, weights, init_centers, max_iter, tol, row_chunks, precision,
+        policy,
+    )
+
+
+def _lloyd_rows(x, weights, init_centers, max_iter, tol, row_chunks,
+                precision, policy, reduce_axis=None):
+    """The chunked XLA Lloyd over the rows at hand (traced; the jitted
+    entries are :func:`_lloyd_run_jit` and the ``shard_map`` program of
+    :func:`lloyd_run_data_sharded`).  ``reduce_axis`` names the mesh
+    axis the rows are sharded over: ``x`` is then ONE device's shard —
+    chunking it is legal because the shard is local — and every
+    accumulate's ``(sums, counts, cost)`` is summed over that axis
+    before the replicated centre update.  None emits no collective."""
     # rows that don't divide the chunk count pad with weight-0 rows HERE
     # — once per compiled program, outside the while_loop, so the copy
     # cannot re-run per iteration — keeping auto_row_chunks' budget a
@@ -404,10 +425,14 @@ def _lloyd_run_jit(
             "f32" if need_cost and x.dtype != jnp.bfloat16 else policy
         )
         if row_chunks > 1:
-            return _accumulate_chunked(
+            moments = _accumulate_chunked(
                 x, weights, centers, row_chunks, p, need_cost, pol
             )
-        return _accumulate(x, weights, centers, p, need_cost, pol)
+        else:
+            moments = _accumulate(x, weights, centers, p, need_cost, pol)
+        if reduce_axis is None:
+            return moments
+        return collective.psum(moments, reduce_axis)
 
     return _lloyd_loop(
         accum, lambda m: m, init_centers, max_iter, tol * tol
@@ -630,6 +655,132 @@ def lloyd_run_model_sharded(
         return fn(x, weights, jnp.asarray(init_centers), tol * tol)
 
 
+def _lloyd_data_sharded_fn(mesh, dax: str, max_iter: int, precision: str,
+                           policy: str, walk: bool, tile_rows: int,
+                           depth: int, row_chunks: int):
+    """Compiled data-parallel Lloyd program, one per (mesh, statics) in
+    the program registry (a fresh jit(shard_map) closure per fit would
+    recompile)."""
+    key = (
+        progcache.mesh_fingerprint(mesh), dax, max_iter, precision, policy,
+        walk, tile_rows, depth, row_chunks,
+    )
+    return progcache.get_or_build(
+        "kmeans.lloyd_data_sharded", key,
+        lambda: _build_lloyd_data_sharded(
+            mesh, dax, max_iter, precision, policy, walk, tile_rows, depth,
+            row_chunks,
+        ),
+    )
+
+
+def _build_lloyd_data_sharded(mesh, dax: str, max_iter: int, precision: str,
+                              policy: str, walk: bool, tile_rows: int,
+                              depth: int, row_chunks: int):
+    """Build the jitted data-parallel Lloyd program (cached above): the
+    whole ``while_loop`` inside ONE ``shard_map`` over the data axis.
+
+    The reference's distributed step (local step on each rank, allgather
+    of the partials, master step on the root; KMeansDALImpl.cpp:70-131)
+    as one program: every device runs the one-device accumulate on ITS
+    row shard — ``walk``: the fused kernel of
+    ops/pallas/kmeans_kernel (the DMA walk on the TPU, its
+    schedule-identical XLA scan elsewhere), same tile geometry and tier
+    as on one chip; else the chunked XLA accumulate, whose scan reshape
+    is legal because the shard is local — the moments are summed over
+    ``dax`` (``collective.psum``: sums, counts, and cost on the final
+    pass), and the centre update and the convergence test run replicated
+    on the summed values.  No root rank; one program a fit."""
+    from jax.sharding import PartitionSpec as P
+
+    def rank_program(x_blk, w_blk, c0, tol):
+        if walk:
+            from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
+
+            k, d = c0.shape
+            x_p, w_p, c_p = kk._pad_operands_traced(
+                x_blk, w_blk, c0, block_rows=tile_rows
+            )
+            centers, n_iter, cost, counts = kk._lloyd_loop_padded(
+                x_p, w_p, c_p, max_iter, tol, precision, False, tile_rows,
+                depth, reduce_axis=dax,
+            )
+            return centers[:k, :d], n_iter, cost, counts[:k]
+        return _lloyd_rows(
+            x_blk, w_blk, c0, max_iter, tol, row_chunks, precision, policy,
+            reduce_axis=dax,
+        )
+
+    return jax.jit(
+        shard_map(
+            rank_program,
+            mesh=mesh,
+            in_specs=(P(dax, None), P(dax), P(), P()),
+            out_specs=(P(), P(), P(), P()),
+            check_vma=False,
+        )
+    )
+
+
+def lloyd_reduce_bytes(k: int, d: int, itemsize: int, n_iter: int,
+                       walk: bool) -> int:
+    """Bytes ONE device hands to the reductions of a data-parallel Lloyd
+    run of ``n_iter`` iterations: ``(k, d)`` sums and ``(k,)`` counts an
+    iteration, counts and the cost scalar after the final pass.  The
+    walk reduces its lane-padded blocks (k and d up to multiples of
+    128), the XLA accumulate the exact shapes."""
+    if walk:
+        k, d = -(-k // 128) * 128, -(-d // 128) * 128
+    return (n_iter * (k * d + k) + k + 1) * itemsize
+
+
+def lloyd_run_data_sharded(
+    x: jax.Array,
+    weights: jax.Array,
+    init_centers: jax.Array,
+    max_iter: int,
+    tol: jax.Array,
+    mesh,
+    data_axis: str,
+    walk: bool = False,
+    precision: str = "highest",
+    policy: str = "f32",
+    tile_rows: int = 512,
+    depth: int = 2,
+    row_chunks: int = 0,
+    timings=None,
+    phase: str = "lloyd_loop",
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Lloyd loop over a table row-sharded on ``data_axis``: the normal
+    in-memory route on any mesh of more than one device.
+
+    Same semantics and return contract as :func:`lloyd_run` (centres,
+    cost and counts come back replicated).  ``walk`` runs the fused
+    kernel at ``tile_rows``/``depth`` on each shard (f32 only; the
+    caller's dispatch rule is :func:`use_pallas_path`); otherwise the
+    chunked XLA accumulate at ``row_chunks`` chunks a SHARD (0 = the
+    occupancy rule :func:`auto_row_chunks` on the shard's rows).  A
+    model axis, where the mesh has one, holds replicas."""
+    rows_per_shard = x.shape[0] // mesh.shape[data_axis]
+    if walk:
+        row_chunks = 1
+    elif row_chunks <= 0:
+        row_chunks = auto_row_chunks(rows_per_shard, init_centers.shape[0])
+    fn = _lloyd_data_sharded_fn(
+        mesh, data_axis, max_iter, precision, policy, bool(walk),
+        int(tile_rows), int(depth), int(row_chunks),
+    )
+    key = (
+        progcache.mesh_fingerprint(mesh),
+        progcache.array_key(x, weights),
+        np.asarray(init_centers).shape, max_iter, precision, policy,
+        bool(walk), int(tile_rows), int(depth), int(row_chunks),
+    )
+    with progcache.launch("kmeans.lloyd_data_sharded.run", key, timings,
+                          phase):
+        return fn(x, weights, jnp.asarray(init_centers), tol)
+
+
 @jax.jit
 def total_cost(x: jax.Array, weights: jax.Array, centers: jax.Array) -> jax.Array:
     _, _, cost = _accumulate(x, weights, centers)
@@ -672,6 +823,13 @@ def _to_host(a) -> np.ndarray:
             ),
         )(a)
     return np.asarray(a)
+
+
+def _row_shards(x) -> int:
+    """How many row shards ``x`` lies in (1 for a host array)."""
+    if not isinstance(x, jax.Array):
+        return 1
+    return x.shape[0] // x.sharding.shard_shape(x.shape)[0]
 
 
 def _gather_rows(x, idx: np.ndarray) -> np.ndarray:
@@ -864,6 +1022,7 @@ def init_kmeans_parallel(
         )[: len(cand)]
         cand, cand_w = cand[valid], cand_w[valid]
         span.attrs["rounds"] = len(all_slots) - 1
+        span.attrs["shards"] = _row_shards(x_dev)
 
     # the host's reduction of the candidates to k centers
     with spans.child("kmeanspp_host") as span:

@@ -54,6 +54,7 @@ from oap_mllib_tpu.ops.pallas._tiers import (
     pad_to,
     split_bf16,
 )
+from oap_mllib_tpu.parallel import collective
 from oap_mllib_tpu.utils import progcache
 
 _BLOCK_ROWS = 512
@@ -469,12 +470,28 @@ def lloyd_accumulate_pallas(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("max_iter", "mode", "interpret", "tile_rows", "depth"),
+    static_argnames=("max_iter", "mode", "interpret", "tile_rows", "depth",
+                     "reduce_axis"),
 )
 def _lloyd_loop_padded(x_p, w_p, c_p, max_iter, tol, mode="highest",
-                       interpret=False, tile_rows=_BLOCK_ROWS, depth=0):
-    """while_loop over the fused kernel on pre-padded operands."""
+                       interpret=False, tile_rows=_BLOCK_ROWS, depth=0,
+                       reduce_axis=None):
+    """while_loop over the fused kernel on pre-padded operands.
+
+    ``reduce_axis`` names the mesh axis the rows are sharded over when
+    the loop runs inside a ``shard_map`` (ops/kmeans_ops
+    .lloyd_run_data_sharded): each device's operands are then ITS shard,
+    the moments ``(sums, counts)`` are summed over that axis after every
+    accumulate and ``(cost, counts)`` after the final cost pass, and the
+    centre update and the convergence test run replicated on the summed
+    values.  None (one device) emits no collective: the program is the
+    one it was."""
     tol_sq = tol * tol
+
+    def reduce(*moments):
+        if reduce_axis is None:
+            return moments
+        return collective.psum(moments, reduce_axis)
 
     def cond(state):
         _, it, converged = state
@@ -485,6 +502,7 @@ def _lloyd_loop_padded(x_p, w_p, c_p, max_iter, tol, mode="highest",
         sums, counts, _ = _accum_any(
             x_p, w_p, centers, mode, interpret, False, tile_rows, depth
         )
+        sums, counts = reduce(sums, counts)
         counts_col = counts[0][:, None]  # (k_pad, 1)
         new_centers = jnp.where(
             counts_col > 0, sums / jnp.maximum(counts_col, 1e-30), centers
@@ -503,6 +521,7 @@ def _lloyd_loop_padded(x_p, w_p, c_p, max_iter, tol, mode="highest",
         _, counts, cost = _accum_any(
             x_p, w_p, centers, "highest", interpret, True, tile_rows, depth
         )
+        cost, counts = reduce(cost, counts)
     return centers, n_iter, cost[0, 0], counts[0]
 
 

@@ -103,19 +103,25 @@ def _instrumented(op: str, x: jax.Array, dispatch):
     t0 = time.perf_counter()
     out = recovery.guarded_dispatch(op, axis, dispatch)
     dt = time.perf_counter() - t0
+    _tm.histogram("oap_collective_dispatch_seconds", {"op": op},
+                  help="Per-dispatch wall (compile included on first shape)"
+                  ).observe(dt)
+    _book(op, 1, nbytes, dt)
+    return out
+
+
+def _book(op: str, ops: int, nbytes: int, dispatch_s: float) -> None:
+    """Count ``ops`` collectives of ``nbytes`` in all into the registry
+    and onto the thread's active span."""
     lab = {"op": op}
     _tm.counter("oap_collective_ops_total", lab,
-                help="Collective facade dispatches by op").inc()
+                help="Collective facade dispatches by op").inc(ops)
     _tm.counter("oap_collective_bytes_total", lab,
                 help="Operand bytes through the collective facade"
                 ).inc(nbytes)
-    _tm.histogram("oap_collective_dispatch_seconds", lab,
-                  help="Per-dispatch wall (compile included on first shape)"
-                  ).observe(dt)
     sp = current_span()
     if sp is not None:
-        sp.note_collective(op, nbytes, dt)
-    return out
+        sp.note_collective(op, nbytes, dispatch_s, ops=ops)
 
 
 # -- in-jit collective seam --------------------------------------------------
@@ -137,6 +143,17 @@ def _note_emitted(op: str) -> None:
         help="Collective ops emitted into compiled programs "
              "(trace-time census, not a dispatch count)",
     ).inc()
+
+
+def note_in_program(op: str, ops: int, nbytes: int) -> None:
+    """Book ``ops`` collectives that ran INSIDE one compiled program
+    (a ``while_loop`` that reduces every iteration), with the ``nbytes``
+    this process's devices each handed to them: the seam below counts
+    at trace time only, so the caller counts from what it knows once
+    the program has returned (iterations x payload).  Same registry
+    names and span note as a facade dispatch; no dispatch wall — the
+    reductions' time is the device trace's to tell."""
+    _book(op, ops, nbytes, 0.0)
 
 
 def psum(x, axis_name):

@@ -87,13 +87,15 @@ class Span:
         self.duration_s += seconds
         self.count += 1
 
-    def note_collective(self, op: str, nbytes: int, dispatch_s: float) -> None:
-        """Accumulate one collective dispatch onto this span's attributes
+    def note_collective(self, op: str, nbytes: int, dispatch_s: float,
+                        ops: int = 1) -> None:
+        """Accumulate collective dispatches (one, or ``ops`` that ran
+        inside one program) onto this span's attributes
         (parallel/collective.py calls this on ``current_span()``)."""
         per = self.attrs.setdefault("collectives", {}).setdefault(
             op, {"ops": 0, "bytes": 0, "dispatch_s": 0.0}
         )
-        per["ops"] += 1
+        per["ops"] += int(ops)
         per["bytes"] += int(nbytes)
         per["dispatch_s"] += float(dispatch_s)
 

@@ -459,10 +459,12 @@ def _dtype_bytes() -> int:
     return 8 if get_config().enable_x64 else 4
 
 
-def _padded_rows(n: int) -> int:
+def _padded_rows(n: int, shards: int = 1) -> int:
+    """Padded rows ONE device holds of an n-row table cut into
+    ``shards`` row shards (data/table.py pads to 256 rows a shard)."""
     from oap_mllib_tpu.data.bucketing import bucket_rows
 
-    return bucket_rows(max(int(n), 1), 256)
+    return bucket_rows(max(int(n), 1), 256 * shards) // shards
 
 
 def _depth() -> int:
@@ -498,11 +500,20 @@ def _calibrated(algo: str, estimate: int) -> int:
 def plan_kmeans(n: Optional[int], d: int, k: int, *,
                 source_backing: Optional[str] = None,
                 chunk_rows: int = 0,
-                row_chunks_hint: int = 1) -> RoutePlan:
+                row_chunks_hint: int = 1,
+                shards: int = 1) -> RoutePlan:
     """Route plan for one K-Means fit.  ``source_backing`` None = array
     input (candidates: in-memory / chunked / streamed); a ChunkSource
     input passes its ``backing`` (natural route: streamed).  ``n`` None
-    = un-sized source (footprints unknown; streams unconditionally)."""
+    = un-sized source (footprints unknown; streams unconditionally).
+
+    ``shards`` is the mesh's data-axis size: the HBM budget is ONE
+    device's, so the resident routes are priced on what one device
+    holds — its row shard and the Lloyd program that runs over it
+    (models/kmeans._run_lloyd_data_sharded: a walk or chunked scan over
+    the shard, never a whole-table sheet) — with ``row_chunks_hint`` the
+    occupancy rule on a shard's rows.  The host holds the whole array
+    whatever the mesh.  One shard prices as before."""
     b = _dtype_bytes()
     budgets = Budgets.resolve()
     from oap_mllib_tpu.data.stream import DEFAULT_CHUNK_ROWS
@@ -520,7 +531,7 @@ def plan_kmeans(n: Optional[int], d: int, k: int, *,
         int((_depth() * rows * (d + k + 1) * b + centroids) * _OVERHEAD),
     )
     if source_backing is None:
-        np_ = _padded_rows(n)
+        np_ = _padded_rows(n, shards)
         table = np_ * (d + 1) * b
         host = n * d * b
         in_mem = RouteEstimate(

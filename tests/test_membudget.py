@@ -145,6 +145,63 @@ class TestDecisionTable:
         assert plan.chunk_rows >= OOM_CHUNK_FLOOR_ROWS
 
 
+class TestPerDevicePricing:
+    """On a mesh the HBM budget is ONE device's, and so is the price: its
+    row shard plus the buffers of the Lloyd that runs over it (a walk or
+    chunked scan of the shard — no whole-table sheet)."""
+
+    ROWS, D, K = 8_388_608, 256, 1000  # the four-chip benchmark cell
+
+    def _chunked(self, n, shards):
+        from oap_mllib_tpu.ops.kmeans_ops import auto_row_chunks
+
+        plan = mb.plan_kmeans(
+            n, self.D, self.K,
+            row_chunks_hint=auto_row_chunks(-(-n // shards), self.K),
+            shards=shards,
+        )
+        assert plan.natural == mb.ROUTE_CHUNKED
+        return plan, plan.estimate_for(mb.ROUTE_CHUNKED)
+
+    def test_a_device_of_four_holds_a_quarter_table_and_the_buffers(self):
+        from oap_mllib_tpu.ops.kmeans_ops import SCORE_BUDGET_ELEMS
+
+        _, four = self._chunked(self.ROWS, 4)
+        shard = (self.ROWS // 4) * (self.D + 1) * 4  # rows + mask lane, f32
+        buffers = (
+            SCORE_BUDGET_ELEMS * 4  # the live (chunk, k) score block
+            + 3 * self.K * self.D * 4 + mb._PROGRAM_BYTES  # centres, sums
+        )
+        assert four.hbm_bytes == int((shard + buffers) * mb._OVERHEAD)
+        # the host still holds the whole array
+        assert four.host_bytes == self.ROWS * self.D * 4
+
+    def test_one_device_prices_as_before(self):
+        _, whole = self._chunked(self.ROWS // 4, 1)
+        _, four = self._chunked(self.ROWS, 4)
+        # a quarter of the rows on one device IS a device's share of four
+        assert whole.hbm_bytes == four.hbm_bytes
+        default = mb.plan_kmeans(self.ROWS // 4, self.D, self.K,
+                                 row_chunks_hint=64)
+        assert default.estimate_for(mb.ROUTE_CHUNKED).hbm_bytes == (
+            whole.hbm_bytes
+        )
+
+    def test_the_budget_is_held_against_the_shard(self):
+        # 16 GB a device: the whole 8.6 GB table fits one device's budget
+        # either way, four times the rows only as four shards
+        set_config(memory_budget_hbm="16G")
+        plan, _ = self._chunked(4 * self.ROWS, 4)
+        assert plan.route == mb.ROUTE_CHUNKED
+        from oap_mllib_tpu.ops.kmeans_ops import auto_row_chunks
+
+        alone = mb.plan_kmeans(
+            4 * self.ROWS, self.D, self.K,
+            row_chunks_hint=auto_row_chunks(4 * self.ROWS, self.K),
+        )
+        assert alone.route == mb.ROUTE_STREAMED
+
+
 class TestPolicy:
     def test_strict_raises_instead_of_degrading(self):
         set_config(memory_budget_hbm="120M", scale_policy="strict")

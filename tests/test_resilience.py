@@ -308,11 +308,24 @@ class TestLadderRungs:
         res = m.summary.resilience
         assert res["degradations"] == 1 and res["retries"] == 0
         assert m.summary.accelerated  # the DEGRADED rung, not fallback
-        # halved chunks only re-block the passes; results match
-        np.testing.assert_allclose(
-            m.summary.training_cost, baseline.summary.training_cost,
-            rtol=1e-5,
-        )
+        # halved chunks only re-block the passes: the centres, which are
+        # ratios of well-conditioned sums, agree to float32 rounding
+        c = baseline.cluster_centers_
+        np.testing.assert_allclose(m.cluster_centers_, c, atol=1e-6)
+        # the cost does NOT agree to 1e-5 relative, and need not: each
+        # row's term is |x|^2 + |c|^2 - 2 x.c in float32, a difference
+        # of numbers ~100 times the distance it leaves (blobs at radius
+        # ~10, spread 0.2), so a row carries an absolute rounding error
+        # of up to one float32 step of |x|^2 + |c|^2 whatever the
+        # distance is, and re-blocking the pass re-rounds every row.
+        # The bound is that step summed over the rows (7e-5 of this
+        # cost; 1.1e-5 is what this CPU shows — ROADMAP D12)
+        x = _blobs(np.random.default_rng(42)).astype(np.float64)
+        near = c[((x[:, None, :] - c[None]) ** 2).sum(-1).argmin(1)]
+        magnitude = (x ** 2).sum() + (near.astype(np.float64) ** 2).sum()
+        assert abs(
+            m.summary.training_cost - baseline.summary.training_cost
+        ) <= np.finfo(np.float32).eps * magnitude
 
     def test_persistent_oom_escalates_to_fallback(self, rng):
         set_config(fault_spec="fit.execute:oom=*", fallback=True)

@@ -9,6 +9,7 @@ import jax
 import numpy as np
 import pytest
 
+from oap_mllib_tpu.config import set_config
 from oap_mllib_tpu.data import table as table_mod
 from oap_mllib_tpu.data.table import DenseTable
 from oap_mllib_tpu.parallel.mesh import get_mesh
@@ -223,3 +224,102 @@ def test_fit_result_does_not_depend_on_the_layout(blobs, estimator):
     copy = timings.root.node("table_convert/host_copy")
     assert copy.attrs["copied_bytes"] == blobs.nbytes
     assert np.asarray(got_c).tobytes() == np.asarray(got_f).tobytes()
+
+
+class TestUploadInPieces:
+    """On a mesh of several devices every device's row slice goes up in
+    pieces of bounded size, one a device in flight, and a shard of several
+    pieces is joined on its device (``data/table._put_rows``): the same
+    global array as the one ``device_put``, from views of the caller's
+    array."""
+
+    def _table(self, monkeypatch, x, piece_bytes, n_devices=4):
+        from oap_mllib_tpu.data import table as table_mod
+        from oap_mllib_tpu.parallel.mesh import get_mesh
+
+        monkeypatch.setattr(table_mod, "_UPLOAD_PIECE_BYTES", piece_bytes)
+        waits = []
+        real = table_mod.jax.block_until_ready
+        monkeypatch.setattr(
+            table_mod.jax, "block_until_ready",
+            lambda v: (waits.append(len(jax.tree_util.tree_leaves(v))), real(v))[1],
+        )
+        return DenseTable.from_numpy(x, get_mesh(n_devices=n_devices)), waits
+
+    # pieces a shard: whole, halves, three with a ragged tail, one row each
+    @pytest.mark.parametrize("pieces", [1, 2, 2.5, None])
+    def test_same_array_whatever_the_piece(self, blobs, monkeypatch, pieces):
+        from oap_mllib_tpu.parallel.mesh import data_sharding, get_mesh
+
+        shard = blobs.nbytes // 4
+        row = blobs.nbytes // blobs.shape[0]
+        piece = row if pieces is None else int(shard / pieces)
+        table, waits = self._table(monkeypatch, blobs, piece)
+        mesh = get_mesh(n_devices=4)
+        assert table.data.sharding == data_sharding(mesh, 2)
+        assert np.asarray(table.data).tobytes() == blobs.tobytes()
+        assert np.asarray(table.mask).tolist() == [1.0] * blobs.shape[0]
+        assert [s.data.shape for s in table.data.addressable_shards] == (
+            [(blobs.shape[0] // 4, blobs.shape[1])] * 4
+        )
+        # every wave is one piece a device, waited for before the next
+        # goes; then the mask's waves (an item a row), then the upload
+        # span's own wait for table and mask
+        shard_rows = blobs.shape[0] // 4
+        waves = {1: 1, 2: 2, 2.5: 3, None: shard_rows}[pieces]
+        mask_waves = -(-shard_rows // (piece // blobs.itemsize))
+        assert waits == [4] * (waves + mask_waves) + [2]
+
+    def test_one_device_goes_up_as_it_did(self, blobs, monkeypatch):
+        from oap_mllib_tpu.data import table as table_mod
+
+        puts = []
+        real = table_mod.jax.device_put
+        monkeypatch.setattr(
+            table_mod.jax, "device_put",
+            lambda v, where: (puts.append(v), real(v, where))[1],
+        )
+        # far over the piece size, yet one device_put of the caller's array
+        table, waits = self._table(monkeypatch, blobs, 1, n_devices=1)
+        assert puts[0] is blobs and len(puts) == 2 and waits == [2]
+        assert np.asarray(table.data).tobytes() == blobs.tobytes()
+
+    def test_a_model_axis_holds_replicas_of_the_pieces(self, blobs, monkeypatch):
+        from oap_mllib_tpu.data import table as table_mod
+        from oap_mllib_tpu.parallel.mesh import get_mesh
+
+        monkeypatch.setattr(table_mod, "_UPLOAD_PIECE_BYTES", blobs.nbytes // 4)
+        set_config(model_parallel=2)
+        try:
+            mesh = get_mesh(n_devices=4)
+            table = DenseTable.from_numpy(blobs, mesh)
+        finally:
+            set_config(model_parallel=1)
+        assert np.asarray(table.data).tobytes() == blobs.tobytes()
+        half = blobs.shape[0] // 2
+        assert sorted(s.index[0].start or 0 for s in table.data.addressable_shards) == (
+            [0, 0, half, half]
+        )
+
+    def test_a_fit_through_the_pieces_copies_nothing(self, blobs, monkeypatch):
+        from oap_mllib_tpu import KMeans
+        from oap_mllib_tpu.data import table as table_mod
+        from oap_mllib_tpu.utils import progcache
+
+        whole = KMeans(k=4, max_iter=3, seed=0).fit(blobs)
+        monkeypatch.setattr(
+            table_mod, "_UPLOAD_PIECE_BYTES", blobs.nbytes // 24
+        )
+        pieced = KMeans(k=4, max_iter=3, seed=0).fit(blobs)
+        root = pieced.summary.timings.root
+        assert root.node("table_convert/host_copy").attrs["copied_bytes"] == 0
+        assert root.node("table_convert/upload").attrs["shards"] == 8
+        assert (
+            pieced.cluster_centers_.tobytes() == whole.cluster_centers_.tobytes()
+        )
+        # the join is one program a mesh, found again by the next table
+        joins = lambda: dict(progcache.stats()["by_algo"]["table.join_pieces"])
+        before = joins()
+        KMeans(k=4, max_iter=3, seed=0).fit(blobs)
+        assert joins()["hits"] == before["hits"] + 1
+        assert joins()["misses"] == before["misses"]

@@ -279,3 +279,95 @@ class TestRingKernel:
             ),
             _s((4, 1024, 512), NamedSharding(mesh, spec)),
         )
+
+
+class TestDataParallelKMeans:
+    """The four-chip K-Means cell (``kmeans_d256_k1000_host4``) at its
+    shapes — 2,097,152 x 256 float32 rows a device, k=1000 — before any
+    chip time is spent on it: the Lloyd loop as ONE shard_map with the
+    walk on every device's shard and the moments all-reduced, and the
+    k-means|| round on the row-sharded table as GSPMD cuts it."""
+
+    ROWS, D, K = 4 * 2097152, 256, 1000
+    HBM = 15.75 * 2**30  # what the v5e's compiler lets one program hold
+
+    @pytest.fixture()
+    def mesh(self, topo):
+        return Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
+
+    def test_sharded_lloyd_program(self, mesh, monkeypatch):
+        from oap_mllib_tpu.ops import kmeans_ops
+
+        # the walk's dispatch asks the backend: take its TPU branch
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        fn = kmeans_ops._build_lloyd_data_sharded(
+            mesh, "data", 20, "highest", "f32", True, TILE, DEPTH, 1
+        )
+        rows = NamedSharding(mesh, P("data", None))
+        rep = NamedSharding(mesh, P())
+        compiled = fn.lower(
+            _s((self.ROWS, self.D), rows),
+            _s((self.ROWS,), NamedSharding(mesh, P("data"))),
+            _s((self.K, self.D), rep),
+            _s((), rep),
+        ).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text  # the kernel, on every device
+        assert "kmeans_accumulate_walk" in text
+        assert "all-reduce" in text  # the moments
+        assert "all-gather" not in text  # no device ever sees the table
+        mem = compiled.memory_analysis()
+        # a device holds its shard and the walk's padded copy of it,
+        # not a (rows, k) sheet
+        held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        assert held < 3 * (self.ROWS // 4) * self.D * 4
+
+    def test_pll_round_on_a_row_sharded_table(self, mesh):
+        from oap_mllib_tpu.ops import kmeans_ops
+
+        rows = NamedSharding(mesh, P("data", None))
+        row = NamedSharding(mesh, P("data"))
+        rep = NamedSharding(mesh, P())
+        cap = 4 * self.K
+        compiled = kmeans_ops._pll_round.lower(
+            _s((self.ROWS, self.D), rows),
+            _s((self.ROWS,), row),
+            _s((self.ROWS,), row),
+            jax.ShapeDtypeStruct((self.ROWS,), jnp.int32, sharding=row),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
+            _s((), rep),
+            cap=cap, chunk=kmeans_ops._slot_chunk_size(cap),
+        ).compile()
+        text = compiled.as_text()
+        assert "all-gather" not in text  # the scatter stays local + reduced
+        mem = compiled.memory_analysis()
+        held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        # the distance sheet is a DEVICE's rows x 1000, never the table's
+        sheet = (self.ROWS // 4) * self.K * 4
+        assert sheet <= mem.temp_size_in_bytes < 1.1 * sheet
+        assert held < self.HBM
+        # slots come back replicated: the host fetch needs no re-gather
+        slots_sharding = compiled.output_shardings[0]
+        assert slots_sharding.is_fully_replicated
+
+    def test_upload_joins_a_shards_pieces_on_its_device(self, mesh):
+        from oap_mllib_tpu.data import table as table_mod
+        from oap_mllib_tpu.utils import progcache
+
+        rows = NamedSharding(mesh, P("data", None))
+        progcache.clear()  # the registry may hold another mesh's program
+        # the cell's shard (2 GiB) goes up as two pieces of 1 GiB a device
+        piece = _s((self.ROWS // 2, self.D), rows)
+        compiled = table_mod._join_pieces(rows).lower([piece, piece]).compile()
+        text = compiled.as_text()
+        for collective in ("all-gather", "all-reduce", "collective-permute",
+                           "all-to-all"):
+            assert collective not in text  # a local copy, no traffic
+        assert compiled.output_shardings == rows
+        mem = compiled.memory_analysis()
+        shard = (self.ROWS // 4) * self.D * 4
+        # pieces + the joined shard: a device holds its rows twice, no more
+        held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+        assert held <= 2 * shard + 2**20
