@@ -956,6 +956,96 @@ def _candidate_weights(w, amin, n_cand: int):
     return jnp.zeros((n_cand,), w.dtype).at[amin].add(w)
 
 
+def _reduce_candidates(slots, weights, valid, key, k: int):
+    """Weighted k-means++ over a fixed-shape candidate buffer, on the
+    device: :func:`_weighted_kmeans_pp` step for step, one program.
+
+    ``slots`` (m, d) hold the candidates, ``valid`` (m,) is positive where
+    a slot holds one, ``weights`` (m,) the row weight each owns.  An empty
+    slot weighs 0 and is never drawn.  Each of the k draws is an inverse
+    CDF over ``p = d2 * w`` from one of k uniforms made before the loop;
+    ``d2`` is the direct ``sum((slots - c) ** 2)``, as on the host, so no
+    cancellation of the matmul identity enters.  The host's two
+    degenerate cases keep their meaning: no weight at all -> every valid
+    slot weighs 1; no mass left at a step (every candidate already a
+    centre) -> a uniform draw among the valid slots.  Elementwise work
+    and reductions in the slots' dtype (the table's: float32, float64
+    under x64), no product."""
+    dtype = slots.dtype
+    live = (valid > 0).astype(dtype)
+    w = weights.astype(dtype) * live
+    w = jnp.where(jnp.sum(w) > 0, w, live)
+    ids = jnp.arange(slots.shape[0], dtype=jnp.int32)
+    u = jax.random.uniform(key, (k,), dtype)
+
+    def draw(p, ui):
+        p = jnp.where(jnp.sum(p) > 0, p, live)
+        cdf = jnp.cumsum(p)
+        idx = jnp.sum(cdf <= ui * cdf[-1]).astype(jnp.int32)
+        # a prefix sum in parallel is not monotone to the last bit, and
+        # ui * total can round up to the total: either can leave idx on a
+        # slot of no mass or past the end, so settle on the nearest slot
+        # WITH mass at or before it, else on the first one
+        mass = p > 0
+        before = jnp.max(jnp.where(mass & (ids <= idx), ids, -1))
+        return jnp.where(before >= 0, before, jnp.argmax(mass))
+
+    def pick(idx, d2):
+        c = lax.dynamic_index_in_dim(slots, idx, 0, keepdims=False)
+        return c, jnp.minimum(d2, jnp.sum((slots - c) ** 2, axis=1))
+
+    def step(i, carry):
+        centers, d2 = carry
+        c, d2 = pick(draw(d2 * w, u[i]), d2)
+        return lax.dynamic_update_index_in_dim(centers, c, i, 0), d2
+
+    c, d2 = pick(draw(w, u[0]), jnp.full((slots.shape[0],), jnp.inf, dtype))
+    centers = jnp.zeros((k, slots.shape[1]), dtype).at[0].set(c)
+    centers, _ = lax.fori_loop(1, k, step, (centers, d2))
+    return centers
+
+
+def _reduce_candidates_fn(k: int, mesh=None):
+    """The jitted :func:`_reduce_candidates`, one per (k, mesh) in the
+    program registry.  On a ``mesh`` it runs replicated — inputs and
+    output under ``PartitionSpec()``, what fetching the candidates to the
+    host forced before — so every process runs the same program on the
+    same key and holds the same centres."""
+    shardings = {}
+    world = progcache.backend_fingerprint()
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        rep = NamedSharding(mesh, PartitionSpec())
+        shardings = {"in_shardings": rep, "out_shardings": rep}
+        world = progcache.mesh_fingerprint(mesh)
+    return progcache.get_or_build(
+        "kmeans.reduce_candidates", (world, k),
+        lambda: jax.jit(
+            functools.partial(_reduce_candidates, k=k), **shardings
+        ),
+    )
+
+
+def reduce_candidates(slots, weights, valid, key, k: int,
+                      mesh=None) -> np.ndarray:
+    """Reduce the k-means|| candidates to k centres on the device and
+    fetch the (k, d) result: the one call both accelerated routes make."""
+    return np.asarray(
+        _reduce_candidates_fn(k, mesh)(slots, weights, valid, key)
+    )
+
+
+@jax.jit
+def _candidate_buffer(c0, slots, valids):
+    """The rounds' slot buffers behind candidate 0, as one (m, d) buffer
+    with its validity: the layout ``amin`` numbers the candidates in."""
+    return (
+        jnp.concatenate([c0.astype(slots[0].dtype), *slots], axis=0),
+        jnp.concatenate([jnp.ones((1,), valids[0].dtype), *valids]),
+    )
+
+
 def init_kmeans_parallel(
     x_dev: jax.Array,
     weights_dev: jax.Array,
@@ -967,21 +1057,24 @@ def init_kmeans_parallel(
 ) -> np.ndarray:
     """k-means|| (Bahmani et al.) with oversampling l = 2k, Spark defaults.
 
-    Device-side redesign (round-1 round-tripped all n distances + weights
-    to host per round): the candidate set lives in a static-shape device
-    buffer (1 + 4k*steps slots — 2x the expected 2k picks per round, so
-    overflow-dropping is vanishingly rare), per-round sampling/prefix
-    -scatter/min-fold run in one jitted program, and only the <=1+4k*steps
-    candidates plus their ownership weights are fetched for the host-side
-    weighted k-means++ reduction (Spark runs the same reduction on the
-    driver, mllib/clustering/KMeans.scala initKMeansParallel).  Every
-    device op is GSPMD-global, so the same code serves multi-host meshes.
+    The candidate set lives in a static-shape device buffer (1 +
+    4k*steps slots — 2x the expected 2k picks per round, so
+    overflow-dropping is vanishingly rare) and never goes to the host:
+    per-round sampling/prefix-scatter/min-fold run in one jitted
+    program, the ownership weights in another, and the weighted
+    k-means++ reduction of the candidates to k centres (Spark runs it on
+    the driver, mllib/clustering/KMeans.scala initKMeansParallel) in a
+    third, :func:`_reduce_candidates`.  The host fetches what it decides
+    on — each round's ``phi`` and slot validity — and the (k, d)
+    centres.  Every device op is GSPMD-global, so the same code serves
+    multi-host meshes.  Only a table that yields no more than k
+    candidates is topped up with random rows, on the host.
     """
     rng = np.random.default_rng(seed)
     n, d = x_dev.shape
 
     # everything that waits on the device: the seed-row gather, the
-    # rounds with their phi and slot fetches, the candidate weights
+    # rounds with their phi and validity fetches, the candidate weights
     with spans.child("rounds") as span:
         # first center: uniform valid row (index_map: valid -> padded layout)
         first = np.asarray([rng.integers(n_valid)])
@@ -999,47 +1092,65 @@ def init_kmeans_parallel(
         dmin = d2_0
         amin = jnp.zeros((n,), jnp.int32)
 
-        all_slots = [np.asarray(c0)]
-        all_valid = [np.ones((1,), np.float32)]
-        base = 1
+        all_slots, all_valid, filled = [], [], []
         for step in range(init_steps):
             slots, slot_valid, dmin, amin, phi = _pll_round(
                 x_dev, weights_dev, dmin, amin,
-                jnp.asarray(base, jnp.int32),
+                jnp.asarray(1 + cap * step, jnp.int32),
                 jax.random.fold_in(key, step), l, cap, chunk,
             )
             if float(phi) <= 0.0:
                 break
+            all_slots.append(slots)
+            all_valid.append(slot_valid)
             # small host fetch, re-replicated if GSPMD left the output sharded
-            all_slots.append(_to_host(slots))
-            all_valid.append(_to_host(slot_valid))
-            base += cap
-
-        cand = np.concatenate(all_slots, axis=0)
-        valid = np.concatenate(all_valid, axis=0) > 0
-        cand_w = _to_host(
-            _candidate_weights(weights_dev, amin, base)
-        )[: len(cand)]
-        cand, cand_w = cand[valid], cand_w[valid]
-        span.attrs["rounds"] = len(all_slots) - 1
+            filled.append(_to_host(slot_valid) > 0)
+        n_cand = 1 + sum(int(f.sum()) for f in filled)
+        rounds = len(all_slots)
+        span.attrs["rounds"] = rounds
         span.attrs["shards"] = _row_shards(x_dev)
+        if n_cand > k:
+            # rounds that did not run leave their slots empty, so the
+            # buffer, and with it the programs below, keep one shape
+            for _ in range(rounds, init_steps):
+                all_slots.append(jnp.zeros_like(all_slots[0]))
+                all_valid.append(jnp.zeros_like(all_valid[0]))
+            cand, valid = _candidate_buffer(
+                c0, tuple(all_slots), tuple(all_valid)
+            )
+            # waited for here: this span ends when its device work has,
+            # and the reduction's span is not billed for the weights
+            cand_w = jax.block_until_ready(
+                _candidate_weights(weights_dev, amin, cand.shape[0])
+            )
 
-    # the host's reduction of the candidates to k centers
+    # the reduction of the candidates to k centers (the span's name dates
+    # from the host's loop; it ends when the centers are on the host)
     with spans.child("kmeanspp_host") as span:
-        span.attrs["candidates"] = int(cand.shape[0])
-        if cand.shape[0] <= k:
-            # not enough candidates: top up with random rows
-            extra = init_random(
-                x_dev, n_valid, k - cand.shape[0] + 1, seed + 1, index_map
+        span.attrs["candidates"] = n_cand
+        if n_cand > k:
+            span.attrs["reduced_on"] = "device"
+            # weight candidates by how much row weight they own
+            return reduce_candidates(
+                cand, cand_w, valid, jax.random.fold_in(key, init_steps), k,
+                getattr(x_dev.sharding, "mesh", None),
             )
-            cand = np.concatenate([cand, extra], axis=0)[: max(k, 1)]
-            return (
-                cand[:k]
-                if cand.shape[0] >= k
-                else np.resize(cand, (k, cand.shape[1]))
-            )
-        # weight candidates by how much row weight they own, k-means++ reduce
-        return _weighted_kmeans_pp(cand, cand_w, k, rng)
+        # not enough candidates: top up with random rows, on the host
+        span.attrs["reduced_on"] = "host"
+        cand = np.concatenate(
+            [np.asarray(c0)]
+            + [_to_host(s)[f] for s, f in zip(all_slots, filled)],
+            axis=0,
+        )
+        extra = init_random(
+            x_dev, n_valid, k - cand.shape[0] + 1, seed + 1, index_map
+        )
+        cand = np.concatenate([cand, extra], axis=0)[: max(k, 1)]
+        return (
+            cand[:k]
+            if cand.shape[0] >= k
+            else np.resize(cand, (k, cand.shape[1]))
+        )
 
 
 def _weighted_kmeans_pp(points: np.ndarray, weights: np.ndarray, k: int, rng) -> np.ndarray:

@@ -725,7 +725,7 @@ def init_kmeans_parallel_streamed(
     per-round picks, and the ownership weights are reduced/gathered across
     processes, so every process ends each round with the SAME candidate
     set (the sampling rng is per-process — distinct shards — while the
-    final weighted k-means++ rng is shared).
+    final weighted k-means++ key is shared).
 
     ``weights``: optional width-1 ChunkSource of per-row weights, walked
     in lockstep — they scale the sampling cost (phi = sum w*dmin, like
@@ -747,10 +747,9 @@ def init_kmeans_parallel_streamed(
     # oversampling is robust to far larger perturbations, and parity
     # compares converged cost, survey §7.3)
     stage_dtype = psn.staging_dtype(policy, dtype)
-    # per-process stream for sampling OWN rows; shared stream for the
-    # final reduction (must be identical on every process)
+    # per-process stream for sampling OWN rows (the final reduction's key
+    # is shared: it must be identical on every process)
     samp_rng = np.random.default_rng(seed + 31 * jax.process_index())
-    final_rng = np.random.default_rng(seed + 7777)
 
     c0 = reservoir_sample(source, 1, seed, timings=timings)
     cands = [c0[0]]
@@ -846,7 +845,7 @@ def init_kmeans_parallel_streamed(
         )
         return np.concatenate([cand_arr, extra], axis=0)[:k]
 
-    # ownership pass: weight candidates, then host-side weighted k-means++
+    # ownership pass: weight candidates
     cands_dev = jnp.asarray(cand_arr.astype(dtype))
     own = np.zeros((cand_arr.shape[0],), np.float64)
     stats = PrefetchStats()
@@ -866,7 +865,19 @@ def init_kmeans_parallel_streamed(
                 own += np.asarray(_chunk_ownership(cj, wj, cands_dev))
     stats.finalize(timings, "init_centers", elapsed())
     (own,) = _psum_host([own], guard=guard)
-    return kmeans_ops._weighted_kmeans_pp(cand_arr, own, k, final_rng)
+    # the weighted k-means++ reduction, on the device like the in-memory
+    # route's: slots in whole blocks of ``cap`` (the picks of a round are
+    # not capped in one process, and a buffer shaped by their count would
+    # be a program a fit), the padding marked invalid
+    n_cand = cand_arr.shape[0]
+    slots = np.zeros((cap * -(-n_cand // cap), d), dtype)
+    slots[:n_cand] = cand_arr
+    cand_w = np.zeros((slots.shape[0],), dtype)
+    cand_w[:n_cand] = own
+    return kmeans_ops.reduce_candidates(
+        slots, cand_w, np.arange(slots.shape[0]) < n_cand,
+        jax.random.fold_in(jax.random.PRNGKey(seed), 7777), k,
+    )
 
 
 # ---------------------------------------------------------------------------

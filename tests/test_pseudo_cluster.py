@@ -295,7 +295,12 @@ class TestPseudoCluster:
         from oap_mllib_tpu.models.kmeans import KMeans
 
         x = _oracle_data()
-        oracle = KMeans(k=5, seed=7, max_iter=30).fit(
+        # not the workers' seed 7: in ONE process that seed's k-means++
+        # draws put two centres into one blob and Lloyd stays there (cost
+        # 67487 against 2990.7; on this table 1 seed in 40 does so, a
+        # property of D^2 seeding — P about 3% at the last of the five
+        # draws — not of a world size), and the premise below is the optimum
+        oracle = KMeans(k=5, seed=8, max_iter=30).fit(
             ChunkSource.from_array(x, chunk_rows=512)
         )
         for rank in (0, 1):

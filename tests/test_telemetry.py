@@ -490,12 +490,26 @@ class TestSubSpans:
         assert root.node("init_centers/rounds").attrs["rounds"] == 2
         cand = root.node("init_centers/kmeanspp_host").attrs["candidates"]
         assert 4 < cand <= 1 + 2 * 16  # one seed row + 4k slots a round
+        host = root.node("init_centers/kmeanspp_host")
+        assert host.attrs["reduced_on"] == "device"
         # the tree the exporters serialize carries them too
         tree = dict(_tree_paths(model.summary.telemetry["spans"]))
         up = tree["kmeans.fit/table_convert/upload"]
         assert up["attrs"]["bytes"] == table.data.nbytes + table.mask.nbytes
         copy = tree["kmeans.fit/table_convert/host_copy"]
         assert copy["attrs"]["copied_bytes"] == table.data.nbytes
+
+    def test_too_few_candidates_are_topped_up_on_the_host(self):
+        """Four rows cannot give more than k = 4 candidates: the top-up
+        branch stays on the host and says so."""
+        from oap_mllib_tpu import KMeans
+
+        x = np.eye(4, 6, dtype=np.float32)
+        model = KMeans(k=4, max_iter=2, seed=0).fit(x)
+        host = model.summary.timings.root.node("init_centers/kmeanspp_host")
+        assert 1 <= host.attrs["candidates"] <= 4
+        assert host.attrs["reduced_on"] == "host"
+        assert model.cluster_centers_.shape == (4, 6)
 
     def test_pca_fit_records_the_staging_pair(self, rng):
         from oap_mllib_tpu import PCA

@@ -139,6 +139,29 @@ class TestKMeansKernels:
         )
 
 
+    def test_candidate_reduction_at_the_cells_shape(self, one_chip):
+        """The k-means|| reduction as the benchmark's cell runs it: 1 +
+        4k * 2 = 8001 slots x 256, k = 1000 dependent draws in ONE
+        program whose buffers stay small (no (slots, k) sheet)."""
+        import functools
+
+        from oap_mllib_tpu.ops import kmeans_ops
+
+        m, d, k = 8001, 256, 1000
+        compiled = jax.jit(
+            functools.partial(kmeans_ops._reduce_candidates, k=k)
+        ).lower(
+            _s((m, d), one_chip), _s((m,), one_chip), _s((m,), one_chip),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+        ).compile()
+        text = compiled.as_text()
+        assert " while(" in text  # the k draws are one loop on the device
+        assert "bf16" not in text  # f32 throughout: no product, no argmin
+        mem = compiled.memory_analysis()
+        assert mem.output_size_in_bytes == k * d * 4
+        assert mem.temp_size_in_bytes < m * d * 4
+
+
 class TestAssignment:
     """The XLA assignment every non-Pallas route shares (serving, the
     sharded Lloyd, k-means||): at ``highest`` it promises the f32
@@ -350,6 +373,25 @@ class TestDataParallelKMeans:
         # slots come back replicated: the host fetch needs no re-gather
         slots_sharding = compiled.output_shardings[0]
         assert slots_sharding.is_fully_replicated
+
+    def test_candidate_reduction_runs_replicated(self, mesh):
+        """On the host's mesh every chip reduces the same 8001 slots from
+        the same key: inputs and centres replicated, no traffic."""
+        from oap_mllib_tpu.ops import kmeans_ops
+        from oap_mllib_tpu.utils import progcache
+
+        rep = NamedSharding(mesh, P())
+        progcache.clear()  # the registry may hold another mesh's program
+        m = 1 + 2 * 4 * self.K
+        compiled = kmeans_ops._reduce_candidates_fn(self.K, mesh).lower(
+            _s((m, self.D), rep), _s((m,), rep), _s((m,), rep),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
+        ).compile()
+        text = compiled.as_text()
+        for collective in ("all-gather", "all-reduce", "collective-permute",
+                           "all-to-all"):
+            assert collective not in text
+        assert compiled.output_shardings.is_fully_replicated
 
     def test_upload_joins_a_shards_pieces_on_its_device(self, mesh):
         from oap_mllib_tpu.data import table as table_mod
