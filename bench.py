@@ -204,20 +204,20 @@ def bench_kmeans(precision="highest", cpu_ips=None, extra=None,
     wj = jnp.asarray(w)
     cj = jnp.asarray(init)
     tol = jnp.asarray(0.0, jnp.float32)
-    chunks = kmeans_ops.auto_row_chunks(n, k)
+    # the estimator's own route — one shared function, cannot diverge
+    from oap_mllib_tpu.config import get_config
+    from oap_mllib_tpu.parallel.mesh import get_mesh
 
-    # the estimator's own dispatch rule — one shared helper, cannot diverge
-    use_pallas = kmeans_ops.use_pallas_path("auto", d, k, precision, np.float32)
+    route = kmeans_ops.lloyd_route(
+        get_config(), get_mesh(n_devices=1), n, d, k, np.float32, precision
+    )
+    use_pallas = route.kernel == "pallas"
 
     def run():
-        if use_pallas:
-            from oap_mllib_tpu.ops.pallas.kmeans_kernel import lloyd_run_pallas
-
-            c, it, cost, _ = lloyd_run_pallas(xj, wj, cj, iters, tol, mode=precision)
-        else:
-            c, it, cost, _ = kmeans_ops.lloyd_run(
-                xj, wj, cj, iters, tol, chunks, precision, policy=policy
-            )
+        c, it, cost, _ = kmeans_ops.lloyd_run(
+            xj, wj, cj, iters, tol, route.row_chunks, precision,
+            policy=policy, accumulate=route.kernel, **route.geometry,
+        )
         # fetching the centers synchronizes
         return np.asarray(c), int(it)
 

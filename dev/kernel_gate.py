@@ -55,9 +55,7 @@ def kernel_parity(failures) -> dict:
     from oap_mllib_tpu.ops.pallas.als_kernel import (
         factor_gram_pallas, solve_normal_eq_pallas,
     )
-    from oap_mllib_tpu.ops.pallas.kmeans_kernel import (
-        lloyd_accumulate_pallas,
-    )
+    from oap_mllib_tpu.ops.pallas.kmeans_kernel import lloyd_accumulate_walk
     from oap_mllib_tpu.ops.pallas.pca_kernel import covariance_pallas
     from oap_mllib_tpu.ops.pca_ops import _covariance_jit
     from oap_mllib_tpu.utils import precision as psn
@@ -81,7 +79,7 @@ def kernel_parity(failures) -> dict:
     c = jnp.asarray(centers_true + rng.normal(size=(k, d)).astype(np.float32))
     for mode, atol in (("highest", 1e-3), ("high", 5e-2), ("default", 2.0)):
         s_r, c_r, _ = _accumulate(x, w, c, precision=mode)
-        s_p, c_p, _ = lloyd_accumulate_pallas(
+        s_p, c_p, _ = lloyd_accumulate_walk(
             x, w, c, mode=mode, interpret=True
         )
         dev = float(np.abs(np.asarray(s_p) - np.asarray(s_r)).max())

@@ -130,9 +130,8 @@ def geometry_parity(failures) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from oap_mllib_tpu.ops.pallas.kmeans_kernel import (
-        _BLOCK_ROWS, lloyd_accumulate_pallas, lloyd_accumulate_walk,
-    )
+    from oap_mllib_tpu.ops.kmeans_ops import _accumulate
+    from oap_mllib_tpu.ops.pallas.kmeans_kernel import lloyd_accumulate_walk
     from oap_mllib_tpu.ops.pallas.pca_kernel import pca_moments_pallas
 
     rng = np.random.default_rng(3)
@@ -140,13 +139,16 @@ def geometry_parity(failures) -> dict:
     w = jnp.ones((700,), jnp.float32)
     c = jnp.asarray(rng.normal(size=(5, 9)).astype(np.float32))
 
-    # grid kernel vs walk at the grid's own partition: bit-identical
-    ref = [np.asarray(o) for o in
-           lloyd_accumulate_pallas(x, w, c, interpret=True)]
-    out = [np.asarray(o) for o in lloyd_accumulate_walk(
-        x, w, c, interpret=True, tile_rows=_BLOCK_ROWS, depth=2)]
-    _check(failures, all(np.array_equal(a, b) for a, b in zip(out, ref)),
-           "kmeans walk not bit-identical to grid kernel at _BLOCK_ROWS")
+    # the walk at its default geometry vs the XLA accumulate: f32
+    # rounding of reordered sums (counts exact)
+    ref = [np.asarray(o) for o in _accumulate(x, w, c)]
+    out = [np.asarray(o) for o in
+           lloyd_accumulate_walk(x, w, c, interpret=True)]
+    _check(failures,
+           np.array_equal(out[1], ref[1])
+           and np.allclose(out[0], ref[0], atol=1e-4)
+           and np.allclose(out[2], ref[2], rtol=1e-5),
+           "kmeans walk diverges from the XLA accumulate")
 
     max_dev = 0.0
     refs = {}

@@ -29,9 +29,7 @@ from oap_mllib_tpu.data.table import DenseTable
 from oap_mllib_tpu.fallback.kmeans_np import lloyd_np, predict_np
 from oap_mllib_tpu.ops import kmeans_ops
 from oap_mllib_tpu.ops.pallas import autotune
-from oap_mllib_tpu.parallel import collective
 from oap_mllib_tpu.parallel.mesh import get_mesh
-from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import checkpoint as ckpt_mod
 from oap_mllib_tpu.utils import precision as psn
 from oap_mllib_tpu.utils import progcache
@@ -518,15 +516,14 @@ class KMeans:
         # effect on a retry; the legacy kernel tier maps off it
         pol = psn.resolve("kmeans")
         tier = psn.kernel_tier(pol.name, cfg.matmul_precision)
-        # kmeans_kernel/ring_reduction validation must run on EVERY
-        # accelerated fit (the _run_lloyd invariant): a typo'd value
-        # raises here too, even though the streamed path always runs the
-        # chunked XLA programs (the ring engages in its multi-process
-        # per-pass reductions — stream_ops._ring_mesh)
-        kmeans_ops.use_pallas_path(
-            cfg.kmeans_kernel, source.n_features, self.k, tier, dtype,
+        # the route validates kmeans_kernel/ring_reduction on EVERY
+        # accelerated fit: a typo'd value raises here too, even though
+        # the streamed passes are always the chunked XLA programs (the
+        # ring engages in their multi-process per-pass reductions —
+        # stream_ops._ring_mesh)
+        route = kmeans_ops.lloyd_route(
+            cfg, None, None, source.n_features, self.k, dtype, tier
         )
-        kmeans_ops.ring_mode_cfg(cfg)
         timings = Timings("kmeans.fit")
         cache_before = progcache.stats()
         tune_before = autotune.mark()
@@ -563,7 +560,7 @@ class KMeans:
             cluster_sizes=np.asarray(counts),
         )
         summary.streamed = True
-        summary.kernel = "xla"  # the streamed passes run the chunked XLA programs
+        summary.kernel = route.kernel
         summary.progcache = progcache.delta(cache_before)
         summary.tuning = autotune.delta(tune_before)
         psn.record(summary, timings, pol)
@@ -593,12 +590,15 @@ class KMeans:
         mesh = get_mesh()
         mp = mesh.shape[cfg.model_axis]
         d_orig = x.shape[1]
-        if mp > 1 and cfg.kmeans_kernel != "xla" and d_orig % mp:
+        if d_orig % mp and kmeans_ops.lloyd_route(
+            cfg, mesh, None, d_orig, self.k, dtype,
+            psn.kernel_tier(pol.name, cfg.matmul_precision),
+        ).kernel == "model_sharded":
             # model-sharded Lloyd needs d % model == 0; zero-pad feature
             # columns (zero in data AND centroids — no distance or move
             # contribution) and slice them back off the final centers.
             # Skipped when no padding is needed or when "xla" forces the
-            # GSPMD route — np.pad would copy the whole dataset.
+            # data-parallel route — np.pad would copy the whole dataset.
             from oap_mllib_tpu.data import sparse as _sparse
 
             if _sparse.is_sparse(x):
@@ -677,199 +677,36 @@ class KMeans:
     def _run_lloyd(self, table, weights, centers0, dtype, cfg, mesh,
                    timings=None, degraded=False, pol=None, ckpt=None,
                    resume=None, d_orig=None):
-        """Dispatch the hot loop to the configured kernel.
-
-        ``auto`` follows kmeans_ops.pallas_preferred: the fused Pallas
-        kernel at every tier when (k, d) fits its VMEM blocks — its
-        loop-mode assignment + exact-split cluster sums cut the
-        per-iteration MXU/VPU passes — else the chunked XLA Lloyd.
-        ``xla``/``pallas`` force a path;
-        ``pallas`` requires TPU + one process + f32 and falls back
-        otherwise.  On one device the loop is one jitted program over
-        the table.  On a mesh of more devices it is the data-parallel
-        program (kmeans_ops.lloyd_run_data_sharded): ONE shard_map in
-        which every device runs that same accumulate — kernel or chunked
-        XLA — on its own row shard and the moments are all-reduced each
-        iteration; chunking is legal there because the shard is local.
-        A mesh with a model axis > 1 routes to the feature-sharded
-        shard_map Lloyd — unless ``xla`` is forced, which runs the
-        data-parallel program (centroids replicated, the model axis
-        holding replicas) so the two can be A/B'd on the same mesh.
-        """
+        """Run the hot loop on the route kmeans_ops.lloyd_route picks for
+        this fit — whole, or in checkpointed segments."""
         # the compute-precision policy maps onto the legacy kernel tier
         # (utils/precision.kernel_tier: f32 keeps matmul_precision, tf32
         # the bf16_3x "high" tier, bf16 the single-pass "default" tier) so
-        # the kernel-dispatch rules price it like the tier it runs at —
-        # the bf16 policy now prices ON Pallas (kmeans_ops
-        # .pallas_preferred accepts "default"; ISSUE 9 retired the
-        # routes-off-Pallas workaround)
+        # the route prices it like the tier it runs at
         pol = pol or psn.resolve("kmeans")
         tier = psn.kernel_tier(pol.name, cfg.matmul_precision)
-        # use_pallas_path is the single kmeans_kernel validation point and
-        # must run on EVERY accelerated fit — a typo'd value raises even
-        # when the model-sharded route below makes its answer moot; the
-        # ring_reduction knob validates under the same contract
-        use_pallas = kmeans_ops.use_pallas_path(
-            cfg.kmeans_kernel, table.data.shape[1], self.k, tier, dtype,
-        )
-        kmeans_ops.ring_mode_cfg(cfg)
-        if degraded:
-            # the halved-chunk rung after a device OOM: route off the
-            # fused Pallas kernel (whole-table VMEM residency is exactly
-            # what OOMed) onto the chunked XLA Lloyd at doubled chunk
-            # count — half the live distance buffer per step
-            use_pallas = False
-        if ckpt is not None:
-            # checkpointing segments the loop between compiled calls; the
-            # fused whole-fit Pallas kernel has no segment boundary to
-            # checkpoint at, so route onto the chunked XLA Lloyd
-            # (docs/distributed.md "Elastic worlds")
-            use_pallas = False
-        model_sharded = (
-            mesh.shape[cfg.model_axis] > 1 and cfg.kmeans_kernel != "xla"
+        route = kmeans_ops.lloyd_route(
+            cfg, mesh, table.n_padded, table.data.shape[1], self.k, dtype,
+            tier, degraded=degraded, checkpoint=ckpt is not None,
         )
         if timings is not None:
-            # which Lloyd program the dispatch chose, for the summary
-            timings.root.attrs["kernel"] = (
-                "model_sharded" if model_sharded
-                else "pallas" if use_pallas else "xla"
-            )
-        if model_sharded:
-            # segmented-start ring epilogue geometry: pure function of
-            # (config, cache, bucket) so every rank resolves identically
-            ring_segments = autotune.resolve(
-                "ring",
-                autotune.shape_bucket(
-                    mesh.shape[cfg.data_axis], table.data.shape[1]
-                ),
-            )["segments"]
-
-            def run_iters(c0, iters):
-                return kmeans_ops.lloyd_run_model_sharded(
-                    table.data,
-                    weights,
-                    c0,
-                    iters,
-                    jnp.asarray(self.tol, dtype),
-                    mesh,
-                    cfg.data_axis,
-                    cfg.model_axis,
-                    precision=tier,
-                    timings=timings,
-                    policy=pol.name,
-                    ring_segments=ring_segments,
-                )
-
-            if ckpt is None:
-                return run_iters(centers0, self.max_iter)
-            return self._run_lloyd_segmented(
-                run_iters, centers0, ckpt, resume, d_orig
-            )
-        # tuned tile geometry for the hot loop, resolved for BOTH kernel
-        # routes (the XLA Lloyd derives its chunking from the same tile
-        # rows, so a tuned bucket steers either program)
-        geo = autotune.resolve(
-            "kmeans",
-            autotune.shape_bucket(self.k, table.data.shape[1]),
-            tier,
-        )
-        # one device: the loop is one jitted program over the table; more:
-        # the data-parallel program, the same accumulate on every shard
-        shards = mesh.shape[cfg.data_axis] if mesh.devices.size > 1 else 1
-        if use_pallas and shards == 1:
-            from oap_mllib_tpu.ops.pallas.kmeans_kernel import lloyd_run_pallas
-
-            key = (
-                progcache.backend_fingerprint(),
-                progcache.array_key(table.data, weights),
-                np.asarray(centers0).shape, self.max_iter, tier,
-                geo["tile_rows"], geo["depth"],
-            )
-            with progcache.launch(
-                "kmeans.lloyd_pallas", key, timings, "lloyd_loop"
-            ):
-                return lloyd_run_pallas(
-                    table.data,
-                    weights,
-                    jnp.asarray(centers0),
-                    self.max_iter,
-                    self.tol,
-                    mode=tier,
-                    tile_rows=geo["tile_rows"],
-                    depth=geo["depth"],
-                )
-        rows_per_shard = table.n_padded // shards
-        row_chunks = (
-            1 if use_pallas
-            else self._row_chunks(rows_per_shard, geo, degraded)
-        )
-        ran = []  # iterations of each launch (a checkpointed fit has several)
+            # which Lloyd program the route named, for the summary
+            timings.root.attrs["kernel"] = route.kernel
+        tol = jnp.asarray(self.tol, dtype)
 
         def run_iters(c0, iters):
-            tol = jnp.asarray(self.tol, dtype)
-            if shards == 1:
-                return kmeans_ops.lloyd_run(
-                    table.data, weights, jnp.asarray(c0), iters, tol,
-                    row_chunks=row_chunks, precision=tier, timings=timings,
-                    policy=pol.name,
-                )
-            out = kmeans_ops.lloyd_run_data_sharded(
-                table.data, weights, jnp.asarray(c0), iters, tol,
-                mesh, cfg.data_axis, walk=use_pallas, precision=tier,
-                policy=pol.name, tile_rows=geo["tile_rows"],
-                depth=geo["depth"], row_chunks=row_chunks, timings=timings,
+            return kmeans_ops.lloyd_run(
+                table.data, weights, c0, iters, tol,
+                route.row_chunks, tier, timings, policy=pol.name, mesh=mesh,
+                data_axis=cfg.data_axis, model_axis=cfg.model_axis,
+                accumulate=route.kernel, **route.geometry,
             )
-            ran.append(out[1])
-            return out
 
         if ckpt is None:
-            out = run_iters(centers0, self.max_iter)
-        else:
-            out = self._run_lloyd_segmented(
-                run_iters, centers0, ckpt, resume, d_orig
-            )
-        if shards > 1:
-            # what was reduced is booked once the loop has returned: the
-            # program runs its iterations on the device and only then
-            # says how many there were
-            ran = [int(n) for n in ran]
-            k_rows, d_cols = np.asarray(centers0).shape
-            nbytes = sum(
-                kmeans_ops.lloyd_reduce_bytes(
-                    k_rows, d_cols, np.dtype(dtype).itemsize, n, use_pallas
-                )
-                for n in ran
-            )
-            collective.note_in_program(
-                "psum",
-                sum(n + 1 for n in ran),  # + each launch's final cost pass
-                nbytes * max(1, shards // jax.process_count()),
-            )
-            span = spans.current_span()
-            if span is not None:
-                span.attrs["shards"] = shards
-                span.attrs["rows_per_shard"] = rows_per_shard
-                span.attrs["reduce_bytes"] = nbytes
-        return out
-
-    def _row_chunks(self, rows, geo, degraded):
-        """Chunk count of the XLA Lloyd's scan over ``rows`` resident
-        rows (the table on one device, one shard on a mesh)."""
-        if geo != autotune.DEFAULTS["kmeans"]:
-            # tuned bucket: chunk the scan at the tuned tile rows (the
-            # default geometry keeps auto_row_chunks' occupancy rule
-            # bit-for-bit, so untuned fits are unchanged)
-            row_chunks = max(1, -(-rows // max(geo["tile_rows"], 1)))
-        else:
-            row_chunks = kmeans_ops.auto_row_chunks(rows, self.k)
-        if degraded:
-            # auto_row_chunks returns a chunk COUNT — each geometric
-            # rung doubles it again, halving the rows (and the live
-            # (chunk, k) buffer) per scan step
-            row_chunks = min(
-                row_chunks * (2 ** int(degraded)), max(rows, 1)
-            )
-        return row_chunks
+            return run_iters(centers0, self.max_iter)
+        return self._run_lloyd_segmented(
+            run_iters, centers0, ckpt, resume, d_orig
+        )
 
     def _run_lloyd_segmented(self, run_iters, centers0, ckpt, resume,
                              d_orig):

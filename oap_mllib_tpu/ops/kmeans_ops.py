@@ -18,8 +18,8 @@ TPU-first redesign:
 - Cross-device reduction (per-cluster sums/counts/cost over the row-sharded
   table): the estimator's loop on a mesh is one ``shard_map`` in which
   every device accumulates its own row shard and ``psum`` sums the moments
-  over the ``data`` axis (:func:`lloyd_run_data_sharded`); the k-means||
-  rounds and a direct :func:`lloyd_run` on sharded arrays are global
+  over the ``data`` axis (:func:`lloyd_run` with a ``mesh``); the k-means||
+  rounds and a :func:`lloyd_run` without one on sharded arrays are global
   ``jnp.sum``/matmul/scatter that GSPMD lowers to the same collectives.
   No root rank: results land replicated.
 - Padded rows carry mask weight 0 so they never contribute (survey §2.6
@@ -33,12 +33,16 @@ Spark when a weight column is set, spark-3.1.1/ml/clustering/KMeans.scala:349-35
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from contextlib import nullcontext
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from oap_mllib_tpu.ops.pallas import _dbuf, autotune
+from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
+from oap_mllib_tpu.ops.pallas._tiers import check_mode, kernel_launch
 from oap_mllib_tpu.utils import precision as psn
 from oap_mllib_tpu.parallel import collective
 from oap_mllib_tpu.telemetry import spans
@@ -73,9 +77,9 @@ def _prec(precision: str):
 # temporaries live in VMEM for the whole walk.  Fitted against the
 # chip's compiler under the plane's scoped-VMEM ceiling
 # (ops/pallas/_tiers.VMEM_LIMIT_BYTES): every (k_pad, d_pad) inside
-# these bounds compiles at all three tiers, walk and grid kernel alike
-# (tests/test_tpu_compile.py holds the edge); just outside, k=16384 at
-# d=256 needs 185 MB of the core's 128 and k=d=2048 at "high" 124.
+# these bounds compiles at all three tiers (tests/test_tpu_compile.py
+# holds the edge); just outside, k=16384 at d=256 needs 185 MB of the
+# core's 128 and k=d=2048 at "high" 124.
 PALLAS_MAX_KD = 1 << 21
 PALLAS_MAX_K = 4096
 PALLAS_MAX_D = 4096
@@ -87,9 +91,7 @@ def pallas_preferred(d: int, k: int, precision: str) -> bool:
     half-score assignment + exact-split sums pay 1+2 bf16 passes where
     XLA "high" pays 3+3 and "highest" 6+6; "default" (= the bf16 compute
     policy via precision.kernel_tier) prices ON Pallas too since the
-    counts run as bf16 matmuls (kmeans_kernel._tile_update) —
-    dev/profile_kernels.py's fused-vs-unfused sweep regenerates the
-    evidence per backend.
+    counts run as bf16 matmuls (kmeans_kernel._tile_update).
 
     Large k·d is excluded (bounds above): those fits stay on the chunked
     XLA path."""
@@ -99,29 +101,6 @@ def pallas_preferred(d: int, k: int, precision: str) -> bool:
             or d_pad > PALLAS_MAX_D):
         return False
     return precision in ("highest", "high", "default")
-
-
-def use_pallas_path(kernel_cfg: str, d: int, k: int, precision: str, dtype) -> bool:
-    """Single source of truth for the kernel dispatch (estimator AND
-    bench): the fused Pallas kernel runs only when configured/preferred
-    AND its preconditions hold — TPU backend, one process, f32.  The
-    device count is no precondition: on a mesh every device walks its
-    own row shard with the same kernel (:func:`lloyd_run_data_sharded`).
-    Keeping this in one place prevents the two call sites from silently
-    diverging."""
-    if kernel_cfg not in ("auto", "xla", "pallas"):
-        raise ValueError(
-            f"kmeans_kernel must be auto|xla|pallas, got {kernel_cfg!r}"
-        )
-    want = kernel_cfg == "pallas" or (
-        kernel_cfg == "auto" and pallas_preferred(d, k, precision)
-    )
-    return (
-        want
-        and jax.default_backend() == "tpu"
-        and jax.process_count() == 1
-        and np.dtype(dtype) == np.float32
-    )
 
 
 def ring_mode_cfg(cfg=None) -> str:
@@ -273,8 +252,8 @@ def _accumulate_chunked(x, weights, centers, row_chunks: int,
 
     NOTE the rows must be local: the reshape assumes the leading dim can
     be freely split, which conflicts with GSPMD row-sharding.  On a mesh
-    the estimator calls this inside its ``shard_map``
-    (:func:`lloyd_run_data_sharded`), where ``x`` is one device's shard.
+    :func:`lloyd_run` calls this inside its ``shard_map``, where ``x`` is
+    one device's shard.
     """
     n = x.shape[0]
     if n % row_chunks != 0:
@@ -335,8 +314,9 @@ def auto_row_chunks(n: int, k: int, budget_elems: int = SCORE_BUDGET_ELEMS) -> i
 
 
 def _lloyd_loop(accum, moved_reduce, init_centers, max_iter, tol_sq):
-    """Shared Lloyd loop skeleton (single-program AND model-sharded paths
-    — one definition so convergence/empty-cluster semantics cannot drift).
+    """The Lloyd loop skeleton of every in-memory route — XLA accumulate,
+    Pallas walk, model-sharded — one definition so convergence and
+    empty-cluster semantics cannot drift.
 
     Reference semantics (KMeansDALImpl.cpp:135-168): stop when every
     center's squared L2 move <= tol^2, or at max_iter.  Empty clusters
@@ -371,35 +351,11 @@ def _lloyd_loop(accum, moved_reduce, init_centers, max_iter, tol_sq):
     return centers, n_iter, cost, counts
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("max_iter", "row_chunks", "precision", "policy"),
-)
-def _lloyd_run_jit(
-    x: jax.Array,
-    weights: jax.Array,
-    init_centers: jax.Array,
-    max_iter: int,
-    tol: jax.Array,
-    row_chunks: int = 1,
-    precision: str = "highest",
-    policy: str = "f32",
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    return _lloyd_rows(
-        x, weights, init_centers, max_iter, tol, row_chunks, precision,
-        policy,
-    )
-
-
-def _lloyd_rows(x, weights, init_centers, max_iter, tol, row_chunks,
-                precision, policy, reduce_axis=None):
-    """The chunked XLA Lloyd over the rows at hand (traced; the jitted
-    entries are :func:`_lloyd_run_jit` and the ``shard_map`` program of
-    :func:`lloyd_run_data_sharded`).  ``reduce_axis`` names the mesh
-    axis the rows are sharded over: ``x`` is then ONE device's shard —
-    chunking it is legal because the shard is local — and every
-    accumulate's ``(sums, counts, cost)`` is summed over that axis
-    before the replicated centre update.  None emits no collective."""
+def _xla_accum(x, weights, row_chunks, precision, policy):
+    """``accum(centers, prec)`` of :func:`_lloyd_loop` over the rows at
+    hand with the chunked XLA accumulate.  The rows must be local (one
+    device's table or, inside the ``shard_map``, one device's shard):
+    the scan's reshape splits the leading dim."""
     # rows that don't divide the chunk count pad with weight-0 rows HERE
     # — once per compiled program, outside the while_loop, so the copy
     # cannot re-run per iteration — keeping auto_row_chunks' budget a
@@ -425,18 +381,244 @@ def _lloyd_rows(x, weights, init_centers, max_iter, tol, row_chunks,
             "f32" if need_cost and x.dtype != jnp.bfloat16 else policy
         )
         if row_chunks > 1:
-            moments = _accumulate_chunked(
+            return _accumulate_chunked(
                 x, weights, centers, row_chunks, p, need_cost, pol
             )
-        else:
-            moments = _accumulate(x, weights, centers, p, need_cost, pol)
-        if reduce_axis is None:
-            return moments
-        return collective.psum(moments, reduce_axis)
+        return _accumulate(x, weights, centers, p, need_cost, pol)
 
-    return _lloyd_loop(
-        accum, lambda m: m, init_centers, max_iter, tol * tol
+    return accum
+
+
+def _walk_accum(x_p, w_p, mode, interpret, tile_rows, depth):
+    """``accum(centers, prec)`` of :func:`_lloyd_loop` over the fused
+    tile walk (ops/pallas/kmeans_kernel) on operands already in its
+    padded layout; the kernel's ``(1, k_pad)`` counts and ``(1, 1)``
+    cost come back as the ``(k_pad,)`` and scalar the loop expects."""
+
+    def accum(centers, prec):
+        need_cost = prec is not None
+        scope = "kmeans.lloyd_cost" if need_cost else "kmeans.lloyd_walk"
+        with jax.named_scope(scope):
+            sums, counts, cost = kk._accumulate_walk_any(
+                x_p, w_p, centers, prec or mode, interpret, need_cost,
+                tile_rows, depth,
+            )
+        return sums, counts[0], cost[0, 0]
+
+    return accum
+
+
+def _build_lloyd(mesh, dax, shards, max_iter, precision, policy, walk,
+                 tile_rows, depth, interpret, row_chunks):
+    """Build THE jitted Lloyd program of the in-memory routes (cached by
+    :func:`lloyd_run`): :func:`_lloyd_loop` over one accumulate, on one
+    device or on every shard of the data axis.
+
+    ``walk``: the fused kernel of ops/pallas/kmeans_kernel (the DMA walk
+    on the TPU or under ``interpret``, its schedule-identical XLA scan
+    elsewhere) over operands padded to its layout once, before the loop,
+    inside this program; else the chunked XLA accumulate.
+
+    ``shards == 1``: the program is jitted directly and emits no
+    collective.  More: the whole loop runs inside ONE ``shard_map`` over
+    ``dax`` — the reference's distributed step (local step on each rank,
+    allgather of the partials, master step on the root;
+    KMeansDALImpl.cpp:70-131) as one program: every device accumulates
+    ITS row shard, ``collective.psum`` sums the moments (sums and counts
+    an iteration, counts and cost after the final pass), and the centre
+    update and the convergence test run replicated on the summed values.
+    No root rank."""
+
+    def reduced(accum):
+        if shards == 1:
+            return accum
+
+        def over_shards(centers, prec):
+            sums, counts, cost = accum(centers, prec)
+            if prec is None:
+                sums, counts = collective.psum((sums, counts), dax)
+            else:
+                counts, cost = collective.psum((counts, cost), dax)
+            return sums, counts, cost
+
+        return over_shards
+
+    def program(x, weights, c0, tol):
+        k, d = c0.shape
+        if walk:
+            x_p, w_p, c0 = kk._pad_operands_traced(
+                x, weights, c0, block_rows=tile_rows
+            )
+            accum = _walk_accum(
+                x_p, w_p, precision, interpret, tile_rows, depth
+            )
+        else:
+            accum = _xla_accum(x, weights, row_chunks, precision, policy)
+        centers, n_iter, cost, counts = _lloyd_loop(
+            reduced(accum), lambda m: m, c0, max_iter, tol * tol
+        )
+        # the walk's lane padding comes off (nothing to cut on the XLA route)
+        return centers[:k, :d], n_iter, cost, counts[:k]
+
+    if shards == 1:
+        return jax.jit(program)
+    from jax.sharding import PartitionSpec as P
+
+    return jax.jit(
+        shard_map(
+            program,
+            mesh=mesh,
+            in_specs=(P(dax, None), P(dax), P(), P()),
+            out_specs=(P(), P(), P(), P()),
+            check_vma=False,
+        )
     )
+
+
+def lloyd_shards(mesh, data_axis: str) -> int:
+    """Row shards a Lloyd program reduces over: the data axis of a mesh
+    of more than one device; 1 — one jitted program, no collective — on
+    one device or without a mesh."""
+    if mesh is None or mesh.devices.size == 1:
+        return 1
+    return mesh.shape[data_axis]
+
+
+# what summary.kernel can say: the feature-sharded program, the fused
+# Pallas walk, the chunked XLA accumulate
+LLOYD_ROUTES = ("model_sharded", "pallas", "xla")
+
+
+class LloydRoute(NamedTuple):
+    """:func:`lloyd_route`'s answer; :func:`lloyd_run` takes it as
+    ``row_chunks``, ``accumulate=kernel`` and ``**geometry``."""
+
+    kernel: str  # one of LLOYD_ROUTES: what summary.kernel reports
+    shards: int  # row shards the loop reduces over (1: no collective)
+    row_chunks: int  # scan chunks of the XLA accumulate over a shard's rows
+    geometry: Dict[str, int]  # tile_rows/depth (model_sharded: segments)
+
+
+def _row_chunks(rows: int, k: int, geometry, degraded: int) -> int:
+    """Chunk count of the XLA Lloyd's scan over ``rows`` resident rows
+    (the table on one device, one shard on a mesh)."""
+    if geometry != autotune.DEFAULTS["kmeans"]:
+        # tuned bucket: chunk the scan at the tuned tile rows (the
+        # default geometry keeps auto_row_chunks' occupancy rule
+        # bit-for-bit, so untuned fits are unchanged)
+        row_chunks = max(1, -(-rows // max(geometry["tile_rows"], 1)))
+    else:
+        row_chunks = auto_row_chunks(rows, k)
+    if degraded:
+        # auto_row_chunks returns a chunk COUNT — each geometric rung of
+        # the ladder doubles it again, halving the rows (and the live
+        # (chunk, k) buffer) per scan step
+        row_chunks = min(row_chunks * (2 ** int(degraded)), max(rows, 1))
+    return row_chunks
+
+
+def lloyd_route(cfg, mesh, rows, d: int, k: int, dtype, precision: str,
+                degraded: int = 0, checkpoint: bool = False,
+                backend: str = None, processes: int = None) -> LloydRoute:
+    """The one place that decides which Lloyd program a fit runs, from
+    what can be observed, and the one validator of ``Config.kmeans_kernel``
+    and ``Config.ring_reduction`` — called on EVERY accelerated fit, so a
+    typo raises even where its answer is moot (a streamed fit, a single
+    device).
+
+    ``mesh`` None: the rows are streamed (ops/stream_ops runs its own
+    chunked XLA passes): ``"xla"``.  A model axis > 1: the feature-sharded
+    program — unless ``kmeans_kernel="xla"`` is forced, which runs the
+    data-parallel program with the model axis holding replicas, so the
+    two can be A/B'd on one mesh.  Else the fused walk when it is
+    configured (``"pallas"``) or preferred (``"auto"`` and
+    :func:`pallas_preferred`: the resident blocks fit VMEM) AND its
+    preconditions hold: a TPU ``backend``, one process, f32, and neither
+    of the estimator's two facts — ``degraded`` (the resilience ladder's
+    level after a device OOM: whole-table residency is what OOMed, and
+    each level doubles the XLA chunk count) and ``checkpoint`` (an armed
+    checkpoint segments the loop between compiled calls).  The device
+    count is no precondition: on a mesh every device walks its own shard.
+
+    ``precision`` is the kernel tier the compute-precision policy maps
+    to (utils/precision.kernel_tier).  ``rows`` are the table's padded
+    rows; None (the table does not exist yet, or never will) names the
+    route without resolving tile geometry or chunks.  ``backend`` and
+    ``processes`` default to what jax reports."""
+    kernel_cfg = cfg.kmeans_kernel
+    if kernel_cfg not in ("auto", "xla", "pallas"):
+        raise ValueError(
+            f"kmeans_kernel must be auto|xla|pallas, got {kernel_cfg!r}"
+        )
+    ring_mode_cfg(cfg)
+    if mesh is None:
+        return LloydRoute("xla", 1, 0, {})
+    if mesh.shape[cfg.model_axis] > 1 and kernel_cfg != "xla":
+        # segmented-start ring epilogue geometry: pure function of
+        # (config, cache, bucket) so every rank resolves identically
+        geometry = {} if rows is None else autotune.resolve(
+            "ring", autotune.shape_bucket(mesh.shape[cfg.data_axis], d)
+        )
+        return LloydRoute(
+            "model_sharded", mesh.shape[cfg.data_axis], 1, geometry
+        )
+    want = kernel_cfg == "pallas" or (
+        kernel_cfg == "auto" and pallas_preferred(d, k, precision)
+    )
+    walk = (
+        want
+        and (backend or jax.default_backend()) == "tpu"
+        and (processes or jax.process_count()) == 1
+        and np.dtype(dtype) == np.float32
+        and not degraded
+        and not checkpoint
+    )
+    kernel = "pallas" if walk else "xla"
+    shards = lloyd_shards(mesh, cfg.data_axis)
+    if rows is None:
+        return LloydRoute(kernel, shards, 0, {})
+    # resolved for BOTH accumulates: the XLA Lloyd derives its chunking
+    # from the same tile rows, so a tuned bucket steers either program
+    geometry = autotune.resolve(
+        "kmeans", autotune.shape_bucket(k, d), precision
+    )
+    row_chunks = (
+        1 if walk else _row_chunks(rows // shards, k, geometry, degraded)
+    )
+    return LloydRoute(kernel, shards, row_chunks, geometry)
+
+
+def lloyd_reduce_bytes(k: int, d: int, itemsize: int, n_iter: int,
+                       walk: bool) -> int:
+    """Bytes ONE device hands to the reductions of a data-parallel Lloyd
+    run of ``n_iter`` iterations: ``(k, d)`` sums and ``(k,)`` counts an
+    iteration, counts and the cost scalar after the final pass.  The
+    walk reduces its lane-padded blocks (k and d up to multiples of
+    128), the XLA accumulate the exact shapes."""
+    if walk:
+        k, d = -(-k // 128) * 128, -(-d // 128) * 128
+    return (n_iter * (k * d + k) + k + 1) * itemsize
+
+
+def _book_reductions(shards, rows_per_shard, k, d, itemsize, n_iter, walk):
+    """What one launch of a data-parallel program reduced, booked once it
+    has returned: the program runs its iterations on the device and only
+    then says how many there were.  A checkpointed fit launches several
+    segments; the ``lloyd_loop`` span sums their bytes."""
+    n_iter = int(n_iter)
+    nbytes = lloyd_reduce_bytes(k, d, itemsize, n_iter, walk)
+    collective.note_in_program(
+        "psum",
+        n_iter + 1,  # + the final cost pass
+        nbytes * max(1, shards // jax.process_count()),
+    )
+    span = spans.current_span()
+    if span is not None:
+        span.attrs["shards"] = shards
+        span.attrs["rows_per_shard"] = rows_per_shard
+        span.attrs["reduce_bytes"] = (
+            span.attrs.get("reduce_bytes", 0) + nbytes
+        )
 
 
 def lloyd_run(
@@ -450,27 +632,90 @@ def lloyd_run(
     timings=None,
     phase: str = "lloyd_loop",
     policy: str = "f32",
+    *,
+    mesh=None,
+    data_axis: str = "data",
+    model_axis: str = "model",
+    accumulate: str = "xla",
+    tile_rows: int = autotune.DEFAULTS["kmeans"]["tile_rows"],
+    depth: int = autotune.DEFAULTS["kmeans"]["depth"],
+    segments: int = 1,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Full Lloyd optimization: returns (centers, n_iter, cost, counts).
+    The one entry of every in-memory route; semantics in
+    :func:`_lloyd_loop` (the reference's convergence contract,
+    KMeansDALImpl.cpp:135-168).
 
-    Semantics in :func:`_lloyd_loop` (the reference's convergence contract,
-    KMeansDALImpl.cpp:135-168).  The launch is registered with the
-    program-cache registry (utils/progcache) so fits report how many
-    programs they compiled vs reused; ``timings`` (when given) receives
-    the ``<phase>/compile`` / ``<phase>/execute`` wall split.  ``policy``
-    is the compute-precision policy (utils/precision.py) threaded into
-    every matmul of the loop.
+    ``accumulate`` names the route, in :func:`lloyd_route`'s vocabulary
+    (pass its answer: ``accumulate=route.kernel, **route.geometry``):
+    ``"xla"`` the chunked XLA accumulate at ``row_chunks`` chunks of the
+    resident rows, under ``policy`` (utils/precision.py); ``"pallas"``
+    the fused tile walk at ``tile_rows``/``depth`` (f32 only;
+    ``interpret`` runs the DMA kernel's program structure on the CPU);
+    ``"model_sharded"`` the feature-sharded program
+    (:func:`lloyd_run_model_sharded`, ``segments`` its ring epilogue's).
+
+    ``mesh`` of more than one device: the table is row-sharded on
+    ``data_axis``, every device accumulates its own shard and the
+    moments are all-reduced (:func:`_build_lloyd`; centres, cost and
+    counts come back replicated; a model axis holds replicas).  One
+    device, or no mesh: one jitted program over the arrays as they lie —
+    on sharded arrays GSPMD places it.
+
+    The program is built once per (world, statics) in the program-cache
+    registry and each launch is registered there, so fits report how
+    many programs they compiled vs reused; ``timings`` (when given)
+    receives the ``<phase>/compile`` / ``<phase>/execute`` wall split.
+    A launch on more than one shard books what it reduced
+    (``oap_collective_ops_total{op="psum"}``, the active span's
+    ``shards`` / ``rows_per_shard`` / ``reduce_bytes``) once it has
+    returned, which waits for its iteration count.
     """
-    key = (
-        progcache.backend_fingerprint(),
-        progcache.array_key(x, weights, init_centers),
-        max_iter, row_chunks, precision, policy,
-    )
-    with progcache.launch("kmeans.lloyd_run", key, timings, phase):
-        return _lloyd_run_jit(
-            x, weights, init_centers, max_iter, tol,
-            row_chunks=row_chunks, precision=precision, policy=policy,
+    if accumulate not in LLOYD_ROUTES:
+        raise ValueError(
+            f"accumulate must be one of {LLOYD_ROUTES}, got {accumulate!r}"
         )
+    if accumulate == "model_sharded":
+        return lloyd_run_model_sharded(
+            x, weights, init_centers, max_iter, tol, mesh, data_axis,
+            model_axis, precision, timings, phase, policy,
+            ring_segments=segments,
+        )
+    walk = accumulate == "pallas"
+    shards = lloyd_shards(mesh, data_axis)
+    # only what the chosen accumulate reads keys its program
+    if walk:
+        check_mode(precision)
+        statics = (policy, True, int(tile_rows), _dbuf.check_depth(depth),
+                   bool(interpret), 1)
+    else:
+        statics = (policy, False, 0, 0, False, int(row_chunks))
+    world = (
+        (progcache.mesh_fingerprint(mesh), data_axis) if shards > 1
+        else progcache.backend_fingerprint()
+    )
+    fn = progcache.get_or_build(
+        "kmeans.lloyd", (world, shards, max_iter, precision) + statics,
+        lambda: _build_lloyd(
+            mesh, data_axis, shards, max_iter, precision, *statics
+        ),
+    )
+    key = (
+        world, progcache.array_key(x, weights),
+        np.shape(init_centers), max_iter, precision,
+    ) + statics
+    # the walk books a Pallas wrapper dispatch like every kernel entry
+    booked = kernel_launch("kmeans.lloyd_loop") if walk else nullcontext()
+    with progcache.launch("kmeans.lloyd_run", key, timings, phase), booked:
+        out = fn(x, weights, jnp.asarray(init_centers), tol)
+    if shards > 1:
+        k, d = np.shape(init_centers)
+        _book_reductions(
+            shards, x.shape[0] // shards, k, d, np.dtype(x.dtype).itemsize,
+            out[1], walk,
+        )
+    return out
 
 
 def _lloyd_model_sharded_fn(mesh, dax: str, max_: str, max_iter: int,
@@ -653,132 +898,6 @@ def lloyd_run_model_sharded(
     with progcache.launch("kmeans.lloyd_model_sharded.run", key, timings,
                           phase):
         return fn(x, weights, jnp.asarray(init_centers), tol * tol)
-
-
-def _lloyd_data_sharded_fn(mesh, dax: str, max_iter: int, precision: str,
-                           policy: str, walk: bool, tile_rows: int,
-                           depth: int, row_chunks: int):
-    """Compiled data-parallel Lloyd program, one per (mesh, statics) in
-    the program registry (a fresh jit(shard_map) closure per fit would
-    recompile)."""
-    key = (
-        progcache.mesh_fingerprint(mesh), dax, max_iter, precision, policy,
-        walk, tile_rows, depth, row_chunks,
-    )
-    return progcache.get_or_build(
-        "kmeans.lloyd_data_sharded", key,
-        lambda: _build_lloyd_data_sharded(
-            mesh, dax, max_iter, precision, policy, walk, tile_rows, depth,
-            row_chunks,
-        ),
-    )
-
-
-def _build_lloyd_data_sharded(mesh, dax: str, max_iter: int, precision: str,
-                              policy: str, walk: bool, tile_rows: int,
-                              depth: int, row_chunks: int):
-    """Build the jitted data-parallel Lloyd program (cached above): the
-    whole ``while_loop`` inside ONE ``shard_map`` over the data axis.
-
-    The reference's distributed step (local step on each rank, allgather
-    of the partials, master step on the root; KMeansDALImpl.cpp:70-131)
-    as one program: every device runs the one-device accumulate on ITS
-    row shard — ``walk``: the fused kernel of
-    ops/pallas/kmeans_kernel (the DMA walk on the TPU, its
-    schedule-identical XLA scan elsewhere), same tile geometry and tier
-    as on one chip; else the chunked XLA accumulate, whose scan reshape
-    is legal because the shard is local — the moments are summed over
-    ``dax`` (``collective.psum``: sums, counts, and cost on the final
-    pass), and the centre update and the convergence test run replicated
-    on the summed values.  No root rank; one program a fit."""
-    from jax.sharding import PartitionSpec as P
-
-    def rank_program(x_blk, w_blk, c0, tol):
-        if walk:
-            from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
-
-            k, d = c0.shape
-            x_p, w_p, c_p = kk._pad_operands_traced(
-                x_blk, w_blk, c0, block_rows=tile_rows
-            )
-            centers, n_iter, cost, counts = kk._lloyd_loop_padded(
-                x_p, w_p, c_p, max_iter, tol, precision, False, tile_rows,
-                depth, reduce_axis=dax,
-            )
-            return centers[:k, :d], n_iter, cost, counts[:k]
-        return _lloyd_rows(
-            x_blk, w_blk, c0, max_iter, tol, row_chunks, precision, policy,
-            reduce_axis=dax,
-        )
-
-    return jax.jit(
-        shard_map(
-            rank_program,
-            mesh=mesh,
-            in_specs=(P(dax, None), P(dax), P(), P()),
-            out_specs=(P(), P(), P(), P()),
-            check_vma=False,
-        )
-    )
-
-
-def lloyd_reduce_bytes(k: int, d: int, itemsize: int, n_iter: int,
-                       walk: bool) -> int:
-    """Bytes ONE device hands to the reductions of a data-parallel Lloyd
-    run of ``n_iter`` iterations: ``(k, d)`` sums and ``(k,)`` counts an
-    iteration, counts and the cost scalar after the final pass.  The
-    walk reduces its lane-padded blocks (k and d up to multiples of
-    128), the XLA accumulate the exact shapes."""
-    if walk:
-        k, d = -(-k // 128) * 128, -(-d // 128) * 128
-    return (n_iter * (k * d + k) + k + 1) * itemsize
-
-
-def lloyd_run_data_sharded(
-    x: jax.Array,
-    weights: jax.Array,
-    init_centers: jax.Array,
-    max_iter: int,
-    tol: jax.Array,
-    mesh,
-    data_axis: str,
-    walk: bool = False,
-    precision: str = "highest",
-    policy: str = "f32",
-    tile_rows: int = 512,
-    depth: int = 2,
-    row_chunks: int = 0,
-    timings=None,
-    phase: str = "lloyd_loop",
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Lloyd loop over a table row-sharded on ``data_axis``: the normal
-    in-memory route on any mesh of more than one device.
-
-    Same semantics and return contract as :func:`lloyd_run` (centres,
-    cost and counts come back replicated).  ``walk`` runs the fused
-    kernel at ``tile_rows``/``depth`` on each shard (f32 only; the
-    caller's dispatch rule is :func:`use_pallas_path`); otherwise the
-    chunked XLA accumulate at ``row_chunks`` chunks a SHARD (0 = the
-    occupancy rule :func:`auto_row_chunks` on the shard's rows).  A
-    model axis, where the mesh has one, holds replicas."""
-    rows_per_shard = x.shape[0] // mesh.shape[data_axis]
-    if walk:
-        row_chunks = 1
-    elif row_chunks <= 0:
-        row_chunks = auto_row_chunks(rows_per_shard, init_centers.shape[0])
-    fn = _lloyd_data_sharded_fn(
-        mesh, data_axis, max_iter, precision, policy, bool(walk),
-        int(tile_rows), int(depth), int(row_chunks),
-    )
-    key = (
-        progcache.mesh_fingerprint(mesh),
-        progcache.array_key(x, weights),
-        np.asarray(init_centers).shape, max_iter, precision, policy,
-        bool(walk), int(tile_rows), int(depth), int(row_chunks),
-    )
-    with progcache.launch("kmeans.lloyd_data_sharded.run", key, timings,
-                          phase):
-        return fn(x, weights, jnp.asarray(init_centers), tol)
 
 
 @jax.jit

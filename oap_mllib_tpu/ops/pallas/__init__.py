@@ -5,7 +5,3 @@ the table.  Each kernel has the same contract as its XLA counterpart in
 ops/ and is opt-in via config (``use_pallas``) with automatic fallback
 off-TPU (interpret mode keeps them testable on the CPU pseudo-cluster).
 """
-
-from oap_mllib_tpu.ops.pallas.kmeans_kernel import lloyd_accumulate_pallas
-
-__all__ = ["lloyd_accumulate_pallas"]

@@ -1,6 +1,6 @@
 """Shared double-buffered tile-walk plumbing for the Pallas kernel plane.
 
-The grid-pipelined kernels (kmeans/pca/als ``pallas_call`` grids) lean on
+The grid-pipelined kernels (pca/als ``pallas_call`` grids) lean on
 the Mosaic pipeline to stage the next block while the current one
 computes.  The communication-avoiding restructure (ROADMAP item 4, the
 rank-k-update formulation of arXiv:2601.17136) makes that overlap
@@ -39,7 +39,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from oap_mllib_tpu.ops.pallas._tiers import LANE
 
-DEPTHS = (2, 3, 4)  # supported rotation depths (1 means "use the grid kernel")
+# supported rotation depths.  Below 2 PCA's and ALS's entries switch to
+# their grid kernels (ROADMAP D3); K-Means has the walk alone, and
+# autotune.parse_mode refuses any other depth for it
+DEPTHS = (2, 3, 4)
 
 
 def check_depth(depth: int) -> int:

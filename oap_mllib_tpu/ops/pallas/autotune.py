@@ -48,6 +48,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from oap_mllib_tpu.ops.pallas import _dbuf
 from oap_mllib_tpu.utils import locktrace, progcache
 
 log = logging.getLogger("oap_mllib_tpu")
@@ -62,6 +63,11 @@ KNOBS = {
     "als_gram": ("tile_rows", "depth"),
     "ring": ("segments",),
 }
+
+# kernels with ONE Pallas form, the double-buffered walk: their ``depth``
+# is a rotation depth (_dbuf.DEPTHS) and nothing else.  The others still
+# switch to their grid form below depth 2 (ROADMAP D3).
+WALK_ONLY = ("kmeans",)
 
 # the hand-picked constants every kernel shipped with — mode "off", and
 # the no-cache fallback of mode "auto"
@@ -147,6 +153,13 @@ def parse_mode(spec: str) -> Tuple[str, Optional[Dict[str, Dict[str, int]]]]:
                         f"Config.tuning pin {kern}.{knob} must be an "
                         f"integer, got {val!r}"
                     )
+                if (kern in WALK_ONLY and knob == "depth"
+                        and val not in _dbuf.DEPTHS):
+                    raise ValueError(
+                        f"Config.tuning pin {kern}.depth must be one of "
+                        f"{_dbuf.DEPTHS} (the walk's rotation depth; "
+                        f"{kern} has no other kernel form), got {val!r}"
+                    )
         return "pin", pins
     raise ValueError(
         f"Config.tuning must be one of {MODES} or 'pin:<json>', "
@@ -197,6 +210,7 @@ def _valid_geometry(kernel: str, geo: Any) -> bool:
             isinstance(v, int) and not isinstance(v, bool)
             for v in geo.values()
         )
+        and (kernel not in WALK_ONLY or geo["depth"] in _dbuf.DEPTHS)
     )
 
 

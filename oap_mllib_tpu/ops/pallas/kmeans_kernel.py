@@ -3,12 +3,11 @@
 The XLA path (ops/kmeans_ops._accumulate) materializes the (n, k) distance
 matrix and an (n, k) one-hot in HBM each iteration — 2*n*k*4 bytes of
 traffic on top of reading X.  This kernel streams X once per iteration:
-for each row block, it computes the (bn, k) distances in VMEM, reduces
-min/argmin on the VPU, forms the block one-hot in VMEM, and accumulates
-``one_hot.T @ x`` into the (k, d) sums output, exploiting the TPU grid's
-sequential execution for safe read-modify-write accumulation (the pallas
-accumulate pattern).  HBM traffic per iteration drops from
-O(n*d + 2*n*k) to O(n*d + k*d).
+for each row tile, it computes the (bn, k) distances in VMEM, reduces
+min/argmin on the VPU, forms the tile one-hot in VMEM, and accumulates
+``one_hot.T @ x`` into the (k, d) sums, which stay VMEM-resident for the
+whole walk (tiles are visited strictly in order).  HBM traffic per
+iteration drops from O(n*d + 2*n*k) to O(n*d + k*d).
 
 Precision tiers (``mode``) — shared vocabulary in ops/pallas/_tiers.py
 (Mosaic only lowers Precision.HIGHEST/DEFAULT, so split tiers are
@@ -24,12 +23,19 @@ implemented by hand with bf16 hi/lo splits):
 - ``default``: bf16 assignment + SINGLE-pass bf16 sums — the XLA default
   tier's ~1e-3 error envelope at its speed.
 
-Caller contract (see ``lloyd_accumulate_pallas``): rows padded to the block
-size with weight 0; k and d padded to lane multiples (128) by the wrapper —
-dummy centers get +inf-like coordinates so no row ever selects them.  The
-single-shot path pads INSIDE one jitted program (pad + kernel + slice),
-so progcache sees one program per input signature instead of a spray of
-eager padding dispatches per call (ISSUE 9 satellite).
+One Pallas form: the double-buffered tile walk
+(``_pallas_accumulate_dbuf``: x stays in HBM, each ``(tile_rows, d)`` tile
+streams into a rotating VMEM buffer while the previous tile's update runs)
+on the TPU or under ``interpret``, and its schedule-identical ``lax.scan``
+twin (``_xla_walk``) elsewhere, both over ``_tile_update``.
+
+Caller contract (``_pad_operands_traced``): rows padded to the tile size
+with weight 0; k and d padded to lane multiples (128) — dummy centers get
++inf-like coordinates so no row ever selects them.  The Lloyd loop over
+this accumulate, its row sharding and its reductions are
+ops/kmeans_ops.lloyd_run's; this module holds the tile program and the
+single-shot :func:`lloyd_accumulate_walk` (pad + walk + slice in one
+jitted program) that the autotuner and the tests call.
 """
 
 from __future__ import annotations
@@ -54,7 +60,6 @@ from oap_mllib_tpu.ops.pallas._tiers import (
     pad_to,
     split_bf16,
 )
-from oap_mllib_tpu.parallel import collective
 from oap_mllib_tpu.utils import progcache
 
 _BLOCK_ROWS = 512
@@ -93,9 +98,9 @@ def _cluster_sums(one_hot01, wx, mode):
 def _tile_update(x, w, c, mode, need_cost):
     """One resident tile's full fused update: assignment + moment
     accumulation with the one-hot/centered intermediates living and
-    dying in VMEM (never HBM).  Shared by the grid kernel, the
-    double-buffered walk kernel, and the schedule-identical XLA
-    fallback, so the three cannot drift a bit.  Returns
+    dying in VMEM (never HBM).  Shared by the double-buffered walk
+    kernel and its schedule-identical XLA twin, so the two cannot drift
+    a bit.  Returns
     ``(sums_inc (k, d), counts_inc (1, k), cost_inc | None)``."""
     k = c.shape[0]
     c_sq = jnp.sum(c * c, axis=1)[None, :]  # (1, k)
@@ -142,58 +147,6 @@ def _tile_update(x, w, c, mode, need_cost):
         counts_inc = dot_bf16(w_hi.T, oh, dn) + dot_bf16(w_lo.T, oh, dn)
     cost_inc = jnp.sum(min_d2 * w) if need_cost else None
     return sums_inc, counts_inc, cost_inc
-
-
-def _make_kernel(mode, need_cost=True):
-    def _kernel(x_ref, w_ref, c_ref, sums_ref, counts_ref, cost_ref):
-        """One grid step: process a (bn, d) row block against all k centers."""
-        # zero accumulators on the first block (sequential TPU grid)
-        @pl.when(pl.program_id(0) == 0)
-        def _init():
-            sums_ref[:] = jnp.zeros_like(sums_ref)
-            counts_ref[:] = jnp.zeros_like(counts_ref)
-            cost_ref[0, 0] = jnp.float32(0.0)
-
-        sums_inc, counts_inc, cost_inc = _tile_update(
-            x_ref[:], w_ref[:], c_ref[:], mode, need_cost
-        )
-        sums_ref[:] += sums_inc
-        counts_ref[:] += counts_inc
-        if need_cost:
-            cost_ref[0, 0] += cost_inc
-
-    return _kernel
-
-
-def _pallas_accumulate(x, w, centers, mode="highest", interpret=False,
-                       need_cost=True, block_rows=_BLOCK_ROWS):
-    """Raw pallas_call on pre-padded operands (traced inside the jitted
-    wrappers below — no jit of its own)."""
-    n, d = x.shape
-    k = centers.shape[0]
-    grid = (n // block_rows,)
-    sums, counts, cost = pl.pallas_call(
-        _make_kernel(mode, need_cost),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((k, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, k), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        **compiled_kwargs(interpret, vmem_limit_bytes=VMEM_LIMIT_BYTES),
-    )(x, w, centers)
-    return sums, counts, cost
 
 
 # -- double-buffered walk (explicit DMA overlap; ROADMAP item 4) -------------
@@ -310,6 +263,26 @@ def _accumulate_walk_any(x_p, w_p, c_p, mode, interpret, need_cost,
     return _xla_walk(x_p, w_p, c_p, mode, need_cost, tile_rows)
 
 
+def _pad_operands_traced(x, weights, centers, block_rows=_BLOCK_ROWS):
+    """The walk's operand layout (traced, never eager — the caller's
+    jitted program pads once, before its loop): rows to the tile
+    multiple, k and d to lane multiples.  Dummy
+    centers sit at 1e15 so no real row selects them; dummy feature
+    columns of real centers are 0 (matching padded x columns)."""
+    n, d = x.shape
+    k = centers.shape[0]
+    n_pad = pad_to(max(n, block_rows), block_rows)
+    d_pad = pad_to(d, LANE)
+    k_pad = pad_to(k, LANE)
+    x_p = jnp.zeros((n_pad, d_pad), jnp.float32).at[:n, :d].set(x.astype(jnp.float32))
+    w_p = jnp.zeros((n_pad, 1), jnp.float32).at[:n, 0].set(weights.astype(jnp.float32))
+    c_p = jnp.full((k_pad, d_pad), 1e15, jnp.float32).at[:k, :d].set(
+        centers.astype(jnp.float32)
+    )
+    c_p = c_p.at[:k, d:].set(0.0)
+    return x_p, w_p, c_p
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("mode", "interpret", "need_cost", "tile_rows", "depth"),
@@ -335,9 +308,11 @@ def lloyd_accumulate_walk(
     tile_rows: int = _BLOCK_ROWS,
     depth: int = 2,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Double-buffered fused accumulate: same contract (and bits) as
-    :func:`lloyd_accumulate_pallas`, with explicit DMA/compute overlap
-    and tunable geometry (ops/pallas/autotune.py)."""
+    """Single-shot fused accumulate, a drop-in for
+    ops.kmeans_ops._accumulate (f32 only): ``(sums (k, d), counts (k,),
+    cost)``.  One registry-tracked jitted program per input signature,
+    padding included; ``tile_rows``/``depth`` are the tunable geometry
+    (ops/pallas/autotune.py)."""
     mode = check_mode(mode)
     _dbuf.check_depth(depth)
     progcache.note(
@@ -351,197 +326,3 @@ def lloyd_accumulate_walk(
             x, weights, centers, mode, interpret, True, int(tile_rows),
             int(depth),
         )
-
-
-@functools.partial(jax.jit, static_argnames=("mode", "interpret", "need_cost"))
-def _call(x, w, centers, mode="highest", interpret=False, need_cost=True):
-    return _pallas_accumulate(x, w, centers, mode, interpret, need_cost)
-
-
-def _pad_operands_traced(x, weights, centers, block_rows=_BLOCK_ROWS):
-    """Padding math shared by the jitted wrappers (traced, never eager):
-    rows to the row-block multiple, k and d to lane multiples.  Dummy
-    centers sit at 1e15 so no real row selects them; dummy feature
-    columns of real centers are 0 (matching padded x columns)."""
-    n, d = x.shape
-    k = centers.shape[0]
-    n_pad = pad_to(max(n, block_rows), block_rows)
-    d_pad = pad_to(d, LANE)
-    k_pad = pad_to(k, LANE)
-    x_p = jnp.zeros((n_pad, d_pad), jnp.float32).at[:n, :d].set(x.astype(jnp.float32))
-    w_p = jnp.zeros((n_pad, 1), jnp.float32).at[:n, 0].set(weights.astype(jnp.float32))
-    c_p = jnp.full((k_pad, d_pad), 1e15, jnp.float32).at[:k, :d].set(
-        centers.astype(jnp.float32)
-    )
-    c_p = c_p.at[:k, d:].set(0.0)
-    return x_p, w_p, c_p
-
-
-def _pad_operands(x, weights, centers, block_rows=_BLOCK_ROWS):
-    """One compiled program per shape signature for the loop entry's pad
-    step — previously ~6 eager dispatches per call.  Built through the
-    program-cache registry (R1: jit lives in a get_or_build builder)."""
-    fn = progcache.get_or_build(
-        "kmeans.pallas_pad", (block_rows,),
-        lambda: jax.jit(
-            functools.partial(_pad_operands_traced, block_rows=block_rows)
-        ),
-    )
-    return fn(x, weights, centers)
-
-
-def _accum_any(x_p, w_p, centers, mode, interpret, need_cost, tile_rows,
-               depth):
-    """Kernel-variant dispatch on pre-padded operands: the grid-pipelined
-    kernel at depth < 2, the explicit double-buffered walk (DMA kernel on
-    TPU/interpret, schedule-identical XLA scan elsewhere) at depth >= 2.
-    All variants share ``_tile_update``, so this choice never moves a
-    result bit — only the overlap."""
-    if depth >= 2:
-        return _accumulate_walk_any(
-            x_p, w_p, centers, mode, interpret, need_cost, tile_rows, depth
-        )
-    return _pallas_accumulate(
-        x_p, w_p, centers, mode, interpret, need_cost, tile_rows
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("mode", "interpret", "need_cost", "tile_rows", "depth"),
-)
-def _accumulate_jit(x, weights, centers, mode, interpret, need_cost,
-                    tile_rows=_BLOCK_ROWS, depth=0):
-    """Single-shot fused accumulate: pad + kernel + slice in ONE jitted
-    program.  The old path ran ``_pad_operands`` eagerly before a jitted
-    kernel call — roughly six XLA dispatches of padding scatter/concat per
-    invocation that the program cache could not see (``lloyd_run_pallas``
-    pads once outside its loop and never had the problem)."""
-    k, d = centers.shape[0], x.shape[1]
-    x_p, w_p, c_p = _pad_operands_traced(
-        x, weights, centers, block_rows=tile_rows
-    )
-    sums, counts, cost = _accum_any(
-        x_p, w_p, c_p, mode, interpret, need_cost, tile_rows, depth
-    )
-    return sums[:k, :d], counts[0, :k], cost[0, 0]
-
-
-def _norm_geometry(tile_rows, depth):
-    """Normalize optional tuned geometry to the static (tile_rows, depth)
-    pair the jitted entries key on: None -> the hand-picked defaults
-    (grid kernel at the 512-row block)."""
-    tile_rows = _BLOCK_ROWS if tile_rows is None else int(tile_rows)
-    depth = 0 if depth is None else int(depth)
-    if depth >= 2:
-        _dbuf.check_depth(depth)
-    return tile_rows, depth
-
-
-def lloyd_accumulate_pallas(
-    x: jax.Array,
-    weights: jax.Array,
-    centers: jax.Array,
-    mode: str = "highest",
-    interpret: bool = False,
-    tile_rows: int = None,
-    depth: int = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Drop-in replacement for ops.kmeans_ops._accumulate (f32 only).
-
-    One registry-tracked jitted program per input signature (padding
-    included — see ``_accumulate_jit``).  ``tile_rows``/``depth`` carry
-    tuned geometry (ops/pallas/autotune.py); depth >= 2 routes to the
-    double-buffered walk, bit-identical by construction.
-    """
-    mode = check_mode(mode)
-    tile_rows, depth = _norm_geometry(tile_rows, depth)
-    progcache.note(
-        "kmeans.pallas_accumulate",
-        (progcache.backend_fingerprint(),
-         progcache.array_key(x, weights, centers), mode, interpret,
-         tile_rows, depth),
-    )
-    with kernel_launch("kmeans.accumulate"):
-        return _accumulate_jit(
-            x, weights, centers, mode, interpret, True, tile_rows, depth
-        )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("max_iter", "mode", "interpret", "tile_rows", "depth",
-                     "reduce_axis"),
-)
-def _lloyd_loop_padded(x_p, w_p, c_p, max_iter, tol, mode="highest",
-                       interpret=False, tile_rows=_BLOCK_ROWS, depth=0,
-                       reduce_axis=None):
-    """while_loop over the fused kernel on pre-padded operands.
-
-    ``reduce_axis`` names the mesh axis the rows are sharded over when
-    the loop runs inside a ``shard_map`` (ops/kmeans_ops
-    .lloyd_run_data_sharded): each device's operands are then ITS shard,
-    the moments ``(sums, counts)`` are summed over that axis after every
-    accumulate and ``(cost, counts)`` after the final cost pass, and the
-    centre update and the convergence test run replicated on the summed
-    values.  None (one device) emits no collective: the program is the
-    one it was."""
-    tol_sq = tol * tol
-
-    def reduce(*moments):
-        if reduce_axis is None:
-            return moments
-        return collective.psum(moments, reduce_axis)
-
-    def cond(state):
-        _, it, converged = state
-        return jnp.logical_and(it < max_iter, jnp.logical_not(converged))
-
-    def body(state):
-        centers, it, _ = state
-        sums, counts, _ = _accum_any(
-            x_p, w_p, centers, mode, interpret, False, tile_rows, depth
-        )
-        sums, counts = reduce(sums, counts)
-        counts_col = counts[0][:, None]  # (k_pad, 1)
-        new_centers = jnp.where(
-            counts_col > 0, sums / jnp.maximum(counts_col, 1e-30), centers
-        )
-        moved_sq = jnp.sum((new_centers - centers) ** 2, axis=1)
-        converged = jnp.all(moved_sq <= tol_sq)
-        return new_centers, it + 1, converged
-
-    state = (c_p, jnp.asarray(0, jnp.int32), jnp.asarray(False))
-    with jax.named_scope("kmeans.lloyd_walk"):
-        centers, n_iter, _ = jax.lax.while_loop(cond, body, state)
-    # final cost + counts w.r.t. the returned centers, always at full
-    # precision — the user-facing objective should not carry the fast
-    # tiers' distance error
-    with jax.named_scope("kmeans.lloyd_cost"):
-        _, counts, cost = _accum_any(
-            x_p, w_p, centers, "highest", interpret, True, tile_rows, depth
-        )
-        cost, counts = reduce(cost, counts)
-    return centers, n_iter, cost[0, 0], counts[0]
-
-
-def lloyd_run_pallas(x, weights, init_centers, max_iter, tol,
-                     mode: str = "highest", interpret: bool = False,
-                     tile_rows: int = None, depth: int = None):
-    """Fused-kernel Lloyd loop; same contract as ops.kmeans_ops.lloyd_run
-    (f32, adds per-cluster counts). Pads once outside the loop (one
-    compiled pad program), slices the result back.  Tuned geometry rides
-    ``tile_rows``/``depth`` (depth >= 2 = the double-buffered walk)."""
-    mode = check_mode(mode)
-    tile_rows, depth = _norm_geometry(tile_rows, depth)
-    d = x.shape[1]
-    k = init_centers.shape[0]
-    with kernel_launch("kmeans.lloyd_loop"):
-        x_p, w_p, c_p = _pad_operands(
-            x, weights, init_centers, block_rows=tile_rows
-        )
-        centers, n_iter, cost, counts = _lloyd_loop_padded(
-            x_p, w_p, c_p, max_iter, jnp.asarray(tol, jnp.float32), mode,
-            interpret, tile_rows, depth,
-        )
-    return centers[:k, :d], n_iter, cost, counts[:k]

@@ -220,10 +220,9 @@ def _xla_walk(x_p, m_p, mean_p, mode, need_gram, tile_rows):
 
 def _moments_any(x_p, m_p, mean_p, mode, interpret, need_gram, tile_rows,
                  depth):
-    """Kernel-variant dispatch on pre-padded operands (the kmeans_kernel
-    ``_accum_any`` pattern): grid pipeline at depth < 2, double-buffered
-    walk at depth >= 2 (DMA kernel on TPU/interpret, XLA scan
-    elsewhere)."""
+    """Kernel-variant dispatch on pre-padded operands: grid pipeline at
+    depth < 2, double-buffered walk at depth >= 2 (DMA kernel on
+    TPU/interpret, XLA scan elsewhere)."""
     if depth >= 2:
         if interpret or jax.default_backend() == "tpu":
             return _pallas_moments_dbuf(
@@ -255,7 +254,7 @@ def _pad_rows_cols(x, mask, mean, block_rows=_BLOCK_ROWS):
     return x_p, m_p, mean_p
 
 
-def _norm_geometry(tile_rows, depth):
+def _static_geometry(tile_rows, depth):
     """None -> the hand-picked defaults (grid kernel, 512-row block)."""
     tile_rows = _BLOCK_ROWS if tile_rows is None else int(tile_rows)
     depth = 0 if depth is None else int(depth)
@@ -268,7 +267,7 @@ def moments_traced(x, mask, mean, mode, interpret, need_gram,
                    tile_rows=None, depth=None):
     """Traced pad + kernel + slice (no jit of its own) — the seam the
     streamed per-chunk accumulators jit around (ops/stream_ops)."""
-    tile_rows, depth = _norm_geometry(tile_rows, depth)
+    tile_rows, depth = _static_geometry(tile_rows, depth)
     d = x.shape[1]
     x_p, m_p, mean_p = _pad_rows_cols(x, mask, mean, block_rows=tile_rows)
     gram, colsum, count = _moments_any(
@@ -283,9 +282,8 @@ def moments_traced(x, mask, mean, mode, interpret, need_gram,
 )
 def _moments_jit(x, mask, mean, mode, interpret, need_gram,
                  tile_rows=_BLOCK_ROWS, depth=0):
-    """Pad + kernel + slice in ONE jitted program (the
-    kmeans_kernel._accumulate_jit pattern — progcache sees one program
-    per input signature, never eager padding dispatches)."""
+    """Pad + kernel + slice in ONE jitted program (progcache sees one
+    program per input signature, never eager padding dispatches)."""
     return moments_traced(
         x, mask, mean, mode, interpret, need_gram, tile_rows, depth
     )
@@ -311,7 +309,7 @@ def pca_moments_pallas(
     zero vector (pass-1 usage).
     """
     mode = check_mode(mode)
-    tile_rows, depth = _norm_geometry(tile_rows, depth)
+    tile_rows, depth = _static_geometry(tile_rows, depth)
     if mean is None:
         mean = jnp.zeros((x.shape[1],), jnp.float32)
     progcache.note(
@@ -361,7 +359,7 @@ def covariance_pallas(
     no HBM-materialized centered temp.  ``tile_rows``/``depth`` carry
     tuned geometry (depth >= 2 = the double-buffered walk)."""
     mode = check_mode(mode)
-    tile_rows, depth = _norm_geometry(tile_rows, depth)
+    tile_rows, depth = _static_geometry(tile_rows, depth)
     progcache.note(
         "pca.pallas_covariance",
         (progcache.backend_fingerprint(),

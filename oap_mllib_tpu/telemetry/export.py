@@ -19,7 +19,7 @@ the metrics registry):
 - **Human report** — :func:`report` renders one fit's span tree with
   its per-phase walls, streamed overlap, compile split, progcache and
   resilience counters; with no summary it renders process-wide
-  highlights instead (bench.py and dev/profile_kernels.py print it).
+  highlights instead (bench.py prints it).
 
 Telemetry-off is one falsy-string check per fit (`Config.telemetry_log`
 empty -> no file is ever opened).

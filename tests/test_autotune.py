@@ -343,25 +343,6 @@ class TestGeometryParity:
                 scale = max(1.0, float(np.abs(b).max()))
                 np.testing.assert_allclose(a, b, atol=1e-6 * scale)
 
-    def test_kmeans_walk_matches_grid_kernel_at_its_partition(self, rng):
-        """The dbuf walk at the grid kernel's own tile partition
-        (_BLOCK_ROWS) shares _tile_update with it — bit-identical."""
-        from oap_mllib_tpu.ops.pallas.kmeans_kernel import (
-            _BLOCK_ROWS,
-            lloyd_accumulate_pallas,
-            lloyd_accumulate_walk,
-        )
-
-        x = jnp.asarray(rng.normal(size=(700, 9)).astype(np.float32))
-        w = jnp.ones((700,), jnp.float32)
-        c = jnp.asarray(rng.normal(size=(5, 9)).astype(np.float32))
-        ref = lloyd_accumulate_pallas(x, w, c, interpret=True)
-        out = lloyd_accumulate_walk(
-            x, w, c, interpret=True, tile_rows=_BLOCK_ROWS, depth=2
-        )
-        for a, b in zip(out, ref):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
-
     def test_pca_moments_within_1e6_across_geometry(self, rng):
         from oap_mllib_tpu.ops.pallas.pca_kernel import pca_moments_pallas
 

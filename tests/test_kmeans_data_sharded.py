@@ -1,4 +1,4 @@
-"""The data-parallel Lloyd (ops/kmeans_ops.lloyd_run_data_sharded): what a
+"""The data-parallel Lloyd (ops/kmeans_ops.lloyd_run on a mesh): what a
 ``KMeans.fit`` runs on any mesh of more than one device.
 
 Every device accumulates its own row shard with the one-device accumulate
@@ -67,9 +67,9 @@ def _sharded_run(x, w, c0, n_devices, max_iter=15, tol=0.0, **kw):
     mesh = get_mesh(n_devices=n_devices)
     xs = jax.device_put(x, data_sharding(mesh, 2))
     ws = jax.device_put(w, data_sharding(mesh, 1))
-    out = kmeans_ops.lloyd_run_data_sharded(
+    out = kmeans_ops.lloyd_run(
         xs, ws, jnp.asarray(c0), max_iter, jnp.asarray(tol, jnp.float32),
-        mesh, get_config().data_axis, **kw,
+        mesh=mesh, data_axis=get_config().data_axis, **kw,
     )
     return [np.asarray(o) for o in out]
 
@@ -149,17 +149,11 @@ class TestDeviceCounts:
         x, labels = _blobs(rng, 4096)
         w = np.ones((len(x),), np.float32)
         c0 = _start(x, labels)
-        kw = dict(walk=walk, tile_rows=256)
-        if walk:
-            one = [np.asarray(o) for o in kk.lloyd_run_pallas(
-                jnp.asarray(x), jnp.asarray(w), jnp.asarray(c0), 15, 0.0,
-                tile_rows=256, depth=2,
-            )]
-        else:
-            one = [np.asarray(o) for o in kmeans_ops.lloyd_run(
-                jnp.asarray(x), jnp.asarray(w), jnp.asarray(c0), 15,
-                jnp.asarray(0.0, jnp.float32),
-            )]
+        kw = dict(accumulate="pallas" if walk else "xla", tile_rows=256)
+        one = [np.asarray(o) for o in kmeans_ops.lloyd_run(
+            jnp.asarray(x), jnp.asarray(w), jnp.asarray(c0), 15,
+            jnp.asarray(0.0, jnp.float32), **kw,
+        )]
         for n_devices in (1, 2, 4, 8):
             c, it, cost, counts = _sharded_run(x, w, c0, n_devices, **kw)
             # 1e-5 absolute on centres of size ~10 (a few float32 steps of
@@ -201,7 +195,8 @@ class TestRaggedAndEmptyShards:
         wp = np.zeros((1024,), np.float32)
         wp[:300] = 1.0
         c, it, cost, counts = _sharded_run(
-            xp, wp, c0, 4, max_iter=10, walk=walk, tile_rows=256
+            xp, wp, c0, 4, max_iter=10,
+            accumulate="pallas" if walk else "xla", tile_rows=256,
         )
         ref_c, _, ref_cost = lloyd_np(x, c0, 10, 0.0)
         np.testing.assert_allclose(c, ref_c, atol=1e-5)

@@ -11,10 +11,7 @@ import pytest
 import jax.numpy as jnp
 
 from oap_mllib_tpu.ops.kmeans_ops import _accumulate, lloyd_run
-from oap_mllib_tpu.ops.pallas.kmeans_kernel import (
-    lloyd_accumulate_pallas,
-    lloyd_run_pallas,
-)
+from oap_mllib_tpu.ops.pallas.kmeans_kernel import lloyd_accumulate_walk
 
 
 class TestFusedAccumulate:
@@ -24,7 +21,7 @@ class TestFusedAccumulate:
         w = jnp.asarray((rng.random(n) < 0.9).astype(np.float32))
         c = jnp.asarray(rng.normal(size=(k, d)).astype(np.float32))
         s1, c1, t1 = _accumulate(x, w, c)
-        s2, c2, t2 = lloyd_accumulate_pallas(x, w, c, interpret=True)
+        s2, c2, t2 = lloyd_accumulate_walk(x, w, c, interpret=True)
         np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-4)
         np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=0)
         np.testing.assert_allclose(float(t1), float(t2), rtol=1e-5)
@@ -35,7 +32,7 @@ class TestFusedAccumulate:
         w = jnp.asarray(rng.random(n).astype(np.float32))  # fractional weights
         c = jnp.asarray(rng.normal(size=(k, d)).astype(np.float32))
         s1, c1, t1 = _accumulate(x, w, c)
-        s2, c2, t2 = lloyd_accumulate_pallas(x, w, c, interpret=True)
+        s2, c2, t2 = lloyd_accumulate_walk(x, w, c, interpret=True)
         np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-4)
         np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=1e-5)
 
@@ -50,7 +47,7 @@ class TestFusedAccumulate:
         w = jnp.asarray((rng.random(n) + 0.5).astype(np.float32))
         c = jnp.asarray(rng.normal(size=(k, d)).astype(np.float32))
         s1, c1, t1 = _accumulate(x, w, c)
-        s2, c2, t2 = lloyd_accumulate_pallas(x, w, c, mode=mode, interpret=True)
+        s2, c2, t2 = lloyd_accumulate_walk(x, w, c, mode=mode, interpret=True)
         # well-separated random clusters: assignments identical
         np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=1e-3)
         np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=sums_atol)
@@ -61,7 +58,7 @@ class TestFusedAccumulate:
         w = jnp.ones((8,), jnp.float32)
         c = jnp.zeros((2, 4), jnp.float32)
         with pytest.raises(ValueError, match="mode"):
-            lloyd_accumulate_pallas(x, w, c, mode="fast", interpret=True)
+            lloyd_accumulate_walk(x, w, c, mode="fast", interpret=True)
 
     def test_unaligned_shapes_padded(self, rng):
         """n, k, d all unaligned to blocks/lanes: padding must be invisible."""
@@ -70,7 +67,7 @@ class TestFusedAccumulate:
         w = jnp.ones((n,), jnp.float32)
         c = jnp.asarray(rng.normal(size=(k, d)).astype(np.float32))
         s1, c1, _ = _accumulate(x, w, c)
-        s2, c2, _ = lloyd_accumulate_pallas(x, w, c, interpret=True)
+        s2, c2, _ = lloyd_accumulate_walk(x, w, c, interpret=True)
         assert float(jnp.sum(c2)) == n  # no row lost to padding
         np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-4)
 
@@ -84,7 +81,9 @@ class TestFusedLloydLoop:
         cj = jnp.asarray(init)
         tol = jnp.asarray(1e-6, jnp.float32)
         c1, i1, t1, n1 = lloyd_run(xj, wj, cj, 25, tol)
-        c2, i2, t2, n2 = lloyd_run_pallas(xj, wj, cj, 25, tol, interpret=True)
+        c2, i2, t2, n2 = lloyd_run(
+            xj, wj, cj, 25, tol, accumulate="pallas", interpret=True
+        )
         assert int(i1) == int(i2)
         np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=1e-3)
         np.testing.assert_allclose(float(t1), float(t2), rtol=1e-3)

@@ -305,22 +305,22 @@ class TestAlsSolveKernel:
 
 class TestSingleShotPaddingJitted:
     def test_second_call_compiles_nothing(self, rng):
-        """lloyd_accumulate_pallas pads INSIDE one jitted program now: a
+        """lloyd_accumulate_walk pads INSIDE its one jitted program: a
         repeat call with the same signature must hit jit's executable
-        cache — zero new XLA backend compiles (the old path re-dispatched
-        ~6 eager padding ops per call that the cache could not see)."""
+        cache — zero new XLA backend compiles (eager padding would
+        dispatch ~6 ops per call that the cache could not see)."""
         from oap_mllib_tpu.ops.pallas.kmeans_kernel import (
-            lloyd_accumulate_pallas,
+            lloyd_accumulate_walk,
         )
 
         n, d, k = 333, 5, 3
         x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
         w = jnp.ones((n,), jnp.float32)
         c = jnp.asarray(rng.normal(size=(k, d)).astype(np.float32))
-        s1, c1, t1 = lloyd_accumulate_pallas(x, w, c, interpret=True)
+        s1, c1, t1 = lloyd_accumulate_walk(x, w, c, interpret=True)
         np.asarray(s1)
         before = progcache.xla_compile_count()
-        s2, c2, t2 = lloyd_accumulate_pallas(x, w, c, interpret=True)
+        s2, c2, t2 = lloyd_accumulate_walk(x, w, c, interpret=True)
         np.asarray(s2)
         assert progcache.xla_compile_count() - before == 0
         assert np.array_equal(np.asarray(s1), np.asarray(s2))

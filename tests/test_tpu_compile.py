@@ -87,9 +87,8 @@ def _kmeans_shapes(sharding, n=N_KMEANS, k=1024, d=256):
 
 
 class TestKMeansKernels:
-    """k=1000 pads to 1024 lanes; d=256; the walk is what the default
-    geometry (depth 2) launches, the grid kernel what ``depth`` < 2
-    pins."""
+    """k=1000 pads to 1024 lanes; d=256; the walk at the default
+    geometry (depth 2) is the one Pallas form a fit launches."""
 
     @pytest.mark.parametrize("mode", ["highest", "high", "default"])
     def test_walk_loop_mode(self, one_chip, mode):
@@ -110,14 +109,30 @@ class TestKMeansKernels:
             *_kmeans_shapes(one_chip),
         )
 
-    def test_grid_kernel(self, one_chip):
-        from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
+    def test_one_device_lloyd_program(self, one_chip, monkeypatch):
+        """The one-chip cell's whole Lloyd program at its shapes
+        (2,097,152 x 256, k=1000, 20 iterations): pad, loop and final
+        cost pass in one jit, no collective."""
+        from oap_mllib_tpu.ops import kmeans_ops
 
-        _compile(
-            lambda x, w, c: kk._pallas_accumulate(
-                x, w, c, "highest", False, True, TILE),
-            *_kmeans_shapes(one_chip),
+        # the walk's dispatch asks the backend: take its TPU branch
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        fn = kmeans_ops._build_lloyd(
+            None, "data", 1, 20, "highest", "f32", True, TILE, DEPTH,
+            False, 1,
         )
+        rows, d, k = 2097152, 256, 1000
+        compiled = fn.lower(
+            _s((rows, d), one_chip), _s((rows,), one_chip),
+            _s((k, d), one_chip), _s((), one_chip),
+        ).compile()
+        text = compiled.as_text()
+        assert "kmeans_accumulate_walk" in text
+        assert "all-reduce" not in text
+        mem = compiled.memory_analysis()
+        # the table and at most the walk's padded copy of it
+        held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        assert held < 2.1 * rows * d * 4
 
     def test_walk_at_the_dispatch_rule_edge(self, one_chip):
         """The largest resident blocks ``pallas_preferred`` admits: a
@@ -323,8 +338,9 @@ class TestDataParallelKMeans:
 
         # the walk's dispatch asks the backend: take its TPU branch
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        fn = kmeans_ops._build_lloyd_data_sharded(
-            mesh, "data", 20, "highest", "f32", True, TILE, DEPTH, 1
+        fn = kmeans_ops._build_lloyd(
+            mesh, "data", 4, 20, "highest", "f32", True, TILE, DEPTH,
+            False, 1,
         )
         rows = NamedSharding(mesh, P("data", None))
         rep = NamedSharding(mesh, P())
