@@ -667,10 +667,12 @@ def lloyd_run(
     registry and each launch is registered there, so fits report how
     many programs they compiled vs reused; ``timings`` (when given)
     receives the ``<phase>/compile`` / ``<phase>/execute`` wall split.
-    A launch on more than one shard books what it reduced
-    (``oap_collective_ops_total{op="psum"}``, the active span's
-    ``shards`` / ``rows_per_shard`` / ``reduce_bytes``) once it has
-    returned, which waits for its iteration count.
+    A launch of the walk notes the kernel's bf16 MXU passes a tile on the
+    active span (``mxu_passes``: ``{"cross": 6, "sums": 3}`` at
+    ``highest``; kmeans_kernel.MXU_PASSES).  A launch on more than one
+    shard books what it reduced (``oap_collective_ops_total{op="psum"}``,
+    the active span's ``shards`` / ``rows_per_shard`` / ``reduce_bytes``)
+    once it has returned, which waits for its iteration count.
     """
     if accumulate not in LLOYD_ROUTES:
         raise ValueError(
@@ -686,7 +688,7 @@ def lloyd_run(
     shards = lloyd_shards(mesh, data_axis)
     # only what the chosen accumulate reads keys its program
     if walk:
-        check_mode(precision)
+        tier = check_mode(precision)
         statics = (policy, True, int(tile_rows), _dbuf.check_depth(depth),
                    bool(interpret), 1)
     else:
@@ -709,6 +711,10 @@ def lloyd_run(
     booked = kernel_launch("kmeans.lloyd_loop") if walk else nullcontext()
     with progcache.launch("kmeans.lloyd_run", key, timings, phase), booked:
         out = fn(x, weights, jnp.asarray(init_centers), tol)
+    span = spans.current_span()
+    if walk and span is not None:
+        # what the kernel issues a tile, for the reader of lloyd_roofline
+        span.attrs["mxu_passes"] = dict(kk.MXU_PASSES[tier])
     if shards > 1:
         k, d = np.shape(init_centers)
         _book_reductions(

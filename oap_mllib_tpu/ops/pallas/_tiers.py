@@ -91,6 +91,22 @@ def split_bf16(a):
     return hi, lo
 
 
+def split3_bf16(a):
+    """f32 -> (hi, mid, lo) bf16 triple with a == hi + mid + lo EXACTLY:
+    24 significand bits are three times bf16's 8, bf16 has f32's
+    exponent range, and both residuals are taken in f32 (where they are
+    exact; below 2^-103 a value's last bits are subnormal and a backend
+    that flushes those loses them, at most 2^-126 absolute).  A product
+    of the triple with an operand that is exact in bf16 (a 0/1 one-hot)
+    therefore equals the f32 product in three single passes, no term
+    dropped.  For kernels (Mosaic rounds as written) and the CPU: a
+    program XLA:TPU compiles keeps excess precision across the convert
+    to bf16 and back, so there ``mid`` and ``lo`` come out zero."""
+    hi = a.astype(jnp.bfloat16)
+    mid, lo = split_bf16(a - hi.astype(jnp.float32))
+    return hi, mid, lo
+
+
 def dot_f32(a, b, dn):
     return jax.lax.dot_general(
         a, b, dimension_numbers=dn,

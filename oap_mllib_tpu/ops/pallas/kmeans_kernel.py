@@ -11,17 +11,27 @@ iteration drops from O(n*d + 2*n*k) to O(n*d + k*d).
 
 Precision tiers (``mode``) — shared vocabulary in ops/pallas/_tiers.py
 (Mosaic only lowers Precision.HIGHEST/DEFAULT, so split tiers are
-implemented by hand with bf16 hi/lo splits):
+implemented by hand with bf16 splits).  The cluster sums rest on one
+fact of the kernel's own construction: the unweighted one-hot is 0/1 —
+exactly representable in bf16 (the weights fold into ``w*x``) — so every
+bf16 part of ``w*x`` multiplies it exactly and the MXU accumulates in
+f32.  Per tier, with its bf16 passes of the MXU for the cross term + the
+sums (:data:`MXU_PASSES`; a pass is ``2 * rows * k * d`` operations):
 
-- ``highest``: both matmuls f32 Precision.HIGHEST.  Parity default.
-- ``high``: distance cross-term single-pass bf16 (the tier contract —
+- ``highest`` (6 + 3): distance cross-term f32 Precision.HIGHEST — the
+  assignment the tier promises; cluster sums from the EXACT three-way
+  split of ``w*x`` (``split3_bf16``: hi+mid+lo, 8+8+8 significand bits)
+  in three single passes.  Nothing is lost against a HIGHEST product,
+  which splits BOTH operands three ways and runs six passes (hi*hi,
+  hi*mid, mid*hi, hi*lo, lo*hi, mid*mid): the one-hot's mid and lo
+  parts are identically zero, so three of those six multiply by zero
+  and the other three are the ones issued here.  Parity default.
+- ``high`` (1 + 2): cross-term single-pass bf16 (the tier contract —
   kmeans_ops._assign_prec — runs the assignment matmul at bf16: argmin is
-  decision-only); cluster sums via an *exact-split* trick: the unweighted
-  one-hot is 0/1 — exactly representable in bf16 — so ``one_hot.T @
-  (w*x)`` with (w*x) split into bf16 hi+lo needs only TWO bf16 passes
-  and is accurate to ~f32, meeting the XLA "high" tier's error envelope.
-- ``default``: bf16 assignment + SINGLE-pass bf16 sums — the XLA default
-  tier's ~1e-3 error envelope at its speed.
+  decision-only); sums from the hi+lo split of ``w*x``, TWO passes,
+  accurate to ~f32, meeting the XLA "high" tier's error envelope.
+- ``default`` (1 + 1): bf16 assignment + SINGLE-pass bf16 sums — the XLA
+  default tier's ~1e-3 error envelope at its speed.
 
 One Pallas form: the double-buffered tile walk
 (``_pallas_accumulate_dbuf``: x stays in HBM, each ``(tile_rows, d)`` tile
@@ -58,11 +68,22 @@ from oap_mllib_tpu.ops.pallas._tiers import (
     dot_f32,
     kernel_launch,
     pad_to,
+    split3_bf16,
     split_bf16,
 )
 from oap_mllib_tpu.utils import progcache
 
 _BLOCK_ROWS = 512
+
+# bf16 passes of the MXU a tile, by tier: the cross term (_cross_term) and
+# the cluster sums (_cluster_sums).  What kmeans_ops.lloyd_run reports as
+# lloyd_loop.attrs["mxu_passes"]: passes * 2*n*k_pad*d_pad / the bf16
+# peak is the walk's own ceiling an iteration.
+MXU_PASSES = {
+    "highest": {"cross": 6, "sums": 3},
+    "high": {"cross": 1, "sums": 2},
+    "default": {"cross": 1, "sums": 1},
+}
 
 
 def _cross_term(x, c, mode):
@@ -81,14 +102,22 @@ def _cross_term(x, c, mode):
 
 
 def _cluster_sums(one_hot01, wx, mode):
-    """one_hot.T @ (w*x) (k, d).  one_hot is exactly 0/1 in bf16, so the
-    split tiers lose nothing on it; "high" hi/lo-splits wx for ~f32
-    accuracy (2 bf16 passes); "default" is single-pass all-bf16 — the
-    same error envelope as the XLA default tier (~1e-3)."""
+    """one_hot.T @ (w*x) (k, d) in single bf16 passes with f32
+    accumulation.  one_hot is exactly 0/1 in bf16, so no tier loses
+    anything on it: "highest" splits wx exactly three ways and sums the
+    three products smallest first — what Precision.HIGHEST computes,
+    without its three passes against the one-hot's zero mid/lo parts;
+    "high" hi/lo-splits wx for ~f32 accuracy (2 passes); "default" is
+    single-pass all-bf16 — the same error envelope as the XLA default
+    tier (~1e-3)."""
     dn = (((0,), (0,)), ((), ()))
-    if mode == "highest":
-        return dot_f32(one_hot01, wx, dn)
     oh = one_hot01.astype(jnp.bfloat16)  # exact
+    if mode == "highest":
+        wx_hi, wx_mid, wx_lo = split3_bf16(wx)
+        return (
+            dot_bf16(oh, wx_lo, dn) + dot_bf16(oh, wx_mid, dn)
+            + dot_bf16(oh, wx_hi, dn)
+        )
     if mode == "default":
         return dot_bf16(oh, wx.astype(jnp.bfloat16), dn)
     wx_hi, wx_lo = split_bf16(wx)
@@ -226,7 +255,11 @@ def _xla_walk(x_p, w_p, c_p, mode, need_cost, tile_rows):
     """Schedule-identical XLA fallback for the double-buffered walk: a
     ``lax.scan`` over the SAME (tile_rows, d) tiles in the SAME order
     through the SAME ``_tile_update``, so the CPU tier-1 suite exercises
-    the exact program structure (and bits) the DMA kernel produces."""
+    the exact program structure (and bits) the DMA kernel produces.  Not
+    a program for the TPU: XLA:TPU keeps excess precision across a
+    convert to bf16 and back, which voids ``_cluster_sums``' exact splits
+    (Mosaic rounds as written; tests_tpu/ compiles the twin with
+    ``xla_allow_excess_precision`` off)."""
     n, d = x_p.shape
     k = c_p.shape[0]
     num_tiles = n // tile_rows

@@ -17,6 +17,7 @@ from oap_mllib_tpu.config import get_config, set_config
 from oap_mllib_tpu.ops import kmeans_ops
 from oap_mllib_tpu.ops.pallas import autotune
 from oap_mllib_tpu.parallel.mesh import get_mesh
+from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import progcache
 
 ROWS, D, K = 1 << 20, 256, 1000  # 32 chunks of 32768 rows by the occupancy rule
@@ -118,3 +119,25 @@ def test_one_device_and_one_shard_mesh_share_the_program(rng):
     assert after["hits"] == before["hits"] + 1
     for a, b in zip(plain, meshed):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("accumulate,precision,expected", [
+    ("pallas", "highest", {"cross": 6, "sums": 3}),
+    ("pallas", "high", {"cross": 1, "sums": 2}),
+    ("pallas", "default", {"cross": 1, "sums": 1}),
+    ("xla", "highest", None),
+])
+def test_the_walk_notes_its_mxu_passes_on_the_phase(
+        rng, accumulate, precision, expected):
+    """``lloyd_loop.attrs["mxu_passes"]``: the bf16 passes the tile walk
+    issues a tile, by tier (six for the HIGHEST cross term, three for the
+    exact-split sums); absent where no walk ran."""
+    x = jnp.asarray(rng.normal(size=(512, 6)).astype(np.float32))
+    w = jnp.ones((512,), jnp.float32)
+    loop = spans.Span("lloyd_loop")
+    with spans.enter(loop, annotate=False):
+        kmeans_ops.lloyd_run(
+            x, w, x[:3], 2, jnp.asarray(0.0, jnp.float32),
+            precision=precision, accumulate=accumulate, tile_rows=256,
+        )
+    assert loop.attrs.get("mxu_passes") == expected

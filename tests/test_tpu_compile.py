@@ -35,6 +35,7 @@ from jax.sharding import (  # noqa: E402
 F32 = jnp.float32
 N_KMEANS = 1 << 20  # chip_smoke's K-Means / PCA row count
 TILE, DEPTH = 512, 2  # autotune.DEFAULTS geometry
+MiB = 1 << 20
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +82,37 @@ def _s(shape, sharding):
     return jax.ShapeDtypeStruct(shape, F32, sharding=sharding)
 
 
+def _mosaic_matmuls(compiled_text, kernel):
+    """The MXU products of every ``kernel`` custom call in a compiled
+    program, as ``(fp32 contract precision?, lhs type, result type)``:
+    the call's Mosaic body rides in its backend_config as MLIR bytecode,
+    which parses without the chip."""
+    import base64
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    calls = []
+    for line in compiled_text.splitlines():
+        if f"%{kernel}" not in line or "custom-call(" not in line:
+            continue
+        body = base64.b64decode(
+            re.search(r'"body":"([A-Za-z0-9+/=]+)"', line).group(1)
+        )
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        found = []
+        for op in str(ir.Module.parse(body, ctx)).splitlines():
+            if "tpu.matmul" not in op:
+                continue
+            lhs, out = re.search(
+                r": \(vector<([^>]+)>.*\) -> vector<([^>]+)>", op
+            ).groups()
+            found.append(("contract_precision<fp32>" in op, lhs, out))
+        calls.append(found)
+    return calls
+
+
 def _kmeans_shapes(sharding, n=N_KMEANS, k=1024, d=256):
     """(x, weight column, centres) of one padded K-Means launch."""
     return _s((n, d), sharding), _s((n, 1), sharding), _s((k, d), sharding)
@@ -109,6 +141,25 @@ class TestKMeansKernels:
             *_kmeans_shapes(one_chip),
         )
 
+    @pytest.mark.parametrize("need_cost,cap", [
+        (False, 9 * MiB), (True, 10.5 * MiB),
+    ])
+    def test_walk_scoped_vmem_at_the_cells_shape(
+            self, one_chip, monkeypatch, need_cost, cap):
+        """The ``highest`` walk at the cell's shape fits a scoped-VMEM cap
+        well under one more ``(tile_rows, k)`` f32 sheet (2 MiB) above
+        what it uses (7.6 MiB in the loop, 9.1 MiB with the cost, found
+        by bisecting the cap; the six-pass sums read the same): a change
+        that brings a sheet-sized temporary back fails here."""
+        from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
+
+        monkeypatch.setattr(kk, "VMEM_LIMIT_BYTES", int(cap))
+        _compile(
+            lambda x, w, c: kk._pallas_accumulate_dbuf(
+                x, w, c, "highest", False, need_cost, TILE, DEPTH),
+            *_kmeans_shapes(one_chip, n=2097152),
+        )
+
     def test_one_device_lloyd_program(self, one_chip, monkeypatch):
         """The one-chip cell's whole Lloyd program at its shapes
         (2,097,152 x 256, k=1000, 20 iterations): pad, loop and final
@@ -130,14 +181,27 @@ class TestKMeansKernels:
         assert "kmeans_accumulate_walk" in text
         assert "all-reduce" not in text
         mem = compiled.memory_analysis()
-        # the table and at most the walk's padded copy of it
-        held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-        assert held < 2.1 * rows * d * 4
+        # the table alone: no padded copy of it (2.1 GB), no (rows, k)
+        # sheet (8.4 GB), only the centres' and moments' blocks
+        assert mem.argument_size_in_bytes < 1.01 * rows * d * 4
+        assert mem.temp_size_in_bytes < 4 * MiB
+        # the tier the configuration states: in the loop's walk and in the
+        # cost pass ONE product at f32 contract precision, the cross term
+        # x @ c.T (six bf16 passes: the assignment step_gap holds), and
+        # three single bf16 passes for the sums
+        walks = _mosaic_matmuls(text, "kmeans_accumulate_walk")
+        assert len(walks) == 2
+        for products in walks:
+            assert sorted(products) == [
+                (False, "512x1024xbf16", "1024x256xf32")] * 3 + [
+                (True, "512x256xf32", "512x1024xf32")]
 
-    def test_walk_at_the_dispatch_rule_edge(self, one_chip):
+    @pytest.mark.parametrize("mode", ["high", "highest"])
+    def test_walk_at_the_dispatch_rule_edge(self, one_chip, mode):
         """The largest resident blocks ``pallas_preferred`` admits: a
-        shape just inside the bound must compile (here at the tier
-        whose split passes hold the most temporaries per element)."""
+        shape just inside the bound must compile, at both tiers whose
+        sums hold several ``(k, d)`` f32 partials (two at ``high``, three
+        at ``highest``)."""
         from oap_mllib_tpu.ops import kmeans_ops
         from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
 
@@ -149,10 +213,9 @@ class TestKMeansKernels:
         assert not kmeans_ops.pallas_preferred(2 * d, k, "high")
         _compile(
             lambda x, w, c: kk._pallas_accumulate_dbuf(
-                x, w, c, "high", False, False, TILE, DEPTH),
+                x, w, c, mode, False, False, TILE, DEPTH),
             *_kmeans_shapes(one_chip, n=1 << 16, k=k, d=d),
         )
-
 
     def test_candidate_reduction_at_the_cells_shape(self, one_chip):
         """The k-means|| reduction as the benchmark's cell runs it: 1 +

@@ -102,12 +102,19 @@ class TestWalkKernelsCompiled:
             # test_kmeans_tpu.py's)
             return
         # the same tiles in the same order through the same tile body,
-        # compiled by XLA: f32 rounding of the two compilers' reductions
+        # compiled by XLA: f32 rounding of the two compilers' reductions.
+        # XLA:TPU keeps "excess precision" by default — a convert to
+        # bf16 and back leaves the f32 as it was — which makes the mid
+        # and lo parts of the sums' exact split zero (the twin then sums
+        # bf16(w*x), off by 4e-4 of the sums); Mosaic rounds as written.
+        # The twin is the CPU's program; here it is held to what it says.
         twin = jax.jit(
             lambda x, w, c: kk._xla_walk(
                 *kk._pad_operands_traced(x, w, c, block_rows=512),
                 "highest", True, 512,
             )
+        ).lower(x, w, c).compile(
+            compiler_options={"xla_allow_excess_precision": False}
         )(x, w, c)
         refs = [
             (twin[0][:k, :d], twin[1][0, :k], twin[2][0, 0]),
