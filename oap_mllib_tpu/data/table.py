@@ -18,6 +18,7 @@ Memory lifetime is JAX's (GC'd device buffers) — no explicit
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Optional
 
@@ -73,12 +74,21 @@ def _stage_rows(x, multiple: int, dtype):
 
 
 # Host-to-device bytes ONE device has in flight at a time.  Measured on a
-# v5e host (PERF.md section 6, PR 27): the four 2.15 GB row shards of an
-# 8.6 GB table put all at once — by one ``device_put`` under the row
-# sharding or by four — land at 1.8-2.1 GB/s, their time spent mapping
-# DMA buffers; the same shards in pieces of 1.07 GB, one a device in
-# flight, at 25 GB/s, and in pieces of 268 MB at 24.
+# v5e host.  Four chips (PERF.md section 6, PR 27): the four 2.15 GB row
+# shards of an 8.6 GB table put all at once — by one ``device_put`` under
+# the row sharding or by four — land at 1.8-2.1 GB/s, their time spent
+# mapping DMA buffers; the same shards in pieces of 1.07 GB, one a device
+# in flight, at 25 GB/s, and in pieces of 268 MB at 24.  One chip
+# (PERF.md section 6, PR 31): 8.6 GB in one ``device_put`` at 0.8-1.3
+# GB/s; one 1 GiB piece at a time at 9.8 (2.1 GB whole: 10.4).
 _UPLOAD_PIECE_BYTES = 1 << 30
+# The pieces that GiB goes as where ONE device takes the table: in
+# flight together, so that the host prepares one transfer under
+# another's DMA — 2 x 512, 4 x 256 or 8 x 128 MiB land at 11.0-11.3
+# GB/s (PR 31).  Devices that are several are those transfers already:
+# four chips with two or eight pieces each in flight read 21.7 GB/s
+# against 24.5 with one.
+_ONE_DEVICE_PIECES_IN_FLIGHT = 4
 
 
 def _join_pieces(sharding):
@@ -99,18 +109,74 @@ def _join_pieces(sharding):
     )
 
 
+def _write_piece():
+    """The program that writes a piece into a table at a row offset IN
+    PLACE: the table-sized buffer is donated, so the output is the
+    input's memory and the device holds the table and the pieces in
+    flight, never the table twice.  Kept in the program registry like
+    every jitted program of the package."""
+
+    def write_piece(rows, piece, lo):
+        return jax.lax.dynamic_update_slice_in_dim(rows, piece, lo, axis=0)
+
+    return progcache.get_or_build(
+        "table.write_piece", (progcache.backend_fingerprint(),),
+        lambda: jax.jit(write_piece, donate_argnums=0),
+    )
+
+
+def _put_in_place(host: np.ndarray, sharding):
+    """``_put_rows`` on ONE device, where joined pieces would hold the
+    table twice: ``host`` goes up in row-block views of at most
+    ``_UPLOAD_PIECE_BYTES`` in flight, ``_ONE_DEVICE_PIECES_IN_FLIGHT``
+    of them together, each written into ONE table-sized buffer
+    (``_write_piece``) and dropped before the next is sent."""
+    step = max(
+        1,
+        _UPLOAD_PIECE_BYTES // _ONE_DEVICE_PIECES_IN_FLIGHT
+        * host.shape[0] // max(host.nbytes, 1),
+    )
+    flying = collections.deque()  # (piece, its row offset), oldest first
+    write = _write_piece()
+    table = None
+
+    def write_oldest(table):
+        piece, lo = flying.popleft()
+        if table is None:
+            table = jnp.zeros(host.shape, piece.dtype, device=sharding)
+        return write(table, piece, np.int32(lo))
+
+    for lo in range(0, host.shape[0], step):
+        flying.append((jax.device_put(host[lo:lo + step], sharding), lo))
+        if len(flying) == _ONE_DEVICE_PIECES_IN_FLIGHT:
+            table = write_oldest(table)
+            # the oldest piece has landed, is written and is gone: room
+            # for the next one, which goes while the others are in flight
+            jax.block_until_ready(table)
+    while flying:
+        table = write_oldest(table)
+    return table, -(-host.shape[0] // step)
+
+
 def _put_rows(host: np.ndarray, sharding):
-    """``jax.device_put(host, sharding)`` of a table every row of which
-    this process holds, and on one device, or in a world of several
-    processes, just that.  Else every device's row slice goes to it
-    in pieces of at most ``_UPLOAD_PIECE_BYTES`` (views of ``host``:
-    nothing is copied on the host), one piece a device in flight, so
-    that what is in flight does not grow with the table; a shard of
-    several pieces is joined on its device, where it is held twice
+    """(``jax.device_put(host, sharding)`` of a table every row of which
+    this process holds, the pieces a row shard went up in).  In a world
+    of several processes, and on one device for a table of at most
+    ``_UPLOAD_PIECE_BYTES``, just that.  Else no device has more than
+    ``_UPLOAD_PIECE_BYTES`` in flight, so that what is in flight does
+    not grow with the table, and the pieces are views of ``host``
+    (nothing is copied on the host).  One device: written in place into
+    one buffer (``_put_in_place``), table + 1 GiB live.  Several: every
+    device's row slice in pieces, one piece a device in flight; a shard
+    of several pieces is joined on its device, where it is held twice
     until the pieces are dropped."""
     index = sharding.addressable_devices_indices_map(host.shape)
-    if len(index) == 1 or jax.process_count() > 1:
-        return jax.device_put(host, sharding)
+    if jax.process_count() > 1:
+        return jax.device_put(host, sharding), 1
+    if len(index) == 1:
+        if host.nbytes <= _UPLOAD_PIECE_BYTES:
+            return jax.device_put(host, sharding), 1
+        return _put_in_place(host, sharding)
     slices = [(dev, host[idx]) for dev, idx in index.items()]
     shard_rows = slices[0][1].shape[0]
     step = max(1, _UPLOAD_PIECE_BYTES * host.shape[0] // max(host.nbytes, 1))
@@ -122,7 +188,8 @@ def _put_rows(host: np.ndarray, sharding):
         pieces.append(
             jax.make_array_from_single_device_arrays(shape, sharding, parts)
         )
-    return pieces[0] if len(pieces) == 1 else _join_pieces(sharding)(pieces)
+    table = pieces[0] if len(pieces) == 1 else _join_pieces(sharding)(pieces)
+    return table, len(pieces)
 
 
 def _upload(put, padded: np.ndarray, mask: np.ndarray, mesh):
@@ -130,15 +197,17 @@ def _upload(put, padded: np.ndarray, mask: np.ndarray, mesh):
     sharding)`` for the table and its mask, then the wait for both —
     ``device_put`` returns before the bytes land, and without the wait
     the rest of the upload is booked to whichever phase first blocks on
-    the table.  ``attrs["bytes"]`` is what this process sent, and
+    the table.  ``attrs["bytes"]`` is what this process sent,
     ``attrs["shards"]`` the row shards the table was cut into (one a
-    device of the data axis)."""
+    device of the data axis) and ``attrs["pieces"]`` the pieces each of
+    them went up in."""
     with spans.child("upload") as span:
-        data = put(padded, data_sharding(mesh, 2))
-        mask_dev = put(mask, data_sharding(mesh, 1))
+        data, pieces = put(padded, data_sharding(mesh, 2))
+        mask_dev, _ = put(mask, data_sharding(mesh, 1))
         jax.block_until_ready((data, mask_dev))
         span.attrs["bytes"] = padded.nbytes + mask.nbytes
         span.attrs["shards"] = mesh.shape[mesh.axis_names[0]]
+        span.attrs["pieces"] = pieces
     return data, mask_dev
 
 
@@ -156,9 +225,16 @@ class DenseTable:
     and the mask: pure host, with what the pass wrote in
     ``attrs["copied_bytes"]`` — and ``upload``, which ends when the
     bytes have LANDED (``block_until_ready``), not when ``device_put``
-    returns, and carries their count in ``attrs["bytes"]``.  The wait
-    costs no wall where the caller's next statement depends on the table
-    anyway (every in-memory fit's does).
+    returns, and carries their count in ``attrs["bytes"]``, the row
+    shards in ``attrs["shards"]`` and the pieces a shard went up in in
+    ``attrs["pieces"]``.  The wait costs no wall where the caller's next
+    statement depends on the table anyway (every in-memory fit's does).
+
+    A shard of more than 1 GiB goes up in row-block views of the host
+    array, 1 GiB a device in flight (``_put_rows``): what is in flight
+    does not grow with the table.  On one device the pieces are written
+    in place into the table's one buffer — it holds table + 1 GiB, never
+    the table twice; on a mesh a shard's pieces are joined on its device.
 
     The caller's array is uploaded AS IS, with no host copy
     (``copied_bytes`` 0), when it already has the table's dtype, is
@@ -167,8 +243,8 @@ class DenseTable:
     into it.  Off the CPU ``data`` is a device buffer of its own from
     then on.  On the CPU backend ``device_put`` SHARES a host buffer
     that is 64-byte aligned instead of copying it (jax 0.9.0), so there
-    an array uploaded as is must stay unchanged while the table lives —
-    inside ``fit`` it does: the table dies with the fit.
+    an array uploaded as is and WHOLE must stay unchanged while the
+    table lives — inside ``fit`` it does: the table dies with the fit.
     """
 
     data: jax.Array
@@ -283,8 +359,8 @@ class DenseTable:
             mask_local = np.zeros((padded.shape[0],), dtype=padded.dtype)
             mask_local[:n_valid_local] = 1.0
         data, mask = _upload(
-            lambda host, sharding: jax.make_array_from_process_local_data(
-                sharding, host
+            lambda host, sharding: (
+                jax.make_array_from_process_local_data(sharding, host), 1
             ),
             padded, mask_local, mesh,
         )

@@ -27,7 +27,9 @@ from oap_mllib_tpu.data.table import DenseTable
 from oap_mllib_tpu.fallback.pca_np import pca_np
 from oap_mllib_tpu.ops import pca_ops
 from oap_mllib_tpu.ops.pallas import autotune
+from oap_mllib_tpu.ops.pallas.pca_kernel import MXU_PASSES
 from oap_mllib_tpu.parallel.mesh import get_mesh
+from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import checkpoint as ckpt_mod
 from oap_mllib_tpu.utils import precision as psn
 from oap_mllib_tpu.utils import progcache
@@ -464,7 +466,7 @@ class PCA:
                     else psn.kernel_tier(pol.name, cfg.matmul_precision)
                 )
                 # which Gram program the dispatch chooses, for the summary
-                timings.root.attrs["kernel"] = (
+                kernel = timings.root.attrs["kernel"] = (
                     "model_sharded" if mp > 1
                     else _gram_kernel(cfg, table.data.shape[1], tier, dtype)
                 )
@@ -478,6 +480,18 @@ class PCA:
                         table.data, table.mask, n_rows, tier,
                         timings=timings, policy=pol.name,
                     )
+                # the phase ends on a READY covariance: without the wait
+                # the Gram's device time is booked to eigh
+                # oaplint: disable=stream-host-sync -- eigh waits for cov anyway
+                jax.block_until_ready(cov)
+                span = spans.current_span()
+                span.attrs["kernel"] = kernel
+                span.attrs["rows"] = table.n_padded
+                if kernel == "pallas":
+                    # what the kernel issues a tile, for the reader of
+                    # pca_device_roofline: passes * 2*rows*d_pad^2 / the
+                    # bf16 peak is the Gram pass's own ceiling
+                    span.attrs["mxu_passes"] = dict(MXU_PASSES[tier])
             if ckpt is not None:
                 ckpt.maybe_write(
                     1,

@@ -56,6 +56,16 @@ from oap_mllib_tpu.utils import progcache
 
 _BLOCK_ROWS = 512
 
+# bf16 passes of the MXU a tile for the centred Gram (_tile_moments), by
+# tier; the column sums are VPU work at every tier.  What models/pca
+# reports as covariance.attrs["mxu_passes"]: a pass is 2 * rows * d_pad^2
+# operations.
+MXU_PASSES = {
+    "highest": {"gram": 6},
+    "high": {"gram": 3},
+    "default": {"gram": 1},
+}
+
 
 def _tile_moments(x, m, mean, mode, need_gram):
     """One resident tile's moment update — center + mask + Gram with the
@@ -121,6 +131,7 @@ def _pallas_moments(x, m, mean, mode, interpret, need_gram,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="pca_moments_grid",
         **compiled_kwargs(interpret, vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(x, m, mean)
     return gram, colsum, count
@@ -184,6 +195,7 @@ def _pallas_moments_dbuf(x, m, mean, mode, interpret, need_gram,
             depth, [(tile_rows, d), (tile_rows // LANE, LANE)]
         ),
         interpret=interpret,
+        name="pca_moments_walk",
         **compiled_kwargs(
             interpret, vmem_limit_bytes=VMEM_LIMIT_BYTES,
             has_side_effects=True,

@@ -349,3 +349,163 @@ class TestPersistence:
         np.testing.assert_array_equal(
             loaded.explained_variance_, model.explained_variance_
         )
+
+
+def _bench_harness():
+    """``benchmarks/run.py`` as a module (under a name of its own: the
+    suite has no other ``run``), which finds the cell's files by name."""
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks", "run.py",
+    )
+    spec = importlib.util.spec_from_file_location("oap_bench_run", path)
+    harness = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(harness)
+    return harness
+
+
+class TestCovariancePhase:
+    """The ``covariance`` phase ends on a READY covariance and says what
+    ran (ISSUE 31): without the wait the Gram's device time is booked to
+    ``eigh``."""
+
+    @pytest.mark.parametrize("cfg,want,rows", [
+        ({}, "xla", 8 * 256), ({"model_parallel": 2}, "model_sharded", 4 * 256),
+    ], ids=["one-axis", "model-axis"])
+    def test_attrs_say_what_ran(self, rng, cfg, want, rows):
+        set_config(**cfg)
+        m = PCA(k=3).fit(_data(rng, n=200, d=8).astype(np.float32))
+        cov = m.summary["timings"].root.node("covariance")
+        assert cov.attrs["kernel"] == want == m.summary["kernel"]
+        # the rows the program walks: the table's bucket (256 a device of
+        # the data axis), pad included
+        assert cov.attrs["rows"] == rows
+        # the Gram's bf16 passes are the Pallas kernel's to state
+        assert "mxu_passes" not in cov.attrs
+
+    def test_pallas_route_states_its_mxu_passes(self, rng, monkeypatch):
+        from oap_mllib_tpu.models import pca as pca_mod
+        from oap_mllib_tpu.ops.pallas import pca_kernel
+
+        assert pca_kernel.MXU_PASSES["highest"] == {"gram": 6}
+        assert set(pca_kernel.MXU_PASSES) == {"highest", "high", "default"}
+        # the dispatch picks Pallas on a TPU alone: name it, run the XLA pass
+        monkeypatch.setattr(pca_mod, "_gram_kernel", lambda *a: "pallas")
+        set_config(matmul_precision="high")
+        m = PCA(k=3).fit(_data(rng, n=200, d=8).astype(np.float32))
+        cov = m.summary["timings"].root.node("covariance")
+        assert cov.attrs["kernel"] == "pallas"
+        assert cov.attrs["mxu_passes"] == {"gram": 3}
+
+    def test_phase_waits_for_its_covariance(self, rng, monkeypatch):
+        import jax
+
+        from oap_mllib_tpu.telemetry import spans
+
+        waits = []
+        real = jax.block_until_ready
+
+        def spy(v):
+            span = spans.current_span()
+            waits.append((
+                span.path if span is not None else None,
+                [np.shape(a) for a in jax.tree_util.tree_leaves(v)],
+            ))
+            return real(v)
+
+        monkeypatch.setattr(jax, "block_until_ready", spy)
+        PCA(k=3).fit(_data(rng, n=200, d=8).astype(np.float32))
+        assert [w for w in waits if w[0] == "covariance"] == [
+            ("covariance", [(8, 8)])
+        ]
+        # and the staging phase's wait for table and mask, as before
+        assert ("table_convert/upload", [(2048, 8), (2048,)]) in waits
+
+
+class TestBenchmarkCellAtRehearseSize:
+    """``pca_d512_k10`` at its ``rehearse`` size, on ONE device and
+    through three pieces: the benchmark's plain reference judges the fit
+    under the configuration's limits, as it judges every fit of a window
+    on the chip."""
+
+    @pytest.mark.parametrize("seed", [3, 2_147_483_659])
+    def test_fit_through_three_pieces_is_correct(self, monkeypatch, seed):
+        from oap_mllib_tpu.data import table as table_mod
+        from oap_mllib_tpu.models import pca as pca_mod
+        from oap_mllib_tpu.parallel.mesh import get_mesh
+
+        harness = _bench_harness()
+        _, cell, cfg, _ = harness.load_cell(
+            "pca_d512_k10.fit_loop", rehearse=True
+        )
+        adapter = harness._module("estimators", cfg["estimator"])
+        ref = harness._module("reference", adapter.REFERENCE)
+        x = adapter.make_data(cfg, cfg["rows_per_chip"] * cell["chips"], seed)
+        mesh = get_mesh(n_devices=1)
+        monkeypatch.setattr(pca_mod, "get_mesh", lambda: mesh)
+        # three pieces, two in flight on the one device
+        monkeypatch.setattr(
+            table_mod, "_UPLOAD_PIECE_BYTES",
+            2 * -(-x.shape[0] // 3) * x.shape[1] * x.itemsize,
+        )
+        monkeypatch.setattr(table_mod, "_ONE_DEVICE_PIECES_IN_FLIGHT", 2)
+        set_config(matmul_precision=cfg["matmul_precision"],
+                   pca_solver=cfg["pca_solver"])
+        result, info = adapter.fit(cfg, x, seed)
+        m_up = PCA(k=cfg["k"]).fit(x).summary["timings"].root.node(
+            "table_convert/upload"
+        )
+        assert m_up.attrs["pieces"] == 3 and m_up.attrs["shards"] == 1
+        assert info["accelerated"] and not any(info["resilience"].get(k) for k in
+                                               ("degradations", "retries", "faults"))
+        plain = ref.fit_plain(x, cfg, seed)
+        limits = cfg["limits"]
+        for name, answer in (("program", result), ("plain", plain)):
+            numbers = ref.judge(x, cfg, [answer], seed)
+            assert set(numbers) == set(limits)
+            for n, v in numbers.items():
+                assert v <= limits[n], (name, n, v, limits[n])
+        np.testing.assert_allclose(
+            result["ratios"], plain["ratios"], rtol=2 * limits["ratio_gap"]
+        )
+
+    @pytest.mark.parametrize("pieces,breach", [(3, False), (1, True)])
+    def test_adapter_holds_the_upload_to_the_configuration(
+            self, monkeypatch, capsys, pieces, breach):
+        """``estimators/pca_staged.py`` ends the run (exit code 4, a line
+        on stderr) at a fit whose table went up in larger pieces than the
+        configuration's ``expect_upload`` allows, and lets every other
+        fit through."""
+        from oap_mllib_tpu.data import table as table_mod
+        from oap_mllib_tpu.models import pca as pca_mod
+        from oap_mllib_tpu.parallel.mesh import get_mesh
+
+        harness = _bench_harness()
+        _, cell, cfg, _ = harness.load_cell(
+            "pca_d512_k10.fit_loop", rehearse=True
+        )
+        adapter = harness._module("estimators", cfg["estimator"])
+        x = adapter.make_data(cfg, cfg["rows_per_chip"] * cell["chips"], 5)
+        mesh = get_mesh(n_devices=1)
+        monkeypatch.setattr(pca_mod, "get_mesh", lambda: mesh)
+        third = -(-x.shape[0] // 3) * x.shape[1] * x.itemsize
+        # the allowance of a third of the table (and of its mask); the
+        # program's piece at that or, for the breach, over the table
+        cfg = dict(cfg, expect_upload={"piece_bytes_max": third + x.shape[0] * 4})
+        monkeypatch.setattr(
+            table_mod, "_UPLOAD_PIECE_BYTES", third if pieces == 3 else x.nbytes
+        )
+        monkeypatch.setattr(table_mod, "_ONE_DEVICE_PIECES_IN_FLIGHT", 1)
+        if not breach:
+            result, info = adapter.fit(cfg, x, 5)
+            assert result["components"].shape == (cfg["d"], cfg["k"])
+            assert "table_convert/upload" in info["phases"]
+            return
+        with pytest.raises(SystemExit) as stop:
+            adapter.fit(cfg, x, 5)
+        assert stop.value.code == adapter.EXIT_CANNOT_STAGE == 4
+        said = capsys.readouterr().err
+        assert "cannot run pca_d512_k10" in said and "1 piece(s)" in said

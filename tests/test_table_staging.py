@@ -3,6 +3,7 @@ at most ONE host pass over the table before the upload, and none when
 dtype, layout and row bucket already match — the caller's array itself is
 what ``_upload`` receives then."""
 
+import math
 import tracemalloc
 
 import jax
@@ -227,65 +228,195 @@ def test_fit_result_does_not_depend_on_the_layout(blobs, estimator):
 
 
 class TestUploadInPieces:
-    """On a mesh of several devices every device's row slice goes up in
-    pieces of bounded size, one a device in flight, and a shard of several
-    pieces is joined on its device (``data/table._put_rows``): the same
-    global array as the one ``device_put``, from views of the caller's
-    array."""
+    """A row shard of more than a device may have in flight goes up in
+    pieces of bounded size, views of the caller's array
+    (``data/table._put_rows``).  On a mesh of several devices: one piece
+    a device in flight, a shard's pieces joined on its device.  On ONE
+    device, where a join would hold the table twice: a few pieces in
+    flight together, each written IN PLACE into the one table-sized
+    buffer (``_put_in_place`` / ``_write_piece``).  Either way the same
+    array as the one ``device_put``."""
 
-    def _table(self, monkeypatch, x, piece_bytes, n_devices=4):
-        from oap_mllib_tpu.data import table as table_mod
+    def _table(self, monkeypatch, x, piece_bytes, n_devices=4, in_flight=1):
+        """(the table of ``x`` with pieces of ``piece_bytes`` — on one
+        device ``in_flight`` of them together — and what the upload did:
+        ``("put", the host array sent)`` and ``("wait", arrays waited
+        for)`` in order)."""
         from oap_mllib_tpu.parallel.mesh import get_mesh
 
-        monkeypatch.setattr(table_mod, "_UPLOAD_PIECE_BYTES", piece_bytes)
-        waits = []
-        real = table_mod.jax.block_until_ready
+        self._limits(monkeypatch, piece_bytes, in_flight)
+        events = []
+        wait = table_mod.jax.block_until_ready
+        put = table_mod.jax.device_put
         monkeypatch.setattr(
             table_mod.jax, "block_until_ready",
-            lambda v: (waits.append(len(jax.tree_util.tree_leaves(v))), real(v))[1],
+            lambda v: (
+                events.append(("wait", len(jax.tree_util.tree_leaves(v)))),
+                wait(v),
+            )[1],
         )
-        return DenseTable.from_numpy(x, get_mesh(n_devices=n_devices)), waits
+        monkeypatch.setattr(
+            table_mod.jax, "device_put",
+            lambda v, where: (events.append(("put", v)), put(v, where))[1],
+        )
+        table = DenseTable.from_numpy(x, get_mesh(n_devices=n_devices))
+        return table, events
+
+    @staticmethod
+    def _limits(monkeypatch, piece_bytes, in_flight=1):
+        """Pieces of ``piece_bytes``, on one device ``in_flight`` of them
+        together, in the module's own terms: the bytes a device has in
+        flight and the pieces they go as on one device."""
+        monkeypatch.setattr(
+            table_mod, "_UPLOAD_PIECE_BYTES", piece_bytes * in_flight
+        )
+        monkeypatch.setattr(
+            table_mod, "_ONE_DEVICE_PIECES_IN_FLIGHT", in_flight
+        )
+
+    @staticmethod
+    def _piece_bytes(x, shard_rows, pieces):
+        """A piece size that cuts ``shard_rows`` rows into ``pieces``
+        pieces (a fraction leaves a shorter last piece; None: a row)."""
+        row = x.nbytes // x.shape[0]
+        return row * (1 if pieces is None else math.ceil(shard_rows / pieces))
 
     # pieces a shard: whole, halves, three with a ragged tail, one row each
     @pytest.mark.parametrize("pieces", [1, 2, 2.5, None])
     def test_same_array_whatever_the_piece(self, blobs, monkeypatch, pieces):
         from oap_mllib_tpu.parallel.mesh import data_sharding, get_mesh
 
-        shard = blobs.nbytes // 4
-        row = blobs.nbytes // blobs.shape[0]
-        piece = row if pieces is None else int(shard / pieces)
-        table, waits = self._table(monkeypatch, blobs, piece)
+        shard_rows = blobs.shape[0] // 4
+        piece = self._piece_bytes(blobs, shard_rows, pieces)
+        table, events = self._table(monkeypatch, blobs, piece)
         mesh = get_mesh(n_devices=4)
         assert table.data.sharding == data_sharding(mesh, 2)
         assert np.asarray(table.data).tobytes() == blobs.tobytes()
         assert np.asarray(table.mask).tolist() == [1.0] * blobs.shape[0]
         assert [s.data.shape for s in table.data.addressable_shards] == (
-            [(blobs.shape[0] // 4, blobs.shape[1])] * 4
+            [(shard_rows, blobs.shape[1])] * 4
         )
         # every wave is one piece a device, waited for before the next
         # goes; then the mask's waves (an item a row), then the upload
         # span's own wait for table and mask
-        shard_rows = blobs.shape[0] // 4
         waves = {1: 1, 2: 2, 2.5: 3, None: shard_rows}[pieces]
         mask_waves = -(-shard_rows // (piece // blobs.itemsize))
+        waits = [n for what, n in events if what == "wait"]
         assert waits == [4] * (waves + mask_waves) + [2]
 
-    def test_one_device_goes_up_as_it_did(self, blobs, monkeypatch):
-        from oap_mllib_tpu.data import table as table_mod
+    # on ONE device: whole, halves, a ragged tail twice over, one row each
+    @pytest.mark.parametrize("in_flight", [1, 3])
+    @pytest.mark.parametrize("pieces", [1, 2, 2.5, 3.7, None])
+    def test_one_device_table_is_what_device_put_makes(
+        self, blobs, monkeypatch, pieces, in_flight
+    ):
+        from oap_mllib_tpu.parallel.mesh import data_sharding, get_mesh
 
-        puts = []
-        real = table_mod.jax.device_put
-        monkeypatch.setattr(
-            table_mod.jax, "device_put",
-            lambda v, where: (puts.append(v), real(v, where))[1],
+        want = jax.device_put(blobs, data_sharding(get_mesh(n_devices=1), 2))
+        piece = self._piece_bytes(blobs, blobs.shape[0], pieces)
+        table, _ = self._table(
+            monkeypatch, blobs, piece, n_devices=1, in_flight=in_flight
         )
-        # far over the piece size, yet one device_put of the caller's array
-        table, waits = self._table(monkeypatch, blobs, 1, n_devices=1)
-        assert puts[0] is blobs and len(puts) == 2 and waits == [2]
+        assert table.data.sharding == want.sharding
+        assert table.data.dtype == want.dtype and table.data.shape == want.shape
+        assert np.asarray(table.data).tobytes() == np.asarray(want).tobytes()
+        assert np.asarray(table.mask).tolist() == [1.0] * blobs.shape[0]
+
+    @pytest.mark.parametrize("pieces,in_flight", [
+        (1, 1), (2, 2), (2, 1), (2.5, 1), (2.5, 2), (7, 3),
+    ])
+    def test_one_device_goes_up_as_it_did(
+        self, blobs, monkeypatch, pieces, in_flight
+    ):
+        """No more than a device may have in flight: one ``device_put``
+        of the caller's array itself.  Over it: ``ceil(bytes / piece)``
+        puts, each a view of the caller's array and none larger than the
+        piece, never more in flight than allowed — a piece counts until
+        the wait for the table it was written into returns."""
+        piece = self._piece_bytes(blobs, blobs.shape[0], pieces)
+        table, events = self._table(
+            monkeypatch, blobs, piece, n_devices=1, in_flight=in_flight
+        )
+        n = -(-blobs.nbytes // piece)
+        assert n == math.ceil(pieces)
+        sent = [v for what, v in events if what == "put" and v.ndim == 2]
+        if n <= in_flight:
+            assert len(sent) == 1 and sent[0] is blobs
+        else:
+            assert len(sent) == n
+        for part in sent:
+            assert np.shares_memory(part, blobs) and part.flags.c_contiguous
+            assert part.nbytes <= piece * (in_flight if n <= in_flight else 1)
+        assert sum(part.shape[0] for part in sent) == blobs.shape[0]
+        flying, most = 0, 0
+        for what, v in events:
+            if what == "put" and v.ndim == 2:
+                flying += 1
+                most = max(most, flying)
+            elif what == "wait" and flying:
+                flying -= 1
+        assert most == (1 if n <= in_flight else in_flight)
         assert np.asarray(table.data).tobytes() == blobs.tobytes()
 
+    def test_one_device_sends_four_pieces_together(self, blobs, monkeypatch):
+        """The module's own numbers: 1 GiB a device in flight, as four
+        pieces on one device."""
+        assert table_mod._UPLOAD_PIECE_BYTES == 1 << 30
+        assert table_mod._ONE_DEVICE_PIECES_IN_FLIGHT == 4
+        monkeypatch.setattr(table_mod, "_UPLOAD_PIECE_BYTES", blobs.nbytes // 4)
+        timings = Timings("test.fit")
+        with phase_timer(timings, "table_convert"):
+            table = DenseTable.from_numpy(blobs, get_mesh(n_devices=1))
+        up = timings.root.node("table_convert/upload")
+        assert up.attrs["pieces"] == 16 and up.attrs["shards"] == 1
+        assert np.asarray(table.data).tobytes() == blobs.tobytes()
+
+    @pytest.mark.parametrize("in_flight", [1, 2])
+    def test_no_piece_outlives_the_upload(self, blobs, monkeypatch, in_flight):
+        """After the upload the device holds the table and the mask: the
+        pieces are gone, and the buffer each write was handed went INTO
+        the next (donated), so nothing table-sized is left beside it."""
+        import gc
+
+        gc.collect()
+        before = {id(a) for a in jax.live_arrays()}
+        table, _ = self._table(
+            monkeypatch, blobs, self._piece_bytes(blobs, blobs.shape[0], 5),
+            n_devices=1, in_flight=in_flight,
+        )
+        gc.collect()
+        new = [a for a in jax.live_arrays() if id(a) not in before]
+        assert sorted(a.shape for a in new) == sorted(
+            [table.data.shape, table.mask.shape]
+        )
+        assert {id(a) for a in new} == {id(table.data), id(table.mask)}
+
+    @pytest.mark.parametrize("n_devices,pieces,in_flight", [
+        (1, 1, 1), (1, 3, 1), (1, 3, 2), (1, 3, 3), (4, 1, 1), (4, 3, 1),
+    ])
+    def test_upload_span_says_what_went_up(
+        self, blobs, monkeypatch, n_devices, pieces, in_flight
+    ):
+        from oap_mllib_tpu.parallel.mesh import get_mesh
+
+        self._limits(
+            monkeypatch,
+            self._piece_bytes(blobs, blobs.shape[0] // n_devices, pieces),
+            in_flight,
+        )
+        timings = Timings("test.fit")
+        with phase_timer(timings, "table_convert"):
+            table = DenseTable.from_numpy(blobs, get_mesh(n_devices=n_devices))
+        up = timings.root.node("table_convert/upload")
+        assert up.attrs == {
+            "bytes": blobs.nbytes + blobs.shape[0] * blobs.itemsize,
+            "shards": n_devices,
+            # a shard that may all be in flight at once goes up whole
+            "pieces": pieces if pieces > in_flight else 1,
+        }
+        assert up.duration_s > 0 and table.n_rows == blobs.shape[0]
+
     def test_a_model_axis_holds_replicas_of_the_pieces(self, blobs, monkeypatch):
-        from oap_mllib_tpu.data import table as table_mod
         from oap_mllib_tpu.parallel.mesh import get_mesh
 
         monkeypatch.setattr(table_mod, "_UPLOAD_PIECE_BYTES", blobs.nbytes // 4)
@@ -303,7 +434,6 @@ class TestUploadInPieces:
 
     def test_a_fit_through_the_pieces_copies_nothing(self, blobs, monkeypatch):
         from oap_mllib_tpu import KMeans
-        from oap_mllib_tpu.data import table as table_mod
         from oap_mllib_tpu.utils import progcache
 
         whole = KMeans(k=4, max_iter=3, seed=0).fit(blobs)
@@ -314,6 +444,7 @@ class TestUploadInPieces:
         root = pieced.summary.timings.root
         assert root.node("table_convert/host_copy").attrs["copied_bytes"] == 0
         assert root.node("table_convert/upload").attrs["shards"] == 8
+        assert root.node("table_convert/upload").attrs["pieces"] == 4
         assert (
             pieced.cluster_centers_.tobytes() == whole.cluster_centers_.tobytes()
         )
@@ -323,3 +454,36 @@ class TestUploadInPieces:
         KMeans(k=4, max_iter=3, seed=0).fit(blobs)
         assert joins()["hits"] == before["hits"] + 1
         assert joins()["misses"] == before["misses"]
+
+    @pytest.mark.parametrize("estimator", ["kmeans", "pca"])
+    def test_a_one_device_fit_through_three_pieces(
+        self, blobs, monkeypatch, estimator
+    ):
+        """On one device, three pieces, two in flight: nothing copied on
+        the host, and the model of the one-``device_put`` fit, bit for
+        bit."""
+        from oap_mllib_tpu.models import kmeans as kmeans_mod
+        from oap_mllib_tpu.models import pca as pca_mod
+        from oap_mllib_tpu.parallel.mesh import get_mesh
+        from oap_mllib_tpu.utils import progcache
+
+        mesh = get_mesh(n_devices=1)
+        monkeypatch.setattr(kmeans_mod, "get_mesh", lambda: mesh)
+        monkeypatch.setattr(pca_mod, "get_mesh", lambda: mesh)
+        whole, timings = _fit(estimator, blobs)
+        assert timings.root.node("table_convert/upload").attrs["pieces"] == 1
+        self._limits(
+            monkeypatch, self._piece_bytes(blobs, blobs.shape[0], 3), 2
+        )
+        pieced, timings = _fit(estimator, blobs)
+        up = timings.root.node("table_convert/upload")
+        assert up.attrs["pieces"] == 3 and up.attrs["shards"] == 1
+        copy = timings.root.node("table_convert/host_copy")
+        assert copy.attrs["copied_bytes"] == 0
+        assert np.asarray(pieced).tobytes() == np.asarray(whole).tobytes()
+        # the writer is one program a backend, found again by the next table
+        writes = lambda: dict(progcache.stats()["by_algo"]["table.write_piece"])
+        before = writes()
+        _fit(estimator, blobs)
+        assert writes()["hits"] == before["hits"] + 1
+        assert writes()["misses"] == before["misses"]

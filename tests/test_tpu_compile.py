@@ -492,3 +492,40 @@ class TestDataParallelKMeans:
         held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes)
         assert held <= 2 * shard + 2**20
+
+
+class TestOneChipUpload:
+    """The PCA cell (``pca_d512_k10``): an 8 GiB float32 table on ONE
+    chip, up in 32 pieces of 256 MiB, each written into the one donated
+    table-sized buffer — or a fit would ask for 17 GB where
+    ``fallback=False`` hides nothing."""
+
+    ROWS, D, PIECE_ROWS = 4194304, 512, 131072
+
+    def test_writer_aliases_the_donated_table(self, topo):
+        from oap_mllib_tpu.data import table as table_mod
+        from oap_mllib_tpu.utils import progcache
+
+        mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+        rows = NamedSharding(mesh, P("data", None))
+        piece = self.PIECE_ROWS * self.D * 4
+        assert piece * table_mod._ONE_DEVICE_PIECES_IN_FLIGHT == (
+            table_mod._UPLOAD_PIECE_BYTES
+        )
+        progcache.clear()  # the registry may hold another backend's program
+        compiled = table_mod._write_piece().lower(
+            _s((self.ROWS, self.D), rows),
+            _s((self.PIECE_ROWS, self.D), rows),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P())),
+        ).compile()
+        assert "input_output_alias={ {}: (0, {}" in compiled.as_text()
+        assert compiled.output_shardings == rows
+        # the output IS the donated table: the device holds the table and
+        # the piece (and the offset), never the table twice
+        mem = compiled.memory_analysis()
+        table = self.ROWS * self.D * 4
+        assert mem.alias_size_in_bytes == table == mem.output_size_in_bytes
+        assert mem.temp_size_in_bytes < piece
+        held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        assert held <= table + piece + 2**20
