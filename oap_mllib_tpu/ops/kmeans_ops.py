@@ -1000,26 +1000,43 @@ def init_random(
     return _gather_rows(x, idx)
 
 
-def _slot_chunk_size(cap: int, target: int = 1024) -> int:
-    """Largest divisor of ``cap`` that is <= target (slot-chunking the
-    min-distance update bounds the live (n, chunk) buffer).
+def _slot_chunk_size(cap: int, target: int = 512) -> int:
+    """The slots a k-means|| round folds at a time: the largest divisor
+    of ``cap`` in [target // 4, target], and for a ``cap`` that has none
+    (4k for a prime k above the target) the largest divisor <= 2 *
+    target, so that no k falls to a chunk of a few slots while one of
+    about a thousand would do.
+
+    A chunk bounds the live (n, chunk) distance sheet, and it is the
+    step by which the fold follows the slots a round filled
+    (:func:`_live_chunks`): about half of ``cap``, so a finer chunk ends
+    the fold closer behind the last pick.  The target is a few MXU
+    column tiles — cap = 4000 folds in chunks of 500, which fill 512
+    columns as well as 1000 fill 1024 — and a chunk is not finer than
+    one tile where ``cap`` allows, or a step's read of the table shows
+    from under its product.
 
     Direct paired-divisor enumeration up to sqrt(cap): every divisor d
     <= sqrt(cap) pairs with cap // d, so scanning the square root covers
-    them all — O(sqrt cap) where the old loop scanned all of [1, cap]."""
-    if cap <= target:
-        return max(cap, 1)
-    best = 1
+    them all."""
+    fine, coarse = 0, 1
     d = 1
     while d * d <= cap:
         if cap % d == 0:
-            if best < d <= target:
-                best = d
-            q = cap // d
-            if best < q <= target:
-                best = q
+            for c in (d, cap // d):
+                if target // 4 <= c <= target:
+                    fine = max(fine, c)
+                if c <= 2 * target:
+                    coarse = max(coarse, c)
         d += 1
-    return best
+    return fine or coarse
+
+
+def _live_chunks(filled, chunk: int):
+    """Chunks of ``chunk`` slots that hold a filled one when the first
+    ``filled`` slots are: what a round folds.  One formula for the
+    device's loop bound and the host's count of it."""
+    return (filled + chunk - 1) // chunk
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "chunk"))
@@ -1032,8 +1049,13 @@ def _pll_round(x, w, dmin, amin, base_id, key, l, cap, chunk):
     scatters the picked rows into a fixed ``cap``-slot buffer via their
     picked-prefix position (overflow beyond cap is dropped — cap is 2x the
     expected pick count), then folds the new slots into the running
-    (min-distance, nearest-candidate) state chunk-by-chunk so no (n, cap)
-    buffer ever materializes.  All reductions/scatters are global: under a
+    (min-distance, nearest-candidate) state ``chunk`` slots at a time, so
+    no (n, cap) buffer ever materializes, and only as far as the slots
+    are filled: the loop's trip count is :func:`_live_chunks` of the
+    round's own pick count, not ``cap // chunk``, because a chunk with no
+    valid slot is a full-price distance sheet that changes no row.  About
+    half the capacity is never multiplied; a round whose picks reach
+    ``cap`` folds every chunk.  All reductions/scatters are global: under a
     row-sharded mesh GSPMD lowers them to psums, so the round is
     multi-host-safe with zero O(n) host transfers (round-1 pulled all n
     distances AND weights to host each round).
@@ -1054,23 +1076,27 @@ def _pll_round(x, w, dmin, amin, base_id, key, l, cap, chunk):
         picked.astype(x.dtype), mode="drop"
     )
 
-    # fold new candidates into (dmin, amin) without an (n, cap) buffer
+    # picks fill slots 0 .. picks-1: a chunk past the last filled slot is
+    # a sheet of inf (cm < dm false on every row), so the fold ends there
     q = cap // chunk
     slots_c = slots.reshape(q, chunk, x.shape[1])
     valid_c = slot_valid.reshape(q, chunk)
-    bases = base_id + chunk * jnp.arange(q, dtype=jnp.int32)
+    filled = jnp.sum((slot_valid > 0).astype(jnp.int32))
 
-    def fold(carry, sl):
+    def fold(i, carry):
         dm, am = carry
-        s, v, b = sl
+        s = lax.dynamic_index_in_dim(slots_c, i, 0, keepdims=False)
+        v = lax.dynamic_index_in_dim(valid_c, i, 0, keepdims=False)
         d2 = pairwise_sq_dists(x, s)
         d2 = jnp.where(v[None, :] > 0, d2, jnp.inf)
         cm = jnp.min(d2, axis=1)
-        ca = argmin_rows(d2, cm).astype(jnp.int32) + b
+        ca = argmin_rows(d2, cm).astype(jnp.int32) + base_id + chunk * i
         better = cm < dm
-        return (jnp.where(better, cm, dm), jnp.where(better, ca, am)), None
+        return jnp.where(better, cm, dm), jnp.where(better, ca, am)
 
-    (dmin, amin), _ = lax.scan(fold, (dmin, amin), (slots_c, valid_c, bases))
+    dmin, amin = lax.fori_loop(
+        0, _live_chunks(filled, chunk), fold, (dmin, amin)
+    )
     return slots, slot_valid, dmin, amin, phi
 
 
@@ -1184,7 +1210,10 @@ def init_kmeans_parallel(
 
     The candidate set lives in a static-shape device buffer (1 +
     4k*steps slots — 2x the expected 2k picks per round, so
-    overflow-dropping is vanishingly rare) and never goes to the host:
+    overflow-dropping is vanishingly rare; a round folds only the slot
+    chunks its picks reached, and the ``rounds`` span says how many:
+    ``slot_chunks`` of ``slot_chunks_cap``, over ``slots_filled`` slots)
+    and never goes to the host:
     per-round sampling/prefix-scatter/min-fold run in one jitted
     program, the ownership weights in another, and the weighted
     k-means++ reduction of the candidates to k centres (Spark runs it on
@@ -1230,10 +1259,16 @@ def init_kmeans_parallel(
             all_valid.append(slot_valid)
             # small host fetch, re-replicated if GSPMD left the output sharded
             filled.append(_to_host(slot_valid) > 0)
-        n_cand = 1 + sum(int(f.sum()) for f in filled)
+        picks = [int(f.sum()) for f in filled]
+        n_cand = 1 + sum(picks)
         rounds = len(all_slots)
         span.attrs["rounds"] = rounds
         span.attrs["shards"] = _row_shards(x_dev)
+        # what the rounds' folds multiplied, against what the capacity
+        # would have asked for
+        span.attrs["slots_filled"] = sum(picks)
+        span.attrs["slot_chunks"] = sum(_live_chunks(p, chunk) for p in picks)
+        span.attrs["slot_chunks_cap"] = (cap // chunk) * rounds
         if n_cand > k:
             # rounds that did not run leave their slots empty, so the
             # buffer, and with it the programs below, keep one shape

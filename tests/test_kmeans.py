@@ -457,18 +457,38 @@ class TestRegressions:
         # small fits still take the no-scan-overhead single chunk
         assert auto_row_chunks(1000, 4) == 1
 
-    def test_slot_chunk_size_matches_brute_force(self):
+    @pytest.mark.parametrize("target", [1, 7, 64, 256, 512, 1024])
+    def test_slot_chunk_size_matches_brute_force(self, target):
         """The O(sqrt cap) paired-divisor enumeration must agree with
-        the old exhaustive scan: largest divisor of cap <= target."""
+        the exhaustive scan: the largest divisor of cap in [target // 4,
+        target], else the largest one <= 2 * target."""
         from oap_mllib_tpu.ops.kmeans_ops import _slot_chunk_size
 
-        for cap in list(range(1, 700, 13)) + [1024, 1536, 2048, 4100]:
-            for target in (1, 7, 64, 1024):
-                brute = max(
-                    c for c in range(1, cap + 1)
-                    if cap % c == 0 and c <= target
-                ) if cap >= 1 else 1
-                assert _slot_chunk_size(cap, target) == brute, (cap, target)
+        for cap in list(range(1, 700, 13)) + [
+            1024, 1536, 2048, 2084, 3200, 4000, 4100, 4124,
+        ]:
+            divisors = [c for c in range(1, cap + 1) if cap % c == 0]
+            fine = [c for c in divisors if target // 4 <= c <= target]
+            brute = max(fine or [c for c in divisors if c <= 2 * target])
+            assert _slot_chunk_size(cap, target) == brute, (cap, target)
+
+    @pytest.mark.parametrize("cap, chunk", [
+        (4000, 500),  # the cells' k = 1000: eight chunks, 512 MXU columns
+        (8000, 500), (4096, 512), (640, 320), (3200, 400),
+        (16, 16), (400, 400), (512, 512),  # a small k keeps one chunk
+        (4 * 131, 262), (4 * 263, 263),  # a prime k: 2k, or k past 256
+        # a prime k past the target has no divisor in [128, 512]: not the
+        # 4 a plain "largest <= 512" gives (521 sheets a round), but k,
+        # the largest <= 1024
+        (4 * 521, 521), (4 * 1021, 1021),
+        (4 * 1031, 4),  # a prime k past 1024 has only 1, 2, 4 below it
+    ])
+    def test_slot_chunk_size_of_a_rounds_capacity(self, cap, chunk):
+        """The rule the rounds use (the default target), at cap = 4k."""
+        from oap_mllib_tpu.ops.kmeans_ops import _slot_chunk_size
+
+        assert _slot_chunk_size(cap) == chunk
+        assert cap % chunk == 0
 
     def test_bad_precision_string_raises(self, rng):
         import jax.numpy as jnp

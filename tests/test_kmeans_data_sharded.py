@@ -233,6 +233,35 @@ class TestCounters:
         assert root.node("table_convert/upload").attrs["shards"] == 8
         assert root.node("init_centers/rounds").attrs["shards"] == 8
 
+    def test_what_the_rounds_fold_on_the_mesh(self, rng, monkeypatch):
+        """k = 160 gives 640 slots a round in two chunks of 320 and about
+        320 picks: the ``rounds`` span counts the chunks each round's
+        picks reached, by the device's own formula."""
+        k = 160
+        cap, chunk = 4 * k, kmeans_ops._slot_chunk_size(4 * k)
+        assert (cap, chunk) == (640, 320)
+        x, _ = _blobs(rng, 4096, k=40)
+        filled = []
+        round_ = kmeans_ops._pll_round
+
+        def spy(*a, **kw):
+            out = round_(*a, **kw)
+            assert a[-2:] == (cap, chunk)
+            filled.append(int((np.asarray(out[1]) > 0).sum()))
+            return out
+
+        monkeypatch.setattr(kmeans_ops, "_pll_round", spy)
+        model = KMeans(k=k, max_iter=2, seed=3).fit(x)  # 8 devices
+        attrs = model.summary.timings.root.node("init_centers/rounds").attrs
+        assert attrs["shards"] == 8 and attrs["rounds"] == len(filled) == 2
+        assert all(0 < f < cap for f in filled)
+        assert attrs["slots_filled"] == sum(filled)
+        assert attrs["slot_chunks"] == sum(-(-f // chunk) for f in filled)
+        assert attrs["slot_chunks_cap"] == 2 * (cap // chunk)
+        assert attrs["slot_chunks"] <= attrs["slot_chunks_cap"]
+        # picks near l = 2k = 320 of 640: a round folds one chunk or two
+        assert 2 <= attrs["slot_chunks"] <= 4
+
     def test_the_walk_reduces_its_lane_padded_blocks(self):
         # k=1000, d=256 (the benchmark's cell): 1024 x 256 sums and 1024
         # counts an iteration

@@ -487,9 +487,16 @@ class TestSubSpans:
         # table (the zero-pass route reads 0: tests/test_table_staging.py)
         copy = root.node("table_convert/host_copy")
         assert copy.attrs["copied_bytes"] == table.data.nbytes
-        assert root.node("init_centers/rounds").attrs["rounds"] == 2
+        rounds = root.node("init_centers/rounds").attrs
+        assert rounds["rounds"] == 2
         cand = root.node("init_centers/kmeanspp_host").attrs["candidates"]
         assert 4 < cand <= 1 + 2 * 16  # one seed row + 4k slots a round
+        # what the rounds folded: 4k = 16 slots are ONE chunk a round, so
+        # sum(ceil(filled_r / 16)) counts the rounds that picked a row
+        assert rounds["slots_filled"] == cand - 1
+        assert rounds["slot_chunks_cap"] == 2
+        assert 1 <= rounds["slot_chunks"] <= rounds["slot_chunks_cap"]
+        assert rounds["slot_chunks"] >= -(-rounds["slots_filled"] // 16)
         host = root.node("init_centers/kmeanspp_host")
         assert host.attrs["reduced_on"] == "device"
         # the tree the exporters serialize carries them too

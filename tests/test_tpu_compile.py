@@ -424,34 +424,60 @@ class TestDataParallelKMeans:
         held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
         assert held < 3 * (self.ROWS // 4) * self.D * 4
 
-    def test_pll_round_on_a_row_sharded_table(self, mesh):
+    def _compiled_pll_round(self, rows, table, row, rep):
+        """The k-means|| round at the cells' widths for ``rows`` rows, and
+        what one device may hold while it runs."""
         from oap_mllib_tpu.ops import kmeans_ops
 
-        rows = NamedSharding(mesh, P("data", None))
-        row = NamedSharding(mesh, P("data"))
-        rep = NamedSharding(mesh, P())
         cap = 4 * self.K
+        chunk = kmeans_ops._slot_chunk_size(cap)
         compiled = kmeans_ops._pll_round.lower(
-            _s((self.ROWS, self.D), rows),
-            _s((self.ROWS,), row),
-            _s((self.ROWS,), row),
-            jax.ShapeDtypeStruct((self.ROWS,), jnp.int32, sharding=row),
+            _s((rows, self.D), table),
+            _s((rows,), row),
+            _s((rows,), row),
+            jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=row),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
             _s((), rep),
-            cap=cap, chunk=kmeans_ops._slot_chunk_size(cap),
+            cap=cap, chunk=chunk,
         ).compile()
         text = compiled.as_text()
         assert "all-gather" not in text  # the scatter stays local + reduced
+        # the fold's trip count is the round's own pick count: a value
+        # read on the device, so the compiler knows none
+        fold = [
+            line for line in text.splitlines()
+            if " while(" in line and "pll_round/while" in line
+            and "_uniform" not in line
+        ]
+        assert len(fold) == 1 and "known_trip_count" not in fold[0]
         mem = compiled.memory_analysis()
-        held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-        # the distance sheet is a DEVICE's rows x 1000, never the table's
-        sheet = (self.ROWS // 4) * self.K * 4
-        assert sheet <= mem.temp_size_in_bytes < 1.1 * sheet
-        assert held < self.HBM
+        on_device = self.ROWS // 4
+        # a step's distance sheet is a DEVICE's rows x the slot chunk,
+        # never rows x k nor the table's rows; the scatter's table-sized
+        # operand (x * picked) is live before it, not beside it
+        sheet = on_device * chunk * 4
+        scatter_operand = on_device * self.D * 4
+        assert chunk < self.K
+        larger = max(sheet, scatter_operand)
+        assert larger <= mem.temp_size_in_bytes < 1.05 * larger
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < self.HBM
+        return compiled
+
+    def test_pll_round_on_a_row_sharded_table(self, mesh):
+        compiled = self._compiled_pll_round(
+            self.ROWS,
+            NamedSharding(mesh, P("data", None)),
+            NamedSharding(mesh, P("data")),
+            NamedSharding(mesh, P()),
+        )
         # slots come back replicated: the host fetch needs no re-gather
         slots_sharding = compiled.output_shardings[0]
         assert slots_sharding.is_fully_replicated
+
+    def test_pll_round_on_one_chip(self, one_chip):
+        """The one-chip cell's round: the same rows a device, no mesh."""
+        self._compiled_pll_round(self.ROWS // 4, one_chip, one_chip, one_chip)
 
     def test_candidate_reduction_runs_replicated(self, mesh):
         """On the host's mesh every chip reduces the same 8001 slots from
