@@ -19,7 +19,9 @@ Memory lifetime is JAX's (GC'd device buffers) — no explicit
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import dataclasses
+import os
 from typing import Optional
 
 import jax
@@ -31,6 +33,7 @@ from oap_mllib_tpu.parallel.mesh import data_sharding
 from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import progcache
 from oap_mllib_tpu.utils.jax_compat import shard_map
+from oap_mllib_tpu.utils.timing import tick
 
 # rows are padded per shard to this multiple (cheap: padding is masked)
 _ROW_MULTIPLE = 256
@@ -46,14 +49,17 @@ def _padded_row_target(n: int, multiple: int) -> int:
     return bucket_rows(n, multiple)
 
 
-def _stage_rows(x, multiple: int, dtype):
-    """The host side of both ndarray constructors: the array to upload,
-    its valid-row count, and the bytes copied to make it.  Decided from
-    the input alone: ``x`` itself (no allocation, no pass over the
-    table) when its dtype matches, it is C-contiguous and its rows sit
-    on their bucket; otherwise ONE pass into a fresh array of the
-    bucket's shape — the assignment casts and un-strides, and only the
-    tail rows are zeroed."""
+def _stage_rows(x, multiple: int, dtype, shards: int = 0):
+    """The host side of both ndarray constructors: what to upload, its
+    valid-row count, and the bytes a pass of its own copied to make it.
+    Decided from the input alone.  ``x`` itself (no allocation, no pass
+    over the table) when its dtype matches, it is C-contiguous and its
+    rows sit on their bucket.  Otherwise ONE pass into a fresh array of
+    the bucket's shape — the assignment casts and un-strides, and only
+    the tail rows are zeroed — unless, with ``shards`` the row shards of
+    a one-process mesh, a padded shard is more than a device may have in
+    flight: such a table is never made whole on the host, and goes up
+    as ``_RowBlocks``, cast and padded under the upload (0 bytes)."""
     x = np.asarray(x)
     if x.ndim != 2:
         raise ValueError(f"expected 2-D data, got shape {x.shape}")
@@ -67,6 +73,8 @@ def _stage_rows(x, multiple: int, dtype):
     target = _padded_row_target(n, multiple)
     if x.dtype == dtype and x.flags.c_contiguous and n == target:
         return x, n, 0
+    if shards and target // shards * x.shape[1] * dtype.itemsize > _UPLOAD_PIECE_BYTES:
+        return _RowBlocks(x, target, dtype), n, 0
     padded = np.empty((target, x.shape[1]), dtype)
     padded[:n] = x
     padded[n:] = 0
@@ -158,6 +166,135 @@ def _put_in_place(host: np.ndarray, sharding):
     return table, -(-host.shape[0] // step)
 
 
+# What the cast route (``_RowBlocks``) sends at a time, and how.  Its
+# staging buffers are fresh memory, touched for the first time by the
+# cast that fills them, and a v5e host without transparent hugepages
+# faults 256 MiB in at about 0.1 s — three times what the cast itself
+# takes.  So the ring is small.  3.2 GB of float32 out of float64, by
+# block size x buffers a shard (PERF.md section 6, PR 33): 256 MiB x 4
+# 0.63-0.70 s, 128 x 4 0.41-0.42, 64 x 4 0.38, **64 x 2 0.344-0.349**,
+# 32 x 4 0.42, 16 x 8 0.66-0.70; the same four of 256 MiB kept from the
+# table before, 0.335: the link's own rate.  ``device_put`` returns in a
+# millisecond, so two buffers a shard keep the link busy: one in flight
+# while the other is cast.  The cast is bound by the host's memory, not
+# its cores, and NumPy's cast loop releases the GIL: at 64 MiB x 4,
+# twelve threads read 0.379-0.381 s, eight 0.387-0.395 (256 MiB x 4:
+# 0.63, 0.67, and 0.78 for four).
+_CAST_BLOCK_BYTES = 64 << 20
+_CAST_RING_SLOTS = 2
+_CAST_THREADS_MAX = 12
+
+
+def _cast_threads() -> int:
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:  # not Linux
+        cores = os.cpu_count() or 1
+    return max(1, min(_CAST_THREADS_MAX, cores))
+
+
+class _RowBlocks:
+    """The caller's array where it cannot go up as it is (dtype differs,
+    not C-contiguous, or rows off their bucket) and its padded shard is
+    more than a device may have in flight: never made whole on the host.
+    ``put`` walks it in row blocks of ``_CAST_BLOCK_BYTES``, each cast /
+    un-strided into a staging buffer by host threads (NumPy's own cast
+    of the block, once: the table is bit for bit
+    ``np.pad(x.astype(dtype), ...)``) and handed to ``device_put``, which
+    returns at once, while earlier blocks are still in flight.  Every
+    device's row shard starts as zeros made on that device and each block
+    is written into it in place (``_write_piece``, donated): only the
+    valid rows cross the link, the pad is what was never written, and a
+    device holds its shard and the blocks in flight, never a shard twice.
+    The staging buffers are a ring of ``_CAST_RING_SLOTS`` a shard, which
+    is also what is in flight: far under ``_UPLOAD_PIECE_BYTES`` a
+    device.
+
+    ``shape`` is the padded table's, ``nbytes`` what crosses the link;
+    after ``put``: ``cast_bytes`` (what the block casts wrote) and
+    ``cast_wait_s`` (seconds the sender stood waiting for block casts,
+    sending nothing; the blocks in flight go on landing meanwhile)."""
+
+    def __init__(self, x: np.ndarray, padded_rows: int, dtype):
+        self.x = x
+        self.dtype = np.dtype(dtype)
+        self.shape = (padded_rows, x.shape[1])
+        self.row_bytes = x.shape[1] * self.dtype.itemsize
+        self.nbytes = x.shape[0] * self.row_bytes
+        self.cast_bytes = 0
+        self.cast_wait_s = 0.0
+        self.cast_threads = _cast_threads()
+
+    def _cast(self, pool, buf, lo):
+        """``buf[:] = x[lo:lo + len(buf)]``, the rows shared out among the
+        pool's threads."""
+        cuts = np.linspace(0, buf.shape[0], self.cast_threads + 1).astype(int)
+        waited = tick()
+        casts = [
+            pool.submit(np.copyto, buf[a:b], self.x[lo + a:lo + b], "unsafe")
+            for a, b in zip(cuts[:-1], cuts[1:]) if b > a
+        ]
+        for cast in casts:
+            cast.result()
+        self.cast_wait_s += waited()
+        self.cast_bytes += buf.nbytes
+
+    def put(self, sharding):
+        n, (rows, d) = self.x.shape[0], self.shape
+        index = sharding.addressable_devices_indices_map(self.shape)
+        # the devices of a model axis hold replicas of a row shard: one
+        # cast for them all
+        shards = collections.defaultdict(list)
+        for dev, idx in index.items():
+            shards[idx[0].indices(rows)[0]].append(dev)
+        shard_rows = rows // len(shards)
+        step = max(1, _CAST_BLOCK_BYTES // self.row_bytes)
+        # (index in its shard, the shard's first row, rows) of every
+        # block, a block of every shard in turn so that the devices'
+        # transfers overlap
+        blocks = sorted(
+            (at, start, min(step, start + shard_rows - lo, n - lo))
+            for start in shards
+            for at, lo in enumerate(range(start, min(start + shard_rows, n), step))
+        )
+        slots = _CAST_RING_SLOTS * len(shards)
+        ring = [
+            np.empty((step, d), self.dtype) for _ in range(min(slots, len(blocks)))
+        ]
+        write = _write_piece()
+        table = {
+            dev: jnp.zeros((shard_rows, d), self.dtype, device=dev)
+            for dev in index
+        }
+        flying = collections.deque()  # (device, piece, row in its shard)
+
+        def write_oldest():
+            dev, piece, at = flying.popleft()
+            table[dev] = write(table[dev], piece, np.int32(at))
+            return table[dev]
+
+        with concurrent.futures.ThreadPoolExecutor(self.cast_threads) as pool:
+            for i, (at, start, height) in enumerate(blocks):
+                if i >= slots:
+                    # the block that had this staging buffer has landed
+                    # and is written: the buffer is free to be cast into
+                    jax.block_until_ready(
+                        [write_oldest() for _ in shards[blocks[i - slots][1]]]
+                    )
+                buf = ring[i % slots][:height]
+                self._cast(pool, buf, start + at * step)
+                for dev in shards[start]:
+                    flying.append((dev, jax.device_put(buf, dev), at * step))
+        while flying:
+            write_oldest()
+        return (
+            jax.make_array_from_single_device_arrays(
+                self.shape, sharding, [table[dev] for dev in index]
+            ),
+            max(1, -(-min(n, shard_rows) // step)),
+        )
+
+
 def _put_rows(host: np.ndarray, sharding):
     """(``jax.device_put(host, sharding)`` of a table every row of which
     this process holds, the pieces a row shard went up in).  In a world
@@ -169,7 +306,10 @@ def _put_rows(host: np.ndarray, sharding):
     one buffer (``_put_in_place``), table + 1 GiB live.  Several: every
     device's row slice in pieces, one piece a device in flight; a shard
     of several pieces is joined on its device, where it is held twice
-    until the pieces are dropped."""
+    until the pieces are dropped.  ``_RowBlocks`` (an array that needs a
+    cast, an un-striding or a pad under those same bounds) puts itself."""
+    if isinstance(host, _RowBlocks):
+        return host.put(sharding)
     index = sharding.addressable_devices_indices_map(host.shape)
     if jax.process_count() > 1:
         return jax.device_put(host, sharding), 1
@@ -192,7 +332,7 @@ def _put_rows(host: np.ndarray, sharding):
     return table, len(pieces)
 
 
-def _upload(put, padded: np.ndarray, mask: np.ndarray, mesh):
+def _upload(put, padded, mask: np.ndarray, mesh, n_valid: int):
     """The ``upload`` sub-span of both constructors: ``put(host array,
     sharding)`` for the table and its mask, then the wait for both —
     ``device_put`` returns before the bytes land, and without the wait
@@ -200,7 +340,11 @@ def _upload(put, padded: np.ndarray, mask: np.ndarray, mesh):
     the table.  ``attrs["bytes"]`` is what this process sent,
     ``attrs["shards"]`` the row shards the table was cut into (one a
     device of the data axis) and ``attrs["pieces"]`` the pieces each of
-    them went up in."""
+    them went up in; ``attrs["valid_rows"]`` of ``attrs["padded_rows"]``
+    are the caller's.  Where the table was cast block by block under the
+    transfers (``padded`` a ``_RowBlocks``): ``attrs["cast_bytes"]`` the
+    casts wrote, by ``attrs["cast_threads"]`` threads, the sender
+    waiting ``attrs["cast_wait_s"]`` for them; all 0 elsewhere."""
     with spans.child("upload") as span:
         data, pieces = put(padded, data_sharding(mesh, 2))
         mask_dev, _ = put(mask, data_sharding(mesh, 1))
@@ -208,6 +352,10 @@ def _upload(put, padded: np.ndarray, mask: np.ndarray, mesh):
         span.attrs["bytes"] = padded.nbytes + mask.nbytes
         span.attrs["shards"] = mesh.shape[mesh.axis_names[0]]
         span.attrs["pieces"] = pieces
+        span.attrs["valid_rows"] = n_valid
+        span.attrs["padded_rows"] = padded.shape[0]
+        for name in ("cast_bytes", "cast_wait_s", "cast_threads"):
+            span.attrs[name] = getattr(padded, name, 0)
     return data, mask_dev
 
 
@@ -220,31 +368,54 @@ class DenseTable:
     way so masked reductions stay local + psum.
 
     The constructors split the phase that calls them (``table_convert``)
-    into two sub-spans (telemetry/spans.child): ``host_copy`` — the one
-    pass that casts, un-strides and pads (``_stage_rows``) or densifies,
-    and the mask: pure host, with what the pass wrote in
+    into two sub-spans (telemetry/spans.child): ``host_copy`` — whatever
+    is made on the host BEFORE the upload begins: the mask, and, where
+    there is one, the one pass that casts, un-strides and pads
+    (``_stage_rows``) or densifies, with what that pass wrote in
     ``attrs["copied_bytes"]`` — and ``upload``, which ends when the
     bytes have LANDED (``block_until_ready``), not when ``device_put``
-    returns, and carries their count in ``attrs["bytes"]``, the row
-    shards in ``attrs["shards"]`` and the pieces a shard went up in in
-    ``attrs["pieces"]``.  The wait costs no wall where the caller's next
-    statement depends on the table anyway (every in-memory fit's does).
+    returns, and carries what was sent in ``attrs["bytes"]``, the row
+    shards in ``attrs["shards"]``, the pieces a shard went up in in
+    ``attrs["pieces"]``, the caller's rows in ``attrs["valid_rows"]`` of
+    the table's ``attrs["padded_rows"]``, and what was cast under it in
+    ``attrs["cast_bytes"]``, ``["cast_wait_s"]`` and ``["cast_threads"]``
+    (0 where nothing was).  The wait costs no wall where the caller's
+    next statement depends on the table anyway (every in-memory fit's
+    does).
 
-    A shard of more than 1 GiB goes up in row-block views of the host
-    array, 1 GiB a device in flight (``_put_rows``): what is in flight
-    does not grow with the table.  On one device the pieces are written
-    in place into the table's one buffer — it holds table + 1 GiB, never
-    the table twice; on a mesh a shard's pieces are joined on its device.
+    What a caller pays for its array (one process), decided from the
+    array alone:
 
-    The caller's array is uploaded AS IS, with no host copy
-    (``copied_bytes`` 0), when it already has the table's dtype, is
-    C-contiguous and has a row count on its bucket; it must not be
-    mutated until the constructor returns.  The program never writes
-    into it.  Off the CPU ``data`` is a device buffer of its own from
-    then on.  On the CPU backend ``device_put`` SHARES a host buffer
-    that is 64-byte aligned instead of copying it (jax 0.9.0), so there
-    an array uploaded as is and WHOLE must stay unchanged while the
-    table lives — inside ``fit`` it does: the table dies with the fit.
+    - the table's dtype, C-contiguous, rows on their bucket: uploaded AS
+      IS, with no host copy (``copied_bytes`` 0, ``cast_bytes`` 0).  A
+      shard of more than 1 GiB goes up in row-block views of the array,
+      1 GiB a device in flight (``_put_rows``): what is in flight does
+      not grow with the table.  On one device the pieces are written in
+      place into the table's one buffer — it holds table + 1 GiB, never
+      the table twice; on a mesh a shard's pieces are joined on its
+      device.  The array must not be mutated until the constructor
+      returns; the program never writes into it;
+    - another dtype (Spark's float64 rows), another layout, or rows off
+      their bucket, and a padded shard of more than 1 GiB: cast,
+      un-strided and padded UNDER the upload (``_RowBlocks``), 64 MiB
+      blocks by host threads while earlier blocks are in flight.  No
+      table-sized array is ever made on the host (``copied_bytes`` 0,
+      ``cast_bytes`` = the valid rows in the table's dtype), only the
+      valid rows cross the link, the pad rows are zeros made on the
+      device, and each value is rounded once, by NumPy's own cast: the
+      table is ``np.pad(x.astype(dtype), ...)`` bit for bit;
+    - the same, and a padded shard of at most 1 GiB: ONE pass of its own
+      into a padded host array (``copied_bytes`` = its size), put whole.
+      No device program's shape follows the valid rows there, so fits
+      whose sizes share a bucket share every compiled program; the
+      blocks route compiles one small in-place write for the block its
+      valid rows end in.
+
+    Off the CPU ``data`` is a device buffer of its own from then on.  On
+    the CPU backend ``device_put`` SHARES a host buffer that is 64-byte
+    aligned instead of copying it (jax 0.9.0), so there an array
+    uploaded as is and WHOLE must stay unchanged while the table lives —
+    inside ``fit`` it does: the table dies with the fit.
     """
 
     data: jax.Array
@@ -269,8 +440,9 @@ class DenseTable:
     def from_numpy(cls, x: np.ndarray, mesh, dtype=None) -> "DenseTable":
         """The table of a host array (or SciPy matrix) ``x`` on ``mesh``,
         as ``dtype`` (``x``'s own when None).  At most one host pass
-        over the rows, and none when ``x`` can go up as it is (class
-        docstring): do not mutate ``x`` until this returns."""
+        over the rows: none when ``x`` can go up as it is, and none of
+        its own when a large ``x`` is cast under the upload (class
+        docstring).  Do not mutate ``x`` until this returns."""
         from oap_mllib_tpu.data import sparse as _sparse
 
         n_data = mesh.shape[mesh.axis_names[0]]
@@ -295,12 +467,13 @@ class DenseTable:
                 copied = padded.nbytes
             else:
                 padded, n_valid, copied = _stage_rows(
-                    x, n_data * _ROW_MULTIPLE, dtype
+                    x, n_data * _ROW_MULTIPLE, dtype,
+                    shards=n_data if jax.process_count() == 1 else 0,
                 )
             span.attrs["copied_bytes"] = copied
             mask = np.zeros((padded.shape[0],), dtype=padded.dtype)
             mask[:n_valid] = 1.0
-        data, mask = _upload(_put_rows, padded, mask, mesh)
+        data, mask = _upload(_put_rows, padded, mask, mesh, n_valid)
         return cls(data=data, mask=mask, n_rows=n_valid)
 
     @classmethod
@@ -362,7 +535,7 @@ class DenseTable:
             lambda host, sharding: (
                 jax.make_array_from_process_local_data(sharding, host), 1
             ),
-            padded, mask_local, mesh,
+            padded, mask_local, mesh, n_valid_local,
         )
         return cls(
             data=data,

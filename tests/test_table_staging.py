@@ -32,13 +32,13 @@ def _bucket(mesh):
     return mesh.shape[mesh.axis_names[0]] * table_mod._ROW_MULTIPLE
 
 
-def _make(rng, n, src_dtype, layout):
-    base = (rng.normal(size=(2 * n, 2 * D)) * 100).astype(src_dtype)
+def _make(rng, n, src_dtype, layout, d=D):
+    base = (rng.normal(size=(2 * n, 2 * d)) * 100).astype(src_dtype)
     if layout == "strided":
         x = base[::2, ::2]
         assert not x.flags.c_contiguous and not x.flags.f_contiguous
         return x
-    x = np.ascontiguousarray(base[:n, :D])
+    x = np.ascontiguousarray(base[:n, :d])
     if layout == "fortran":
         x = np.asfortranarray(x)
         assert not x.flags.c_contiguous
@@ -67,9 +67,9 @@ def staged(monkeypatch):
     uploads = []
     upload = table_mod._upload
 
-    def spy(put, padded, mask, mesh):
+    def spy(put, padded, mask, mesh, n_valid):
         uploads.append(padded)
-        return upload(put, padded, mask, mesh)
+        return upload(put, padded, mask, mesh, n_valid)
 
     monkeypatch.setattr(table_mod, "_upload", spy)
 
@@ -413,6 +413,9 @@ class TestUploadInPieces:
             "shards": n_devices,
             # a shard that may all be in flight at once goes up whole
             "pieces": pieces if pieces > in_flight else 1,
+            # the caller's array itself: nothing to cast, nothing padded
+            "valid_rows": blobs.shape[0], "padded_rows": blobs.shape[0],
+            "cast_bytes": 0, "cast_wait_s": 0, "cast_threads": 0,
         }
         assert up.duration_s > 0 and table.n_rows == blobs.shape[0]
 
@@ -487,3 +490,190 @@ class TestUploadInPieces:
         _fit(estimator, blobs)
         assert writes()["hits"] == before["hits"] + 1
         assert writes()["misses"] == before["misses"]
+
+
+# -- the table cast, un-strided and padded UNDER the upload (ISSUE 33) -------
+
+W = 16  # a width at which the mask is a sixteenth of the table
+# how the caller's array differs from what can go up as it is:
+# (its dtype, its layout, whether its rows sit on their bucket)
+KINDS = {
+    "f64_off_bucket": (np.float64, "c", False),
+    "f64_on_bucket": (np.float64, "c", True),
+    "f32_off_bucket": (np.float32, "c", False),
+    "fortran": (np.float32, "fortran", False),
+    "row_strided": (np.float32, "strided", False),
+}
+
+
+def _callers_array(rng, kind, bucket):
+    src_dtype, layout, on_bucket = KINDS[kind]
+    return _make(rng, bucket if on_bucket else bucket - 137, src_dtype, layout, W)
+
+
+class TestCastUnderTheUpload:
+    """A caller's array that cannot go up as it is, and whose padded shard
+    is more than a device may have in flight, is never made whole on the
+    host: ``_RowBlocks`` casts it a row block at a time by host threads
+    while earlier blocks are in flight, sends the valid rows alone and
+    writes them into shards that start as zeros made on their devices.
+    The table is ``np.pad(x.astype(dtype))`` bit for bit."""
+
+    def _build(self, monkeypatch, x, n_devices, piece_rows, in_flight=3):
+        """(table, upload span, host_copy span, nbytes of every 2-D array
+        the staging module allocated with ``np.empty``) with pieces of
+        ``piece_rows`` rows — views of an array that goes up as it is,
+        cast blocks of one that cannot — and ``in_flight`` of them a
+        device: a shard of more than that is over what may be in flight."""
+        monkeypatch.setattr(
+            table_mod, "_UPLOAD_PIECE_BYTES",
+            piece_rows * W * 4 * (in_flight if n_devices == 1 else 1),
+        )
+        monkeypatch.setattr(table_mod, "_ONE_DEVICE_PIECES_IN_FLIGHT", in_flight)
+        monkeypatch.setattr(table_mod, "_CAST_BLOCK_BYTES", piece_rows * W * 4)
+        monkeypatch.setattr(table_mod, "_CAST_RING_SLOTS", in_flight)
+        made = []
+        empty = np.empty
+        monkeypatch.setattr(
+            table_mod.np, "empty",
+            lambda *a, **k: (made.append(empty(*a, **k)), made[-1])[1],
+        )
+        timings = Timings("test.fit")
+        with phase_timer(timings, "table_convert"):
+            table = DenseTable.from_numpy(
+                x, get_mesh(n_devices=n_devices), np.float32
+            )
+        monkeypatch.setattr(table_mod.np, "empty", empty)
+        return (
+            table, timings.root.node("table_convert/upload"),
+            timings.root.node("table_convert/host_copy"),
+            [a.nbytes for a in made if a.ndim == 2],
+        )
+
+    @pytest.mark.parametrize("n_devices", [1, 4])
+    @pytest.mark.parametrize("kind", sorted(KINDS))
+    def test_table_is_the_plain_statement(self, rng, monkeypatch, kind, n_devices):
+        bucket = n_devices * table_mod._ROW_MULTIPLE * 4
+        x = _callers_array(rng, kind, bucket)
+        n, before = x.shape[0], x.copy()
+        # several pieces a shard and an uneven last one
+        piece_rows = 100
+        table, up, copy, made = self._build(monkeypatch, x, n_devices, piece_rows)
+
+        assert table.n_rows == n and table.n_padded == bucket
+        assert table.data.dtype == np.float32
+        whole = np.asarray(table.data)
+        want = np.zeros((bucket, W), np.float32)
+        want[:n] = before.astype(np.float32)
+        assert whole.tobytes() == want.tobytes()  # the pad rows are zero
+        assert table.to_numpy().tobytes() == before.astype(np.float32).tobytes()
+        mask = np.asarray(table.mask)
+        assert mask.sum() == n and mask[:n].all()
+        assert x.tobytes() == before.tobytes()
+        # the host never held a table: no pass of its own, and for staging
+        # a ring of three buffers a shard, a block each
+        assert copy.attrs["copied_bytes"] == 0
+        assert set(made) == {piece_rows * W * 4} and len(made) <= 3 * n_devices
+        assert sum(made) < x.astype(np.float32).nbytes // 2
+        shard_rows = bucket // n_devices
+        assert up.attrs == {
+            "bytes": n * W * 4 + bucket * 4,  # the valid rows, and the mask
+            "shards": n_devices,
+            "pieces": -(-min(n, shard_rows) // piece_rows),
+            "valid_rows": n, "padded_rows": bucket,
+            "cast_bytes": n * W * 4,
+            "cast_wait_s": up.attrs["cast_wait_s"],
+            "cast_threads": table_mod._cast_threads(),
+        }
+        assert 0 < up.attrs["cast_wait_s"] <= up.duration_s
+
+    @pytest.mark.parametrize("n_devices", [1, 4])
+    def test_as_is_input_reports_as_before(self, rng, monkeypatch, n_devices):
+        """dtype, layout and bucket match: the caller's array itself, in
+        views, and nothing cast."""
+        bucket = n_devices * table_mod._ROW_MULTIPLE * 2
+        x = (rng.normal(size=(bucket, W)) * 100).astype(np.float32)
+        table, up, copy, made = self._build(monkeypatch, x, n_devices, 100)
+        assert np.asarray(table.data).tobytes() == x.tobytes()
+        assert copy.attrs["copied_bytes"] == 0 and not made
+        assert up.attrs == {
+            "bytes": x.nbytes + bucket * 4, "shards": n_devices,
+            "pieces": -(-bucket // n_devices // 100),
+            "valid_rows": bucket, "padded_rows": bucket,
+            "cast_bytes": 0, "cast_wait_s": 0, "cast_threads": 0,
+        }
+
+    @pytest.mark.parametrize("n_devices", [1, 4])
+    def test_a_shard_that_may_all_be_in_flight_is_staged_whole(
+        self, rng, monkeypatch, n_devices
+    ):
+        """No device program's shape follows the valid rows there, so
+        sizes that share a bucket share every program: ONE pass of its
+        own into the padded table, as before."""
+        bucket = n_devices * table_mod._ROW_MULTIPLE * 2
+        x = _callers_array(rng, "f64_off_bucket", bucket)
+        table, up, copy, made = self._build(
+            monkeypatch, x, n_devices, bucket // n_devices
+        )
+        assert made == [bucket * W * 4] == [copy.attrs["copied_bytes"]]
+        assert up.attrs["bytes"] == bucket * W * 4 + bucket * 4
+        assert up.attrs["pieces"] == 1 and up.attrs["cast_bytes"] == 0
+        assert up.attrs["valid_rows"] == x.shape[0]
+        assert table.to_numpy().tobytes() == x.astype(np.float32).tobytes()
+
+    def test_pad_past_whole_shards_is_made_on_the_device(self, rng, monkeypatch):
+        """Valid rows that end inside the second of four shards: the third
+        and fourth are zeros that never crossed the link."""
+        bucket = 4 * table_mod._ROW_MULTIPLE * 4
+        x = rng.normal(size=(bucket // 2 + 300, W))  # on the 4096 bucket
+        assert table_mod._padded_row_target(x.shape[0], 1024) == bucket
+        puts = []
+        put = table_mod.jax.device_put
+        monkeypatch.setattr(
+            table_mod.jax, "device_put",
+            lambda v, where: (puts.append(np.shape(v)), put(v, where))[1],
+        )
+        table, up, _, _ = self._build(monkeypatch, x, 4, 400)
+        monkeypatch.setattr(table_mod.jax, "device_put", put)
+        assert sum(s[0] for s in puts if len(s) == 2) == x.shape[0]
+        assert np.asarray(table.data)[: x.shape[0]].tobytes() == (
+            x.astype(np.float32).tobytes()
+        )
+        assert not np.asarray(table.data)[x.shape[0]:].any()
+        assert np.asarray(table.mask).sum() == x.shape[0]
+
+    def test_a_model_axis_casts_a_shard_once(self, rng, monkeypatch):
+        bucket = 2 * table_mod._ROW_MULTIPLE * 2
+        x = _callers_array(rng, "f64_off_bucket", bucket)
+        monkeypatch.setattr(table_mod, "_UPLOAD_PIECE_BYTES", 100 * W * 4)
+        monkeypatch.setattr(table_mod, "_CAST_BLOCK_BYTES", 100 * W * 4)
+        set_config(model_parallel=2)
+        try:
+            timings = Timings("test.fit")
+            with phase_timer(timings, "table_convert"):
+                table = DenseTable.from_numpy(
+                    x, get_mesh(n_devices=4), np.float32
+                )
+        finally:
+            set_config(model_parallel=1)
+        up = timings.root.node("table_convert/upload")
+        assert up.attrs["cast_bytes"] == x.shape[0] * W * 4
+        assert up.attrs["shards"] == 2
+        assert table.to_numpy().tobytes() == x.astype(np.float32).tobytes()
+        assert len(table.data.addressable_shards) == 4
+
+    @pytest.mark.parametrize("n_devices", [1, 4])
+    def test_nothing_but_table_and_mask_outlives_the_upload(
+        self, rng, monkeypatch, n_devices
+    ):
+        import gc
+
+        x = _callers_array(
+            rng, "f64_off_bucket", n_devices * table_mod._ROW_MULTIPLE * 2
+        )
+        gc.collect()
+        before = {id(a) for a in jax.live_arrays()}
+        table, _, _, _ = self._build(monkeypatch, x, n_devices, 100)
+        gc.collect()
+        new = [a for a in jax.live_arrays() if id(a) not in before]
+        assert {id(a) for a in new} == {id(table.data), id(table.mask)}

@@ -555,3 +555,84 @@ class TestOneChipUpload:
         held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
         assert held <= table + piece + 2**20
+
+
+class TestListedScaleOnOneChip:
+    """``kmeans_d256_k1000_f64rows``: the listed 3,125,000 rows a chip pad
+    to the 2^22-row bucket, twice the rows of every other K-Means cell.
+    Every program of the fit that holds the table, at 4,194,304 x 256,
+    k=1000, for one described v5e, with what ``memory_analysis`` says it
+    holds under what the compiler lets one program have."""
+
+    ROWS, VALID, D, K = 4194304, 3125000, 256, 1000
+    HBM = 15.75 * 2**30
+    TABLE = ROWS * D * 4
+
+    @staticmethod
+    def _held(compiled):
+        mem = compiled.memory_analysis()
+        return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+
+    def test_pll_round(self, one_chip):
+        from oap_mllib_tpu.ops import kmeans_ops
+
+        cap = 4 * self.K
+        chunk = kmeans_ops._slot_chunk_size(cap)
+        compiled = kmeans_ops._pll_round.lower(
+            _s((self.ROWS, self.D), one_chip),
+            _s((self.ROWS,), one_chip),
+            _s((self.ROWS,), one_chip),
+            jax.ShapeDtypeStruct((self.ROWS,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+            _s((), one_chip),
+            cap=cap, chunk=chunk,
+        ).compile()
+        mem = compiled.memory_analysis()
+        # the table and its row vectors; the sheet of one slot chunk
+        # (rows x 500 x 4 = 8.4 GB) is the larger temporary, the
+        # table-sized scatter operand lives before it, not beside it
+        assert self.TABLE <= mem.argument_size_in_bytes < 1.02 * self.TABLE
+        sheet = self.ROWS * chunk * 4
+        assert sheet <= mem.temp_size_in_bytes < 1.05 * sheet
+        assert self._held(compiled) < self.HBM
+
+    def test_lloyd_program(self, one_chip, monkeypatch):
+        from oap_mllib_tpu.ops import kmeans_ops
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        fn = kmeans_ops._build_lloyd(
+            None, "data", 1, 20, "highest", "f32", True, TILE, DEPTH,
+            False, 1,
+        )
+        compiled = fn.lower(
+            _s((self.ROWS, self.D), one_chip), _s((self.ROWS,), one_chip),
+            _s((self.K, self.D), one_chip), _s((), one_chip),
+        ).compile()
+        assert "kmeans_accumulate_walk" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        # the table once: no padded copy of it, no (rows, k) sheet
+        assert mem.argument_size_in_bytes < 1.01 * self.TABLE
+        assert mem.temp_size_in_bytes < 4 * MiB
+        assert self._held(compiled) < self.HBM
+
+    # a whole cast block of 64 MiB, and the one the valid rows end in
+    @pytest.mark.parametrize("piece_rows", [65536, 3125000 % 65536])
+    def test_write_piece(self, topo, piece_rows):
+        from oap_mllib_tpu.data import table as table_mod
+        from oap_mllib_tpu.utils import progcache
+
+        assert table_mod._CAST_BLOCK_BYTES == 65536 * self.D * 4
+        dev = SingleDeviceSharding(topo.devices[0])
+        progcache.clear()  # the registry may hold another backend's program
+        compiled = table_mod._write_piece().lower(
+            _s((self.ROWS, self.D), dev), _s((piece_rows, self.D), dev),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=dev),
+        ).compile()
+        assert "input_output_alias={ {}: (0, {}" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == self.TABLE == mem.output_size_in_bytes
+        piece = piece_rows * self.D * 4
+        assert self._held(compiled) <= self.TABLE + 2 * piece + 2**20
+        assert self._held(compiled) < self.HBM
