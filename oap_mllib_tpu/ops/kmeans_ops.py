@@ -389,11 +389,13 @@ def _xla_accum(x, weights, row_chunks, precision, policy):
     return accum
 
 
-def _walk_accum(x_p, w_p, mode, interpret, tile_rows, depth):
+def _walk_accum(x_p, w_p, live, mode, interpret, tile_rows, depth):
     """``accum(centers, prec)`` of :func:`_lloyd_loop` over the fused
     tile walk (ops/pallas/kmeans_kernel) on operands already in its
-    padded layout; the kernel's ``(1, k_pad)`` counts and ``(1, 1)``
-    cost come back as the ``(k_pad,)`` and scalar the loop expects."""
+    padded layout, every pass ending at tile ``live``
+    (``kmeans_kernel.live_tiles``); the kernel's ``(1, k_pad)`` counts
+    and ``(1, 1)`` cost come back as the ``(k_pad,)`` and scalar the
+    loop expects."""
 
     def accum(centers, prec):
         need_cost = prec is not None
@@ -401,7 +403,7 @@ def _walk_accum(x_p, w_p, mode, interpret, tile_rows, depth):
         with jax.named_scope(scope):
             sums, counts, cost = kk._accumulate_walk_any(
                 x_p, w_p, centers, prec or mode, interpret, need_cost,
-                tile_rows, depth,
+                tile_rows, depth, live,
             )
         return sums, counts[0], cost[0, 0]
 
@@ -415,9 +417,14 @@ def _build_lloyd(mesh, dax, shards, max_iter, precision, policy, walk,
     device or on every shard of the data axis.
 
     ``walk``: the fused kernel of ops/pallas/kmeans_kernel (the DMA walk
-    on the TPU or under ``interpret``, its schedule-identical XLA scan
+    on the TPU or under ``interpret``, its schedule-identical XLA loop
     elsewhere) over operands padded to its layout once, before the loop,
-    inside this program; else the chunked XLA accumulate.
+    inside this program; else the chunked XLA accumulate.  The walk's
+    bound — one past the last tile that holds a non-zero weight — is
+    read once too, from the padded weights (each shard its own), and
+    every iteration and the cost pass end there; the program returns it
+    after ``(centers, n_iter, cost, counts)`` as ``int32[shards]`` (0 a
+    shard on the XLA route, which walks no tile).
 
     ``shards == 1``: the program is jitted directly and emits no
     collective.  More: the whole loop runs inside ONE ``shard_map`` over
@@ -449,16 +456,18 @@ def _build_lloyd(mesh, dax, shards, max_iter, precision, policy, walk,
             x_p, w_p, c0 = kk._pad_operands_traced(
                 x, weights, c0, block_rows=tile_rows
             )
+            live = kk.live_tiles(w_p, tile_rows)
             accum = _walk_accum(
-                x_p, w_p, precision, interpret, tile_rows, depth
+                x_p, w_p, live, precision, interpret, tile_rows, depth
             )
         else:
+            live = jnp.int32(0)
             accum = _xla_accum(x, weights, row_chunks, precision, policy)
         centers, n_iter, cost, counts = _lloyd_loop(
             reduced(accum), lambda m: m, c0, max_iter, tol * tol
         )
         # the walk's lane padding comes off (nothing to cut on the XLA route)
-        return centers[:k, :d], n_iter, cost, counts[:k]
+        return centers[:k, :d], n_iter, cost, counts[:k], live.reshape(1)
 
     if shards == 1:
         return jax.jit(program)
@@ -469,7 +478,7 @@ def _build_lloyd(mesh, dax, shards, max_iter, precision, policy, walk,
             program,
             mesh=mesh,
             in_specs=(P(dax, None), P(dax), P(), P()),
-            out_specs=(P(), P(), P(), P()),
+            out_specs=(P(), P(), P(), P(), P(dax)),
             check_vma=False,
         )
     )
@@ -669,7 +678,10 @@ def lloyd_run(
     receives the ``<phase>/compile`` / ``<phase>/execute`` wall split.
     A launch of the walk notes the kernel's bf16 MXU passes a tile on the
     active span (``mxu_passes``: ``{"cross": 6, "sums": 3}`` at
-    ``highest``; kmeans_kernel.MXU_PASSES).  A launch on more than one
+    ``highest``; kmeans_kernel.MXU_PASSES) and the tiles it walked:
+    ``walk_tiles`` a shard's padded rows hold, ``walk_tiles_live`` where
+    the device's bound ended every pass (the fullest shard's; reading it
+    waits for the program).  A launch on more than one
     shard books what it reduced (``oap_collective_ops_total{op="psum"}``,
     the active span's ``shards`` / ``rows_per_shard`` / ``reduce_bytes``)
     once it has returned, which waits for its iteration count.
@@ -710,18 +722,24 @@ def lloyd_run(
     # the walk books a Pallas wrapper dispatch like every kernel entry
     booked = kernel_launch("kmeans.lloyd_loop") if walk else nullcontext()
     with progcache.launch("kmeans.lloyd_run", key, timings, phase), booked:
-        out = fn(x, weights, jnp.asarray(init_centers), tol)
+        *out, live = fn(x, weights, jnp.asarray(init_centers), tol)
     span = spans.current_span()
     if walk and span is not None:
         # what the kernel issues a tile, for the reader of lloyd_roofline
         span.attrs["mxu_passes"] = dict(kk.MXU_PASSES[tier])
+        # a shard's tiles, and where the device's own bound ended its
+        # walk (the fullest shard's): passes x live tiles is its work
+        span.attrs["walk_tiles"] = kk.walk_tiles(
+            x.shape[0] // shards, tile_rows
+        )
+        span.attrs["walk_tiles_live"] = int(np.asarray(live).max())
     if shards > 1:
         k, d = np.shape(init_centers)
         _book_reductions(
             shards, x.shape[0] // shards, k, d, np.dtype(x.dtype).itemsize,
             out[1], walk,
         )
-    return out
+    return tuple(out)
 
 
 def _lloyd_model_sharded_fn(mesh, dax: str, max_: str, max_iter: int,

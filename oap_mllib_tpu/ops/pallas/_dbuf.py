@@ -32,6 +32,8 @@ buffering, 3+ trades VMEM for slack against DMA-latency jitter.
 
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
@@ -102,7 +104,8 @@ def rotation_scratch(depth: int, tile_shapes):
     return shapes
 
 
-def tile_walk(inputs, bufs, sems, tile, num_tiles, depth, body, axes=None):
+def tile_walk(inputs, bufs, sems, tile, num_tiles, depth, body, axes=None,
+              live=None):
     """Drive one double-buffered walk inside a kernel body.
 
     ``inputs`` are HBM (``ANY``) refs, ``bufs``/``sems`` the matching
@@ -113,6 +116,13 @@ def tile_walk(inputs, bufs, sems, tile, num_tiles, depth, body, axes=None):
     ``num_tiles`` the static tile count.
     ``body(t, views)`` receives the tile index and the resident
     ``(tile, ...)`` views; it mutates the kernel's accumulator refs.
+
+    ``live``: a traced int32 scalar ``<= num_tiles`` (read from SMEM by
+    the kernel) bounds the walk to tiles ``[0, live)``: the loop's trip
+    count, the prefetch guard and the warm-up starts all follow it, so
+    every DMA that is started is waited for and ``live == 0`` starts
+    none.  Tiles past it are never read.  ``None`` walks all
+    ``num_tiles`` on static bounds.
 
     The start/wait pair rebuilds the same copy descriptor (the async
     copy contract), keyed by rotation slot ``t % depth``.
@@ -139,14 +149,19 @@ def tile_walk(inputs, bufs, sems, tile, num_tiles, depth, body, axes=None):
         for ref, buf, sem, ax in zip(inputs, bufs, sems, axes):
             _dma(ref, buf, sem, ax, slot, t).wait()
 
+    bound = num_tiles if live is None else live
+
     # warm-up: fill the pipeline with the first depth-1 tiles
     for t in range(min(depth - 1, num_tiles)):
-        _start(jnp.int32(t))
+        if live is None:
+            _start(jnp.int32(t))
+        else:
+            pl.when(t < live)(functools.partial(_start, jnp.int32(t)))
 
     def _step(t, carry):
         nxt = t + depth - 1
 
-        @pl.when(nxt < num_tiles)
+        @pl.when(nxt < bound)
         def _prefetch():
             _start(nxt)
 
@@ -155,4 +170,4 @@ def tile_walk(inputs, bufs, sems, tile, num_tiles, depth, body, axes=None):
         body(t, [buf[slot] for buf in bufs])
         return carry
 
-    lax.fori_loop(0, num_tiles, _step, jnp.int32(0))
+    lax.fori_loop(0, bound, _step, jnp.int32(0))

@@ -36,12 +36,20 @@ sums (:data:`MXU_PASSES`; a pass is ``2 * rows * k * d`` operations):
 One Pallas form: the double-buffered tile walk
 (``_pallas_accumulate_dbuf``: x stays in HBM, each ``(tile_rows, d)`` tile
 streams into a rotating VMEM buffer while the previous tile's update runs)
-on the TPU or under ``interpret``, and its schedule-identical ``lax.scan``
-twin (``_xla_walk``) elsewhere, both over ``_tile_update``.
+on the TPU or under ``interpret``, and its schedule-identical ``fori_loop``
+twin (``_xla_walk``) elsewhere, both over ``_tile_update``.  The walk ends
+at the last tile that holds a row: its trip count is :func:`live_tiles`,
+read on the device from the weights (one past the last tile with a
+non-zero weight), not the static tile count — a tile past it would add
+exact zeros to the sums, the counts and the cost, so the result's bits
+are those of a walk over every tile.
 
 Caller contract (``_pad_operands_traced``): rows padded to the tile size
 with weight 0; k and d padded to lane multiples (128) — dummy centers get
-+inf-like coordinates so no row ever selects them.  The Lloyd loop over
++inf-like coordinates so no row ever selects them.  Rows past the last
+weighted tile are never read (whatever they hold, a NaN included, stays
+out of the result); a zero-weight row inside a live tile is read and
+must be finite.  The Lloyd loop over
 this accumulate, its row sharding and its reductions are
 ops/kmeans_ops.lloyd_run's; this module holds the tile program and the
 single-shot :func:`lloyd_accumulate_walk` (pad + walk + slice in one
@@ -182,12 +190,12 @@ def _tile_update(x, w, c, mode, need_cost):
 
 
 def _make_dbuf_kernel(mode, need_cost, tile_rows, depth, num_tiles):
-    def _kernel(x_hbm, w_hbm, c_ref, sums_ref, counts_ref, cost_ref,
-                xbuf, wbuf, xsem, wsem):
+    def _kernel(live_ref, x_hbm, w_hbm, c_ref, sums_ref, counts_ref,
+                cost_ref, xbuf, wbuf, xsem, wsem):
         """Single-invocation walk: x/w stay in HBM, each (tile_rows, d)
         tile streams into the rotation buffer while the previous tile's
         fused update runs — the accumulators are VMEM-resident for the
-        whole walk."""
+        whole walk, which ends at tile ``live_ref[0]`` (SMEM)."""
         sums_ref[:] = jnp.zeros_like(sums_ref)
         counts_ref[:] = jnp.zeros_like(counts_ref)
         cost_ref[0, 0] = jnp.float32(0.0)
@@ -206,17 +214,20 @@ def _make_dbuf_kernel(mode, need_cost, tile_rows, depth, num_tiles):
         _dbuf.tile_walk(
             [x_hbm, w_hbm], [xbuf, wbuf], [xsem, wsem],
             tile_rows, num_tiles, depth, body, axes=(0, None),
+            live=live_ref[0],
         )
 
     return _kernel
 
 
 def _pallas_accumulate_dbuf(x, w, centers, mode, interpret, need_cost,
-                            tile_rows, depth):
+                            tile_rows, depth, live):
     """Raw double-buffered pallas_call on pre-padded operands (rows a
-    multiple of ``tile_rows``).  The weight column rides lane-dense
-    (``_dbuf.lane_dense``): Mosaic refuses a ``(tile_rows, 1)`` DMA
-    window on an ``(n, 1)`` HBM operand."""
+    multiple of ``tile_rows``), over tiles ``[0, live)``
+    (:func:`live_tiles`; an int32 scalar the kernel reads from SMEM).
+    The weight column rides lane-dense (``_dbuf.lane_dense``): Mosaic
+    refuses a ``(tile_rows, 1)`` DMA window on an ``(n, 1)`` HBM
+    operand."""
     _dbuf.check_tile_rows(tile_rows)
     n, d = x.shape
     k = centers.shape[0]
@@ -224,6 +235,7 @@ def _pallas_accumulate_dbuf(x, w, centers, mode, interpret, need_cost,
     sums, counts, cost = pl.pallas_call(
         _make_dbuf_kernel(mode, need_cost, tile_rows, depth, num_tiles),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -247,53 +259,76 @@ def _pallas_accumulate_dbuf(x, w, centers, mode, interpret, need_cost,
             interpret, vmem_limit_bytes=VMEM_LIMIT_BYTES,
             has_side_effects=True,
         ),
-    )(x, _dbuf.lane_dense(w, tile_rows), centers)
+    )(
+        jnp.asarray(live, jnp.int32).reshape(1), x,
+        _dbuf.lane_dense(w, tile_rows), centers,
+    )
     return sums, counts, cost
 
 
-def _xla_walk(x_p, w_p, c_p, mode, need_cost, tile_rows):
+def _xla_walk(x_p, w_p, c_p, mode, need_cost, tile_rows, live):
     """Schedule-identical XLA fallback for the double-buffered walk: a
-    ``lax.scan`` over the SAME (tile_rows, d) tiles in the SAME order
-    through the SAME ``_tile_update``, so the CPU tier-1 suite exercises
-    the exact program structure (and bits) the DMA kernel produces.  Not
-    a program for the TPU: XLA:TPU keeps excess precision across a
-    convert to bf16 and back, which voids ``_cluster_sums``' exact splits
-    (Mosaic rounds as written; tests_tpu/ compiles the twin with
-    ``xla_allow_excess_precision`` off)."""
+    ``fori_loop`` over the SAME (tile_rows, d) tiles ``[0, live)`` in the
+    SAME order through the SAME ``_tile_update``, so the CPU tier-1 suite
+    exercises the exact program structure (and bits) the DMA kernel
+    produces.  Not a program for the TPU: XLA:TPU keeps excess precision
+    across a convert to bf16 and back, which voids ``_cluster_sums``'
+    exact splits (Mosaic rounds as written; tests_tpu/ compiles the twin
+    with ``xla_allow_excess_precision`` off)."""
     n, d = x_p.shape
     k = c_p.shape[0]
     num_tiles = n // tile_rows
     xt = x_p.reshape(num_tiles, tile_rows, d)
     wt = w_p.reshape(num_tiles, tile_rows, 1)
 
-    def step(carry, tile):
+    def step(t, carry):
         sums, counts, cost = carry
-        xi, wi = tile
         sums_inc, counts_inc, cost_inc = _tile_update(
-            xi, wi, c_p, mode, need_cost
+            jax.lax.dynamic_index_in_dim(xt, t, keepdims=False),
+            jax.lax.dynamic_index_in_dim(wt, t, keepdims=False),
+            c_p, mode, need_cost,
         )
         cost = cost + cost_inc if need_cost else cost
-        return (sums + sums_inc, counts + counts_inc, cost), None
+        return sums + sums_inc, counts + counts_inc, cost
 
     init = (
         jnp.zeros((k, d), jnp.float32),
         jnp.zeros((1, k), jnp.float32),
         jnp.float32(0.0),
     )
-    (sums, counts, cost), _ = jax.lax.scan(step, init, (xt, wt))
+    sums, counts, cost = jax.lax.fori_loop(0, live, step, init)
     return sums, counts, cost.reshape(1, 1)
 
 
+def live_tiles(w_p, tile_rows):
+    """The walk's bound, read from the padded weight column itself: one
+    past the last tile of ``tile_rows`` rows that holds a non-zero
+    weight (int32 scalar; 0 where none does).  Every tile past it would
+    add exact zeros (``w*x``, ``one_hot*w`` and ``min_d2*w`` are all 0
+    there), so a walk that ends at it returns the bits of a walk over
+    every tile.  Computed once a program, outside any loop."""
+    held = jnp.any(w_p.reshape(-1, tile_rows) != 0, axis=1)
+    ends = jnp.arange(1, held.shape[0] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(held, ends, 0))
+
+
 def _accumulate_walk_any(x_p, w_p, c_p, mode, interpret, need_cost,
-                         tile_rows, depth):
-    """Backend dispatch for the walk on pre-padded operands: the DMA
-    kernel on TPU (or under interpret), the schedule-identical XLA scan
-    elsewhere."""
+                         tile_rows, depth, live):
+    """Backend dispatch for the walk over tiles ``[0, live)`` of
+    pre-padded operands: the DMA kernel on TPU (or under interpret), the
+    schedule-identical XLA loop elsewhere."""
     if interpret or jax.default_backend() == "tpu":
         return _pallas_accumulate_dbuf(
-            x_p, w_p, c_p, mode, interpret, need_cost, tile_rows, depth
+            x_p, w_p, c_p, mode, interpret, need_cost, tile_rows, depth,
+            live,
         )
-    return _xla_walk(x_p, w_p, c_p, mode, need_cost, tile_rows)
+    return _xla_walk(x_p, w_p, c_p, mode, need_cost, tile_rows, live)
+
+
+def walk_tiles(n: int, tile_rows: int) -> int:
+    """Tiles the walk's padded layout holds for ``n`` rows (at least
+    one)."""
+    return pad_to(max(n, tile_rows), tile_rows) // tile_rows
 
 
 def _pad_operands_traced(x, weights, centers, block_rows=_BLOCK_ROWS):
@@ -304,7 +339,7 @@ def _pad_operands_traced(x, weights, centers, block_rows=_BLOCK_ROWS):
     columns of real centers are 0 (matching padded x columns)."""
     n, d = x.shape
     k = centers.shape[0]
-    n_pad = pad_to(max(n, block_rows), block_rows)
+    n_pad = walk_tiles(n, block_rows) * block_rows
     d_pad = pad_to(d, LANE)
     k_pad = pad_to(k, LANE)
     x_p = jnp.zeros((n_pad, d_pad), jnp.float32).at[:n, :d].set(x.astype(jnp.float32))
@@ -327,7 +362,8 @@ def _walk_jit(x, weights, centers, mode, interpret, need_cost, tile_rows,
         x, weights, centers, block_rows=tile_rows
     )
     sums, counts, cost = _accumulate_walk_any(
-        x_p, w_p, c_p, mode, interpret, need_cost, tile_rows, depth
+        x_p, w_p, c_p, mode, interpret, need_cost, tile_rows, depth,
+        live_tiles(w_p, tile_rows),
     )
     return sums[:k, :d], counts[0, :k], cost[0, 0]
 

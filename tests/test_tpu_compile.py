@@ -82,17 +82,16 @@ def _s(shape, sharding):
     return jax.ShapeDtypeStruct(shape, F32, sharding=sharding)
 
 
-def _mosaic_matmuls(compiled_text, kernel):
-    """The MXU products of every ``kernel`` custom call in a compiled
-    program, as ``(fp32 contract precision?, lhs type, result type)``:
-    the call's Mosaic body rides in its backend_config as MLIR bytecode,
-    which parses without the chip."""
+def _mosaic_bodies(compiled_text, kernel):
+    """The Mosaic body of every ``kernel`` custom call in a compiled
+    program, as MLIR text: it rides in the call's backend_config as
+    bytecode, which parses without the chip."""
     import base64
     import re
 
     from jax._src.lib.mlir import ir
 
-    calls = []
+    bodies = []
     for line in compiled_text.splitlines():
         if f"%{kernel}" not in line or "custom-call(" not in line:
             continue
@@ -101,8 +100,41 @@ def _mosaic_matmuls(compiled_text, kernel):
         )
         ctx = ir.Context()
         ctx.allow_unregistered_dialects = True
+        bodies.append(str(ir.Module.parse(body, ctx)))
+    return bodies
+
+
+def _assert_walks_to_a_bound_read_on_the_device(compiled_text, calls):
+    """Every ``kmeans_accumulate_walk`` call of the program takes its trip
+    count from SMEM: ONE loop whose upper bound is the loaded scalar (no
+    constant trip count), and DMA starts before it only under a guard."""
+    import re
+
+    bodies = _mosaic_bodies(compiled_text, "kmeans_accumulate_walk")
+    assert len(bodies) == calls
+    for body in bodies:
+        assert re.search(
+            r'"stable_mosaic\.memref\.load"\(%arg0, [^)]*\) : '
+            r"\(memref<1xi32, #tpu\.memory_space<smem>>", body
+        ), "the walk reads no bound from SMEM"
+        loops = re.findall(r'"stable_mosaic\.scf\.for"\(%\d+, (%\d+), ', body)
+        assert len(loops) == 1
+        # its upper bound is computed, not a constant trip count
+        assert f'{loops[0]} = "stable_mosaic.arith.constant"' not in body
+        head = body[:body.index('"stable_mosaic.scf.for"')]
+        assert head.count("tpu.enqueue_dma") == 2  # depth 2: x and w of tile 0
+        assert head.index("scf.if") < head.index("tpu.enqueue_dma")
+
+
+def _mosaic_matmuls(compiled_text, kernel):
+    """The MXU products of every ``kernel`` custom call in a compiled
+    program, as ``(fp32 contract precision?, lhs type, result type)``."""
+    import re
+
+    calls = []
+    for body in _mosaic_bodies(compiled_text, kernel):
         found = []
-        for op in str(ir.Module.parse(body, ctx)).splitlines():
+        for op in body.splitlines():
             if "tpu.matmul" not in op:
                 continue
             lhs, out = re.search(
@@ -114,8 +146,13 @@ def _mosaic_matmuls(compiled_text, kernel):
 
 
 def _kmeans_shapes(sharding, n=N_KMEANS, k=1024, d=256):
-    """(x, weight column, centres) of one padded K-Means launch."""
-    return _s((n, d), sharding), _s((n, 1), sharding), _s((k, d), sharding)
+    """(x, weight column, centres, live tiles) of one padded K-Means
+    launch: the walk's bound is an argument, so what compiles is the
+    dynamic trip count a fit runs (the kernel reads it from SMEM)."""
+    return (
+        _s((n, d), sharding), _s((n, 1), sharding), _s((k, d), sharding),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding),
+    )
 
 
 class TestKMeansKernels:
@@ -127,8 +164,8 @@ class TestKMeansKernels:
         from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
 
         _compile(
-            lambda x, w, c: kk._pallas_accumulate_dbuf(
-                x, w, c, mode, False, False, TILE, DEPTH),
+            lambda x, w, c, live: kk._pallas_accumulate_dbuf(
+                x, w, c, mode, False, False, TILE, DEPTH, live),
             *_kmeans_shapes(one_chip),
         )
 
@@ -136,8 +173,8 @@ class TestKMeansKernels:
         from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
 
         _compile(
-            lambda x, w, c: kk._pallas_accumulate_dbuf(
-                x, w, c, "highest", False, True, TILE, DEPTH),
+            lambda x, w, c, live: kk._pallas_accumulate_dbuf(
+                x, w, c, "highest", False, True, TILE, DEPTH, live),
             *_kmeans_shapes(one_chip),
         )
 
@@ -155,8 +192,8 @@ class TestKMeansKernels:
 
         monkeypatch.setattr(kk, "VMEM_LIMIT_BYTES", int(cap))
         _compile(
-            lambda x, w, c: kk._pallas_accumulate_dbuf(
-                x, w, c, "highest", False, need_cost, TILE, DEPTH),
+            lambda x, w, c, live: kk._pallas_accumulate_dbuf(
+                x, w, c, "highest", False, need_cost, TILE, DEPTH, live),
             *_kmeans_shapes(one_chip, n=2097152),
         )
 
@@ -180,6 +217,8 @@ class TestKMeansKernels:
         text = compiled.as_text()
         assert "kmeans_accumulate_walk" in text
         assert "all-reduce" not in text
+        # the loop's walk and the cost pass both end at the live tile
+        _assert_walks_to_a_bound_read_on_the_device(text, 2)
         mem = compiled.memory_analysis()
         # the table alone: no padded copy of it (2.1 GB), no (rows, k)
         # sheet (8.4 GB), only the centres' and moments' blocks
@@ -212,8 +251,8 @@ class TestKMeansKernels:
         assert not kmeans_ops.pallas_preferred(d, 2 * k, "high")
         assert not kmeans_ops.pallas_preferred(2 * d, k, "high")
         _compile(
-            lambda x, w, c: kk._pallas_accumulate_dbuf(
-                x, w, c, mode, False, False, TILE, DEPTH),
+            lambda x, w, c, live: kk._pallas_accumulate_dbuf(
+                x, w, c, mode, False, False, TILE, DEPTH, live),
             *_kmeans_shapes(one_chip, n=1 << 16, k=k, d=d),
         )
 
@@ -418,6 +457,8 @@ class TestDataParallelKMeans:
         assert "kmeans_accumulate_walk" in text
         assert "all-reduce" in text  # the moments
         assert "all-gather" not in text  # no device ever sees the table
+        # each device walks to the bound it read from its own shard
+        _assert_walks_to_a_bound_read_on_the_device(text, 2)
         mem = compiled.memory_analysis()
         # a device holds its shard and the walk's padded copy of it,
         # not a (rows, k) sheet
@@ -611,6 +652,7 @@ class TestListedScaleOnOneChip:
             _s((self.K, self.D), one_chip), _s((), one_chip),
         ).compile()
         assert "kmeans_accumulate_walk" in compiled.as_text()
+        _assert_walks_to_a_bound_read_on_the_device(compiled.as_text(), 2)
         mem = compiled.memory_analysis()
         # the table once: no padded copy of it, no (rows, k) sheet
         assert mem.argument_size_in_bytes < 1.01 * self.TABLE

@@ -108,12 +108,13 @@ class TestWalkKernelsCompiled:
         # and lo parts of the sums' exact split zero (the twin then sums
         # bf16(w*x), off by 4e-4 of the sums); Mosaic rounds as written.
         # The twin is the CPU's program; here it is held to what it says.
-        twin = jax.jit(
-            lambda x, w, c: kk._xla_walk(
-                *kk._pad_operands_traced(x, w, c, block_rows=512),
-                "highest", True, 512,
+        def twin_of(x, w, c):
+            padded = kk._pad_operands_traced(x, w, c, block_rows=512)
+            return kk._xla_walk(
+                *padded, "highest", True, 512, kk.live_tiles(padded[1], 512)
             )
-        ).lower(x, w, c).compile(
+
+        twin = jax.jit(twin_of).lower(x, w, c).compile(
             compiler_options={"xla_allow_excess_precision": False}
         )(x, w, c)
         refs = [

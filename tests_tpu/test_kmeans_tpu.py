@@ -141,6 +141,76 @@ class TestXlaPrecisionTiers:
             set_config(kmeans_kernel="auto", matmul_precision="highest")
 
 
+class TestTheWalkEndsAtTheLastRow:
+    """The Lloyd walk's trip count is read on the device from the weights
+    (``kmeans_kernel.live_tiles``): a fit whose table does not fill its
+    x2 bucket walks only the tiles that hold a row.  Every tile past the
+    bound would add exact zeros, so centres, counts, cost and
+    ``num_iter`` must equal a walk over EVERY tile bit for bit — the
+    parent's program, rebuilt here by pinning the bound to the tile
+    count.  The cell's table (3,125,000 float64 rows on the 4,194,304
+    bucket: 6104 of 8192 tiles) and an on-bucket one (every tile live)."""
+
+    @pytest.mark.parametrize("rows,dtype,tiles", [
+        (3_125_000, np.float64, (6104, 8192)),
+        (1_048_576, np.float32, (2048, 2048)),
+    ], ids=["f64rows_off_bucket", "f32_on_bucket"])
+    def test_fit_equals_the_full_walk_bit_for_bit(self, monkeypatch, rows,
+                                                  dtype, tiles):
+        import hashlib
+
+        from oap_mllib_tpu.models.kmeans import KMeans
+        from oap_mllib_tpu.ops.pallas import kmeans_kernel as kk
+        from oap_mllib_tpu.utils import progcache
+
+        d, k = 256, 1000
+        rng = np.random.default_rng(rows)
+        proto = 4.0 * rng.standard_normal((k, d), dtype=np.float32)
+        x = np.empty((rows, d), dtype)
+        for lo in range(0, rows, 1 << 18):  # in pieces: no second table
+            hi = min(lo + (1 << 18), rows)
+            x[lo:hi] = proto[rng.integers(k, size=hi - lo)]
+            x[lo:hi] += 0.3 * rng.standard_normal((hi - lo, d), dtype=np.float32)
+
+        def fit():
+            m = KMeans(k=k, max_iter=6, tol=0.0, seed=11,
+                       init_mode="random").fit(x)
+            assert m.summary.kernel == "pallas"
+            loop = m.summary.timings.root.node("lloyd_loop")
+            return m, (loop.attrs["walk_tiles_live"], loop.attrs["walk_tiles"])
+
+        bounded, walked = fit()
+        assert walked == tiles
+        # the parent's walk: every tile, whatever the weights say
+        monkeypatch.setattr(
+            kk, "live_tiles",
+            lambda w_p, tile_rows: jnp.int32(w_p.shape[0] // tile_rows),
+        )
+        progcache.clear()  # the registry holds the bounded program
+        whole, walked_whole = fit()
+        assert walked_whole == (tiles[1], tiles[1])
+        digests = []
+        for m in (bounded, whole):
+            s = m.summary
+            parts = (
+                np.asarray(m.cluster_centers_), np.asarray(s.cluster_sizes),
+                np.float64(s.training_cost), np.int64(s.num_iter),
+            )
+            digests.append(hashlib.sha256(
+                b"".join(np.ascontiguousarray(p).tobytes() for p in parts)
+            ).hexdigest())
+        print(
+            f"walk bound {rows}x{d} {np.dtype(dtype).name}: tiles {walked} "
+            f"vs {walked_whole}, num_iter {bounded.summary.num_iter}, cost "
+            f"{bounded.summary.training_cost!r} vs "
+            f"{whole.summary.training_cost!r}, sha256 {digests[0][:16]} vs "
+            f"{digests[1][:16]}"
+        )
+        assert bounded.summary.num_iter == whole.summary.num_iter == 6
+        assert float(np.sum(bounded.summary.cluster_sizes)) == rows
+        assert digests[0] == digests[1]
+
+
 class TestAssignmentIsF32:
     """At ``highest`` the XLA assignment names the f32 nearest centre.
     ``jnp.argmin`` did not on this compiler (its reduction's value output
