@@ -33,7 +33,6 @@ from oap_mllib_tpu.parallel.mesh import data_sharding
 from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import progcache
 from oap_mllib_tpu.utils.jax_compat import shard_map
-from oap_mllib_tpu.utils.timing import tick
 
 # rows are padded per shard to this multiple (cheap: padding is masked)
 _ROW_MULTIPLE = 256
@@ -99,6 +98,25 @@ _UPLOAD_PIECE_BYTES = 1 << 30
 _ONE_DEVICE_PIECES_IN_FLIGHT = 4
 
 
+def _put(host, to, send=None):
+    """``jax.device_put(host, to)`` (or ``send`` in its place) as one
+    entry of the upload's ``put`` leaf: the host seconds INSIDE the
+    call, which returns before the bytes land, with what it was handed
+    added to the leaf's ``attrs["bytes"]``."""
+    with spans.child(spans.PUT) as span:
+        out = (send or jax.device_put)(host, to)
+        span.attrs["bytes"] = span.attrs.get("bytes", 0) + host.nbytes
+    return out
+
+
+def _land(arrays):
+    """``jax.block_until_ready(arrays)`` as one entry of the upload's
+    ``land`` leaf: the host seconds BLOCKED until bytes put earlier had
+    landed, or an in-place write of them had finished."""
+    with spans.child(spans.LAND):
+        return jax.block_until_ready(arrays)
+
+
 def _join_pieces(sharding):
     """The program that makes every device's row shard of the pieces it
     holds (one ``concatenate`` a device, no traffic), kept in the program
@@ -151,16 +169,18 @@ def _put_in_place(host: np.ndarray, sharding):
     def write_oldest(table):
         piece, lo = flying.popleft()
         if table is None:
-            table = jnp.zeros(host.shape, piece.dtype, device=sharding)
-        return write(table, piece, np.int32(lo))
+            table = spans.launch(
+                jnp.zeros, host.shape, piece.dtype, device=sharding
+            )
+        return spans.launch(write, table, piece, np.int32(lo))
 
     for lo in range(0, host.shape[0], step):
-        flying.append((jax.device_put(host[lo:lo + step], sharding), lo))
+        flying.append((_put(host[lo:lo + step], sharding), lo))
         if len(flying) == _ONE_DEVICE_PIECES_IN_FLIGHT:
             table = write_oldest(table)
             # the oldest piece has landed, is written and is gone: room
             # for the next one, which goes while the others are in flight
-            jax.block_until_ready(table)
+            _land(table)
     while flying:
         table = write_oldest(table)
     return table, -(-host.shape[0] // step)
@@ -211,9 +231,10 @@ class _RowBlocks:
     device.
 
     ``shape`` is the padded table's, ``nbytes`` what crosses the link;
-    after ``put``: ``cast_bytes`` (what the block casts wrote) and
-    ``cast_wait_s`` (seconds the sender stood waiting for block casts,
-    sending nothing; the blocks in flight go on landing meanwhile)."""
+    after ``put``: ``cast_bytes`` (what the block casts wrote).  The
+    seconds the sender stood waiting for block casts, sending nothing
+    (the blocks in flight go on landing meanwhile), are the upload's
+    ``cast`` leaf."""
 
     def __init__(self, x: np.ndarray, padded_rows: int, dtype):
         self.x = x
@@ -222,21 +243,19 @@ class _RowBlocks:
         self.row_bytes = x.shape[1] * self.dtype.itemsize
         self.nbytes = x.shape[0] * self.row_bytes
         self.cast_bytes = 0
-        self.cast_wait_s = 0.0
         self.cast_threads = _cast_threads()
 
     def _cast(self, pool, buf, lo):
         """``buf[:] = x[lo:lo + len(buf)]``, the rows shared out among the
         pool's threads."""
         cuts = np.linspace(0, buf.shape[0], self.cast_threads + 1).astype(int)
-        waited = tick()
-        casts = [
-            pool.submit(np.copyto, buf[a:b], self.x[lo + a:lo + b], "unsafe")
-            for a, b in zip(cuts[:-1], cuts[1:]) if b > a
-        ]
-        for cast in casts:
-            cast.result()
-        self.cast_wait_s += waited()
+        with spans.child(spans.CAST):
+            casts = [
+                pool.submit(np.copyto, buf[a:b], self.x[lo + a:lo + b], "unsafe")
+                for a, b in zip(cuts[:-1], cuts[1:]) if b > a
+            ]
+            for cast in casts:
+                cast.result()
         self.cast_bytes += buf.nbytes
 
     def put(self, sharding):
@@ -263,14 +282,16 @@ class _RowBlocks:
         ]
         write = _write_piece()
         table = {
-            dev: jnp.zeros((shard_rows, d), self.dtype, device=dev)
+            dev: spans.launch(
+                jnp.zeros, (shard_rows, d), self.dtype, device=dev
+            )
             for dev in index
         }
         flying = collections.deque()  # (device, piece, row in its shard)
 
         def write_oldest():
             dev, piece, at = flying.popleft()
-            table[dev] = write(table[dev], piece, np.int32(at))
+            table[dev] = spans.launch(write, table[dev], piece, np.int32(at))
             return table[dev]
 
         with concurrent.futures.ThreadPoolExecutor(self.cast_threads) as pool:
@@ -278,13 +299,13 @@ class _RowBlocks:
                 if i >= slots:
                     # the block that had this staging buffer has landed
                     # and is written: the buffer is free to be cast into
-                    jax.block_until_ready(
+                    _land(
                         [write_oldest() for _ in shards[blocks[i - slots][1]]]
                     )
                 buf = ring[i % slots][:height]
                 self._cast(pool, buf, start + at * step)
                 for dev in shards[start]:
-                    flying.append((dev, jax.device_put(buf, dev), at * step))
+                    flying.append((dev, _put(buf, dev), at * step))
         while flying:
             write_oldest()
         return (
@@ -312,23 +333,26 @@ def _put_rows(host: np.ndarray, sharding):
         return host.put(sharding)
     index = sharding.addressable_devices_indices_map(host.shape)
     if jax.process_count() > 1:
-        return jax.device_put(host, sharding), 1
+        return _put(host, sharding), 1
     if len(index) == 1:
         if host.nbytes <= _UPLOAD_PIECE_BYTES:
-            return jax.device_put(host, sharding), 1
+            return _put(host, sharding), 1
         return _put_in_place(host, sharding)
     slices = [(dev, host[idx]) for dev, idx in index.items()]
     shard_rows = slices[0][1].shape[0]
     step = max(1, _UPLOAD_PIECE_BYTES * host.shape[0] // max(host.nbytes, 1))
     pieces = []  # one global array a wave: that piece of every shard
     for lo in range(0, shard_rows, step):
-        parts = [jax.device_put(rows[lo:lo + step], dev) for dev, rows in slices]
-        jax.block_until_ready(parts)
+        parts = [_put(rows[lo:lo + step], dev) for dev, rows in slices]
+        _land(parts)
         shape = (host.shape[0] // shard_rows * parts[0].shape[0], *host.shape[1:])
         pieces.append(
             jax.make_array_from_single_device_arrays(shape, sharding, parts)
         )
-    table = pieces[0] if len(pieces) == 1 else _join_pieces(sharding)(pieces)
+    table = (
+        pieces[0] if len(pieces) == 1
+        else spans.launch(_join_pieces(sharding), pieces)
+    )
     return table, len(pieces)
 
 
@@ -344,18 +368,31 @@ def _upload(put, padded, mask: np.ndarray, mesh, n_valid: int):
     are the caller's.  Where the table was cast block by block under the
     transfers (``padded`` a ``_RowBlocks``): ``attrs["cast_bytes"]`` the
     casts wrote, by ``attrs["cast_threads"]`` threads, the sender
-    waiting ``attrs["cast_wait_s"]`` for them; all 0 elsewhere."""
+    waiting ``attrs["cast_wait_s"]`` for them; all 0 elsewhere.
+
+    What the host thread did with the span's seconds is in its leaves
+    (telemetry/spans.py): ``put`` inside the ``device_put`` calls,
+    ``land`` blocked until bytes had landed or an in-place write had
+    finished, ``cast`` blocked on a block's cast threads —
+    ``attrs["cast_wait_s"]`` is that leaf's seconds, one clock reading
+    in two views —, ``launch`` inside the calls that start ``jnp.zeros``,
+    an in-place write or the join (a write returns after 2-6 ms while
+    pieces are in flight, 0.3 ms otherwise).  What is left is the span's
+    self time: Python."""
     with spans.child("upload") as span:
         data, pieces = put(padded, data_sharding(mesh, 2))
         mask_dev, _ = put(mask, data_sharding(mesh, 1))
-        jax.block_until_ready((data, mask_dev))
+        _land((data, mask_dev))
         span.attrs["bytes"] = padded.nbytes + mask.nbytes
         span.attrs["shards"] = mesh.shape[mesh.axis_names[0]]
         span.attrs["pieces"] = pieces
         span.attrs["valid_rows"] = n_valid
         span.attrs["padded_rows"] = padded.shape[0]
-        for name in ("cast_bytes", "cast_wait_s", "cast_threads"):
+        for name in ("cast_bytes", "cast_threads"):
             span.attrs[name] = getattr(padded, name, 0)
+        span.attrs["cast_wait_s"] = sum(
+            c.duration_s for c in span.children if c.name == spans.CAST
+        )
     return data, mask_dev
 
 
@@ -533,7 +570,10 @@ class DenseTable:
             mask_local[:n_valid_local] = 1.0
         data, mask = _upload(
             lambda host, sharding: (
-                jax.make_array_from_process_local_data(sharding, host), 1
+                _put(
+                    host, sharding,
+                    lambda h, s: jax.make_array_from_process_local_data(s, h),
+                ), 1
             ),
             padded, mask_local, mesh, n_valid_local,
         )

@@ -30,6 +30,7 @@ from oap_mllib_tpu.fallback.kmeans_np import lloyd_np, predict_np
 from oap_mllib_tpu.ops import kmeans_ops
 from oap_mllib_tpu.ops.pallas import autotune
 from oap_mllib_tpu.parallel.mesh import get_mesh
+from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import checkpoint as ckpt_mod
 from oap_mllib_tpu.utils import precision as psn
 from oap_mllib_tpu.utils import progcache
@@ -659,12 +660,15 @@ class KMeans:
                 degraded=degraded, pol=pol, ckpt=ckpt, resume=resume,
                 d_orig=d_orig,
             )
+            # the model's arrays come back inside the phase's fetch leaf
+            centers, n_iter, cost, counts = spans.fetch(
+                jax.device_get, (centers, n_iter, cost, counts)
+            )
             centers = np.asarray(centers)[:, :d_orig]
             n_iter = int(n_iter)
             cost = float(cost)
         summary = KMeansSummary(
-            cost, n_iter, timings, accelerated=True,
-            cluster_sizes=np.asarray(counts),
+            cost, n_iter, timings, accelerated=True, cluster_sizes=counts,
         )
         summary.progcache = progcache.delta(cache_before)
         summary.tuning = autotune.delta(tune_before)

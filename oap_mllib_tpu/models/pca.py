@@ -18,6 +18,7 @@ import json
 import os
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -190,9 +191,11 @@ class PCA:
                 # genuine one so ties at zero can't surface a padded
                 # basis vector in the top-k
                 cov = pca_ops.mark_padded_features(cov, d)
-            vals, vecs = pca_ops.eigh_descending(cov)
-            vals = np.asarray(vals)[:d]  # genuine spectrum only
-            vecs = np.asarray(vecs)[:d, : self.k]
+            vals, vecs = spans.fetch(
+                jax.device_get, pca_ops.eigh_descending(cov)
+            )
+            vals = vals[:d]  # genuine spectrum only
+            vecs = vecs[:d, : self.k]
         return vals[: self.k], vecs, float(vals.sum()), "eigh"
 
     def fit(self, x) -> PCAModel:
@@ -482,8 +485,7 @@ class PCA:
                     )
                 # the phase ends on a READY covariance: without the wait
                 # the Gram's device time is booked to eigh
-                # oaplint: disable=stream-host-sync -- eigh waits for cov anyway
-                jax.block_until_ready(cov)
+                spans.fetch(jax.block_until_ready, cov)
                 span = spans.current_span()
                 span.attrs["kernel"] = kernel
                 span.attrs["rows"] = table.n_padded

@@ -614,7 +614,7 @@ def _book_reductions(shards, rows_per_shard, k, d, itemsize, n_iter, walk):
     has returned: the program runs its iterations on the device and only
     then says how many there were.  A checkpointed fit launches several
     segments; the ``lloyd_loop`` span sums their bytes."""
-    n_iter = int(n_iter)
+    n_iter = int(spans.fetch(np.asarray, n_iter))
     nbytes = lloyd_reduce_bytes(k, d, itemsize, n_iter, walk)
     collective.note_in_program(
         "psum",
@@ -681,7 +681,8 @@ def lloyd_run(
     ``highest``; kmeans_kernel.MXU_PASSES) and the tiles it walked:
     ``walk_tiles`` a shard's padded rows hold, ``walk_tiles_live`` where
     the device's bound ended every pass (the fullest shard's; reading it
-    waits for the program).  A launch on more than one
+    waits for the program, inside the span's ``fetch`` leaf, as every
+    blocking read of the phase is).  A launch on more than one
     shard books what it reduced (``oap_collective_ops_total{op="psum"}``,
     the active span's ``shards`` / ``rows_per_shard`` / ``reduce_bytes``)
     once it has returned, which waits for its iteration count.
@@ -732,7 +733,9 @@ def lloyd_run(
         span.attrs["walk_tiles"] = kk.walk_tiles(
             x.shape[0] // shards, tile_rows
         )
-        span.attrs["walk_tiles_live"] = int(np.asarray(live).max())
+        span.attrs["walk_tiles_live"] = int(
+            spans.fetch(np.asarray, live).max()
+        )
     if shards > 1:
         k, d = np.shape(init_centers)
         _book_reductions(
@@ -1200,8 +1203,12 @@ def reduce_candidates(slots, weights, valid, key, k: int,
                       mesh=None) -> np.ndarray:
     """Reduce the k-means|| candidates to k centres on the device and
     fetch the (k, d) result: the one call both accelerated routes make."""
-    return np.asarray(
-        _reduce_candidates_fn(k, mesh)(slots, weights, valid, key)
+    # from the launch until the centres are on the host: the host has
+    # nothing else to do meanwhile
+    return spans.fetch(
+        lambda: np.asarray(
+            _reduce_candidates_fn(k, mesh)(slots, weights, valid, key)
+        )
     )
 
 
@@ -1247,12 +1254,15 @@ def init_kmeans_parallel(
 
     # everything that waits on the device: the seed-row gather, the
     # rounds with their phi and validity fetches, the candidate weights
+    # (each blocking read an entry of the span's ``fetch`` leaf, each
+    # call that hands the device a program one of its ``launch`` leaf:
+    # what is left of the span is the host's Python between them)
     with spans.child("rounds") as span:
         # first center: uniform valid row (index_map: valid -> padded layout)
         first = np.asarray([rng.integers(n_valid)])
         if index_map is not None:
             first = np.asarray(index_map(first))
-        c0 = _gather_rows(x_dev, first)  # (1, d)
+        c0 = spans.fetch(_gather_rows, x_dev, first)  # (1, d)
 
         l = jnp.asarray(2.0 * k, jnp.float32)  # Spark's oversampling factor
         cap = 4 * k  # per-round slot buffer
@@ -1260,23 +1270,25 @@ def init_kmeans_parallel(
         key = jax.random.PRNGKey(seed)
 
         # running state: distances/assignments vs candidate 0
-        d2_0 = pairwise_sq_dists(x_dev, jnp.asarray(c0))[:, 0]
+        d2_0 = spans.launch(pairwise_sq_dists, x_dev, jnp.asarray(c0))[:, 0]
         dmin = d2_0
         amin = jnp.zeros((n,), jnp.int32)
 
         all_slots, all_valid, filled = [], [], []
         for step in range(init_steps):
-            slots, slot_valid, dmin, amin, phi = _pll_round(
-                x_dev, weights_dev, dmin, amin,
+            # at 2^22 rows on a v5e the runtime holds this call until the
+            # device has finished the distances above (24 of its 29 ms)
+            slots, slot_valid, dmin, amin, phi = spans.launch(
+                _pll_round, x_dev, weights_dev, dmin, amin,
                 jnp.asarray(1 + cap * step, jnp.int32),
                 jax.random.fold_in(key, step), l, cap, chunk,
             )
-            if float(phi) <= 0.0:
+            if float(spans.fetch(np.asarray, phi)) <= 0.0:
                 break
             all_slots.append(slots)
             all_valid.append(slot_valid)
             # small host fetch, re-replicated if GSPMD left the output sharded
-            filled.append(_to_host(slot_valid) > 0)
+            filled.append(spans.fetch(_to_host, slot_valid) > 0)
         picks = [int(f.sum()) for f in filled]
         n_cand = 1 + sum(picks)
         rounds = len(all_slots)
@@ -1293,13 +1305,16 @@ def init_kmeans_parallel(
             for _ in range(rounds, init_steps):
                 all_slots.append(jnp.zeros_like(all_slots[0]))
                 all_valid.append(jnp.zeros_like(all_valid[0]))
-            cand, valid = _candidate_buffer(
-                c0, tuple(all_slots), tuple(all_valid)
+            cand, valid = spans.launch(
+                _candidate_buffer, c0, tuple(all_slots), tuple(all_valid)
             )
             # waited for here: this span ends when its device work has,
             # and the reduction's span is not billed for the weights
-            cand_w = jax.block_until_ready(
-                _candidate_weights(weights_dev, amin, cand.shape[0])
+            cand_w = spans.fetch(
+                jax.block_until_ready,
+                spans.launch(
+                    _candidate_weights, weights_dev, amin, cand.shape[0]
+                ),
             )
 
     # the reduction of the candidates to k centers (the span's name dates

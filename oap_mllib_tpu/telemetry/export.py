@@ -337,6 +337,16 @@ def report(summary=None) -> str:
             f"{pc.get('misses', 0)} misses"
             + (f" ({rate:.0%} hit rate)" if rate is not None else "")
         )
+        if pc.get("misses"):
+            # the program ledger's view of this fit (utils/progcache.py):
+            # what those misses made ready, the costliest first
+            programs = pc.get("programs", {})
+            for name in sorted(programs, key=lambda n: -programs[n]["seconds"]):
+                per = programs[name]
+                lines.append(
+                    f"    {name}: {_fmt_s(per['seconds'])} "
+                    f"({per['compiled']} compiled, {per['loaded']} loaded)"
+                )
     rs = _summary_get(summary, "resilience")
     if rs and (rs.get("faults") or rs.get("retries")):
         lines.append(

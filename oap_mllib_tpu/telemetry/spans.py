@@ -39,6 +39,11 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import numpy as np
+
+from oap_mllib_tpu.telemetry import flightrec
+from oap_mllib_tpu.utils import profiling
+
 _SEP = "/"
 
 
@@ -156,8 +161,53 @@ def current_span() -> Optional[Span]:
     return stack[-1] if stack else None
 
 
-@contextlib.contextmanager
-def enter(span: Span, annotate: bool = True):
+class _Entry:
+    """One timed entry of a span (what :func:`enter` and :func:`child`
+    hand to ``with``).  A class with slots, not a generator: a fit opens
+    a leaf around every ``device_put`` and every wait of its upload, and
+    a generator-based manager costs more than the clock pair it wraps."""
+
+    __slots__ = ("span", "annotate", "_stack", "_ann", "_rec", "_t0")
+
+    def __init__(self, span: Span, annotate: bool = True):
+        self.span = span
+        self.annotate = annotate
+
+    def __enter__(self) -> Span:
+        span = self.span
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        stack.append(span)
+        self._stack = stack
+        ann = None
+        if self.annotate and profiling.trace_active():
+            import jax
+
+            ann = jax.profiler.TraceAnnotation(span.path or span.name)
+            ann.__enter__()
+        self._ann = ann
+        # both guards are read once an entry: the close event belongs to
+        # the recorder that saw the open
+        rec = self._rec = flightrec.enabled()
+        if rec:
+            flightrec.record("span_open", span.name)
+        self._t0 = time.perf_counter()
+        return span
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self._t0
+        span = self.span
+        span.record(dt)
+        if self._rec:
+            flightrec.record("span_close", span.name, f"{dt:.6f}s")
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._stack.pop()
+        return False
+
+
+def enter(span: Span, annotate: bool = True) -> _Entry:
     """Time one entry of ``span``: push it as the thread's active span,
     record the monotonic wall on exit, and — only when a jax.profiler
     trace is running (one bool check) — emit a TraceAnnotation so the
@@ -167,37 +217,9 @@ def enter(span: Span, annotate: bool = True):
     flight recorder armed (telemetry/flightrec.py — one config check
     when off), span open/close land in the event ring so post-mortems
     and merged timelines see which phases were in flight."""
-    from oap_mllib_tpu.telemetry import flightrec
-
-    stack = getattr(_tls, "stack", None)
-    if stack is None:
-        stack = _tls.stack = []
-    stack.append(span)
-    ann = None
-    if annotate:
-        from oap_mllib_tpu.utils import profiling
-
-        if profiling.trace_active():
-            import jax
-
-            ann = jax.profiler.TraceAnnotation(span.path or span.name)
-            ann.__enter__()
-    if flightrec.enabled():
-        flightrec.record("span_open", span.name)
-    t0 = time.perf_counter()
-    try:
-        yield span
-    finally:
-        dt = time.perf_counter() - t0
-        span.record(dt)
-        if flightrec.enabled():
-            flightrec.record("span_close", span.name, f"{dt:.6f}s")
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        stack.pop()
+    return _Entry(span, annotate)
 
 
-@contextlib.contextmanager
 def child(name: str):
     """Time ``name`` as a sub-span of the thread's active span: how
     ``data/`` and ``ops/`` split the phase that called them
@@ -205,9 +227,51 @@ def child(name: str):
     ``timings`` handle in their signatures and without reading a clock.
     Outside any fit nothing is timed or pushed; the span yielded then is
     a detached one, so a call site sets ``attrs`` unconditionally."""
-    parent = current_span()
-    if parent is None:
-        yield Span(name)
-        return
-    with enter(parent.child(name)) as span:
-        yield span
+    stack = getattr(_tls, "stack", None)
+    if not stack:
+        return contextlib.nullcontext(Span(name))
+    return _Entry(stack[-1].child(name))
+
+
+# -- the seams where the host meets the device --------------------------------
+# Leaf names, each opened with ``child`` where the host thread itself
+# blocks or prepares (docs/observability.md, "Spans"):
+#   put    inside a ``jax.device_put`` call (``attrs["bytes"]`` handed over)
+#   land   blocked until uploaded bytes had landed / an in-place write ended
+#   cast   blocked on the host threads that cast a row block
+#   launch inside a call that hands the device a program: short, unless
+#          the runtime holds the call until the device has room for it
+#   fetch  blocked on device RESULTS (``attrs["bytes"]`` brought back)
+# A phase's wall minus its ``fetch`` and ``land`` descendants is its host
+# gap: seconds in which nothing the host waited on was running.
+PUT, LAND, CAST, LAUNCH, FETCH = "put", "land", "cast", "launch", "fetch"
+
+
+def _host_nbytes(out) -> int:
+    """Bytes of the host arrays in ``out`` (an array, or a tuple / list
+    of them); a device array that was only waited for brought nothing."""
+    if isinstance(out, (tuple, list)):
+        return sum(_host_nbytes(o) for o in out)
+    return out.nbytes if isinstance(out, (np.ndarray, np.generic)) else 0
+
+
+def launch(program, *args, **kwargs):
+    """``program(*args, **kwargs)`` — a call that returns device arrays
+    still to be computed — as one entry of the ``launch`` leaf of the
+    thread's active span: the host seconds INSIDE the call.  The runtime
+    may hold it until the device has finished what was queued before
+    (seen where the device's memory is nearly full), so a long ``launch``
+    is the host blocked on the device, not the device waiting for it."""
+    with child(LAUNCH):
+        return program(*args, **kwargs)
+
+
+def fetch(get, *args):
+    """``get(*args)`` — a read that blocks the host on device results
+    (``np.asarray`` of a device array, ``jax.block_until_ready``) — as
+    one entry of the ``fetch`` leaf of the thread's active span, with
+    what came back to the host added to the leaf's ``attrs["bytes"]``."""
+    with child(FETCH) as span:
+        out = get(*args)
+        span.attrs["bytes"] = span.attrs.get("bytes", 0) + _host_nbytes(out)
+    return out

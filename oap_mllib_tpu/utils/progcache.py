@@ -34,12 +34,15 @@ amortization argument, PAPERS.md, applied across process lifetimes).
 from __future__ import annotations
 
 import contextlib
+import logging
 import threading
 import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional
 
 from oap_mllib_tpu.telemetry import metrics as _tm
+
+_log = logging.getLogger("oap_mllib_tpu")
 
 # -- registry ---------------------------------------------------------------
 
@@ -171,7 +174,12 @@ def note(algo: str, key: tuple) -> bool:
 
 
 def stats() -> Dict[str, Any]:
-    return _CACHE.stats()
+    """The registry's counters, and ``programs_seen``: how many first
+    launches the ledger has booked (the mark :func:`delta` reads from)."""
+    out = _CACHE.stats()
+    with _XLA_EVENTS_LOCK:
+        out["programs_seen"] = len(_READY_LOG)
+    return out
 
 
 def clear() -> None:
@@ -182,13 +190,19 @@ def clear() -> None:
 def delta(before: Dict[str, Any]) -> Dict[str, Any]:
     """Per-fit registry activity: ``stats() - before`` for the scalar
     counters (models snapshot ``stats()`` at fit entry and attach the
-    delta to the training summary)."""
+    delta to the training summary), and under ``programs`` — only where
+    there were any — what XLA made ready meanwhile: ``{name: {seconds,
+    compiled, loaded}}``."""
     now = stats()
     out = {
         k: now[k] - before.get(k, 0) for k in ("hits", "misses", "evictions")
     }
     total = out["hits"] + out["misses"]
     out["hit_rate"] = (out["hits"] / total) if total else None
+    # the programs made ready meanwhile, by name (the program ledger)
+    programs = _programs_since(before.get("programs_seen", now["programs_seen"]))
+    if programs:
+        out["programs"] = programs
     return out
 
 
@@ -280,7 +294,7 @@ def key_digest(key) -> str:
     return hashlib.sha1(repr(key).encode()).hexdigest()[:16]
 
 
-# -- XLA compile ground truth ----------------------------------------------
+# -- XLA compile ground truth, and the program ledger -------------------------
 
 _XLA_EVENTS = {"count": 0, "secs": 0.0}
 # the compile-event listener fires on whatever thread XLA compiles on,
@@ -289,7 +303,134 @@ _XLA_EVENTS = {"count": 0, "secs": 0.0}
 # seam as _CLEAR_LOCK
 _XLA_EVENTS_LOCK = _locktrace.TrackedLock("progcache.xla_events")
 _xla_listener_installed = False
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_LEDGER_FIELD = {_TRACE_EVENT: "trace_s", _LOWER_EVENT: "lower_s",
+                 _BACKEND_COMPILE_EVENT: "backend_s"}  # what the ledger keeps
+READY_SECONDS = "oap_program_ready_seconds_total"
+PROGRAMS_COMPILED = "oap_programs_compiled_total"
+
+# every first launch in arrival order, under _XLA_EVENTS_LOCK: (the name
+# jax gives the program — the jitted function's, ``"?"`` for an event that
+# names none —, trace_s, lower_s, backend_s, load_s, loaded from the
+# persistent cache).  The ledger and a fit's view are groupings of it; one
+# record an executable made ready, so it grows as jit's own caches do
+_READY_LOG: list = []
+# jax emits a program's events in order on the thread that makes it
+# ready: trace, lowering, then — inside the backend event — the
+# persistent cache's hit and retrieval time, which carry no name
+_pending = threading.local()
+
+
+def _program_name(fun_name) -> str:
+    """``write_piece`` of ``jit(write_piece)`` (lowering and backend
+    events wrap the name the trace event gives bare)."""
+    name = str(fun_name) if fun_name else "?"
+    if name.endswith(")") and "(" in name:
+        name = name[name.index("(") + 1:-1] or "?"
+    return name
+
+
+def _on_trace_start(event, value, **kwargs):
+    # jitted functions traced INSIDE another's trace (every jnp call of
+    # a program's body) report durations that the outer one contains:
+    # only the outermost is booked
+    if event == _TRACE_EVENT:
+        _pending.tracing = getattr(_pending, "tracing", 0) + 1
+
+
+def _on_cache_hit(event, **kwargs):
+    if event == _CACHE_HIT_EVENT:
+        _pending.loaded = True
+
+
+def _on_duration(event, duration_secs, **kwargs):
+    secs = float(duration_secs)
+    if event == _CACHE_RETRIEVAL_EVENT:
+        _pending.load_s = secs
+        return
+    field = _LEDGER_FIELD.get(event)
+    if field is None:
+        return
+    tracing = getattr(_pending, "tracing", 0)
+    if event == _TRACE_EVENT:
+        tracing = _pending.tracing = max(tracing - 1, 0)
+        if tracing:
+            return
+    name = _program_name(kwargs.get("fun_name"))
+    # trace and lowering wait here, by name, for the backend event that
+    # makes them a first launch's
+    ready = getattr(_pending, "ready", None)
+    if ready is None:
+        ready = _pending.ready = {}
+    if event == _BACKEND_COMPILE_EVENT:
+        before = ready.pop(name, {})
+        ready.clear()
+        _book_first_launch(
+            name, before.get("trace_s", 0.0), before.get("lower_s", 0.0), secs
+        )
+    else:
+        mine = ready.setdefault(name, {})
+        mine[field] = mine.get(field, 0.0) + secs
+    if not tracing:
+        # lowering or a compile inside a trace (an eager op on a
+        # constant) is inside that trace's seconds already
+        _ready_total().inc(secs)
+
+
+def _book_first_launch(name: str, trace_s: float, lower_s: float,
+                       secs: float) -> None:
+    """One backend event: an executable compiled, or loaded from the
+    persistent cache where the hit event came first on this thread."""
+    loaded = bool(getattr(_pending, "loaded", False))
+    load_s = getattr(_pending, "load_s", 0.0) if loaded else 0.0
+    _pending.loaded, _pending.load_s = False, 0.0
+    with _XLA_EVENTS_LOCK:
+        _XLA_EVENTS["count"] += 1
+        _XLA_EVENTS["secs"] += secs
+        _READY_LOG.append((name, trace_s, lower_s, secs, load_s, loaded))
+    _tm.counter(
+        "oap_xla_compiles_total",
+        help="Real XLA backend compiles (jax monitoring event)",
+    ).inc()
+    _tm.counter(
+        "oap_xla_compile_seconds_total",
+        help="Wall spent in XLA backend compilation",
+    ).inc(secs)
+    _tm.histogram(
+        "oap_xla_compile_seconds",
+        help="Per-program XLA backend compile wall",
+    ).observe(secs)
+    if not loaded:
+        _compiled_total().inc()
+
+
+def _ready_total():
+    return _tm.counter(
+        READY_SECONDS,
+        help="Trace + lowering + load-or-compile: what first launches paid",
+    )
+
+
+def _compiled_total():
+    return _tm.counter(
+        PROGRAMS_COMPILED,
+        help="Backend compiles the persistent cache did not serve",
+    )
+
+
+def _never_raise(listener):
+    """jax calls listeners from inside its compile path: a fault in the
+    accounting must not become a fault of the program."""
+    def guarded(*args, **kwargs):
+        try:
+            listener(*args, **kwargs)
+        except Exception:  # noqa: BLE001 - the boundary that must keep running
+            _log.debug("progcache listener failed", exc_info=True)
+    return guarded
 
 
 def _install_xla_listener() -> None:
@@ -298,31 +439,20 @@ def _install_xla_listener() -> None:
         return
     from jax import monitoring
 
-    def _on_event(event, duration_secs, **kwargs):
-        if event == _BACKEND_COMPILE_EVENT:
-            with _XLA_EVENTS_LOCK:
-                _XLA_EVENTS["count"] += 1
-                _XLA_EVENTS["secs"] += float(duration_secs)
-            _tm.counter(
-                "oap_xla_compiles_total",
-                help="Real XLA backend compiles (jax monitoring event)",
-            ).inc()
-            _tm.counter(
-                "oap_xla_compile_seconds_total",
-                help="Wall spent in XLA backend compilation",
-            ).inc(float(duration_secs))
-            _tm.histogram(
-                "oap_xla_compile_seconds",
-                help="Per-program XLA backend compile wall",
-            ).observe(float(duration_secs))
-
-    monitoring.register_event_duration_secs_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_never_raise(_on_duration))
+    monitoring.register_event_listener(_never_raise(_on_cache_hit))
+    monitoring.register_scalar_listener(_never_raise(_on_trace_start))
+    # the two totals exist (at 0) from the first snapshot on: a reader
+    # tells "nothing compiled" from "a program that has no ledger"
+    _ready_total()
+    _compiled_total()
     _xla_listener_installed = True
 
 
 def xla_compile_count() -> int:
     """Monotone count of real XLA backend compiles in this process (the
-    ``/jax/core/compile/backend_compile_duration`` event).  Snapshot
+    ``/jax/core/compile/backend_compile_duration`` event; a program
+    loaded from the persistent cache fires it too).  Snapshot
     before/after a region and subtract — that difference is the ground
     truth the compile-sweep bench and the CI gate assert on (the
     registry's miss count is what *we* think; this is what XLA did)."""
@@ -337,6 +467,48 @@ def xla_compile_secs() -> float:
     _install_xla_listener()
     with _XLA_EVENTS_LOCK:
         return _XLA_EVENTS["secs"]
+
+
+def program_ledger() -> Dict[str, Dict[str, Any]]:
+    """What making each program of this process ready cost, by name:
+    ``launches_first_seen`` (first launches: backend events under the
+    name, one an executable), of which ``compiled`` were compiled and
+    the rest loaded from the persistent cache (``from_persistent_cache``:
+    all of them were), ``trace_s`` (the outermost trace), ``lower_s``,
+    ``backend_s`` (compile, or the load: ``load_s`` of it).  The sum of
+    the three is in ``oap_program_ready_seconds_total``, the compiled
+    count in ``oap_programs_compiled_total``."""
+    _install_xla_listener()
+    with _XLA_EVENTS_LOCK:
+        log = list(_READY_LOG)
+    out: Dict[str, Dict[str, Any]] = {}
+    for name, trace_s, lower_s, backend_s, load_s, loaded in log:
+        per = out.setdefault(name, {
+            "launches_first_seen": 0, "compiled": 0, "trace_s": 0.0,
+            "lower_s": 0.0, "backend_s": 0.0, "load_s": 0.0,
+        })
+        per["launches_first_seen"] += 1
+        per["compiled"] += int(not loaded)
+        per["trace_s"] += trace_s
+        per["lower_s"] += lower_s
+        per["backend_s"] += backend_s
+        per["load_s"] += load_s
+    for per in out.values():
+        per["from_persistent_cache"] = per["compiled"] == 0
+    return out
+
+
+def _programs_since(mark: int) -> Dict[str, Dict[str, Any]]:
+    """First launches ``mark`` onward, by name: seconds (trace +
+    lowering + load-or-compile), how many were compiled and loaded."""
+    with _XLA_EVENTS_LOCK:
+        log = _READY_LOG[mark:]
+    out: Dict[str, Dict[str, Any]] = {}
+    for name, trace_s, lower_s, backend_s, _, loaded in log:
+        per = out.setdefault(name, {"seconds": 0.0, "compiled": 0, "loaded": 0})
+        per["seconds"] += trace_s + lower_s + backend_s
+        per["loaded" if loaded else "compiled"] += 1
+    return out
 
 
 # install at import so compiles that happen before the first explicit

@@ -418,11 +418,13 @@ class TestCovariancePhase:
 
         monkeypatch.setattr(jax, "block_until_ready", spy)
         PCA(k=3).fit(_data(rng, n=200, d=8).astype(np.float32))
-        assert [w for w in waits if w[0] == "covariance"] == [
-            ("covariance", [(8, 8)])
+        # inside the phase's fetch leaf (the host is blocked on results)
+        assert [w for w in waits if w[0].startswith("covariance")] == [
+            ("covariance/fetch", [(8, 8)])
         ]
-        # and the staging phase's wait for table and mask, as before
-        assert ("table_convert/upload", [(2048, 8), (2048,)]) in waits
+        # and the staging phase's wait for table and mask, as before, in
+        # the upload's land leaf
+        assert ("table_convert/upload/land", [(2048, 8), (2048,)]) in waits
 
 
 class TestBenchmarkCellAtRehearseSize:

@@ -401,3 +401,167 @@ class TestCrossFitReuse:
         np.testing.assert_allclose(
             m1.item_factors_, m2.item_factors_, atol=1e-7
         )
+
+
+def _named(name, scale):
+    """A fresh function object called ``name``: a new trace each time,
+    the same program (and persistent-cache key) for the same ``scale``."""
+    import jax.numpy as jnp
+
+    def fn(a):
+        return jnp.cos(a) * scale + jnp.sin(a)
+
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _ready_seconds():
+    from oap_mllib_tpu import telemetry
+
+    return telemetry.metrics.family_total(progcache.READY_SECONDS)
+
+
+def _compiled_total():
+    from oap_mllib_tpu import telemetry
+
+    return telemetry.metrics.family_total(progcache.PROGRAMS_COMPILED)
+
+
+class TestProgramLedger:
+    """The listener keeps the name jax gives each program it makes ready
+    (ISSUE 35): what a first launch paid, by name, and whether the
+    persistent cache served it."""
+
+    def test_names_a_program_and_what_its_first_launch_paid(self):
+        import jax
+        import jax.numpy as jnp
+
+        x = jnp.arange(7.0)
+        assert "ledger_probe_a" not in progcache.program_ledger()
+        count, ready = progcache.xla_compile_count(), _ready_seconds()
+        before = progcache.stats()
+        jax.jit(_named("ledger_probe_a", 3.0))(x).block_until_ready()
+        entry = progcache.program_ledger()["ledger_probe_a"]
+        assert entry["launches_first_seen"] == 1
+        assert min(entry["trace_s"], entry["lower_s"], entry["backend_s"]) > 0
+        assert progcache.xla_compile_count() == count + 1
+        # the fit-level view: this program, with all three parts
+        made = progcache.delta(before)["programs"]
+        assert set(made) == {"ledger_probe_a"}
+        assert made["ledger_probe_a"]["seconds"] == pytest.approx(
+            entry["trace_s"] + entry["lower_s"] + entry["backend_s"]
+        )
+        assert made["ledger_probe_a"]["compiled"] + made["ledger_probe_a"]["loaded"] == 1
+        # the body's jnp calls are traced inside the program's own trace:
+        # booked once, under the outer name, and the total agrees
+        assert _ready_seconds() - ready == pytest.approx(
+            made["ledger_probe_a"]["seconds"]
+        )
+        assert "programs" not in progcache.delta(progcache.stats())
+
+    def test_a_load_from_the_persistent_cache_is_told_from_a_compile(
+            self, tmp_path, jax_cache_restore, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+
+        monkeypatch.delenv(progcache.CACHE_ENV, raising=False)
+        monkeypatch.setattr(progcache, "_persist_applied", None)
+        progcache.ensure_persistent_cache(str(tmp_path / "xla-cache"))
+        x = jnp.arange(11.0)
+        compiled = _compiled_total()
+        before = progcache.stats()
+        jax.jit(_named("ledger_probe_b", 5.0))(x).block_until_ready()
+        first = progcache.delta(before)["programs"]["ledger_probe_b"]
+        assert (first["compiled"], first["loaded"]) == (1, 0)
+        assert _compiled_total() == compiled + 1
+        entry = progcache.program_ledger()["ledger_probe_b"]
+        assert not entry["from_persistent_cache"] and entry["load_s"] == 0.0
+        # the same program through a fresh trace: served from the directory
+        before = progcache.stats()
+        count = progcache.xla_compile_count()
+        jax.jit(_named("ledger_probe_b", 5.0))(x).block_until_ready()
+        second = progcache.delta(before)["programs"]["ledger_probe_b"]
+        assert (second["compiled"], second["loaded"]) == (0, 1)
+        assert _compiled_total() == compiled + 1
+        # xla_compile_count keeps its meaning: the backend event, load or not
+        assert progcache.xla_compile_count() == count + 1
+        entry = progcache.program_ledger()["ledger_probe_b"]
+        assert entry["launches_first_seen"] == 2 and entry["compiled"] == 1
+        assert entry["load_s"] > 0 and not entry["from_persistent_cache"]
+        # as the next process sees it: a ledger that starts empty
+        monkeypatch.setattr(progcache, "_READY_LOG", [])
+        jax.jit(_named("ledger_probe_b", 5.0))(x).block_until_ready()
+        entry = progcache.program_ledger()["ledger_probe_b"]
+        assert entry["launches_first_seen"] == 1 and entry["compiled"] == 0
+        assert entry["from_persistent_cache"]
+        assert 0 < entry["load_s"] <= entry["backend_s"]
+
+    def test_a_forced_recompile_is_counted_by_name(self):
+        import jax
+        import jax.numpy as jnp
+
+        fn = jax.jit(_named("ledger_probe_c", 2.0))
+        fn(jnp.arange(5.0)).block_until_ready()
+        one = progcache.program_ledger()["ledger_probe_c"]
+        fn(jnp.arange(5.0)).block_until_ready()  # jit's own cache: no event
+        assert progcache.program_ledger()["ledger_probe_c"] == one
+        fn(jnp.arange(6.0)).block_until_ready()  # another shape: made ready anew
+        two = progcache.program_ledger()["ledger_probe_c"]
+        assert two["launches_first_seen"] == one["launches_first_seen"] + 1 == 2
+        assert two["backend_s"] > one["backend_s"] and two["trace_s"] > one["trace_s"]
+
+    @pytest.mark.parametrize("kwargs", [{}, {"fun_name": None}, {"fun_name": ""}])
+    def test_an_event_without_a_name_is_booked_under_a_question_mark(self, kwargs):
+        from jax import monitoring
+
+        def unnamed():
+            return dict(progcache.program_ledger().get(
+                "?", {"launches_first_seen": 0, "backend_s": 0.0}
+            ))
+
+        was, count = unnamed(), progcache.xla_compile_count()
+        monitoring.record_event_duration_secs(
+            progcache._BACKEND_COMPILE_EVENT, 0.25, **kwargs
+        )
+        now = unnamed()
+        assert now["launches_first_seen"] == was["launches_first_seen"] + 1
+        assert now["backend_s"] == pytest.approx(was["backend_s"] + 0.25)
+        assert progcache.xla_compile_count() == count + 1
+
+    @pytest.mark.parametrize("name,want", [
+        ("jit(write_piece)", "write_piece"), ("write_piece", "write_piece"),
+        ("jit(<lambda>)", "<lambda>"), ("pmap(step)", "step"),
+        (None, "?"), ("jit()", "?"),
+    ])
+    def test_one_name_for_the_three_events_of_a_program(self, name, want):
+        assert progcache._program_name(name) == want
+
+    def test_a_listener_never_raises_into_the_compile_path(self):
+        from jax import monitoring
+
+        assert progcache._never_raise(lambda *a, **k: 1 / 0)("event", 1.0) is None
+        # a duration that is no number at all, through the real listeners
+        monitoring.record_event_duration_secs(
+            progcache._BACKEND_COMPILE_EVENT, "soon", fun_name="jit(x)"
+        )
+        # an inner trace's end with no start seen: clamped, not negative
+        monitoring.record_event_duration_secs(
+            progcache._TRACE_EVENT, 0.0, fun_name="ledger_probe_orphan"
+        )
+        assert getattr(progcache._pending, "tracing", 0) == 0
+
+    def test_report_names_the_programs_a_fit_made_ready(self, rng):
+        from oap_mllib_tpu import telemetry
+        from oap_mllib_tpu.models.kmeans import KMeans
+
+        # a shape no other test uses: its programs are made ready here
+        x = rng.normal(size=(211, 11)).astype(np.float32)
+        first = KMeans(k=3, seed=8, init_mode="random", max_iter=2).fit(x)
+        programs = first.summary.progcache["programs"]
+        assert first.summary.progcache["misses"] > 0 and programs
+        text = telemetry.report(first.summary)
+        costliest = max(programs, key=lambda n: programs[n]["seconds"])
+        assert f"    {costliest}: " in text and "compiled" in text
+        second = KMeans(k=3, seed=8, init_mode="random", max_iter=2).fit(x)
+        assert "programs" not in second.summary.progcache
+        assert "compiled," not in telemetry.report(second.summary)
