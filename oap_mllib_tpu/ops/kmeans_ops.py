@@ -20,7 +20,7 @@ TPU-first redesign:
   every device accumulates its own row shard and ``psum`` sums the moments
   over the ``data`` axis (:func:`lloyd_run` with a ``mesh``); the k-means||
   rounds and a :func:`lloyd_run` without one on sharded arrays are global
-  ``jnp.sum``/matmul/scatter that GSPMD lowers to the same collectives.
+  ``jnp.sum``/matmul/gather that GSPMD lowers to the same collectives.
   No root rank: results land replicated.
 - Padded rows carry mask weight 0 so they never contribute (survey §2.6
   fixed-shape design note).
@@ -1067,42 +1067,51 @@ def _pll_round(x, w, dmin, amin, base_id, key, l, cap, chunk):
 
     Samples each row with probability min(l * cost / phi, 1) (Bahmani
     oversampling; padded rows have w=0 so cost=0 and are never picked),
-    scatters the picked rows into a fixed ``cap``-slot buffer via their
-    picked-prefix position (overflow beyond cap is dropped — cap is 2x the
-    expected pick count), then folds the new slots into the running
-    (min-distance, nearest-candidate) state ``chunk`` slots at a time, so
-    no (n, cap) buffer ever materializes, and only as far as the slots
-    are filled: the loop's trip count is :func:`_live_chunks` of the
-    round's own pick count, not ``cap // chunk``, because a chunk with no
-    valid slot is a full-price distance sheet that changes no row.  About
-    half the capacity is never multiplied; a round whose picks reach
-    ``cap`` folds every chunk.  All reductions/scatters are global: under a
-    row-sharded mesh GSPMD lowers them to psums, so the round is
-    multi-host-safe with zero O(n) host transfers (round-1 pulled all n
-    distances AND weights to host each round).
+    gathers the picked rows into a fixed ``cap``-slot buffer, then folds
+    the new slots into the running (min-distance, nearest-candidate)
+    state ``chunk`` slots at a time, so no (n, cap) buffer ever
+    materializes, and only as far as the slots are filled: the loop's
+    trip count is :func:`_live_chunks` of the round's own pick count, not
+    ``cap // chunk``, because a chunk with no valid slot is a full-price
+    distance sheet that changes no row.  About half the capacity is never
+    multiplied; a round whose picks reach ``cap`` folds every chunk.
 
-    Returns (slots, slot_valid, new_dmin, new_amin, phi).
+    Slot ``j`` holds the row whose inclusive picked-prefix first reaches
+    ``j + 1``: a binary search of the prefix for the ordinals ``1 ..
+    cap`` (log2(n) steps of ``cap`` reads) and one gather of at most
+    ``cap`` rows, where a scatter-add would walk every row of the table
+    to place them.  Picks fill slots ``0 .. picks - 1`` in row order;
+    picks past ``cap`` are dropped (cap is 2x the expected pick count);
+    an ordinal past the last pick finds no row, and its slot is zeros
+    with validity 0.  A picked value is copied, not added to zero, so an
+    exact ``-0.0`` stays ``-0.0``: no distance changes by it.  Every
+    reduction, the prefix and the gathers are global: under a row-sharded
+    mesh GSPMD gathers on each shard's own rows and sums the slots with
+    one all-reduce, so the round is multi-host-safe with zero O(n) host
+    transfers.
+
+    Returns (slots, slot_valid, new_dmin, new_amin, phi, picks); ``picks``
+    counts the rows sampled, those past ``cap`` included.
     """
     cost = dmin * w
     phi = jnp.sum(cost)
     prob = jnp.minimum(l * cost / jnp.maximum(phi, 1e-30), 1.0)
     draws = jax.random.uniform(key, dmin.shape, dtype=dmin.dtype)
     picked = draws < prob
-    pos = jnp.cumsum(picked.astype(jnp.int32)) - 1  # global prefix position
-    slot_of = jnp.where(picked, pos, cap)  # cap = out-of-bounds -> dropped
-    slots = jnp.zeros((cap, x.shape[1]), x.dtype).at[slot_of].add(
-        x * picked[:, None].astype(x.dtype), mode="drop"
-    )
-    slot_valid = jnp.zeros((cap,), x.dtype).at[slot_of].add(
-        picked.astype(x.dtype), mode="drop"
-    )
+    prefix = jnp.cumsum(picked.astype(jnp.int32))  # inclusive, global
+    picks = prefix[-1]
+    ordinals = jnp.arange(1, cap + 1, dtype=jnp.int32)
+    # an ordinal past the last pick finds index n: out of range -> zeros
+    rows = jnp.searchsorted(prefix, ordinals, side="left")
+    slots = x.at[rows].get(mode="fill", fill_value=0)
+    filled = jnp.minimum(picks, cap)
+    slot_valid = (ordinals <= filled).astype(x.dtype)
 
     # picks fill slots 0 .. picks-1: a chunk past the last filled slot is
     # a sheet of inf (cm < dm false on every row), so the fold ends there
     q = cap // chunk
     slots_c = slots.reshape(q, chunk, x.shape[1])
     valid_c = slot_valid.reshape(q, chunk)
-    filled = jnp.sum((slot_valid > 0).astype(jnp.int32))
 
     def fold(i, carry):
         dm, am = carry
@@ -1118,7 +1127,7 @@ def _pll_round(x, w, dmin, amin, base_id, key, l, cap, chunk):
     dmin, amin = lax.fori_loop(
         0, _live_chunks(filled, chunk), fold, (dmin, amin)
     )
-    return slots, slot_valid, dmin, amin, phi
+    return slots, slot_valid, dmin, amin, phi, picks
 
 
 @functools.partial(jax.jit, static_argnames=("n_cand",))
@@ -1237,24 +1246,25 @@ def init_kmeans_parallel(
     4k*steps slots — 2x the expected 2k picks per round, so
     overflow-dropping is vanishingly rare; a round folds only the slot
     chunks its picks reached, and the ``rounds`` span says how many:
-    ``slot_chunks`` of ``slot_chunks_cap``, over ``slots_filled`` slots)
+    ``slot_chunks`` of ``slot_chunks_cap``, over ``slots_filled`` slots,
+    with ``picks_dropped`` picks past a round's capacity)
     and never goes to the host:
-    per-round sampling/prefix-scatter/min-fold run in one jitted
+    per-round sampling/prefix-search/gather/min-fold run in one jitted
     program, the ownership weights in another, and the weighted
     k-means++ reduction of the candidates to k centres (Spark runs it on
     the driver, mllib/clustering/KMeans.scala initKMeansParallel) in a
     third, :func:`_reduce_candidates`.  The host fetches what it decides
-    on — each round's ``phi`` and slot validity — and the (k, d)
-    centres.  Every device op is GSPMD-global, so the same code serves
-    multi-host meshes.  Only a table that yields no more than k
+    on — each round's ``phi`` and pick count, in one read — and the
+    (k, d) centres.  Every device op is GSPMD-global, so the same code
+    serves multi-host meshes.  Only a table that yields no more than k
     candidates is topped up with random rows, on the host.
     """
     rng = np.random.default_rng(seed)
     n, d = x_dev.shape
 
     # everything that waits on the device: the seed-row gather, the
-    # rounds with their phi and validity fetches, the candidate weights
-    # (each blocking read an entry of the span's ``fetch`` leaf, each
+    # rounds with the fetch of their phi and pick count, the candidate
+    # weights (each blocking read an entry of the span's ``fetch`` leaf, each
     # call that hands the device a program one of its ``launch`` leaf:
     # what is left of the span is the host's Python between them)
     with spans.child("rounds") as span:
@@ -1269,35 +1279,39 @@ def init_kmeans_parallel(
         chunk = _slot_chunk_size(cap)
         key = jax.random.PRNGKey(seed)
 
-        # running state: distances/assignments vs candidate 0
-        d2_0 = spans.launch(pairwise_sq_dists, x_dev, jnp.asarray(c0))[:, 0]
-        dmin = d2_0
+        # running state: distances/assignments vs candidate 0 — as one
+        # program (the minimum over the one candidate is its distance):
+        # op by op, ``x * x`` would stand whole beside the table
+        dmin = spans.launch(min_sq_dists, x_dev, jnp.asarray(c0))
         amin = jnp.zeros((n,), jnp.int32)
 
-        all_slots, all_valid, filled = [], [], []
+        all_slots, all_valid, picks = [], [], []
         for step in range(init_steps):
-            # at 2^22 rows on a v5e the runtime holds this call until the
-            # device has finished the distances above (24 of its 29 ms)
-            slots, slot_valid, dmin, amin, phi = spans.launch(
+            # the runtime can hold this call until the device has finished
+            # what was queued before it (at 2^22 rows on a v5e 24 of its
+            # 29 ms while the distances above ran op by op; 2 ms since)
+            slots, slot_valid, dmin, amin, phi, picked = spans.launch(
                 _pll_round, x_dev, weights_dev, dmin, amin,
                 jnp.asarray(1 + cap * step, jnp.int32),
                 jax.random.fold_in(key, step), l, cap, chunk,
             )
-            if float(spans.fetch(np.asarray, phi)) <= 0.0:
+            phi, picked = spans.fetch(jax.device_get, (phi, picked))
+            if float(phi) <= 0.0:
                 break
             all_slots.append(slots)
             all_valid.append(slot_valid)
-            # small host fetch, re-replicated if GSPMD left the output sharded
-            filled.append(spans.fetch(_to_host, slot_valid) > 0)
-        picks = [int(f.sum()) for f in filled]
-        n_cand = 1 + sum(picks)
+            picks.append(int(picked))
+        # picks fill a round's slots from 0; those past cap were dropped
+        filled = [min(p, cap) for p in picks]
+        n_cand = 1 + sum(filled)
         rounds = len(all_slots)
         span.attrs["rounds"] = rounds
         span.attrs["shards"] = _row_shards(x_dev)
         # what the rounds' folds multiplied, against what the capacity
         # would have asked for
-        span.attrs["slots_filled"] = sum(picks)
-        span.attrs["slot_chunks"] = sum(_live_chunks(p, chunk) for p in picks)
+        span.attrs["slots_filled"] = sum(filled)
+        span.attrs["picks_dropped"] = sum(picks) - sum(filled)
+        span.attrs["slot_chunks"] = sum(_live_chunks(f, chunk) for f in filled)
         span.attrs["slot_chunks_cap"] = (cap // chunk) * rounds
         if n_cand > k:
             # rounds that did not run leave their slots empty, so the
@@ -1332,7 +1346,7 @@ def init_kmeans_parallel(
         span.attrs["reduced_on"] = "host"
         cand = np.concatenate(
             [np.asarray(c0)]
-            + [_to_host(s)[f] for s, f in zip(all_slots, filled)],
+            + [_to_host(s)[:f] for s, f in zip(all_slots, filled)],
             axis=0,
         )
         extra = init_random(

@@ -198,12 +198,12 @@ def test_every_phase_has_its_fetch_leaf_and_a_host_gap(
         assert timings.root.node("lloyd_loop/fetch").attrs["bytes"] >= (
             4 * D * 4 + 4 * 4 + 4 + 4
         )
-        # the seed row, and phi and the slot validity of each round; the
-        # wait for the candidate weights brings nothing back
+        # the seed row, and phi and the pick count of each round in one
+        # read; the wait for the candidate weights brings nothing back
         rounds = timings.root.node("init_centers/rounds")
         fetch = timings.root.node("init_centers/rounds/fetch")
-        assert fetch.count == 1 + 2 * rounds.attrs["rounds"] + 1
-        assert fetch.attrs["bytes"] == D * 4 + rounds.attrs["rounds"] * (4 + 16 * 4)
+        assert fetch.count == 1 + rounds.attrs["rounds"] + 1
+        assert fetch.attrs["bytes"] == D * 4 + rounds.attrs["rounds"] * (4 + 4)
         # the programs the rounds start: the distances to the seed row, a
         # round each, the candidate buffer and its weights
         launch = timings.root.node("init_centers/rounds/launch")

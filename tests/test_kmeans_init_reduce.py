@@ -263,3 +263,39 @@ class TestThroughTheFit:
         np.testing.assert_array_equal(
             first.cluster_centers_, second.cluster_centers_
         )
+
+    def test_rounds_span_says_no_pick_was_dropped(self):
+        """4k slots a round for about 2k picks: the fit's span counts what
+        fell past the capacity beside what filled it, and that is 0."""
+        from oap_mllib_tpu import KMeans
+
+        rng = np.random.default_rng(3)
+        x = rng.normal(size=(1024, 6)).astype(np.float32)
+        model = KMeans(k=8, max_iter=2, seed=1).fit(x)
+        attrs = model.summary.timings.root.node("init_centers/rounds").attrs
+        assert attrs["picks_dropped"] == 0
+        assert 0 < attrs["slots_filled"] <= attrs["rounds"] * 4 * 8
+
+    def test_rounds_span_counts_picks_past_the_capacity(self):
+        """k = 1 leaves a round 4 slots for 2 expected picks: over a few
+        seeds some round samples 5 rows or more (1 round in 19 would, by
+        Poisson(2)), keeps the first 4 and the span says how many went."""
+        from oap_mllib_tpu.telemetry import spans
+
+        rng = np.random.default_rng(4)
+        x = jnp.asarray(rng.normal(size=(512, 4)).astype(np.float32))
+        ones = jnp.ones((512,), jnp.float32)
+        for seed in range(200):
+            root = spans.Span("fit")
+            with spans.enter(root, annotate=False):
+                centre = kmeans_ops.init_kmeans_parallel(x, ones, 512, 1, seed)
+            attrs = root.node("rounds").attrs
+            assert centre.shape == (1, 4)
+            assert attrs["slots_filled"] <= attrs["rounds"] * 4
+            assert attrs["slot_chunks"] <= attrs["slot_chunks_cap"]
+            if attrs["picks_dropped"]:
+                break
+        else:
+            pytest.fail("no round of 200 fits sampled past its 4 slots")
+        # a round that dropped picks filled every one of its slots
+        assert attrs["picks_dropped"] > 0 and attrs["slots_filled"] >= 4

@@ -1,10 +1,14 @@
-"""The k-means|| round folds only the slot chunks it filled (ISSUE 32).
+"""The k-means|| round folds only the slot chunks it filled (ISSUE 32)
+and gathers the rows it picked (ISSUE 36).
 
-``kmeans_ops._pll_round`` bounds its fold by the round's own pick count.
-The oracle below is the same round with the fold written plainly over
-ALL ``cap // chunk`` chunks, as the program folded them before: a chunk
-with no valid slot reads ``inf`` on every row and moves none, so the two
-must agree bit for bit wherever the picks end.
+``kmeans_ops._pll_round`` bounds its fold by the round's own pick count
+and finds each slot's row by a search of the picked-prefix.  The oracle
+below is the same round written plainly: the picked rows scatter-added
+into their slots by walking every row of the table, and the fold over
+ALL ``cap // chunk`` chunks, as the program did both before: a slot
+receives one row plus zeros, and a chunk with no valid slot reads
+``inf`` on every row and moves none, so the two must agree bit for bit
+wherever the picks lie and end.
 
 Which rows a round picks is steered through the weights: with a huge
 ``l`` every row of positive cost has probability 1, so the picks are
@@ -32,7 +36,8 @@ def _round_folding_every_chunk(x, w, dmin, amin, base_id, key, l, cap, chunk):
     phi = jnp.sum(cost)
     prob = jnp.minimum(l * cost / jnp.maximum(phi, 1e-30), 1.0)
     picked = jax.random.uniform(key, dmin.shape, dtype=dmin.dtype) < prob
-    slot_of = jnp.where(picked, jnp.cumsum(picked.astype(jnp.int32)) - 1, cap)
+    pos = jnp.cumsum(picked.astype(jnp.int32)) - 1
+    slot_of = jnp.where(picked, pos, cap)
     slots = jnp.zeros((cap, x.shape[1]), x.dtype).at[slot_of].add(
         x * picked[:, None].astype(x.dtype), mode="drop"
     )
@@ -48,7 +53,7 @@ def _round_folding_every_chunk(x, w, dmin, amin, base_id, key, l, cap, chunk):
               + base_id + chunk * i)
         better = cm < dmin
         dmin, amin = jnp.where(better, cm, dmin), jnp.where(better, ca, amin)
-    return slots, slot_valid, dmin, amin, phi
+    return slots, slot_valid, dmin, amin, phi, pos[-1] + 1
 
 
 @pytest.fixture(scope="module")
@@ -79,14 +84,17 @@ def _args(table, w, l, seed=5):
 
 
 def _assert_same_bits(got, want):
-    for name, g, o in zip(("slots", "slot_valid", "dmin", "amin", "phi"),
-                          got, want):
+    names = ("slots", "slot_valid", "dmin", "amin", "phi", "picks")
+    assert len(got) == len(want) == len(names)
+    for name, g, o in zip(names, got, want):
+        assert g.shape == o.shape and g.dtype == o.dtype, name
         np.testing.assert_array_equal(np.asarray(g), np.asarray(o), name)
 
 
 def _fold_loops(hlo_text):
     """The compiled round's ``while`` instructions that are the slot fold
-    (the random bits' own loop, under ``_uniform``, has a fixed count)."""
+    (the random bits' own loop, under ``_uniform``, and the search of the
+    prefix, under ``searchsorted``, have fixed counts)."""
     return [
         line for line in hlo_text.splitlines()
         if " while(" in line and "pll_round/while" in line
@@ -130,8 +138,8 @@ class TestAgainstTheFoldOverEveryChunk:
         _assert_same_bits(
             got, _round_folding_every_chunk(*args, cap=CAP, chunk=CHUNK)
         )
-        slots, slot_valid, new_dmin, new_amin, phi = got
-        assert float(phi) == 0.0
+        slots, slot_valid, new_dmin, new_amin, phi, picks = got
+        assert float(phi) == 0.0 and int(picks) == 0
         assert not np.asarray(slots).any() and not np.asarray(slot_valid).any()
         np.testing.assert_array_equal(np.asarray(new_dmin), np.asarray(dmin))
         np.testing.assert_array_equal(np.asarray(new_amin), np.asarray(amin))
@@ -144,7 +152,7 @@ class TestAgainstTheFoldOverEveryChunk:
         args = _args(table, _weights(2 * CHUNK + 5), EVERY_ROW)
         ref = kmeans_ops._pll_round(*args, cap=CAP, chunk=CHUNK)
         got = kmeans_ops._pll_round(*args, cap=CAP, chunk=chunk)
-        for i in (0, 1, 3):  # slots, slot_valid, amin
+        for i in (0, 1, 3, 5):  # slots, slot_valid, amin, picks
             np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(ref[i]))
         # the distances come from |x|^2 + |s|^2 - 2 x.s with |x|^2 near
         # 60: a product of another width may round its last bits apart
@@ -184,7 +192,7 @@ class TestOnTheRowShardedMesh:
             *rest, cap=CAP, chunk=CHUNK,
         )
         assert len(got[2].sharding.device_set) == 8  # the state stays sharded
-        for i in (0, 1, 2, 3):  # slots, slot_valid, dmin, amin
+        for i in (0, 1, 2, 3, 5):  # slots, slot_valid, dmin, amin, picks
             np.testing.assert_array_equal(
                 np.asarray(got[i]), np.asarray(want[i])
             )
@@ -192,3 +200,90 @@ class TestOnTheRowShardedMesh:
         np.testing.assert_allclose(float(got[4]), float(want[4]), rtol=1e-6)
         filled = int((np.asarray(got[1]) > 0).sum())
         assert kmeans_ops._live_chunks(filled, CHUNK) == live
+
+
+def _gather_case(table, name):
+    """``(x, w, dmin, l, rows)``: a table, the weights and ``l`` that
+    steer the picks, and the rows a round must place, in slot order."""
+    x, dmin, _ = table
+    w = np.zeros(N, np.float32)
+    l = EVERY_ROW
+    if name == "picks_under_the_capacity":
+        rows = np.arange(5, N, 23)
+    elif name == "no_picks":
+        # every row weighs 1, so phi > 0, and l is too small to pick one
+        return x, jnp.ones((N,), jnp.float32), dmin, 1e-30, np.arange(0)
+    elif name == "picks_over_the_capacity":
+        rows = np.arange(1, N, 5)  # 103 picks into 64 slots
+    elif name == "last_quarter_is_pad":
+        # a padded table: zero rows of weight 0 behind the valid ones,
+        # over which the prefix does not rise
+        valid = 3 * N // 4
+        x = x.at[valid:].set(0.0)
+        dmin = dmin.at[valid:].set(0.0)
+        rows = np.arange(1, valid, 13)
+    elif name == "first_and_last_row":
+        # the state against row 7, so that row 0 has a cost to be picked by
+        dmin = kmeans_ops.pairwise_sq_dists(x, x[7:8])[:, 0]
+        rows = np.asarray([0, N // 2, N - 1])
+    w[rows] = 1.0
+    return x, jnp.asarray(w), dmin, l, rows
+
+
+GATHER_CASES = ["picks_under_the_capacity", "no_picks",
+                "picks_over_the_capacity", "last_quarter_is_pad",
+                "first_and_last_row"]
+
+
+class TestTheGatherAgainstTheScatter:
+    """ISSUE 36: the round finds each slot's row in the prefix and gathers
+    it; the oracle scatter-adds every row of the table.  The same bits,
+    on one device and on eight row shards."""
+
+    @pytest.mark.parametrize(
+        "shards", [1, 8], ids=["one_device", "eight_row_shards"]
+    )
+    @pytest.mark.parametrize("case", GATHER_CASES)
+    def test_same_slots_state_and_count_as_the_scatter(
+        self, table, case, shards
+    ):
+        x, w, dmin, l, rows = _gather_case(table, case)
+        amin = jnp.zeros((N,), jnp.int32)
+        rest = (jnp.asarray(BASE, jnp.int32), jax.random.PRNGKey(5),
+                jnp.asarray(l, jnp.float32))
+        want = _round_folding_every_chunk(
+            x, w, dmin, amin, *rest, cap=CAP, chunk=CHUNK
+        )
+        if shards > 1:
+            mesh = get_mesh(n_devices=shards)
+            x = jax.device_put(x, data_sharding(mesh, 2))
+            w, dmin, amin = (jax.device_put(a, data_sharding(mesh, 1))
+                             for a in (w, dmin, amin))
+        got = kmeans_ops._pll_round(
+            x, w, dmin, amin, *rest, cap=CAP, chunk=CHUNK
+        )
+        if shards > 1:
+            assert len(got[2].sharding.device_set) == shards
+            assert got[0].sharding.is_fully_replicated
+            # eight partial sums of the cost: another order, the same value
+            np.testing.assert_allclose(
+                float(got[4]), float(want[4]), rtol=1e-6
+            )
+            got = got[:4] + (want[4],) + got[5:]
+        _assert_same_bits(got, want)
+
+        slots, slot_valid, _, new_amin, phi, picks = map(np.asarray, got)
+        kept = rows[:CAP]
+        assert float(phi) > 0 and int(picks) == len(rows)
+        assert int(picks) - int((slot_valid > 0).sum()) == len(rows) - len(kept)
+        # picks fill the slots from 0 in row order; the rest is zeros
+        np.testing.assert_array_equal(slots[: len(kept)], np.asarray(x)[kept])
+        assert not slots[len(kept):].any()
+        np.testing.assert_array_equal(slot_valid, np.arange(CAP) < len(kept))
+        if len(kept) == 0:  # zero fold trips: the state as given
+            np.testing.assert_array_equal(new_amin, 0)
+            np.testing.assert_array_equal(got[2], dmin)
+        else:  # a picked row is its own nearest candidate
+            np.testing.assert_array_equal(
+                new_amin[kept], BASE + np.arange(len(kept))
+            )

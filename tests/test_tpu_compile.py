@@ -104,6 +104,27 @@ def _mosaic_bodies(compiled_text, kernel):
     return bodies
 
 
+def _assert_round_gathers_its_picks(text):
+    """The compiled k-means|| round places its picks by a search of the
+    prefix and a gather: no ``scatter`` walks the table.  It holds two
+    loops, told apart by where they were traced: the search's (log2 of
+    the rows steps, inside ``searchsorted``) and the fold's, whose trip
+    count is the round's own pick count, a value read on the device, so
+    the compiler knows none."""
+    ops = [line.split(" = ", 1)[1] for line in text.splitlines()
+           if " = " in line and "scatter" in line.split("metadata=")[0]]
+    assert not ops, ops[:3]
+    loops = [
+        line for line in text.splitlines()
+        if " while(" in line and "pll_round/" in line
+        and "_uniform" not in line  # the random bits' own loop
+    ]
+    search = [line for line in loops if "searchsorted" in line]
+    fold = [line for line in loops if "pll_round/while" in line]
+    assert len(search) == 1 and len(fold) == 1 and len(loops) == 2
+    assert "known_trip_count" not in fold[0]
+
+
 def _assert_walks_to_a_bound_read_on_the_device(compiled_text, calls):
     """Every ``kmeans_accumulate_walk`` call of the program takes its trip
     count from SMEM: ONE loop whose upper bound is the loaded scalar (no
@@ -483,25 +504,17 @@ class TestDataParallelKMeans:
             cap=cap, chunk=chunk,
         ).compile()
         text = compiled.as_text()
-        assert "all-gather" not in text  # the scatter stays local + reduced
-        # the fold's trip count is the round's own pick count: a value
-        # read on the device, so the compiler knows none
-        fold = [
-            line for line in text.splitlines()
-            if " while(" in line and "pll_round/while" in line
-            and "_uniform" not in line
-        ]
-        assert len(fold) == 1 and "known_trip_count" not in fold[0]
+        # a shard's picked rows are gathered where they lie and summed
+        assert "all-gather" not in text
+        _assert_round_gathers_its_picks(text)
         mem = compiled.memory_analysis()
         on_device = self.ROWS // 4
         # a step's distance sheet is a DEVICE's rows x the slot chunk,
-        # never rows x k nor the table's rows; the scatter's table-sized
-        # operand (x * picked) is live before it, not beside it
+        # never rows x k nor the table's rows, and nothing table-sized
+        # stands beside it: the picked rows are gathered from the table
         sheet = on_device * chunk * 4
-        scatter_operand = on_device * self.D * 4
-        assert chunk < self.K
-        larger = max(sheet, scatter_operand)
-        assert larger <= mem.temp_size_in_bytes < 1.05 * larger
+        assert chunk < self.K and sheet > on_device * self.D * 4
+        assert sheet <= mem.temp_size_in_bytes < 1.05 * sheet
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < self.HBM
         return compiled
 
@@ -519,6 +532,36 @@ class TestDataParallelKMeans:
     def test_pll_round_on_one_chip(self, one_chip):
         """The one-chip cell's round: the same rows a device, no mesh."""
         self._compiled_pll_round(self.ROWS // 4, one_chip, one_chip, one_chip)
+
+    @pytest.mark.parametrize("rows_a_chip", [2097152, 4194304])
+    @pytest.mark.parametrize("chips", [1, 4])
+    def test_seed_row_distances_are_one_pass_over_the_table(
+        self, topo, mesh, chips, rows_a_chip
+    ):
+        """The k-means|| init starts from every row's distance to the
+        seed row, ``min_sq_dists`` against ONE candidate: one program that
+        reads the table once and holds no ``x * x`` beside it (run op by
+        op, that product stood whole beside the table and set the fit's
+        peak), and on the mesh every chip reads its own shard."""
+        from oap_mllib_tpu.ops import kmeans_ops
+
+        if chips == 1:
+            table = row = rep = SingleDeviceSharding(topo.devices[0])
+        else:
+            table = NamedSharding(mesh, P("data", None))
+            row, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+        compiled = kmeans_ops.min_sq_dists.lower(
+            _s((chips * rows_a_chip, self.D), table), _s((1, self.D), rep)
+        ).compile()
+        for collective in ("all-gather", "all-reduce", "collective-permute",
+                           "all-to-all"):
+            assert collective not in compiled.as_text()
+        if chips > 1:
+            assert compiled.output_shardings == row
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes < 1.01 * rows_a_chip * self.D * 4
+        assert mem.output_size_in_bytes == rows_a_chip * 4
+        assert mem.temp_size_in_bytes < MiB
 
     def test_candidate_reduction_runs_replicated(self, mesh):
         """On the host's mesh every chip reduces the same 8001 slots from
@@ -630,10 +673,11 @@ class TestListedScaleOnOneChip:
             _s((), one_chip),
             cap=cap, chunk=chunk,
         ).compile()
+        _assert_round_gathers_its_picks(compiled.as_text())
         mem = compiled.memory_analysis()
         # the table and its row vectors; the sheet of one slot chunk
-        # (rows x 500 x 4 = 8.4 GB) is the larger temporary, the
-        # table-sized scatter operand lives before it, not beside it
+        # (rows x 500 x 4 = 8.4 GB) is the temporary, and no table-sized
+        # operand beside it: the picked rows are gathered from the table
         assert self.TABLE <= mem.argument_size_in_bytes < 1.02 * self.TABLE
         sheet = self.ROWS * chunk * 4
         assert sheet <= mem.temp_size_in_bytes < 1.05 * sheet
