@@ -186,6 +186,60 @@ def _put_in_place(host: np.ndarray, sharding):
     return table, -(-host.shape[0] // step)
 
 
+def upload_arrays(hosts, sharding):
+    """The ``upload`` sub-span for host arrays that are no row table of
+    their own — ALS's grouped edge layouts, eight arrays of one fit —
+    onto ONE device: together they are several GB, and handed to
+    ``jnp.asarray`` one after another they are all in flight at once,
+    which is the host link's slow path (``_UPLOAD_PIECE_BYTES``).  Here
+    they go up under ``_put_in_place``'s bounds, shared by all of them:
+    views of at most ``_UPLOAD_PIECE_BYTES`` /
+    ``_ONE_DEVICE_PIECES_IN_FLIGHT`` along the first axis, that many in
+    flight, each written in place into its array's ONE buffer
+    (``_write_piece``, donated); an array of one piece is that piece.
+    Returns the device arrays, landed.  ``attrs["bytes"]`` is what was
+    sent, ``attrs["pieces"]`` in how many pieces, ``attrs["arrays"]`` of
+    how many arrays; the ``put`` / ``land`` / ``launch`` leaves say what
+    the host thread did meanwhile (``_upload``)."""
+    piece_bytes = _UPLOAD_PIECE_BYTES // _ONE_DEVICE_PIECES_IN_FLIGHT
+    flying = collections.deque()  # (piece, its array, its offset or None)
+    write = _write_piece()
+    out = [None] * len(hosts)
+
+    def write_oldest():
+        piece, k, lo = flying.popleft()
+        if lo is None:
+            out[k] = piece
+        else:
+            if out[k] is None:
+                out[k] = spans.launch(
+                    jnp.zeros, hosts[k].shape, piece.dtype, device=sharding
+                )
+            out[k] = spans.launch(write, out[k], piece, np.int32(lo))
+        return out[k]
+
+    with spans.child("upload") as span:
+        pieces = 0
+        for k, host in enumerate(hosts):
+            n = host.shape[0]
+            whole = host.nbytes <= piece_bytes
+            step = n if whole else max(1, piece_bytes * n // host.nbytes)
+            for lo in range(0, max(n, 1), max(step, 1)):
+                flying.append(
+                    (_put(host[lo:lo + step], sharding), k, None if whole else lo)
+                )
+                pieces += 1
+                if len(flying) == _ONE_DEVICE_PIECES_IN_FLIGHT:
+                    _land(write_oldest())
+        while flying:
+            write_oldest()
+        _land(out)
+        span.attrs["bytes"] = sum(h.nbytes for h in hosts)
+        span.attrs["pieces"] = pieces
+        span.attrs["arrays"] = len(hosts)
+    return out
+
+
 # What the cast route (``_RowBlocks``) sends at a time, and how.  Its
 # staging buffers are fresh memory, touched for the first time by the
 # cast that fills them, and a v5e host without transparent hugepages

@@ -29,6 +29,7 @@ from oap_mllib_tpu import telemetry
 from oap_mllib_tpu.fallback import als_np
 from oap_mllib_tpu.ops import als_ops
 from oap_mllib_tpu.ops.pallas import autotune
+from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import checkpoint as ckpt_mod
 from oap_mllib_tpu.utils import precision as psn
 from oap_mllib_tpu.utils import progcache
@@ -552,15 +553,13 @@ class ALS:
         from oap_mllib_tpu.utils import membudget
 
         multi = world > 1 or jax.process_count() > 1
-        # memory-budget route plan (utils/membudget.py): grouped edge
-        # layouts whose device footprint exceeds the HBM budget run the
-        # streamed (host-resident-edge) kernels instead of assuming the
-        # whole layout fits
-        plan = membudget.plan_als(
-            len(users), n_users, n_items, self.rank,
-            world=world if multi else 1,
-        )
         if multi:
+            # memory-budget route plan (utils/membudget.py); the
+            # single-device fit makes its own once it has counted the
+            # padded edges (_fit_single_device)
+            plan = membudget.plan_als(
+                len(users), n_users, n_items, self.rank, world=world,
+            )
             # distributed 2-D block layout for BOTH modes: ratings shuffled
             # by user block, X block-sharded, Y replicated (~ the
             # reference's full cShuffleData + 4-step pipeline, survey §3.3;
@@ -588,12 +587,10 @@ class ALS:
         def attempt(degraded):
             return self._fit_single_device(
                 users, items, ratings, n_users, n_items, x0, y0, degraded,
-                plan=plan,
             )
 
         model = resilience.resilient_fit("ALS", attempt, fallback, stats=stats)
         resilience.merge_stats(model.summary, stats)
-        membudget.record_plan(model.summary, plan)
         telemetry.finalize_fit(model.summary)
         return model
 
@@ -696,7 +693,7 @@ class ALS:
         return x, y
 
     def _fit_single_device(self, users, items, ratings, n_users, n_items,
-                           x0, y0, degraded=0, plan=None) -> ALSModel:
+                           x0, y0, degraded=0) -> ALSModel:
         """The single-device accelerated fit (grouped or COO layouts).
         ``degraded`` is the ladder's OOM rung level: the grouped path
         re-runs through the streamed kernels (ops/als_stream.py) at
@@ -704,16 +701,21 @@ class ALS:
         moments) HBM — which is exactly the memory-shedding retry a
         device OOM calls for; the COO path has no equivalent knob and
         re-runs unchanged (a persistent OOM then falls through to the
-        NumPy rung).  A ``plan`` routed "streamed" (the HBM budget
-        rejected the resident grouped layouts, utils/membudget.py) runs
-        the same streamed kernels from the start — the budget-driven
-        twin of the OOM rung, decided BEFORE the device ever faults."""
+        NumPy rung).  The route plan (utils/membudget.py) is made here,
+        from the padded edges the grouped build's counting pass has
+        just counted, not from a constant: routed "streamed" (the HBM
+        budget rejected the resident grouped layouts) the fit runs the
+        same streamed kernels from the start — the budget-driven twin
+        of the OOM rung, decided BEFORE the device ever faults.
+
+        Spans of ``table_convert`` (docs/observability.md): ``host_copy``
+        (ids or scores that did not come as int32 / float32 C arrays are
+        copied once; ``attrs["copied_bytes"]``, 0 for the arrays Spark
+        holds), ``group_edges`` (the counting pass, the guard, the plan
+        and both grouped builds, over ``attrs["threads"]`` host
+        threads), ``upload`` (data/table.upload_arrays)."""
         from oap_mllib_tpu.utils import membudget
 
-        planned_streamed = (
-            plan is not None
-            and plan.route == membudget.ROUTE_STREAMED
-        )
         timings = Timings("als.fit")
         cache_before = progcache.stats()
         tune_before = autotune.mark()
@@ -729,15 +731,63 @@ class ALS:
             # :184-230 / .cpp:209-213, rebuilt for batched MXU matmuls —
             # see als_ops grouped-path notes); edge indices are static
             # across iterations so the sort/pad runs once per fit.  The
-            # blowup guard runs on bincounts BEFORE any (G, P) layout is
+            # blowup guard runs on the counts BEFORE any (G, P) layout is
             # materialized (adaptive group sizing keeps typical data under
             # 2x; extreme long-tail degree splits would pad up to 8x nnz,
             # so a "coo" decision must not pay for the build).
             nnz = len(users)
             kernel = _als_kernel_cfg()
-            grouped_ok = _grouped_ok_single(
-                kernel, users, items, n_users, n_items
+            if max(n_users, n_items) > np.iinfo(np.int32).max:
+                raise ValueError(
+                    "the edge layouts hold ids as int32: n_users and "
+                    f"n_items must fit, got {n_users} and {n_items}"
+                )
+            with spans.child("host_copy") as span:
+                handed = (users, items, ratings)
+                users, items = (
+                    np.ascontiguousarray(a, dtype=np.int32)
+                    for a in (users, items)
+                )
+                ratings = np.ascontiguousarray(ratings, dtype=np.float32)
+                span.attrs["copied_bytes"] = sum(
+                    new.nbytes for new, old in
+                    zip((users, items, ratings), handed) if new is not old
+                )
+            grouped_ok = kernel == "grouped"
+            plan_kw = {}
+            # (destinations, sources, their count) of the user side, the
+            # item side
+            sides = ((users, items, n_users), (items, users, n_items))
+            if kernel != "coo":
+                with spans.child("group_edges") as span:
+                    threads = als_ops.build_threads()
+                    counts = [
+                        als_ops.count_edges(dst, n_dst, threads)
+                        for dst, _, n_dst in sides
+                    ]
+                    sizes = [
+                        als_ops.auto_group_size(nnz, n_dst)
+                        for _, _, n_dst in sides
+                    ]
+                    padded = [
+                        als_ops.padded_edges(c, p) for c, p in zip(counts, sizes)
+                    ]
+                    grouped_ok = grouped_ok or (
+                        sum(padded) <= als_ops.GROUPED_MAX_BLOWUP * max(nnz, 1)
+                    )
+                    buckets = [
+                        als_ops.group_bucket(tot // p)
+                        for tot, p in zip(padded, sizes)
+                    ]
+                    if grouped_ok:
+                        # what will be resident: the bucketed layouts
+                        plan_kw["padded_edges"] = sum(
+                            g * p for g, p in zip(buckets, sizes)
+                        )
+            plan = membudget.plan_als(
+                nnz, n_users, n_items, self.rank, world=1, **plan_kw
             )
+            planned_streamed = plan.route == membudget.ROUTE_STREAMED
             if planned_streamed and not grouped_ok:
                 # the planner routed streamed but the degree
                 # distribution forces COO (streaming is grouped-only) —
@@ -751,16 +801,36 @@ class ALS:
                 planned_streamed = False
             stream_route = bool(degraded) or planned_streamed
             if grouped_ok:
-                by_user = als_ops.build_grouped_edges(
-                    users, items, ratings, n_users
-                )
-                by_item = als_ops.build_grouped_edges(
-                    items, users, ratings, n_items
-                )
+                with spans.child("group_edges") as span:
+                    by_user, by_item = (
+                        als_ops.build_grouped_edges(
+                            dst, src, ratings, n_dst, p, counts=c,
+                            # the streamed kernels chunk the layouts
+                            # themselves: no bucket for them
+                            groups=0 if stream_route else g,
+                            threads=threads,
+                        )
+                        for (dst, src, n_dst), c, p, g in
+                        zip(sides, counts, sizes, buckets)
+                    )
+                    span.attrs.update(
+                        ratings=nnz, padded_edges_user=padded[0],
+                        padded_edges_item=padded[1], group_size=sizes,
+                        groups_user=by_user[0].shape[0],
+                        groups_item=by_item[0].shape[0],
+                        threads=int(counts[0].shape[0]),
+                    )
                 if not stream_route:
                     # the streamed route keeps the layouts HOST-resident
                     # for the streamed kernels instead of uploading both
-                    dev = tuple(jnp.asarray(a) for a in (*by_user, *by_item))
+                    from oap_mllib_tpu.data.table import upload_arrays
+
+                    dev = tuple(upload_arrays(
+                        [*by_user, *by_item],
+                        jax.sharding.SingleDeviceSharding(
+                            jax.local_devices()[0]
+                        ),
+                    ))
             else:
                 # COO nnz pads to a shape bucket (data/bucketing.py,
                 # anchored at the 2048 edge-chunk multiple): the COO
@@ -780,7 +850,12 @@ class ALS:
             "als", self._ckpt_signature(n_users, n_items), timings=timings,
             growable=self._GROWABLE,
         )
-        with phase_timer(timings, "als_iterations"), maybe_trace():
+        solve_kernel = als_ops.resolve_solve_kernel(self.rank, x0.dtype)
+        with timings.span("als_iterations") as span, maybe_trace():
+            span.attrs.update(
+                iterations=int(self.max_iter), solve_kernel=solve_kernel,
+                rank=int(self.rank), implicit=bool(self.implicit_prefs),
+            )
             if grouped_ok and stream_route:
                 from oap_mllib_tpu.ops import als_stream
 
@@ -797,7 +872,7 @@ class ALS:
                         *dev, jnp.asarray(xa), jnp.asarray(ya),
                         n_users, n_items, iters, self.reg_param,
                         self.alpha, self.implicit_prefs, timings=timings,
-                        policy=pol.name,
+                        policy=pol.name, solve_kernel=solve_kernel,
                     )
 
                 if ckpt is None:
@@ -834,11 +909,12 @@ class ALS:
                     x, y = self._run_segmented(
                         ckpt, x0, y0, run_iters, n_users, n_items
                     )
-            x = np.asarray(x)
-            y = np.asarray(y)
+            # the fit ends when both factor tables are back on the host
+            x, y = spans.fetch(lambda: (np.asarray(x), np.asarray(y)))
+        timings.root.attrs["kernel"] = "grouped" if grouped_ok else "coo"
         summary = {
             "timings": timings, "accelerated": True,
-            "als_kernel": "grouped" if grouped_ok else "coo",
+            "als_kernel": timings.root.attrs["kernel"],
             "item_layout": "replicated",
             "progcache": progcache.delta(cache_before),
             "tuning": autotune.delta(tune_before),
@@ -848,6 +924,7 @@ class ALS:
             # the OOM rung or the budget plan ran the streamed kernels
             summary["streamed"] = True
         psn.record(summary, timings, pol)
+        membudget.record_plan(summary, plan)
         if ckpt is not None:
             ckpt.record(summary)
         return ALSModel(x, y, summary)
@@ -856,9 +933,15 @@ class ALS:
     def _validate_resolve(users, items, ratings, n_users, n_items):
         """Shared triple validation + id-space resolution (array and
         streamed entries).  Multi-process: global maxima by allgather
-        (the reference's RDD max jobs, ALSDALImpl.scala:62-70)."""
-        users = np.asarray(users, dtype=np.int64)
-        items = np.asarray(items, dtype=np.int64)
+        (the reference's RDD max jobs, ALSDALImpl.scala:62-70).  Ids
+        handed over as int32 ndarrays — what Spark ML ALS holds — and
+        float32 ratings pass as they are, uncopied; anything else is
+        made int64 / float32 here."""
+        users, items = (
+            a if isinstance(a, np.ndarray) and a.dtype == np.int32
+            else np.asarray(a, dtype=np.int64)
+            for a in (users, items)
+        )
         ratings = np.asarray(ratings, dtype=np.float32)
         if not (len(users) == len(items) == len(ratings)):
             raise ValueError("users/items/ratings must have equal length")

@@ -105,6 +105,15 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.oap_als_group_edges.argtypes = [
         ctypes.POINTER(i64), ctypes.POINTER(i64), f32p, i64, i64, i64,
         i64, ctypes.POINTER(i32), f32p, f32p, ctypes.POINTER(i32)]
+    i32p, i64p = ctypes.POINTER(i32), ctypes.POINTER(i64)
+    lib.oap_als_count_range_i32.restype = i64
+    lib.oap_als_count_range_i32.argtypes = [i32p, i64, i64, i64, i32p]
+    lib.oap_als_place_range_i32.restype = i64
+    lib.oap_als_place_range_i32.argtypes = [
+        i32p, i32p, f32p, i64, i64, i64p, i32p, f32p]
+    lib.oap_als_fill_range.restype = i64
+    lib.oap_als_fill_range.argtypes = [
+        i64p, i64p, i64, i64, i64, i32p, f32p, f32p, i32p]
     return lib
 
 
@@ -417,4 +426,87 @@ def als_group_edges(
         conf_g.reshape(g, p),
         valid_g.reshape(g, p),
         group_dst,
+    )
+
+
+# -- the grouped build over host threads, int32 ids --------------------------
+# Each entry point of native/src/grouped_prep.cpp works on a range of its
+# own and allocates nothing; ctypes releases the GIL, so the ranges run on
+# a pool of Python threads.
+
+def _ptr(a: np.ndarray, ctype):
+    return a.ctypes.data_as(ctypes.POINTER(ctype))
+
+
+def _over_ranges(call, cuts) -> list:
+    """``call(k, lo, hi)`` for each range ``cuts[k]:cuts[k + 1]`` that is
+    not empty, on as many threads; their return values."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    todo = [(k, int(a), int(b)) for k, (a, b) in
+            enumerate(zip(cuts[:-1], cuts[1:])) if b > a]
+    if len(todo) <= 1:
+        return [call(*t) for t in todo]
+    with ThreadPoolExecutor(len(todo)) as pool:
+        return list(pool.map(lambda t: call(*t), todo))
+
+
+def _cuts(n: int, parts: int) -> np.ndarray:
+    return np.linspace(0, n, max(1, parts) + 1).astype(np.int64)
+
+
+def als_count_ranges(dst: np.ndarray, n_dst: int,
+                     threads: int) -> Optional[np.ndarray]:
+    """``(threads, n_dst)`` int32: how often each destination occurs in
+    each of ``threads`` equal ranges of the edges (``dst`` int32,
+    C-contiguous).  None if the native lib is unavailable; an id outside
+    [0, n_dst) raises."""
+    lib = _load()
+    if lib is None:
+        return None
+    counts = np.zeros((max(1, threads), n_dst), np.int32)
+    dp = _ptr(dst, ctypes.c_int32)
+    got = _over_ranges(
+        lambda k, lo, hi: lib.oap_als_count_range_i32(
+            dp, lo, hi, n_dst, _ptr(counts[k], ctypes.c_int32)),
+        _cuts(len(dst), threads),
+    )
+    if any(g < 0 for g in got):
+        raise ValueError("destination id out of range for grouped layout")
+    return counts
+
+
+def als_place_ranges(dst, src, conf, counts: np.ndarray, start: np.ndarray,
+                     p: int, src_g, conf_g, valid_g, group_dst) -> None:
+    """Fill the flat grouped layout from ``counts`` (``als_count_ranges``
+    of the same ``dst``) and ``start`` (n_dst + 1 padded offsets): every
+    range of edges places its own from cursors that start behind the
+    earlier ranges' edges of each destination, so edges keep their input
+    order within a destination whatever the number of ranges; ``valid``,
+    ``group_dst`` and the pad slots are written over ranges of
+    destinations.  The outputs need not come zeroed up to ``start[-1]``."""
+    lib = _load()
+    threads, n_dst = counts.shape
+    # cursor[k, d]: where range k's first edge of destination d goes
+    cursor = np.cumsum(counts, axis=0, dtype=np.int64)
+    total = cursor[-1].copy()
+    cursor -= counts
+    cursor += start[:-1]
+    dp, sp = _ptr(dst, ctypes.c_int32), _ptr(src, ctypes.c_int32)
+    cp = _ptr(conf, ctypes.c_float)
+    sg, cg = _ptr(src_g, ctypes.c_int32), _ptr(conf_g, ctypes.c_float)
+    _over_ranges(
+        lambda k, lo, hi: lib.oap_als_place_range_i32(
+            dp, sp, cp, lo, hi, _ptr(cursor[k], ctypes.c_int64), sg, cg),
+        _cuts(len(dst), threads),
+    )
+    stp, tp = _ptr(start, ctypes.c_int64), _ptr(total, ctypes.c_int64)
+    vg, gd = _ptr(valid_g, ctypes.c_float), _ptr(group_dst, ctypes.c_int32)
+    # destination ranges of about equal slots, not equal destinations
+    d_cuts = np.searchsorted(start, _cuts(int(start[-1]), threads))
+    d_cuts[0], d_cuts[-1] = 0, n_dst
+    _over_ranges(
+        lambda k, lo, hi: lib.oap_als_fill_range(
+            stp, tp, lo, hi, p, sg, cg, vg, gd),
+        d_cuts,
     )

@@ -105,4 +105,66 @@ int64_t oap_als_group_edges(const int64_t* dst, const int64_t* src,
   }
 }
 
+// -- the build over host threads, on int32 ids ------------------------------
+// Spark ML ALS holds ids as Int, and a table of 10^8 ratings built by one
+// thread costs tens of seconds.  The three entry points below are called
+// by ops/als_ops.build_grouped_edges from several Python threads at once
+// (ctypes releases the GIL), each on a range of its own: a counting pass
+// over a range of EDGES into that range's own counts, the placement of
+// the same range from cursors that start where the earlier ranges'
+// edges of each destination end (so edges keep their input order within
+// a destination: the layout is the one-thread build's bit for bit), and
+// the fill of `valid`, `group_dst` and the pad slots over a range of
+// DESTINATIONS.  No entry point allocates.
+
+// counts[d] += 1 for every edge of [lo, hi); -1 on an id outside
+// [0, n_dst).  `counts` is the range's own, zeroed by the caller.
+int64_t oap_als_count_range_i32(const int32_t* dst, int64_t lo, int64_t hi,
+                                int64_t n_dst, int32_t* counts) {
+  if (n_dst <= 0 || lo < 0 || hi < lo) return -1;
+  for (int64_t e = lo; e < hi; ++e) {
+    int32_t d = dst[e];
+    if (d < 0 || d >= n_dst) return -1;
+    counts[d]++;
+  }
+  return hi - lo;
+}
+
+// src_g / conf_g at cursor[dst[e]]++ for every edge of [lo, hi);
+// `cursor` is the range's own (n_dst slots) and is consumed.
+int64_t oap_als_place_range_i32(const int32_t* dst, const int32_t* src,
+                                const float* conf, int64_t lo, int64_t hi,
+                                int64_t* cursor, int32_t* src_g,
+                                float* conf_g) {
+  for (int64_t e = lo; e < hi; ++e) {
+    int64_t slot = cursor[dst[e]]++;
+    src_g[slot] = src[e];
+    conf_g[slot] = conf[e];
+  }
+  return hi - lo;
+}
+
+// For destinations [d_lo, d_hi): valid = 1 on each one's first counts[d]
+// slots from start[d] and 0 on its pad slots, whose src and conf are
+// zeroed too (the outputs need not come zeroed), and its groups' entries
+// of group_dst.  start[d_hi] is read: `start` has n_dst + 1 entries.
+int64_t oap_als_fill_range(const int64_t* start, const int64_t* counts,
+                           int64_t d_lo, int64_t d_hi, int64_t P,
+                           int32_t* src_g, float* conf_g, float* valid_g,
+                           int32_t* group_dst) {
+  if (P <= 0 || d_lo < 0 || d_hi < d_lo) return -1;
+  for (int64_t d = d_lo; d < d_hi; ++d) {
+    int64_t s = start[d], live = s + counts[d], end = start[d + 1];
+    for (int64_t k = s; k < live; ++k) valid_g[k] = 1.0f;
+    for (int64_t k = live; k < end; ++k) {
+      valid_g[k] = 0.0f;
+      src_g[k] = 0;
+      conf_g[k] = 0.0f;
+    }
+    for (int64_t g = s / P; g < end / P; ++g)
+      group_dst[g] = static_cast<int32_t>(d);
+  }
+  return d_hi - d_lo;
+}
+
 }  // extern "C"

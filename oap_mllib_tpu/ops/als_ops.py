@@ -359,12 +359,86 @@ def auto_group_size(nnz: int, n_dst: int) -> int:
     return int(max(8, min(256, 2 ** int(np.ceil(np.log2(mean_deg))))))
 
 
+_BUILD_THREADS_MAX = 12
+
+
+def build_threads() -> int:
+    """Host threads the grouped build spreads over: the cores this
+    process may run on, at most ``_BUILD_THREADS_MAX`` (the build is
+    bound by the host's memory well before that many)."""
+    import os
+
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:  # not Linux
+        cores = os.cpu_count() or 1
+    return max(1, min(_BUILD_THREADS_MAX, cores))
+
+
+def count_edges(dst, n_dst: int, threads: int = 0):
+    """``(ranges, n_dst)`` int32: how often each destination occurs in
+    each of ``ranges`` equal ranges of the edges — the one counting pass
+    the blowup guard, the route plan and :func:`build_grouped_edges` all
+    read (``counts=``).  ``threads`` ranges on the native path (0 = the
+    cores at hand), counted on as many host threads from int32 ids as
+    they are; one range by ``np.bincount`` elsewhere.  An id outside
+    [0, n_dst) raises on either path."""
+    import numpy as np
+
+    from oap_mllib_tpu.data.io import _force_py
+
+    dst = np.ascontiguousarray(dst, dtype=np.int32)
+    if not _force_py() and n_dst > 0 and len(dst):
+        from oap_mllib_tpu import native
+
+        counts = native.als_count_ranges(
+            dst, n_dst, threads or build_threads()
+        )
+        if counts is not None:
+            return counts
+    if len(dst) and (dst.min() < 0 or dst.max() >= n_dst):
+        raise ValueError("destination id out of range for grouped layout")
+    return np.bincount(dst, minlength=n_dst).astype(np.int32)[None, :]
+
+
+def padded_edges(counts, group_size: int) -> int:
+    """Slots the grouped layout takes for these :func:`count_edges`
+    counts: every destination's edges rounded up to whole groups."""
+    import numpy as np
+
+    per_dst = counts.sum(axis=0, dtype=np.int64)
+    return int((-(per_dst // -group_size) * group_size).sum())
+
+
+def group_bucket(groups: int) -> int:
+    """The group count a grouped side is padded to: ``groups`` on the
+    row-bucket series of data/bucketing.py (x2 steps by default,
+    ``Config.shape_bucketing``).  ``als.run_grouped`` is keyed on
+    ``(G, P)`` and ``G`` follows the data, so without it every new table
+    — a refit with a few more ratings — compiles anew.  Pad groups carry
+    ``valid = 0`` and the last destination's id; the program walks only
+    as far as the live groups reach (``normal_eq_partials_grouped``)."""
+    from oap_mllib_tpu.data.bucketing import bucket_rows
+
+    return bucket_rows(groups, _GROUP_BUCKET_MULTIPLE)
+
+
+# anchor of the group-count buckets: a power of two, so that a bucketed
+# side splits into its power-of-two block count with no remainder (a
+# remainder is padded INSIDE the program: a second copy of the layout)
+_GROUP_BUCKET_MULTIPLE = 256
+
+
 def build_grouped_edges(
     dst: "np.ndarray",
     src: "np.ndarray",
     conf: "np.ndarray",
     n_dst: int,
     group_size: int = 0,
+    *,
+    counts=None,
+    groups: int = 0,
+    threads: int = 0,
 ):
     """Host-side one-time prep: sort edges by ``dst`` and pad each dst's
     edge list to a multiple of ``group_size`` (0 = auto-size from the
@@ -373,23 +447,52 @@ def build_grouped_edges(
     Returns (src_g (G, P) int32, conf_g (G, P) f32, valid_g (G, P) f32,
     group_dst (G,) int32).  Padding entries carry src=0, valid=0 so they
     vanish from every weighted sum.  ~1.2x edge blowup at P=64 on
-    MovieLens-like degree distributions.
+    MovieLens-like degree distributions.  ``groups`` > the groups the
+    edges fill pads ``G`` up to it (:func:`group_bucket`): pad groups are
+    all zeros and carry the last destination's id, so ``group_dst`` stays
+    sorted.
 
     Prefers the native stable counting sort (O(nnz + n_dst),
     native/src/grouped_prep.cpp — the reference's host-side CSR prep
-    analog, ALSDALImpl.cpp:184-230) over the NumPy argsort path.
+    analog, ALSDALImpl.cpp:184-230) over the NumPy argsort path: int32
+    ids as Spark holds them (other dtypes are cast once), counted and
+    placed over ``threads`` host threads (0 = the cores at hand), each
+    on a range of the edges — the same layout bit for bit whatever their
+    number.  ``counts``: :func:`count_edges` of the same ``dst``, where
+    the caller has counted already.
     """
     import numpy as np
 
     from oap_mllib_tpu.data.io import _force_py
 
     P = group_size or auto_group_size(len(dst), n_dst)
-    if not _force_py():
+    native_ok = False
+    if not _force_py() and n_dst > 0 and len(dst):
         from oap_mllib_tpu import native
 
-        built = native.als_group_edges(dst, src, conf, n_dst, P)
-        if built is not None:
-            return built
+        native_ok = native.available()
+    if native_ok:
+        dst = np.ascontiguousarray(dst, dtype=np.int32)
+        src = np.ascontiguousarray(src, dtype=np.int32)
+        conf = np.ascontiguousarray(conf, dtype=np.float32)
+        if counts is None:
+            counts = count_edges(dst, n_dst, threads)
+        per_dst = counts.sum(axis=0, dtype=np.int64)
+        start = np.zeros(n_dst + 1, np.int64)
+        np.cumsum(-(per_dst // -P) * P, out=start[1:])
+        total = int(start[-1])
+        G = max(total // P, groups)
+        # zeros, not empty: the pad groups that close a bucket are pages
+        # the host never touches
+        src_g = np.zeros((G, P), np.int32)
+        conf_g = np.zeros((G, P), np.float32)
+        valid_g = np.zeros((G, P), np.float32)
+        group_dst = np.full((G,), max(n_dst - 1, 0), np.int32)
+        native.als_place_ranges(
+            dst, src, conf, counts, start, P, src_g.reshape(-1),
+            conf_g.reshape(-1), valid_g.reshape(-1), group_dst,
+        )
+        return src_g, conf_g, valid_g, group_dst
     dst = np.asarray(dst, np.int64)
     order = np.argsort(dst, kind="stable")
     d = dst[order]
@@ -399,14 +502,17 @@ def build_grouped_edges(
     first = np.concatenate([[0], np.cumsum(counts)])[:-1]
     slot = starts[d] + (np.arange(len(d)) - first[d])
     total = int(padded.sum())
-    src_g = np.zeros(total, np.int32)
-    conf_g = np.zeros(total, np.float32)
-    valid_g = np.zeros(total, np.float32)
+    G = max(total // P, groups)
+    src_g = np.zeros(G * P, np.int32)
+    conf_g = np.zeros(G * P, np.float32)
+    valid_g = np.zeros(G * P, np.float32)
     src_g[slot] = np.asarray(src, np.int32)[order]
     conf_g[slot] = np.asarray(conf, np.float32)[order]
     valid_g[slot] = 1.0
-    group_dst = np.repeat(np.arange(n_dst, dtype=np.int32), padded // P)
-    G = total // P
+    group_dst = np.full(G, max(n_dst - 1, 0), np.int32)
+    group_dst[: total // P] = np.repeat(
+        np.arange(n_dst, dtype=np.int32), padded // P
+    )
     return (
         src_g.reshape(G, P),
         conf_g.reshape(G, P),
@@ -488,9 +594,17 @@ def normal_eq_partials_grouped(
     alpha: float,
     implicit: bool,
     policy: str = "f32",
+    live_groups=None,
 ):
     """Scatter-free normal-equation partials: same math and Spark-parity
     weighting as :func:`normal_eq_partials`, grouped-edge layout.
+
+    ``live_groups`` (a traced int32 scalar, :func:`live_group_count`):
+    the groups that hold an edge, where ``G`` was padded up to its
+    bucket (:func:`group_bucket`).  The walk over group blocks then
+    stops behind the block that holds the last of them, so a side pays
+    for the groups it has, not for its bucket; the pad groups it still
+    meets in that block add exact zeros.
 
     Layout note: every (…, G, P) intermediate keeps the big static group
     width P on the minor (128-lane) axis — gathering ``(G, P, r)`` with
@@ -500,9 +614,10 @@ def normal_eq_partials_grouped(
     factor table and the batched matmul contracts the lane axis.
 
     Sides whose (r, G, P) intermediates exceed ``_GROUPED_BUDGET_ELEMS``
-    are processed as a ``lax.scan`` over group blocks, accumulating the
-    per-destination moments in a flat (n_dst, (r+1)*(r+2)) carry (flat so
-    the carry pads to lane tiles once, not per (r+1, r+2) matrix).
+    are processed as a loop over group blocks that keeps every group's
+    flat ((r+1)*(r+2),) moments (flat so they pad to lane tiles once,
+    not per (r+1, r+2) matrix) and folds them by destination in ONE
+    segment-sum at the end.
 
     Returns (a_part (n_dst, r, r), b (n_dst, r), n_reg (n_dst,)).
     """
@@ -531,26 +646,40 @@ def normal_eq_partials_grouped(
     gd_p = jnp.pad(group_dst, (0, pad), constant_values=n_dst - 1)
     width = (r + 1) * (r + 2)
 
-    def step(M_flat, blk):
-        src_b, conf_b, valid_b, gd_b = blk
-        m = block_moments(src_b, conf_b, valid_b).reshape(gb, width)
-        return (
-            M_flat
-            + jax.ops.segment_sum(
-                m, gd_b, num_segments=n_dst, indices_are_sorted=True
-            ),
-            None,
-        )
+    # Every block's group moments are kept, and ONE segment-sum folds all
+    # of them by destination at the end: XLA:TPU's scatter copies its
+    # whole operand a call, so a (n_dst, width) carry scattered into once
+    # a block cost 1.7 ms a block of 4096 groups at 625k destinations —
+    # 0.45 s a half-update, thirty times the scatter itself (PERF.md
+    # section 6, PR 38).  The sheet of all groups' moments is G x width
+    # floats (lane-padded: 1 GB at the cell's 2^20 groups).
+    def moments_of(blk):
+        src_b, conf_b, valid_b = blk
+        return block_moments(src_b, conf_b, valid_b).reshape(gb, width)
 
-    M_flat, _ = lax.scan(
-        step,
-        jnp.zeros((n_dst, width), src_factors.dtype),
-        (
-            src_p.reshape(blocks, gb, P),
-            conf_p.reshape(blocks, gb, P),
-            valid_p.reshape(blocks, gb, P),
-            gd_p.reshape(blocks, gb),
-        ),
+    blocked = (
+        src_p.reshape(blocks, gb, P),
+        conf_p.reshape(blocks, gb, P),
+        valid_p.reshape(blocks, gb, P),
+    )
+    if live_groups is None:
+        _, sheet = lax.scan(lambda c, blk: (c, moments_of(blk)), 0, blocked)
+    else:
+        sheet = lax.fori_loop(
+            0, -(live_groups // -gb),
+            lambda k, out: lax.dynamic_update_index_in_dim(
+                out,
+                moments_of(tuple(
+                    lax.dynamic_index_in_dim(a, k, keepdims=False)
+                    for a in blocked
+                )),
+                k, 0,
+            ),
+            jnp.zeros((blocks, gb, width), src_factors.dtype),
+        )
+    M_flat = jax.ops.segment_sum(
+        sheet.reshape(blocks * gb, width), gd_p, num_segments=n_dst,
+        indices_are_sorted=True,
     )
     M = M_flat.reshape(n_dst, r + 1, r + 2)
     return M[:, :r, :r], M[:, :r, r], M[:, r, r + 1]
@@ -581,11 +710,14 @@ def _als_run_grouped_jit(
 ) -> Tuple[jax.Array, jax.Array]:
     r = x0.shape[1]
     eye = jnp.eye(r, dtype=x0.dtype)
+    # read once a program, before the loop: where each side's bucket of
+    # groups stops holding edges
+    u_live, i_live = live_group_count(u_valid_g), live_group_count(i_valid_g)
 
-    def half(src_g, conf_g, valid_g, group_dst, factors, n_dst):
+    def half(src_g, conf_g, valid_g, group_dst, factors, n_dst, live):
         a, b, n_reg = normal_eq_partials_grouped(
             src_g, conf_g, valid_g, group_dst, factors, n_dst, alpha,
-            implicit, policy,
+            implicit, policy, live,
         )
         gram = (
             _factor_gram(factors, solve_kernel, gram_geo)
@@ -597,12 +729,23 @@ def _als_run_grouped_jit(
 
     def body(carry, _):
         x, y = carry
-        x = half(u_src_g, u_conf_g, u_valid_g, u_group_dst, y, n_users)
-        y = half(i_src_g, i_conf_g, i_valid_g, i_group_dst, x, n_items)
+        x = half(u_src_g, u_conf_g, u_valid_g, u_group_dst, y, n_users,
+                 u_live)
+        y = half(i_src_g, i_conf_g, i_valid_g, i_group_dst, x, n_items,
+                 i_live)
         return (x, y), None
 
     (x, y), _ = lax.scan(body, (x0, y0), None, length=max_iter)
     return x, y
+
+
+def live_group_count(valid_g: jax.Array) -> jax.Array:
+    """Groups of a grouped side that hold an edge, read on the device: a
+    group's slots fill from its first, and :func:`build_grouped_edges`
+    makes no group without an edge, so the live groups are those whose
+    first slot is valid — and they come first (pad groups close the
+    bucket)."""
+    return jnp.sum(valid_g[:, 0] > 0, dtype=jnp.int32)
 
 
 def als_run_grouped(

@@ -615,17 +615,24 @@ _ALS_BLOWUP = 2.0
 
 def plan_als(nnz: int, n_users: int, n_items: int, rank: int, *,
              world: int = 1,
-             source_backing: Optional[str] = None) -> RoutePlan:
+             source_backing: Optional[str] = None,
+             padded_edges: Optional[int] = None) -> RoutePlan:
     """Route plan for one ALS fit.  Candidates: the fully-resident
     grouped/COO layouts (in-memory), host-resident edges with chunked
     uploads (streamed), and the mesh-composed streamed block layout
     (streamed-block, world > 1 — per-rank layouts shrink world-fold).
     Source inputs keep host O(nnz) on every route (the triples ingest
     to host arrays, like the reference's executor partitions) — the
-    streamed property is DEVICE memory."""
+    streamed property is DEVICE memory.  ``padded_edges``: the slots of
+    BOTH grouped sides where the fit has counted them already (the
+    single-device fit, after its counting pass); elsewhere the edges
+    are priced at ``_ALS_BLOWUP`` times the ratings a side."""
     b = 4  # ALS is f32 like the reference
     factors = (n_users + n_items) * rank * b
-    edges = int(2 * nnz * _ALS_EDGE_BYTES * _ALS_BLOWUP)
+    edges = (
+        int(2 * nnz * _ALS_EDGE_BYTES * _ALS_BLOWUP) if padded_edges is None
+        else int(padded_edges) * _ALS_EDGE_BYTES
+    )
     moments = (n_users + n_items) * rank * (rank + 1) * b
     host_edges = edges + 3 * nnz * 8  # grouped layouts + the id triples
     upload = 64 << 20  # bounded per-step group-chunk upload

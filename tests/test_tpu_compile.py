@@ -722,3 +722,107 @@ class TestListedScaleOnOneChip:
         piece = piece_rows * self.D * 4
         assert self._held(compiled) <= self.TABLE + 2 * piece + 2**20
         assert self._held(compiled) < self.HBM
+
+
+class TestALSCellOnOneChip:
+    """``als_implicit_r10_kddcup11``: one user block of the KDD-Cup'11
+    table, 500,495 users x 624,961 items, both grouped sides on the 2^20
+    group bucket at P = 256 (822k and 928k live groups), rank 10, five
+    iterations.  The programs of the fit's ``als_iterations`` for one
+    described v5e, with what ``memory_analysis`` says they hold."""
+
+    USERS, ITEMS, RANK, P, ITERS = 500495, 624961, 10, 256, 5
+    G = 1 << 20
+    HBM = 15.75 * 2**30
+    LAYOUTS = 2 * (G * P * 12 + G * 4)
+
+    def _side(self, sharding):
+        i32 = jax.ShapeDtypeStruct((self.G, self.P), jnp.int32, sharding=sharding)
+        return (i32, _s((self.G, self.P), sharding), _s((self.G, self.P), sharding),
+                jax.ShapeDtypeStruct((self.G,), jnp.int32, sharding=sharding))
+
+    @staticmethod
+    def _lane_padded(text, rows):
+        """float32 arrays of the compiled program whose minor dimension is
+        under 32 (so padded to the 128 lanes of a tile) over at least
+        ``rows`` rows: the gather of a block's edges with the rank minor
+        (``als_ops.py``'s note on the 21 GB gather) would be one."""
+        import re
+
+        found = set()
+        for dims, order in re.findall(r"f32\[([\d,]+)\]\{([\d,]+)", text):
+            dims = [int(d) for d in dims.split(",")]
+            minor = dims[int(order.split(",")[0])]
+            if minor < 32 and int(np.prod(dims)) // minor >= rows:
+                found.add((tuple(dims), order))
+        return found
+
+    def test_the_bucket_is_the_cells(self):
+        from oap_mllib_tpu.ops import als_ops
+
+        assert als_ops.group_bucket(822_501) == als_ops.group_bucket(927_730) == self.G
+        assert als_ops.auto_group_size(126_400_138, self.USERS) == self.P
+        assert als_ops.auto_group_size(126_400_138, self.ITEMS) == self.P
+
+    def test_moments_of_a_side(self, one_chip):
+        from oap_mllib_tpu.ops import als_ops
+
+        def moments(src, conf, valid, group_dst, factors):
+            return als_ops.normal_eq_partials_grouped(
+                src, conf, valid, group_dst, factors, self.ITEMS, 40.0, True,
+                "f32", als_ops.live_group_count(valid),
+            )
+
+        compiled = jax.jit(moments).lower(
+            *self._side(one_chip), _s((self.USERS, self.RANK), one_chip)
+        ).compile()
+        text = compiled.as_text()
+        blocks = als_ops._grouped_block_count(self.G, self.P, self.RANK)
+        assert self.G % blocks == 0  # no remainder: no padded copy of a layout
+        # a block's gather has the rank minor (512 MB of lanes); the whole
+        # side's never
+        assert self._lane_padded(text, self.G // blocks * self.P)
+        assert not self._lane_padded(text, 2 * self.G // blocks * self.P)
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes < 1.01 * self.LAYOUTS / 2
+        assert mem.temp_size_in_bytes < 2 * 2**30
+
+    def test_solve_kernel(self, one_chip):
+        from oap_mllib_tpu.ops import als_ops
+
+        r = self.RANK
+        solve_geo, _ = als_ops._tuned_geometry(r, "pallas", True)
+
+        def solve(a, b, n_reg, gram):
+            return als_ops.regularized_solve(
+                a, b, n_reg, 0.1, jnp.eye(r, dtype=F32), gram, "pallas", solve_geo)
+
+        compiled = _compile(
+            solve, _s((self.ITEMS, r, r), one_chip), _s((self.ITEMS, r), one_chip),
+            _s((self.ITEMS,), one_chip), _s((r, r), one_chip),
+        )
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+    def test_the_whole_run_grouped_program(self, one_chip):
+        from oap_mllib_tpu.ops import als_ops
+
+        r = self.RANK
+        solve_geo, gram_geo = als_ops._tuned_geometry(r, "pallas", True)
+        compiled = als_ops._als_run_grouped_jit.lower(
+            *self._side(one_chip), *self._side(one_chip),
+            _s((self.USERS, r), one_chip), _s((self.ITEMS, r), one_chip),
+            n_users=self.USERS, n_items=self.ITEMS, max_iter=self.ITERS,
+            reg=0.1, alpha=40.0, implicit=True, policy="f32",
+            solve_kernel="pallas", solve_geo=solve_geo, gram_geo=gram_geo,
+        ).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text  # the fused solve and the Gram walk
+        blocks = als_ops._grouped_block_count(self.G, self.P, r)
+        assert not self._lane_padded(text, 2 * self.G // blocks * self.P)
+        mem = compiled.memory_analysis()
+        # both layouts once (no copy of one padded to its blocks) and the
+        # initial factors
+        assert mem.argument_size_in_bytes < 1.02 * self.LAYOUTS
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes) < self.HBM
+        assert mem.temp_size_in_bytes < 2 * 2**30
