@@ -765,10 +765,14 @@ class ALS:
                         als_ops.count_edges(dst, n_dst, threads)
                         for dst, _, n_dst in sides
                     ]
-                    sizes = [
-                        als_ops.auto_group_size(nnz, n_dst)
-                        for _, _, n_dst in sides
-                    ]
+                    # the widths follow the counted degrees, within what
+                    # the plan can hold resident (layouts + sheet)
+                    sizes = als_ops.group_sizes_for(
+                        counts, self.rank,
+                        membudget.als_grouped_room(
+                            n_users, n_items, self.rank
+                        ),
+                    )
                     padded = [
                         als_ops.padded_edges(c, p) for c, p in zip(counts, sizes)
                     ]
@@ -781,9 +785,7 @@ class ALS:
                     ]
                     if grouped_ok:
                         # what will be resident: the bucketed layouts
-                        plan_kw["padded_edges"] = sum(
-                            g * p for g, p in zip(buckets, sizes)
-                        )
+                        plan_kw["grouped"] = list(zip(buckets, sizes))
             plan = membudget.plan_als(
                 nnz, n_users, n_items, self.rank, world=1, **plan_kw
             )
@@ -816,8 +818,18 @@ class ALS:
                     span.attrs.update(
                         ratings=nnz, padded_edges_user=padded[0],
                         padded_edges_item=padded[1], group_size=sizes,
+                        # what the mean degree alone would have chosen
+                        group_size_by_mean=[
+                            als_ops.auto_group_size(nnz, n_dst)
+                            for _, _, n_dst in sides
+                        ],
                         groups_user=by_user[0].shape[0],
                         groups_item=by_item[0].shape[0],
+                        # of the wider side; the streamed kernels hold none
+                        sheet_bytes=0 if stream_route
+                        else membudget.als_sheet_bytes(
+                            max(buckets), self.rank
+                        ),
                         threads=int(counts[0].shape[0]),
                     )
                 if not stream_route:

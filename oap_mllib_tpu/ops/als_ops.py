@@ -216,7 +216,12 @@ def masked_solve(a: jax.Array, b: jax.Array, deg: jax.Array) -> jax.Array:
 # scatter anywhere in the half-iteration partials, which the TPU executes
 # far slower than batched matmuls.  This is the reference's blocked-CSR idea
 # (ALSDALImpl.scala:184-230 builds per-rank CSR precisely so oneDAL can
-# batch row solves) rebuilt for the MXU.
+# batch row solves) rebuilt for the MXU.  The width P is the layout's one
+# free choice: every slot, pad or not, is gathered and multiplied, so a
+# side is as fast as it has few slots, and every halving of P doubles the
+# groups.  The single-device fit picks it from the degrees it has counted
+# (group_sizes_for); callers without counts take the mean's
+# (auto_group_size).
 
 
 # Guard shared by the single-device and block-parallel dispatchers: the
@@ -344,15 +349,15 @@ def grouped_padded_edges(dst, n_dst: int, group_size: int = 0) -> int:
 
 
 def auto_group_size(nnz: int, n_dst: int) -> int:
-    """Group size adapted to the mean degree so padding stays bounded:
-    the next power of two ABOVE the mean degree keeps total padded edges
-    <= nnz + n_dst*P < 3*nnz, and larger P is faster — fewer groups
-    shrink the (G, r+1, r+2) segment-sum and deepen the per-group
-    (P)-contraction on the MXU.  Capped at 256: past it the padding a
-    larger P adds costs more than the deeper contraction returns.
-    Long-tail distributions (millions of destinations with ~2 ratings
-    each) still get small P; the caller's COO fallback guard handles the
-    blowup cases anyway."""
+    """Group size for callers that have no per-destination counts (the
+    block routes' per-block layouts, ops/als_block.py; ``group_size=0``
+    of :func:`build_grouped_edges` / :func:`grouped_padded_edges`): the
+    next power of two ABOVE the mean degree, capped at 256, which keeps
+    total padded edges <= nnz + n_dst*P < 3*nnz.  It never looks at the
+    degrees: on a heavy tail most destinations sit far under the mean and
+    carry one group that is mostly pad.  The single-device fit, which has
+    counted, asks :func:`group_sizes_for` and reports this value beside
+    its choice (``group_size_by_mean`` of the ``group_edges`` span)."""
     import numpy as np
 
     mean_deg = max(1.0, nnz / max(1, n_dst))
@@ -410,6 +415,76 @@ def padded_edges(counts, group_size: int) -> int:
     return int((-(per_dst // -group_size) * group_size).sum())
 
 
+# the widths a grouped side may take, widest first (ties go to the wider)
+_GROUP_SIZES = (256, 128, 64, 32, 16, 8)
+
+# What the width of a grouped side moves in one half-update, in ns on a
+# v5e.  Measured once, at the KDD-Cup'11 cell's shape (500,495 x 624,961,
+# 126.4M ratings, rank 10), by cycling P = 256 / 128 / 64 / 32 over both
+# sides' built layouts in one process: the three terms and a constant a
+# side reproduce all eight half-updates (0.89 ... 1.26 s) within 0.15%
+# (PERF.md section 6, PR 39).
+# - a slot, pad or not: the factor-row gather, ten floats at about four
+#   cycles an index whatever the bytes;
+_SLOT_NS = 4.6
+# - a slot of a group's row as the moment products and layout copies
+#   hold it, padded to the 128 lanes: below P = 128 a group costs 128;
+_LANE_SLOT_NS = 0.53
+# - a group of the BUCKET, live or pad: the sheet of group moments is
+#   zeroed, copied and segment-summed whole (the live groups alone fit a
+#   term of their own at -6 ns: none).
+_BUCKET_GROUP_NS = 16.0
+
+
+def group_sizes_for(counts, r: int, room=None):
+    """The group width ``P`` of each grouped side, from the degrees the
+    fit has counted: ``counts`` holds one :func:`count_edges` a side.
+    Every width of ``_GROUP_SIZES`` is priced from the groups it would
+    make of these degrees (one pass over ``n_dst`` integers),
+
+        groups * (P * _SLOT_NS + max(P, 128) * _LANE_SLOT_NS)
+        + group_bucket(groups) * _BUCKET_GROUP_NS,
+
+    and the cheapest set of widths wins, the wider on a tie: a side
+    whose destinations all have about 250 edges keeps 256, a heavy tail
+    gets what its tail wants.  ``room`` (bytes,
+    ``membudget.als_grouped_room``; None = unbounded) is what the
+    resident route can give the sides' bucketed layouts and the sheet of
+    group moments of the side with more groups
+    (``membudget.als_grouped_bytes``): a set of widths that needs more
+    is no candidate — the sheet doubles with every halving of ``P``, and
+    a row of fewer than 128 slots still takes 128 lanes — unless none
+    fits, and then the streamed route, which holds neither, runs the
+    cheapest."""
+    import itertools
+
+    import numpy as np
+
+    from oap_mllib_tpu.utils.membudget import als_grouped_bytes
+
+    priced = []  # a side: (ns, P, bucketed G) of every width
+    for side in counts:
+        per_dst = side.sum(axis=0, dtype=np.int64)
+        options = []
+        for p in _GROUP_SIZES:
+            groups = int((-(per_dst // -p)).sum())
+            bucket = group_bucket(groups)
+            ns = groups * (
+                p * _SLOT_NS + max(p, 128) * _LANE_SLOT_NS
+            ) + bucket * _BUCKET_GROUP_NS
+            options.append((ns, p, bucket))
+        priced.append(options)
+    choices = list(itertools.product(*priced))  # widest first
+    fitting = [
+        c for c in choices
+        if room is None
+        or als_grouped_bytes([(g, p) for _, p, g in c], r) <= room
+    ]
+    # min keeps the first of equals: the wider
+    chosen = min(fitting or choices, key=lambda c: sum(ns for ns, _, _ in c))
+    return [p for _, p, _ in chosen]
+
+
 def group_bucket(groups: int) -> int:
     """The group count a grouped side is padded to: ``groups`` on the
     row-bucket series of data/bucketing.py (x2 steps by default,
@@ -441,8 +516,9 @@ def build_grouped_edges(
     threads: int = 0,
 ):
     """Host-side one-time prep: sort edges by ``dst`` and pad each dst's
-    edge list to a multiple of ``group_size`` (0 = auto-size from the
-    mean degree, see :func:`auto_group_size`).
+    edge list to a multiple of ``group_size`` — the single-device fit's
+    comes from the counted degrees (:func:`group_sizes_for`); 0 = from
+    the mean degree, for callers without counts (:func:`auto_group_size`).
 
     Returns (src_g (G, P) int32, conf_g (G, P) f32, valid_g (G, P) f32,
     group_dst (G,) int32).  Padding entries carry src=0, valid=0 so they

@@ -43,7 +43,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from oap_mllib_tpu.config import get_config
 from oap_mllib_tpu.telemetry import metrics as _tm
@@ -613,28 +613,78 @@ _ALS_EDGE_BYTES = 12
 _ALS_BLOWUP = 2.0
 
 
+def als_sheet_bytes(groups: int, rank: int) -> int:
+    """The sheet of group moments one grouped half-update holds
+    (``ops/als_ops.normal_eq_partials_grouped``), ``(r+1)(r+2)`` floats a
+    group of the side's bucket, in the two forms XLA:TPU keeps of it:
+    groups-minor while the walk fills it (the width rounded up to 8
+    sublanes) and the row-major copy the segment-sum reads (the width
+    padded to whole 128-lane rows) — 544 + 1024 B a group at rank 10,
+    both live at the copy (compiled for a v5e:
+    tests/test_tpu_compile.py).  It doubles with every halving of the
+    group width."""
+    width = (rank + 1) * (rank + 2)
+    return groups * (-(-width // 8) * 8 + -(-width // 128) * 128) * 4
+
+
+def als_grouped_bytes(layouts: Sequence[Tuple[int, int]], rank: int) -> int:
+    """Device bytes of the resident grouped route's data-shaped part:
+    every side's ``(G, P)`` layout at ``_ALS_EDGE_BYTES`` a slot — of the
+    128 lanes a row of fewer slots takes in the program (compiled for a
+    v5e, a ``(4194304, 64)`` array arrives compact, groups minor, and
+    the program keeps a row-major copy of it of 2 GB, not 1; priced as
+    if every array of a narrow side were held so) — and the sheet of the
+    side with the most groups (one half-update runs at a time)."""
+    return sum(
+        g * max(p, 128) for g, p in layouts
+    ) * _ALS_EDGE_BYTES + als_sheet_bytes(
+        max((g for g, _ in layouts), default=0), rank
+    )
+
+
+def _als_fixed_bytes(n_users: int, n_items: int, rank: int):
+    """(factors, moments): one generation of both factor tables and the
+    destinations' moments — what an ALS fit holds on the device whatever
+    its edges' layout (the resident routes keep three generations)."""
+    b = 4  # ALS is f32 like the reference
+    return (
+        (n_users + n_items) * rank * b,
+        (n_users + n_items) * rank * (rank + 1) * b,
+    )
+
+
+def als_grouped_room(n_users: int, n_items: int, rank: int) -> Optional[int]:
+    """Bytes the in-memory estimate of :func:`plan_als` leaves for
+    :func:`als_grouped_bytes` under the HBM budget; None = unbounded."""
+    hbm = Budgets.resolve().hbm
+    if hbm <= 0:
+        return None
+    factors, moments = _als_fixed_bytes(n_users, n_items, rank)
+    return int(hbm / _OVERHEAD) - 3 * factors - moments - _PROGRAM_BYTES
+
+
 def plan_als(nnz: int, n_users: int, n_items: int, rank: int, *,
              world: int = 1,
              source_backing: Optional[str] = None,
-             padded_edges: Optional[int] = None) -> RoutePlan:
+             grouped: Optional[Sequence[Tuple[int, int]]] = None) -> RoutePlan:
     """Route plan for one ALS fit.  Candidates: the fully-resident
     grouped/COO layouts (in-memory), host-resident edges with chunked
     uploads (streamed), and the mesh-composed streamed block layout
     (streamed-block, world > 1 — per-rank layouts shrink world-fold).
     Source inputs keep host O(nnz) on every route (the triples ingest
     to host arrays, like the reference's executor partitions) — the
-    streamed property is DEVICE memory.  ``padded_edges``: the slots of
+    streamed property is DEVICE memory.  ``grouped``: the ``(G, P)`` of
     BOTH grouped sides where the fit has counted them already (the
-    single-device fit, after its counting pass); elsewhere the edges
-    are priced at ``_ALS_BLOWUP`` times the ratings a side."""
-    b = 4  # ALS is f32 like the reference
-    factors = (n_users + n_items) * rank * b
-    edges = (
-        int(2 * nnz * _ALS_EDGE_BYTES * _ALS_BLOWUP) if padded_edges is None
-        else int(padded_edges) * _ALS_EDGE_BYTES
-    )
-    moments = (n_users + n_items) * rank * (rank + 1) * b
-    host_edges = edges + 3 * nnz * 8  # grouped layouts + the id triples
+    single-device fit, after its counting pass), priced with their sheet
+    (:func:`als_grouped_bytes`); elsewhere the edges are priced at
+    ``_ALS_BLOWUP`` times the ratings a side."""
+    factors, moments = _als_fixed_bytes(n_users, n_items, rank)
+    if grouped is None:
+        edges = host_layouts = int(2 * nnz * _ALS_EDGE_BYTES * _ALS_BLOWUP)
+    else:
+        edges = als_grouped_bytes(grouped, rank)
+        host_layouts = sum(g * p for g, p in grouped) * _ALS_EDGE_BYTES
+    host_edges = host_layouts + 3 * nnz * 8  # grouped layouts + the id triples
     upload = 64 << 20  # bounded per-step group-chunk upload
     in_mem = RouteEstimate(
         ROUTE_IN_MEMORY,

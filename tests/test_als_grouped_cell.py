@@ -211,10 +211,19 @@ class TestTheFit:
         assert root.node("table_convert/host_copy").attrs == {"copied_bytes": 0}
         build = root.node(BUILD_SPAN).attrs
         assert build["ratings"] == 20000 and build["threads"] >= 1
-        assert build["padded_edges_user"] == als_ops.grouped_padded_edges(x[0], 300)
-        assert build["padded_edges_item"] == als_ops.grouped_padded_edges(x[1], 500)
-        assert build["group_size"] == [als_ops.auto_group_size(20000, 300),
-                                       als_ops.auto_group_size(20000, 500)]
+        counts = [als_ops.count_edges(x[0], 300), als_ops.count_edges(x[1], 500)]
+        assert build["group_size"] == als_ops.group_sizes_for(
+            counts, 4, membudget.als_grouped_room(300, 500, 4))
+        assert build["group_size_by_mean"] == [als_ops.auto_group_size(20000, 300),
+                                               als_ops.auto_group_size(20000, 500)]
+        assert all(p <= m for p, m in zip(build["group_size"],
+                                          build["group_size_by_mean"]))
+        assert build["padded_edges_user"] == als_ops.grouped_padded_edges(
+            x[0], 300, build["group_size"][0])
+        assert build["padded_edges_item"] == als_ops.grouped_padded_edges(
+            x[1], 500, build["group_size"][1])
+        assert build["sheet_bytes"] == membudget.als_sheet_bytes(
+            max(build["groups_user"], build["groups_item"]), 4) > 0
         upload = root.node("table_convert/upload").attrs
         slots = (build["groups_user"] * build["group_size"][0]
                  + build["groups_item"] * build["group_size"][1])
@@ -241,15 +250,156 @@ class TestTheFit:
     def test_the_plan_prices_the_padded_edges_the_fit_counted(self):
         model = _fit(_skewed(12), 300, 500)
         build = model.summary["timings"].root.node(BUILD_SPAN).attrs
-        slots = (build["groups_user"] * build["group_size"][0]
-                 + build["groups_item"] * build["group_size"][1])
-        priced = membudget.plan_als(20000, 300, 500, 4, padded_edges=slots)
+        layouts = [(build["groups_user"], build["group_size"][0]),
+                   (build["groups_item"], build["group_size"][1])]
+        slots = sum(g * p for g, p in layouts)
+        priced = membudget.plan_als(20000, 300, 500, 4, grouped=layouts)
         constant = membudget.plan_als(20000, 300, 500, 4)
         route = model.summary["route"]
         assert route["route"] == "in-memory"
         assert route["estimates"][0]["hbm_bytes"] == priced.estimates[0].hbm_bytes
+        # the layouts' slots and the sheet of the side with more groups:
+        # (4+1)(4+2) = 30 floats a group, as 32 sublanes and as one
+        # 128-lane row
+        assert build["sheet_bytes"] == max(g for g, _ in layouts) * (32 + 128) * 4
+        # on the device a row of fewer than 128 slots takes 128 lanes
+        lanes = sum(g * max(p, 128) for g, p in layouts)
+        assert lanes > slots
         assert priced.estimates[0].hbm_bytes - constant.estimates[0].hbm_bytes == int(
-            (12 * slots - 2 * 20000 * 12 * 2.0) * 1.25)
+            (12 * lanes + build["sheet_bytes"] - 2 * 20000 * 12 * 2.0) * 1.25)
+        # the host holds the layouts, never the sheet
+        assert priced.estimates[0].host_bytes == 12 * slots + 3 * 20000 * 8
+
+
+class TestGroupWidthRule:
+    """``als_ops.group_sizes_for``: the width of a grouped side from the
+    degrees the fit has counted (ISSUE 39)."""
+
+    @staticmethod
+    def _cell_counts(bench, seed=1):
+        _, cfg, adapter, _ = bench
+        users, items, _ = adapter.make_data(cfg, cfg["rows_per_chip"], seed)
+        return cfg, [als_ops.count_edges(users, cfg["users"]),
+                     als_ops.count_edges(items, cfg["items"])]
+
+    @pytest.mark.parametrize("degree", [250, 256, 200])
+    def test_a_side_of_equal_degrees_keeps_the_widest(self, degree):
+        counts = np.full((1, 4000), degree, np.int32)
+        assert als_ops.auto_group_size(degree * 4000, 4000) == 256
+        assert als_ops.group_sizes_for([counts], 10) == [256]
+
+    def test_the_cells_laws_get_a_narrower_width_than_their_means(self, bench):
+        cfg, counts = self._cell_counts(bench)
+        sizes = als_ops.group_sizes_for(counts, cfg["rank"])
+        by_mean = [als_ops.auto_group_size(cfg["rows_per_chip"], cfg[n])
+                   for n in ("users", "items")]
+        assert by_mean == [128, 128]  # means 100 and 75
+        for c, p, m in zip(counts, sizes, by_mean):
+            assert p in als_ops._GROUP_SIZES and p < m
+            assert als_ops.padded_edges(c, p) < als_ops.padded_edges(c, m)
+
+    def test_the_sides_choose_apart(self):
+        flat = np.full((1, 2000), 250, np.int32)
+        tail = np.minimum(
+            10 + np.random.default_rng(0).lognormal(3.0, 1.5, 2000), 1e5
+        ).astype(np.int32)[None, :]
+        wide, narrow = als_ops.group_sizes_for([flat, tail], 10)
+        assert wide == 256 and narrow < 256
+        assert als_ops.group_sizes_for([tail, flat], 10) == [narrow, wide]
+
+    def test_a_tie_goes_to_the_wider(self, monkeypatch):
+        # nothing costs anything: every width ties
+        for name in ("_SLOT_NS", "_LANE_SLOT_NS", "_BUCKET_GROUP_NS"):
+            monkeypatch.setattr(als_ops, name, 0.0)
+        counts = als_ops.count_edges(_skewed(14)[0], 300)
+        assert als_ops.group_sizes_for([counts], 4) == [256]
+
+    @pytest.mark.parametrize("slack", [1.0, 1.1, 1.5, 4.0])
+    def test_the_plan_never_refuses_what_the_means_width_would_have_fitted(
+            self, bench, slack):
+        """A budget that just admits the mean's widths resident: the
+        chosen widths are admitted too (the narrower width's sheet is
+        larger, so without the bound the cheapest would not be)."""
+        cfg, counts = self._cell_counts(bench)
+        r, nnz, nu, ni = cfg["rank"], cfg["rows_per_chip"], cfg["users"], cfg["items"]
+
+        def layouts(sizes):
+            return [(als_ops.group_bucket(als_ops.padded_edges(c, p) // p), p)
+                    for c, p in zip(counts, sizes)]
+
+        by_mean = [als_ops.auto_group_size(nnz, nu), als_ops.auto_group_size(nnz, ni)]
+        needs = membudget.plan_als(nnz, nu, ni, r, grouped=layouts(by_mean))
+        budget = int(needs.estimates[0].hbm_bytes * slack) + 1
+        set_config(memory_budget_hbm=str(budget))
+        free = als_ops.group_sizes_for(counts, r)
+        sizes = als_ops.group_sizes_for(
+            counts, r, membudget.als_grouped_room(nu, ni, r))
+        plan = membudget.plan_als(nnz, nu, ni, r, grouped=layouts(sizes))
+        assert plan.route == "in-memory" and not plan.estimates[0].reject
+        if slack == 1.0:
+            # the unbounded choice would have been sent to the streamed route
+            unbounded = membudget.plan_als(nnz, nu, ni, r, grouped=layouts(free))
+            assert unbounded.route == "streamed" and sizes != free
+        if slack == 4.0:
+            assert sizes == free
+
+    def test_where_nothing_fits_the_cheapest_runs_streamed(self, bench):
+        cfg, counts = self._cell_counts(bench)
+        assert als_ops.group_sizes_for(counts, cfg["rank"], 0) == (
+            als_ops.group_sizes_for(counts, cfg["rank"]))
+
+    @pytest.mark.parametrize("width", [256, 128, 64, 8])
+    def test_an_explicit_group_size_is_obeyed(self, width):
+        users, items, ratings = _skewed(15)
+        layout = als_ops.build_grouped_edges(items, users, ratings, 500, width)
+        assert layout[0].shape[1] == width
+        counts = als_ops.count_edges(items, 500)
+        assert layout[0].size == als_ops.padded_edges(counts, width) == (
+            als_ops.grouped_padded_edges(items, 500, width))
+        assert int(layout[2].sum()) == len(items)
+
+    @pytest.fixture(scope="class")
+    def by_width(self, bench):
+        """One table fitted at P = 256 / 128 / 64 on both sides (the rule
+        left one candidate), from the same initial factors."""
+        _, cfg, _, ref = bench
+        x = _skewed(16)
+        small = dict(cfg, users=300, items=500, rank=4, max_iter=3)
+        init = ref.init_factors(small, 33)
+        fits = {}
+        for width in (256, 128, 64):
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(als_ops, "_GROUP_SIZES", (width,))
+                set_config(als_kernel="grouped")
+                fits[width] = ALS(
+                    rank=4, max_iter=3, implicit_prefs=True, alpha=cfg["alpha"],
+                    reg_param=cfg["reg_param"], num_user_blocks=1,
+                ).fit(*x, n_users=300, n_items=500, init=init)
+        return x, small, fits
+
+    @pytest.mark.parametrize("width", [256, 128, 64])
+    def test_every_width_counts_each_rating_once_and_holds_the_reference(
+            self, bench, by_width, width):
+        _, cfg, _, ref = bench
+        x, small, fits = by_width
+        model = fits[width]
+        build = model.summary["timings"].root.node(BUILD_SPAN).attrs
+        assert model.summary["als_kernel"] == "grouped"
+        assert build["group_size"] == [width, width]
+        for dst, n_dst in ((x[0], 300), (x[1], 500)):
+            layout = als_ops.build_grouped_edges(
+                dst, x[2], x[2], n_dst, width)
+            assert int(layout[2].sum()) == len(dst) == 20000
+        for got, want in ((model.user_factors_, fits[256].user_factors_),
+                          (model.item_factors_, fits[256].item_factors_)):
+            # another order of summation, no other arithmetic: a fifth of
+            # what the cell allows three iterations' replay (3e-5 here)
+            assert np.linalg.norm(got - want) <= 2e-4 * np.linalg.norm(want)
+        result = {"user_factors": model.user_factors_,
+                  "item_factors": model.item_factors_, "seed": 33}
+        numbers = ref.judge(x, small, [result], 0)
+        assert numbers["half_step_gap"] <= cfg["limits"]["half_step_gap"], numbers
+        assert numbers["shape_gap"] == 0
 
 
 class TestUploadArrays:

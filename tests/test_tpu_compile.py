@@ -726,46 +726,110 @@ class TestListedScaleOnOneChip:
 
 class TestALSCellOnOneChip:
     """``als_implicit_r10_kddcup11``: one user block of the KDD-Cup'11
-    table, 500,495 users x 624,961 items, both grouped sides on the 2^20
-    group bucket at P = 256 (822k and 928k live groups), rank 10, five
-    iterations.  The programs of the fit's ``als_iterations`` for one
-    described v5e, with what ``memory_analysis`` says they hold."""
+    table, 500,495 users x 624,961 items, rank 10, five iterations, both
+    grouped sides at the width ``als_ops.group_sizes_for`` picks for the
+    cell's degree laws (ISSUE 39; the mean's 256 before) on the group
+    bucket that width fills.  The programs of the fit's
+    ``als_iterations`` for one described v5e, with what
+    ``memory_analysis`` says they hold."""
 
-    USERS, ITEMS, RANK, P, ITERS = 500495, 624961, 10, 256, 5
-    G = 1 << 20
+    USERS, ITEMS, RANK, ITERS = 500495, 624961, 10, 5
+    RATINGS = 126_400_138
     HBM = 15.75 * 2**30
-    LAYOUTS = 2 * (G * P * 12 + G * 4)
 
-    def _side(self, sharding):
-        i32 = jax.ShapeDtypeStruct((self.G, self.P), jnp.int32, sharding=sharding)
-        return (i32, _s((self.G, self.P), sharding), _s((self.G, self.P), sharding),
-                jax.ShapeDtypeStruct((self.G,), jnp.int32, sharding=sharding))
+    @pytest.fixture(scope="class")
+    def cell(self):
+        """``(P, G, layout bytes)``, one entry a side, of the widths the
+        rule picks for degrees re-drawn from the configuration's laws
+        (``benchmarks/estimators/als_implicit.py``: a user's degree 10 + a
+        lognormal share of the rest, an item's a power law, here as one
+        multinomial), under the described chip's memory."""
+        import json
+        import os
+
+        from oap_mllib_tpu.config import set_config
+        from oap_mllib_tpu.ops import als_ops
+        from oap_mllib_tpu.utils import membudget
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(
+                root, "benchmarks", "configs", "als_implicit_r10_kddcup11.json")) as f:
+            cfg = json.load(f)
+        assert (cfg["users"], cfg["items"], cfg["rows_per_chip"], cfg["rank"]) == (
+            self.USERS, self.ITEMS, self.RATINGS, self.RANK)
+        law, rng = cfg["data"], np.random.default_rng(39)
+        w = rng.lognormal(0.0, law["degree_sigma"], self.USERS)
+        rest = self.RATINGS - law["degree_min"] * self.USERS
+        by_user = law["degree_min"] + np.floor(rest * w / w.sum())
+        edges = (np.arange(self.ITEMS + 1) + law["item_offset"]) ** (
+            1.0 - law["item_exponent"])
+        by_item = rng.multinomial(self.RATINGS, np.diff(edges) / (edges[-1] - edges[0]))
+        counts = [by_user.astype(np.int32)[None, :], by_item.astype(np.int32)[None, :]]
+        set_config(memory_budget_hbm=str(int(self.HBM)))
+        sizes = als_ops.group_sizes_for(
+            counts, self.RANK,
+            membudget.als_grouped_room(self.USERS, self.ITEMS, self.RANK))
+        buckets = [als_ops.group_bucket(als_ops.padded_edges(c, p) // p)
+                   for c, p in zip(counts, sizes)]
+        plan = membudget.plan_als(
+            self.RATINGS, self.USERS, self.ITEMS, self.RANK,
+            grouped=list(zip(buckets, sizes)))
+        assert plan.route == "in-memory" and not plan.estimates[0].reject
+        return [(p, g, g * p * 12 + g * 4) for p, g in zip(sizes, buckets)]
+
+    @staticmethod
+    def _side(sharding, p, g):
+        i32 = jax.ShapeDtypeStruct((g, p), jnp.int32, sharding=sharding)
+        return (i32, _s((g, p), sharding), _s((g, p), sharding),
+                jax.ShapeDtypeStruct((g,), jnp.int32, sharding=sharding))
 
     @staticmethod
     def _lane_padded(text, rows):
         """float32 arrays of the compiled program whose minor dimension is
         under 32 (so padded to the 128 lanes of a tile) over at least
         ``rows`` rows: the gather of a block's edges with the rank minor
-        (``als_ops.py``'s note on the 21 GB gather) would be one."""
+        (``als_ops.py``'s note on the 21 GB gather) would be one.  Not a
+        single column (``live_group_count``'s ``valid_g[:, 0]``): that is
+        read inside its reduction and never stored."""
         import re
 
         found = set()
         for dims, order in re.findall(r"f32\[([\d,]+)\]\{([\d,]+)", text):
             dims = [int(d) for d in dims.split(",")]
             minor = dims[int(order.split(",")[0])]
-            if minor < 32 and int(np.prod(dims)) // minor >= rows:
+            if 1 < minor < 32 and int(np.prod(dims)) // minor >= rows:
                 found.add((tuple(dims), order))
         return found
 
-    def test_the_bucket_is_the_cells(self):
+    def _temporaries(self, p, g):
+        """What a half-update holds beside its arguments: the sheet of
+        group moments (544 + 1024 B a group of the bucket: the form the
+        walk fills and the copy the segment-sum reads) and one block's
+        lane-padded gather, its moment operands and products."""
+        from oap_mllib_tpu.ops import als_ops
+        from oap_mllib_tpu.utils import membudget
+
+        block_slots = g // als_ops._grouped_block_count(g, p, self.RANK) * p
+        return membudget.als_sheet_bytes(g, self.RANK), block_slots * 512
+
+    def test_the_bucket_is_the_cells(self, cell):
         from oap_mllib_tpu.ops import als_ops
 
-        assert als_ops.group_bucket(822_501) == als_ops.group_bucket(927_730) == self.G
-        assert als_ops.auto_group_size(126_400_138, self.USERS) == self.P
-        assert als_ops.auto_group_size(126_400_138, self.ITEMS) == self.P
+        # what the mean alone says, and the span reports as group_size_by_mean
+        assert als_ops.auto_group_size(self.RATINGS, self.USERS) == 256
+        assert als_ops.auto_group_size(self.RATINGS, self.ITEMS) == 256
+        assert als_ops.group_bucket(822_501) == als_ops.group_bucket(927_730) == 1 << 20
+        # the heavy tails' own width: half the mean's, on the next bucket,
+        # so the same slots a side and the same block of 1,048,576 slots
+        assert [(p, g) for p, g, _ in cell] == [(128, 1 << 21), (128, 1 << 21)]
+        for p, g, _ in cell:
+            blocks = als_ops._grouped_block_count(g, p, self.RANK)
+            assert g % blocks == 0 and g // blocks * p == 1 << 20
 
-    def test_moments_of_a_side(self, one_chip):
+    def test_moments_of_a_side(self, one_chip, cell):
         from oap_mllib_tpu.ops import als_ops
+
+        p, g, layout = cell[1]
 
         def moments(src, conf, valid, group_dst, factors):
             return als_ops.normal_eq_partials_grouped(
@@ -774,18 +838,19 @@ class TestALSCellOnOneChip:
             )
 
         compiled = jax.jit(moments).lower(
-            *self._side(one_chip), _s((self.USERS, self.RANK), one_chip)
+            *self._side(one_chip, p, g), _s((self.USERS, self.RANK), one_chip)
         ).compile()
         text = compiled.as_text()
-        blocks = als_ops._grouped_block_count(self.G, self.P, self.RANK)
-        assert self.G % blocks == 0  # no remainder: no padded copy of a layout
+        blocks = als_ops._grouped_block_count(g, p, self.RANK)
+        assert g % blocks == 0  # no remainder: no padded copy of a layout
         # a block's gather has the rank minor (512 MB of lanes); the whole
         # side's never
-        assert self._lane_padded(text, self.G // blocks * self.P)
-        assert not self._lane_padded(text, 2 * self.G // blocks * self.P)
+        assert self._lane_padded(text, g // blocks * p)
+        assert not self._lane_padded(text, 2 * g // blocks * p)
         mem = compiled.memory_analysis()
-        assert mem.argument_size_in_bytes < 1.01 * self.LAYOUTS / 2
-        assert mem.temp_size_in_bytes < 2 * 2**30
+        assert mem.argument_size_in_bytes < 1.01 * layout
+        sheet, block = self._temporaries(p, g)
+        assert sheet < mem.temp_size_in_bytes < sheet + 1.25 * block
 
     def test_solve_kernel(self, one_chip):
         from oap_mllib_tpu.ops import als_ops
@@ -803,13 +868,14 @@ class TestALSCellOnOneChip:
         )
         assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
-    def test_the_whole_run_grouped_program(self, one_chip):
+    def test_the_whole_run_grouped_program(self, one_chip, cell):
         from oap_mllib_tpu.ops import als_ops
 
         r = self.RANK
+        (p_u, g_u, layout_u), (p_i, g_i, layout_i) = cell
         solve_geo, gram_geo = als_ops._tuned_geometry(r, "pallas", True)
         compiled = als_ops._als_run_grouped_jit.lower(
-            *self._side(one_chip), *self._side(one_chip),
+            *self._side(one_chip, p_u, g_u), *self._side(one_chip, p_i, g_i),
             _s((self.USERS, r), one_chip), _s((self.ITEMS, r), one_chip),
             n_users=self.USERS, n_items=self.ITEMS, max_iter=self.ITERS,
             reg=0.1, alpha=40.0, implicit=True, policy="f32",
@@ -817,12 +883,16 @@ class TestALSCellOnOneChip:
         ).compile()
         text = compiled.as_text()
         assert "tpu_custom_call" in text  # the fused solve and the Gram walk
-        blocks = als_ops._grouped_block_count(self.G, self.P, r)
-        assert not self._lane_padded(text, 2 * self.G // blocks * self.P)
+        for p, g, _ in cell:
+            blocks = als_ops._grouped_block_count(g, p, r)
+            assert not self._lane_padded(text, 2 * g // blocks * p)
         mem = compiled.memory_analysis()
         # both layouts once (no copy of one padded to its blocks) and the
         # initial factors
-        assert mem.argument_size_in_bytes < 1.02 * self.LAYOUTS
+        assert mem.argument_size_in_bytes < 1.02 * (layout_u + layout_i)
+        # the sheet of the side with more groups and one block (3.3 +
+        # 0.5 GB at P = 128), never both sides' sheets
+        sheet, block = max(self._temporaries(p, g) for p, g, _ in cell)
+        assert sheet < mem.temp_size_in_bytes < sheet + 1.25 * block
         assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 + mem.output_size_in_bytes) < self.HBM
-        assert mem.temp_size_in_bytes < 2 * 2**30
