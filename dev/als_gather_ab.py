@@ -1,10 +1,10 @@
 """A/B the ALS gather levers on the real chip.
 
-When last run on a v5e (before PR 1) both levers were rejected: the
+When first run on a v5e levers A-C were rejected: the
 gather bound was per-index, not per-byte.  Re-run to reproduce;
 protocol follows the kernel-table slope method.
 
-Levers, measured at the ML-1M attribution shape (6040x3706, nnz=1M,
+Levers A-C, measured at the ML-1M attribution shape (6040x3706, nnz=1M,
 r=10, P=256 grouped layout, user side):
   A. bf16 factor table for the gather (halves gathered BYTES; tests
      whether the measured gather bound is byte-bandwidth or per-index).
@@ -14,12 +14,25 @@ r=10, P=256 grouped layout, user side):
   C. degree/src-sorted edge ordering ((dst, src)-lexsorted input ->
      ascending src ids within each group -> gather locality).
 
+Lever D, at the ALS cell's shapes (``als_implicit_r10_kddcup11``: the
+users' 500,495 and the items' 624,961 rank-10 rows, blocks of 8192
+groups of 128 slots): the Pallas walk over the packed VMEM-resident
+table (``ops/pallas/als_gather.py``) against XLA's gather — the gather
+alone, the block's moments through either, and one half-update's group
+moments at P = 256 / 128 / 64 over 2^27 slots — every variant cycled
+three times in one process.  Prints ns a slot for each, the walk's
+table fill per call, and the slot constant of the width rule's walk
+route (``als_ops._WALK_SLOT_NS``) they imply.
+
 Protocol: ONE process, interleaved variants, in-jit repeat slopes with
 runtime trip counts (verify-skill gotchas 3-5); standalone gather slope
 AND full-iteration slope for each lever; parity of final factors vs the
 f32 fit for lever A.
+
+    python dev/als_gather_ab.py [--levers ABCD]
 """
 
+import argparse
 import sys
 
 import os
@@ -57,7 +70,125 @@ def slope(run, r1, r2, reps=3):
     return (t2 - t1) / (r2 - r1)
 
 
-def main():
+def lever_d(cycles=3):
+    """The walk against XLA's gather at the ALS cell's shapes."""
+    from oap_mllib_tpu.ops.pallas import als_gather
+
+    rng = np.random.default_rng(1)
+    r, p, groups = 10, 128, 8192
+    slots = groups * p
+
+    def looped(step, *arrays):
+        """``reps`` runs of ``step(k, *arrays)`` (a float32 scalar a run)
+        in one program, the trip count a runtime argument.  The arrays go
+        in as arguments: closed over, they would be constants of the
+        program (gigabytes of them for a half-update)."""
+        @jax.jit
+        def run(reps, *arrays):
+            return lax.fori_loop(
+                0, reps, lambda k, acc: acc + step(k, *arrays),
+                jnp.float32(0),
+            )
+
+        return lambda reps: float(run(jnp.int32(reps), *arrays))
+
+    def moved(src, k, n_src):
+        # another block each run, so that nothing is hoisted out of the loop
+        return (src + k) % n_src
+
+    def first(a):
+        return lax.optimization_barrier(a)[0, 0, 0]
+
+    variants = {}
+    for side, n_src in (("item", 624961), ("user", 500495)):
+        f = jnp.asarray(rng.normal(size=(n_src, r)).astype(np.float32))
+        table = als_gather.pack_table(f)
+        src = jnp.asarray(rng.integers(0, n_src, (groups, p)).astype(np.int32))
+        small = src[: groups // 8]
+        conf = jnp.asarray((rng.integers(0, 11, (groups, p)) * 10).astype(np.float32))
+        valid = jnp.ones((groups, p), jnp.float32)
+
+        variants[f"{side}/gather_xla"] = (slots, looped(
+            lambda k, f, s, n=n_src: first(f.T[:, moved(s, k, n)]), f, src))
+        # the kernel's own (Gb, r, P) output, before the transpose that
+        # the moments read as a bitcast
+        variants[f"{side}/gather_walk"] = (slots, looped(
+            lambda k, t, s, n=n_src: first(
+                als_gather._walk(t, moved(s, k, n), r, False)), table, src))
+        variants[f"{side}/gather_walk_eighth"] = (slots // 8, looped(
+            lambda k, t, s, n=n_src: first(
+                als_gather._walk(t, moved(s, k, n), r, False)), table, small))
+        for g in ("xla", "pallas"):
+            variants[f"{side}/moments_{g}"] = (slots, looped(
+                lambda k, f, s, c, v, n=n_src, g=g: jnp.sum(
+                    als_ops.grouped_block_moments(
+                        moved(s, k, n), c, v, f, ALPHA, True, "f32", g,
+                        als_gather.pack_table(f) if g == "pallas" else None,
+                    )), f, src, conf, valid))
+
+    # one half-update's group moments over 2^27 slots, the item side's
+    # sources, at the widths the rule weighs
+    n_src = 624961
+    f = jnp.asarray(rng.normal(size=(n_src, r)).astype(np.float32))
+    half_slots = 1 << 27
+    for width in (256, 128, 64):
+        g_count = half_slots // width
+        key = jax.random.PRNGKey(width)
+        src = jax.random.randint(key, (g_count, width), 0, n_src, jnp.int32)
+        conf = (jax.random.randint(key, (g_count, width), 0, 11) * 10).astype(
+            jnp.float32)
+        valid = jnp.ones((g_count, width), jnp.float32)
+        dst = jnp.arange(g_count, dtype=jnp.int32) // 4
+        for g in ("xla", "pallas"):
+            variants[f"half/P{width}_{g}"] = (half_slots, looped(
+                lambda k, f, s, c, v, d, n=g_count, g=g: sum(
+                    jnp.sum(m) for m in als_ops.normal_eq_partials_grouped(
+                        moved(s, k, n_src), c, v, d, f, n // 4, ALPHA, True,
+                        "f32", None, g)), f, src, conf, valid, dst))
+
+    found = {name: [] for name in variants}
+    for name, (_, run) in variants.items():
+        run(1)  # compile + warm
+    for cycle in range(cycles):
+        for name, (n_slots, run) in variants.items():
+            if name.startswith("half/"):
+                s = slope(run, 1, 2, reps=2)
+            else:
+                s = slope(run, 2, 10)
+            found[name].append(s / n_slots * 1e9)
+            print(f"cycle {cycle} {name}: {s * 1e3:.3f} ms a run, "
+                  f"{s / n_slots * 1e9:.4f} ns a slot", flush=True)
+    med = {name: float(np.median(v)) for name, v in found.items()}
+    for side in ("item", "user"):
+        whole = med[f"{side}/gather_walk"] * slots
+        eighth = med[f"{side}/gather_walk_eighth"] * slots / 8
+        # t(slots) = fill + slots * per-slot: two block sizes give both
+        per_slot = (whole - eighth) / (slots - slots // 8)
+        print(f"{side}: walk {per_slot:.4f} ns a slot beyond a fill of "
+              f"{(whole - per_slot * slots) / 1e3:.1f} us a call; XLA gather "
+              f"{med[f'{side}/gather_xla']:.4f}; moments XLA "
+              f"{med[f'{side}/moments_xla']:.4f}, walk "
+              f"{med[f'{side}/moments_pallas']:.4f} ns a slot", flush=True)
+    for width in (256, 128, 64):
+        xla, walk = med[f"half/P{width}_xla"], med[f"half/P{width}_pallas"]
+        print(f"half-update P={width}: XLA {xla:.4f}, walk {walk:.4f} ns a "
+              f"slot -> walk slot constant {als_ops._SLOT_NS - (xla - walk):.3f}",
+              flush=True)
+    print({k: round(v, 4) for k, v in med.items()}, flush=True)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--levers", default="ABCD",
+                    help="A-C at ML-1M's shape, D at the ALS cell's")
+    args = ap.parse_args(argv)
+    if "D" in args.levers:
+        lever_d()
+    if set(args.levers) & set("ABC"):
+        levers_abc()
+
+
+def levers_abc():
     rng = np.random.default_rng(0)
     u = rng.integers(0, NU, NNZ).astype(np.int64)
     i = rng.integers(0, NI, NNZ).astype(np.int64)

@@ -758,6 +758,11 @@ class ALS:
             # (destinations, sources, their count) of the user side, the
             # item side
             sides = ((users, items, n_users), (items, users, n_items))
+            # the program that gathers the grouped moments' factor rows:
+            # one decision a fit, on the larger table
+            gather = als_ops.resolve_gather_kernel(
+                max(n_users, n_items), self.rank, x0.dtype
+            )
             if kernel != "coo":
                 with spans.child("group_edges") as span:
                     threads = als_ops.build_threads()
@@ -772,6 +777,7 @@ class ALS:
                         membudget.als_grouped_room(
                             n_users, n_items, self.rank
                         ),
+                        gather,
                     )
                     padded = [
                         als_ops.padded_edges(c, p) for c, p in zip(counts, sizes)
@@ -868,6 +874,18 @@ class ALS:
                 iterations=int(self.max_iter), solve_kernel=solve_kernel,
                 rank=int(self.rank), implicit=bool(self.implicit_prefs),
             )
+            if grouped_ok:
+                from oap_mllib_tpu.ops.pallas import als_gather
+
+                # the walk's packed tables, the user side's (items) first
+                span.attrs.update(
+                    gather_kernel=gather,
+                    gather_table_bytes=[
+                        als_gather.table_bytes(n_src, self.rank)
+                        if gather.startswith("pallas") else 0
+                        for n_src in (n_items, n_users)
+                    ],
+                )
             if grouped_ok and stream_route:
                 from oap_mllib_tpu.ops import als_stream
 
@@ -885,6 +903,7 @@ class ALS:
                         n_users, n_items, iters, self.reg_param,
                         self.alpha, self.implicit_prefs, timings=timings,
                         policy=pol.name, solve_kernel=solve_kernel,
+                        gather_kernel=gather,
                     )
 
                 if ckpt is None:

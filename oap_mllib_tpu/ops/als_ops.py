@@ -323,6 +323,50 @@ def resolve_solve_kernel(r: int, dtype=None, cfg=None) -> str:
     return "xla"
 
 
+def resolve_gather_kernel(n_src: int, r: int, dtype=None,
+                          backend: str = "") -> str:
+    """Which program gathers the source factors' rows of the grouped
+    moments (:func:`gather_factor_rows`): "pallas", the VMEM-resident
+    walk of ops/pallas/als_gather.py, on a TPU with float32 factors whose
+    packed table fits its bound (``als_gather.fits``: 1,048,576 sources
+    at rank 10); "xla", XLA's gather, elsewhere — the CPU of tier-1
+    included.  One rule over what a fit can observe, no switch: the two
+    give the same bits.  ``backend`` ("" = ``jax.default_backend()``) is
+    the test seam."""
+    import numpy as np
+
+    from oap_mllib_tpu.ops.pallas import als_gather
+
+    if (
+        (backend or jax.default_backend()) == "tpu"
+        and (dtype is None or np.dtype(dtype) == np.float32)
+        and als_gather.fits(n_src, r)
+    ):
+        return "pallas"
+    return "xla"
+
+
+def gather_factor_rows(src_factors: jax.Array, src_b: jax.Array,
+                       kernel: str = "", table=None) -> jax.Array:
+    """``src_factors.T[:, src_b]``: the ``(r, Gb, P)`` factor rows of a
+    block's slots, by XLA's gather or, on ``kernel`` "pallas", the walk
+    over the packed ``table`` (``als_gather.pack_table(src_factors)``,
+    packed here when not handed in) — the same bits either way.
+    ``kernel``: "" resolves :func:`resolve_gather_kernel`;
+    "pallas_interpret" runs the walk in interpret mode (the CPU tests)."""
+    n_src, r = src_factors.shape
+    kernel = kernel or resolve_gather_kernel(n_src, r, src_factors.dtype)
+    if not kernel.startswith("pallas"):
+        return src_factors.T[:, src_b]
+    from oap_mllib_tpu.ops.pallas import als_gather
+
+    if table is None:
+        table = als_gather.pack_table(src_factors)
+    return als_gather.gather_walk(
+        table, src_b, r, interpret=kernel == "pallas_interpret"
+    )
+
+
 GROUPED_MAX_BLOWUP = 6.0
 
 
@@ -425,8 +469,14 @@ _GROUP_SIZES = (256, 128, 64, 32, 16, 8)
 # side reproduce all eight half-updates (0.89 ... 1.26 s) within 0.15%
 # (PERF.md section 6, PR 39).
 # - a slot, pad or not: the factor-row gather, ten floats at about four
-#   cycles an index whatever the bytes;
+#   cycles an index whatever the bytes — XLA's gather;
 _SLOT_NS = 4.6
+#   the walk over the VMEM-resident packed table (ops/pallas/als_gather,
+#   resolve_gather_kernel "pallas"): 2.18 ns a slot beyond its table
+#   fill at the cell's two tables, a block of 2^20 slots in groups of
+#   128, and 2.25 by a half-update's difference from XLA's at P = 128
+#   (dev/als_gather_ab.py lever D; PERF.md section 6);
+_WALK_SLOT_NS = 2.2
 # - a slot of a group's row as the moment products and layout copies
 #   hold it, padded to the 128 lanes: below P = 128 a group costs 128;
 _LANE_SLOT_NS = 0.53
@@ -436,16 +486,19 @@ _LANE_SLOT_NS = 0.53
 _BUCKET_GROUP_NS = 16.0
 
 
-def group_sizes_for(counts, r: int, room=None):
+def group_sizes_for(counts, r: int, room=None, gather: str = "xla"):
     """The group width ``P`` of each grouped side, from the degrees the
     fit has counted: ``counts`` holds one :func:`count_edges` a side.
     Every width of ``_GROUP_SIZES`` is priced from the groups it would
     make of these degrees (one pass over ``n_dst`` integers),
 
-        groups * (P * _SLOT_NS + max(P, 128) * _LANE_SLOT_NS)
+        groups * (P * slot + max(P, 128) * _LANE_SLOT_NS)
         + group_bucket(groups) * _BUCKET_GROUP_NS,
 
-    and the cheapest set of widths wins, the wider on a tie: a side
+    where ``slot`` is what a slot's gather costs on the route that runs
+    (``gather``, :func:`resolve_gather_kernel`): ``_SLOT_NS`` for XLA's,
+    ``_WALK_SLOT_NS`` for the walk; and the cheapest set of widths wins,
+    the wider on a tie: a side
     whose destinations all have about 250 edges keeps 256, a heavy tail
     gets what its tail wants.  ``room`` (bytes,
     ``membudget.als_grouped_room``; None = unbounded) is what the
@@ -462,6 +515,7 @@ def group_sizes_for(counts, r: int, room=None):
 
     from oap_mllib_tpu.utils.membudget import als_grouped_bytes
 
+    slot_ns = _WALK_SLOT_NS if gather.startswith("pallas") else _SLOT_NS
     priced = []  # a side: (ns, P, bucketed G) of every width
     for side in counts:
         per_dst = side.sum(axis=0, dtype=np.int64)
@@ -470,7 +524,7 @@ def group_sizes_for(counts, r: int, room=None):
             groups = int((-(per_dst // -p)).sum())
             bucket = group_bucket(groups)
             ns = groups * (
-                p * _SLOT_NS + max(p, 128) * _LANE_SLOT_NS
+                p * slot_ns + max(p, 128) * _LANE_SLOT_NS
             ) + bucket * _BUCKET_GROUP_NS
             options.append((ns, p, bucket))
         priced.append(options)
@@ -629,6 +683,8 @@ def grouped_block_moments(
     alpha,
     implicit: bool,
     policy: str = "f32",
+    gather: str = "",
+    table=None,
 ) -> jax.Array:
     """(Gb, r+1, r+2) normal-equation moment matrices for one group
     block — the MXU inner kernel shared by the in-memory grouped partials
@@ -636,8 +692,10 @@ def grouped_block_moments(
     accumulate (ops/als_stream.py), so the two paths cannot diverge in
     the weighting math.  Layout note: the transposed gather keeps the big
     static group width P on the 128-lane axis (see the grouped-path
-    module notes)."""
-    ys = src_factors.T[:, src_b]  # (r, Gb, P) transposed gather
+    module notes).  ``gather`` / ``table``: the gather's program and its
+    packed table (:func:`gather_factor_rows`)."""
+    # (r, Gb, P) transposed gather
+    ys = gather_factor_rows(src_factors, src_b, gather, table)
     if implicit:
         a_w = alpha * jnp.abs(conf_b) * valid_b
         pos = (conf_b > 0).astype(conf_b.dtype) * valid_b
@@ -671,9 +729,14 @@ def normal_eq_partials_grouped(
     implicit: bool,
     policy: str = "f32",
     live_groups=None,
+    gather: str = "",
 ):
     """Scatter-free normal-equation partials: same math and Spark-parity
     weighting as :func:`normal_eq_partials`, grouped-edge layout.
+
+    ``gather``: the program that gathers the source factors' rows ("" =
+    :func:`resolve_gather_kernel` on their shape); the walk's packed
+    table is made once here, outside the loop over group blocks.
 
     ``live_groups`` (a traced int32 scalar, :func:`live_group_count`):
     the groups that hold an edge, where ``G`` was padded up to its
@@ -697,12 +760,19 @@ def normal_eq_partials_grouped(
 
     Returns (a_part (n_dst, r, r), b (n_dst, r), n_reg (n_dst,)).
     """
-    r = src_factors.shape[1]
+    n_src, r = src_factors.shape
     G, P = src_g.shape
+    gather = gather or resolve_gather_kernel(n_src, r, src_factors.dtype)
+    table = None
+    if gather.startswith("pallas"):
+        from oap_mllib_tpu.ops.pallas import als_gather
+
+        table = als_gather.pack_table(src_factors)
 
     def block_moments(src_b, conf_b, valid_b):
         return grouped_block_moments(
-            src_b, conf_b, valid_b, src_factors, alpha, implicit, policy
+            src_b, conf_b, valid_b, src_factors, alpha, implicit, policy,
+            gather, table,
         )
 
     blocks = _grouped_block_count(G, P, r)
@@ -765,7 +835,7 @@ def normal_eq_partials_grouped(
     jax.jit,
     static_argnames=(
         "n_users", "n_items", "max_iter", "implicit", "policy",
-        "solve_kernel", "solve_geo", "gram_geo",
+        "solve_kernel", "solve_geo", "gram_geo", "gather_kernel",
     ),
 )
 def _als_run_grouped_jit(
@@ -783,6 +853,7 @@ def _als_run_grouped_jit(
     solve_kernel: str = "xla",
     solve_geo=None,
     gram_geo=None,
+    gather_kernel: str = "xla",
 ) -> Tuple[jax.Array, jax.Array]:
     r = x0.shape[1]
     eye = jnp.eye(r, dtype=x0.dtype)
@@ -793,7 +864,7 @@ def _als_run_grouped_jit(
     def half(src_g, conf_g, valid_g, group_dst, factors, n_dst, live):
         a, b, n_reg = normal_eq_partials_grouped(
             src_g, conf_g, valid_g, group_dst, factors, n_dst, alpha,
-            implicit, policy, live,
+            implicit, policy, live, gather_kernel,
         )
         gram = (
             _factor_gram(factors, solve_kernel, gram_geo)
@@ -839,6 +910,7 @@ def als_run_grouped(
     phase: str = "als_iterations",
     policy: str = "f32",
     solve_kernel: str = "",
+    gather_kernel: str = "",
 ) -> Tuple[jax.Array, jax.Array]:
     """Full ALS loop on the grouped-edge layout (both feedback modes).
 
@@ -849,9 +921,14 @@ def als_run_grouped(
     is the compute-precision policy (utils/precision.py) for the moment
     matmuls — the Gram and every solve stay f32 under all policies.
     ``solve_kernel``: "" resolves Config.als_solve_kernel
-    (:func:`resolve_solve_kernel`); explicit values are the test seam."""
+    (:func:`resolve_solve_kernel`); ``gather_kernel``: "" resolves
+    :func:`resolve_gather_kernel` once for both sides, on the larger
+    table; explicit values are the test seams."""
     solve_kernel = solve_kernel or resolve_solve_kernel(
         x0.shape[1], x0.dtype
+    )
+    gather_kernel = gather_kernel or resolve_gather_kernel(
+        max(n_users, n_items), x0.shape[1], x0.dtype
     )
     solve_geo, gram_geo = _tuned_geometry(
         x0.shape[1], solve_kernel, implicit
@@ -862,14 +939,14 @@ def als_run_grouped(
         progcache.backend_fingerprint(),
         progcache.array_key(u_src_g, i_src_g, x0, y0),
         n_users, n_items, max_iter, implicit, policy, solve_kernel,
-        solve_geo, gram_geo,
+        solve_geo, gram_geo, gather_kernel,
     )
     with progcache.launch("als.run_grouped", key, timings, phase):
         return _als_run_grouped_jit(
             u_src_g, u_conf_g, u_valid_g, u_group_dst,
             i_src_g, i_conf_g, i_valid_g, i_group_dst,
             x0, y0, n_users, n_items, max_iter, reg, alpha, implicit,
-            policy, solve_kernel, solve_geo, gram_geo,
+            policy, solve_kernel, solve_geo, gram_geo, gather_kernel,
         )
 
 

@@ -232,7 +232,8 @@ class TestTheFit:
         assert upload["arrays"] == 8 and upload["pieces"] == 8
         assert root.node("table_convert/upload/put").attrs["bytes"] == upload["bytes"]
         assert root.node("als_iterations").attrs == {
-            "iterations": 3, "solve_kernel": "xla", "rank": 4, "implicit": True}
+            "iterations": 3, "solve_kernel": "xla", "rank": 4, "implicit": True,
+            "gather_kernel": "xla", "gather_table_bytes": [0, 0]}
         assert root.node("als_iterations/fetch").attrs["bytes"] == (300 + 500) * 4 * 4
         after = telemetry.snapshot()["oap_fit_total"]
         assert sum(after.values()) == sum(before.values()) + 1

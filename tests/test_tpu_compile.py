@@ -868,7 +868,8 @@ class TestALSCellOnOneChip:
         )
         assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
-    def test_the_whole_run_grouped_program(self, one_chip, cell):
+    @pytest.mark.parametrize("gather", ["xla", "pallas"])
+    def test_the_whole_run_grouped_program(self, one_chip, cell, gather):
         from oap_mllib_tpu.ops import als_ops
 
         r = self.RANK
@@ -880,12 +881,18 @@ class TestALSCellOnOneChip:
             n_users=self.USERS, n_items=self.ITEMS, max_iter=self.ITERS,
             reg=0.1, alpha=40.0, implicit=True, policy="f32",
             solve_kernel="pallas", solve_geo=solve_geo, gram_geo=gram_geo,
+            gather_kernel=gather,
         ).compile()
         text = compiled.as_text()
         assert "tpu_custom_call" in text  # the fused solve and the Gram walk
         for p, g, _ in cell:
             blocks = als_ops._grouped_block_count(g, p, r)
             assert not self._lane_padded(text, 2 * g // blocks * p)
+        # one walk a side, and its block read by the moments as it lies
+        walks = TestALSGatherWalk.calls(text)
+        assert len(walks) == (2 if gather == "pallas" else 0), walks
+        if gather == "pallas":
+            assert not TestALSGatherWalk.relayouts(text)
         mem = compiled.memory_analysis()
         # both layouts once (no copy of one padded to its blocks) and the
         # initial factors
@@ -896,3 +903,128 @@ class TestALSCellOnOneChip:
         assert sheet < mem.temp_size_in_bytes < sheet + 1.25 * block
         assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 + mem.output_size_in_bytes) < self.HBM
+
+
+class TestALSGatherWalk:
+    """The factor-row gather of the grouped moments as the Pallas walk over
+    the packed table held whole in VMEM (``ops/pallas/als_gather.py``): at
+    the ALS cell's two tables (the items' 624,961 rows, which the user side
+    gathers, and the users' 500,495), one block of 2^20 slots in groups of
+    128, and at the rule's bound (1,048,576 rows, a 64 MiB table)."""
+
+    R, P, SLOTS = 10, 128, 1 << 20
+
+    @staticmethod
+    def calls(text):
+        """The walk's custom calls in a compiled program."""
+        import re
+
+        return re.findall(r"(%als_gather_walk[.\d]*) = \S+ custom-call\(", text)
+
+    @staticmethod
+    def relayouts(text):
+        """Copies the program makes of a walk's output into another
+        layout (following its bitcasts; a move to another memory space
+        in the same layout is none): the moments must read the block as
+        the kernel wrote it."""
+        import re
+
+        def layout(rest):
+            # the first array type of an instruction's result, memory
+            # space dropped
+            return re.sub(r"S\(\d+\)", "", re.search(r"\{[^}]*\}", rest).group(0))
+
+        defs = {}
+        for line in text.splitlines():
+            if " = " in line:
+                name, rest = line.strip().replace("ROOT ", "").split(" = ", 1)
+                defs[name] = rest
+        names = {n for n in defs if n.startswith("%als_gather_walk")}
+        found, grown = [], True
+        while grown:
+            grown = False
+            for name, rest in defs.items():
+                used = [n for n in names if re.search(re.escape(n) + r"\b", rest)]
+                if not used or name in names:
+                    continue
+                if " bitcast(" in rest:
+                    names.add(name)
+                    grown = True
+                elif (" copy(" in rest or " copy-start(" in rest) and any(
+                        layout(rest) != layout(defs[n]) for n in used):
+                    found.append(f"{name} = {rest[:160]}")
+        return sorted(set(found))
+
+    def _table(self, sharding, n_src):
+        from oap_mllib_tpu.ops.pallas import als_gather
+
+        return _s((als_gather.table_rows(n_src, self.R), 128), sharding)
+
+    def _slots(self, sharding, p=None):
+        p = p or self.P
+        return jax.ShapeDtypeStruct((self.SLOTS // p, p), jnp.int32,
+                                    sharding=sharding)
+
+    @pytest.mark.parametrize("n_src", [624961, 500495])
+    def test_a_block_of_the_cell(self, one_chip, n_src):
+        from oap_mllib_tpu.ops.pallas import als_gather
+
+        assert als_gather.fits(n_src, self.R)
+        compiled = _compile(lambda t, s: als_gather._walk(t, s, self.R, False),
+                            self._table(one_chip, n_src), self._slots(one_chip))
+        # the output in the layout the moments read, (Gb, r, P), and in
+        # HBM at most one more block beside it (r padded to 16 sublanes)
+        # and the slots' packed rows and lane groups
+        assert "f32[8192,10,128]{2,1,0" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes <= self.SLOTS * (16 + 2) * 4
+
+    @pytest.mark.parametrize("n_src", [624961, 500495])
+    def test_the_moments_read_the_walks_block_as_it_lies(self, one_chip, n_src):
+        from oap_mllib_tpu.ops import als_ops
+
+        groups = self.SLOTS // self.P
+
+        def moments(src, conf, valid, factors):
+            return als_ops.grouped_block_moments(
+                src, conf, valid, factors, 40.0, True, "f32", "pallas")
+
+        compiled = _compile(
+            moments, self._slots(one_chip), _s((groups, self.P), one_chip),
+            _s((groups, self.P), one_chip), _s((n_src, self.R), one_chip))
+        text = compiled.as_text()
+        assert len(self.calls(text)) == 1
+        assert not self.relayouts(text)
+        # XLA's gather from the (r, n_src) table is gone
+        assert f"f32[{self.SLOTS},{self.R}]" not in text
+
+    @pytest.mark.parametrize("p", [8, 64, 256])
+    def test_the_widths_the_rule_may_choose(self, one_chip, p):
+        from oap_mllib_tpu.ops.pallas import als_gather
+
+        _compile(lambda t, s: als_gather._walk(t, s, self.R, False),
+                 self._table(one_chip, 624961), self._slots(one_chip, p))
+
+    def test_the_table_is_held_once_at_the_rules_bound(self, one_chip, monkeypatch):
+        """At the bound the packed table is 64 MiB: the walk compiles under
+        ``VMEM_LIMIT_BYTES``, and under the table's bytes plus 8 MiB (a
+        double-buffered table would need twice), not under the table's
+        bytes alone (it is resident, not streamed)."""
+        from oap_mllib_tpu.ops.pallas import _tiers, als_gather
+
+        n_src = 1 << 20
+        assert als_gather.fits(n_src, self.R) and not als_gather.fits(n_src + 8, self.R)
+        table = als_gather.table_bytes(n_src, self.R)
+        assert table == als_gather.TABLE_BOUND_BYTES < _tiers.VMEM_LIMIT_BYTES
+
+        def walk():
+            # a function of its own a compile: the limit is read as the
+            # walk is traced
+            return lambda t, s: als_gather._walk(t, s, self.R, False)
+
+        shapes = self._table(one_chip, n_src), self._slots(one_chip)
+        _compile(walk(), *shapes)
+        monkeypatch.setattr(als_gather, "VMEM_LIMIT_BYTES", table + 8 * MiB)
+        _compile(walk(), *shapes)
+        monkeypatch.setattr(als_gather, "VMEM_LIMIT_BYTES", table)
+        with pytest.raises(Exception, match="(?i)vmem"):
+            _compile(walk(), *shapes)

@@ -167,6 +167,54 @@ class TestGroupedChunkedCompiled:
         als_ops._als_run_grouped_jit.clear_cache()
 
 
+class TestGatherWalkCompiled:
+    """The factor-row gather as the Pallas walk over the packed,
+    VMEM-resident table (ops/pallas/als_gather.py) against XLA's gather,
+    bit for bit, at the ALS cell's shapes: one block of 2^20 slots in
+    groups of 128 from each side's table."""
+
+    @pytest.mark.parametrize("n_src", [624961, 500495])
+    def test_a_block_at_the_cells_shape(self, rng, n_src):
+        import jax
+
+        from oap_mllib_tpu.ops.pallas import als_gather
+
+        r, groups, p = 10, 8192, 128
+        f = jnp.asarray(rng.normal(size=(n_src, r)).astype(np.float32))
+        src = rng.integers(0, n_src, (groups, p)).astype(np.int32)
+        src[:, -5:] = 0  # pad slots
+        src[0, 0] = n_src - 1
+        src = jnp.asarray(src)
+        conf = jnp.asarray((rng.integers(0, 11, (groups, p)) * 10).astype(np.float32))
+        valid = jnp.asarray((np.arange(p) < p - 5).astype(np.float32)[None].repeat(groups, 0))
+        want = jax.jit(lambda f, s: f.T[:, s])(f, src)
+        got = jax.jit(lambda f, s: als_ops.gather_factor_rows(f, s, "pallas"))(f, src)
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+        moments = [
+            jax.jit(lambda s, c, v, f, g=g: als_ops.grouped_block_moments(
+                s, c, v, f, 40.0, True, "f32", g))(src, conf, valid, f)
+            for g in ("xla", "pallas")
+        ]
+        assert np.asarray(moments[0]).tobytes() == np.asarray(moments[1]).tobytes()
+        assert als_ops.resolve_gather_kernel(n_src, r, np.float32) == "pallas"
+        assert als_gather.table_bytes(n_src, r) <= als_gather.TABLE_BOUND_BYTES
+
+    def test_a_grouped_fit_through_either_gather(self, rng):
+        n_users, n_items, rank, iters = 3000, 2000, 10, 3
+        u, i, r = _synthetic(rng, n_users, n_items, nnz=60000)
+        x0 = jnp.asarray((rng.normal(size=(n_users, rank)) * 0.1).astype(np.float32))
+        y0 = jnp.asarray((rng.normal(size=(n_items, rank)) * 0.1).astype(np.float32))
+        dev = [jnp.asarray(a) for a in (*als_ops.build_grouped_edges(u, i, r, n_users),
+                                         *als_ops.build_grouped_edges(i, u, r, n_items))]
+        runs = [
+            als_ops.als_run_grouped(*dev, x0, y0, n_users, n_items, iters, 0.1,
+                                    40.0, True, gather_kernel=g)
+            for g in ("xla", "pallas")
+        ]
+        for a, b in zip(*runs):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
 class TestStreamedALSTpu:
     def test_streamed_matches_in_memory_compiled(self, rng):
         """The host-chunked streamed ALS (ops/als_stream.py) on the real
