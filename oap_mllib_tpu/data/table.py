@@ -186,22 +186,53 @@ def _put_in_place(host: np.ndarray, sharding):
     return table, -(-host.shape[0] // step)
 
 
-def upload_arrays(hosts, sharding):
+def _piece_rows(row_bytes: int) -> int:
+    """Rows of one ``upload_arrays`` piece, for rows of ``row_bytes``."""
+    return max(
+        1, _UPLOAD_PIECE_BYTES // _ONE_DEVICE_PIECES_IN_FLIGHT // max(row_bytes, 1)
+    )
+
+
+def held_rows(rows: int, live: int, row_bytes: int) -> int:
+    """How many rows of an array of ``rows`` rows of ``row_bytes``, of
+    which only the first ``live`` hold anything and the rest are zeros,
+    the host holds for ``upload_arrays``: the live rows alone where they
+    fill at least one piece — the zeros behind them are made on the
+    device and never cross the link —, else one piece's rows, or all
+    ``rows`` where the whole array is under a piece (it goes up whole).
+    Every piece of an array is then one piece long whatever ``live``
+    is: a piece of another length would be another write program."""
+    return min(rows, max(live, _piece_rows(row_bytes)))
+
+
+def upload_arrays(hosts, sharding, rows=None):
     """The ``upload`` sub-span for host arrays that are no row table of
     their own — ALS's grouped edge layouts, eight arrays of one fit —
     onto ONE device: together they are several GB, and handed to
     ``jnp.asarray`` one after another they are all in flight at once,
     which is the host link's slow path (``_UPLOAD_PIECE_BYTES``).  Here
     they go up under ``_put_in_place``'s bounds, shared by all of them:
-    views of at most ``_UPLOAD_PIECE_BYTES`` /
-    ``_ONE_DEVICE_PIECES_IN_FLIGHT`` along the first axis, that many in
-    flight, each written in place into its array's ONE buffer
-    (``_write_piece``, donated); an array of one piece is that piece.
+    views of ``_UPLOAD_PIECE_BYTES`` / ``_ONE_DEVICE_PIECES_IN_FLIGHT``
+    along the first axis, that many in flight, each written in place
+    into its array's ONE buffer (``_write_piece``, donated), which
+    starts as zeros made on the device; an array of one piece that is
+    the whole of it is that piece.
+
+    ``rows``: the first-axis length of each device array (None: the
+    host array's).  A host array may hold fewer, its first rows
+    (``held_rows``): the rest of the device array is those zeros, and
+    is never sent.  Every piece is a whole piece: the last ends at the
+    held rows and overlaps the one before it, re-sending rows it wrote
+    with the same bytes, so the pieces of arrays of one shape and dtype
+    are one write program whatever rows the host holds.
+
     Returns the device arrays, landed.  ``attrs["bytes"]`` is what was
-    sent, ``attrs["pieces"]`` in how many pieces, ``attrs["arrays"]`` of
-    how many arrays; the ``put`` / ``land`` / ``launch`` leaves say what
-    the host thread did meanwhile (``_upload``)."""
+    sent (overlaps included), ``attrs["device_bytes"]`` what the device
+    arrays hold, ``attrs["pieces"]`` in how many pieces, ``attrs
+    ["arrays"]`` of how many arrays; the ``put`` / ``land`` / ``launch``
+    leaves say what the host thread did meanwhile (``_upload``)."""
     piece_bytes = _UPLOAD_PIECE_BYTES // _ONE_DEVICE_PIECES_IN_FLIGHT
+    rows = [h.shape[0] for h in hosts] if rows is None else list(rows)
     flying = collections.deque()  # (piece, its array, its offset or None)
     write = _write_piece()
     out = [None] * len(hosts)
@@ -213,28 +244,35 @@ def upload_arrays(hosts, sharding):
         else:
             if out[k] is None:
                 out[k] = spans.launch(
-                    jnp.zeros, hosts[k].shape, piece.dtype, device=sharding
+                    jnp.zeros, (rows[k], *hosts[k].shape[1:]), piece.dtype,
+                    device=sharding,
                 )
             out[k] = spans.launch(write, out[k], piece, np.int32(lo))
         return out[k]
 
     with spans.child("upload") as span:
-        pieces = 0
+        pieces = sent = device_bytes = 0
         for k, host in enumerate(hosts):
             n = host.shape[0]
-            whole = host.nbytes <= piece_bytes
-            step = n if whole else max(1, piece_bytes * n // host.nbytes)
+            row_bytes = host.dtype.itemsize * int(np.prod(host.shape[1:]))
+            device_bytes += rows[k] * row_bytes
+            whole = n == rows[k] and host.nbytes <= piece_bytes
+            step = n if whole else min(n, _piece_rows(row_bytes))
             for lo in range(0, max(n, 1), max(step, 1)):
+                lo = max(0, min(lo, n - step))
+                piece = host[lo:lo + step]
                 flying.append(
-                    (_put(host[lo:lo + step], sharding), k, None if whole else lo)
+                    (_put(piece, sharding), k, None if whole else lo)
                 )
+                sent += piece.nbytes
                 pieces += 1
                 if len(flying) == _ONE_DEVICE_PIECES_IN_FLIGHT:
                     _land(write_oldest())
         while flying:
             write_oldest()
         _land(out)
-        span.attrs["bytes"] = sum(h.nbytes for h in hosts)
+        span.attrs["bytes"] = sent
+        span.attrs["device_bytes"] = device_bytes
         span.attrs["pieces"] = pieces
         span.attrs["arrays"] = len(hosts)
     return out

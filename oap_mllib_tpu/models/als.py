@@ -829,8 +829,8 @@ class ALS:
                             als_ops.auto_group_size(nnz, n_dst)
                             for _, _, n_dst in sides
                         ],
-                        groups_user=by_user[0].shape[0],
-                        groups_item=by_item[0].shape[0],
+                        groups_user=by_user[3].shape[0],
+                        groups_item=by_item[3].shape[0],
                         # of the wider side; the streamed kernels hold none
                         sheet_bytes=0 if stream_route
                         else membudget.als_sheet_bytes(
@@ -848,6 +848,10 @@ class ALS:
                         jax.sharding.SingleDeviceSharding(
                             jax.local_devices()[0]
                         ),
+                        # a layout's G: its group_dst's length; the rest
+                        # of its bucket's pad groups are zeros there
+                        rows=[g[3].shape[0] for g in (by_user, by_item)
+                              for _ in g],
                     ))
             else:
                 # COO nnz pads to a shape bucket (data/bucketing.py,

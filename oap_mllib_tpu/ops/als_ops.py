@@ -574,13 +574,17 @@ def build_grouped_edges(
     comes from the counted degrees (:func:`group_sizes_for`); 0 = from
     the mean degree, for callers without counts (:func:`auto_group_size`).
 
-    Returns (src_g (G, P) int32, conf_g (G, P) f32, valid_g (G, P) f32,
+    Returns (src_g (H, P) int32, conf_g (H, P) f32, valid_g (H, P) f32,
     group_dst (G,) int32).  Padding entries carry src=0, valid=0 so they
     vanish from every weighted sum.  ~1.2x edge blowup at P=64 on
     MovieLens-like degree distributions.  ``groups`` > the groups the
-    edges fill pads ``G`` up to it (:func:`group_bucket`): pad groups are
-    all zeros and carry the last destination's id, so ``group_dst`` stays
-    sorted.
+    edges fill pads ``G`` up to it (:func:`group_bucket`): pad groups
+    carry the last destination's id, so ``group_dst`` stays sorted, and
+    are all zeros — on the DEVICE: the other three arrays hold the
+    first ``H`` groups, the live ones alone where they fill an upload
+    piece (data/table.held_rows; ``H == G`` for small layouts and where
+    ``groups`` pads nothing), and ``data/table.upload_arrays(...,
+    rows=)`` with ``G`` makes the rest as zeros there, never sent.
 
     Prefers the native stable counting sort (O(nnz + n_dst),
     native/src/grouped_prep.cpp — the reference's host-side CSR prep
@@ -594,6 +598,7 @@ def build_grouped_edges(
     import numpy as np
 
     from oap_mllib_tpu.data.io import _force_py
+    from oap_mllib_tpu.data.table import held_rows
 
     P = group_size or auto_group_size(len(dst), n_dst)
     native_ok = False
@@ -612,11 +617,11 @@ def build_grouped_edges(
         np.cumsum(-(per_dst // -P) * P, out=start[1:])
         total = int(start[-1])
         G = max(total // P, groups)
-        # zeros, not empty: the pad groups that close a bucket are pages
-        # the host never touches
-        src_g = np.zeros((G, P), np.int32)
-        conf_g = np.zeros((G, P), np.float32)
-        valid_g = np.zeros((G, P), np.float32)
+        H = held_rows(G, total // P, P * 4)
+        # zeros, not empty: the rows past the live groups go up as pad
+        src_g = np.zeros((H, P), np.int32)
+        conf_g = np.zeros((H, P), np.float32)
+        valid_g = np.zeros((H, P), np.float32)
         group_dst = np.full((G,), max(n_dst - 1, 0), np.int32)
         native.als_place_ranges(
             dst, src, conf, counts, start, P, src_g.reshape(-1),
@@ -633,9 +638,10 @@ def build_grouped_edges(
     slot = starts[d] + (np.arange(len(d)) - first[d])
     total = int(padded.sum())
     G = max(total // P, groups)
-    src_g = np.zeros(G * P, np.int32)
-    conf_g = np.zeros(G * P, np.float32)
-    valid_g = np.zeros(G * P, np.float32)
+    H = held_rows(G, total // P, P * 4)
+    src_g = np.zeros(H * P, np.int32)
+    conf_g = np.zeros(H * P, np.float32)
+    valid_g = np.zeros(H * P, np.float32)
     src_g[slot] = np.asarray(src, np.int32)[order]
     conf_g[slot] = np.asarray(conf, np.float32)[order]
     valid_g[slot] = 1.0
@@ -644,9 +650,9 @@ def build_grouped_edges(
         np.arange(n_dst, dtype=np.int32), padded // P
     )
     return (
-        src_g.reshape(G, P),
-        conf_g.reshape(G, P),
-        valid_g.reshape(G, P),
+        src_g.reshape(H, P),
+        conf_g.reshape(H, P),
+        valid_g.reshape(H, P),
         group_dst,
     )
 

@@ -420,13 +420,128 @@ class TestUploadArrays:
         for host, dev in zip(hosts, out):
             assert dev.dtype == host.dtype and np.array_equal(np.asarray(dev), host)
         node = timings.root.node("table_convert/upload")
-        # 1 KiB a piece = 16 rows of 64 bytes: 3 pieces an array, the ids whole
-        assert node.attrs == {"bytes": sum(h.nbytes for h in hosts),
+        # 1 KiB a piece = 16 rows of 64 bytes: 3 pieces an array, the ids
+        # whole; the third piece is rows 24-40, a whole piece over 8 rows
+        # the second sent
+        assert node.attrs == {"bytes": 2 * 48 * 64 + 160,
+                              "device_bytes": sum(h.nbytes for h in hosts),
                               "pieces": 7, "arrays": 3}
         put = timings.root.node("table_convert/upload/put")
         assert put.count == 7 and put.attrs["bytes"] == node.attrs["bytes"]
         assert timings.root.node("table_convert/upload/launch").count == 2 + 6
         assert spans.current_span() is None
+
+    # 32 rows of 16 int32 / float32 slots a piece (8 KiB in flight)
+    PIECE_ROWS, WIDTH = 32, 16
+
+    def _layout(self, monkeypatch, live, seed=0):
+        """(a grouped side of exactly ``live`` groups built at its bucket
+        with pieces of ``PIECE_ROWS`` rows, the bucket, the padded
+        layout: the same with the bucket's pad groups on the host)."""
+        monkeypatch.setattr(table_mod, "_UPLOAD_PIECE_BYTES",
+                            4 * self.PIECE_ROWS * self.WIDTH * 4)
+        rng = np.random.default_rng(seed)
+        # one group a destination: 1 to WIDTH edges each
+        dst = np.repeat(np.arange(live, dtype=np.int32),
+                        rng.integers(1, self.WIDTH + 1, live))
+        rng.shuffle(dst)
+        src = rng.integers(0, 50, len(dst)).astype(np.int32)
+        conf = rng.integers(0, 5, len(dst)).astype(np.float32) * 25.0
+        bucket = als_ops.group_bucket(live)
+        layout = als_ops.build_grouped_edges(dst, src, conf, live, self.WIDTH,
+                                             groups=bucket)
+        exact = als_ops.build_grouped_edges(dst, src, conf, live, self.WIDTH)
+        assert exact[0].shape[0] == live < bucket
+        padded = [np.zeros((bucket, self.WIDTH), a.dtype) for a in exact[:3]]
+        for pad, a in zip(padded, exact):
+            pad[:live] = a
+        padded.append(np.full((bucket,), live - 1, np.int32))
+        padded[3][:live] = exact[3]
+        return layout, bucket, padded
+
+    def _upload(self, layout, bucket):
+        from oap_mllib_tpu.utils.timing import Timings
+
+        timings = Timings("als.fit")
+        with timings.span("table_convert"):
+            out = table_mod.upload_arrays(
+                layout, jax.sharding.SingleDeviceSharding(jax.local_devices()[0]),
+                rows=[bucket] * 4)
+        return out, timings.root.node("table_convert/upload").attrs
+
+    @pytest.mark.parametrize("live,held", [
+        (20, 32),   # under one piece: one piece's rows, zeros behind them
+        (64, 64),   # on a multiple of the piece
+        (65, 65),   # one row past it: the last piece starts at row 33
+    ])
+    def test_the_live_groups_go_up_into_the_buckets_zeros(
+            self, monkeypatch, live, held):
+        layout, bucket, padded = self._layout(monkeypatch, live)
+        assert [a.shape[0] for a in layout] == [held] * 3 + [bucket]
+        out, attrs = self._upload(layout, bucket)
+        for dev, want in zip(out, padded):
+            assert dev.dtype == want.dtype and dev.shape == want.shape
+            assert np.asarray(dev).tobytes() == want.tobytes()
+        assert int(als_ops.live_group_count(out[2])) == live
+        pieces = -(-held // self.PIECE_ROWS)
+        piece_bytes = self.PIECE_ROWS * self.WIDTH * 4
+        assert attrs == {
+            "bytes": 3 * pieces * piece_bytes + bucket * 4,
+            "device_bytes": sum(a.nbytes for a in padded),
+            "pieces": 3 * pieces + 1, "arrays": 4,
+        }
+
+    def test_another_live_count_in_the_bucket_compiles_nothing(
+            self, monkeypatch, bench):
+        adapter = bench[2]
+        first, bucket, _ = self._layout(monkeypatch, 65, seed=1)
+        second, bucket_2, padded = self._layout(monkeypatch, 90, seed=2)
+        assert bucket == bucket_2
+        self._upload(first, bucket)
+        compiles = progcache.xla_compile_count()
+        out, attrs = self._upload(second, bucket)
+        assert progcache.xla_compile_count() == compiles
+        assert all(np.asarray(d).tobytes() == w.tobytes()
+                   for d, w in zip(out, padded))
+        assert attrs["pieces"] == 3 * 3 + 1 and attrs["bytes"] < attrs["device_bytes"]
+        piece_bytes = self.PIECE_ROWS * self.WIDTH * 4
+        for limit in (None, piece_bytes):
+            cfg = bench[1] if limit is None else dict(
+                bench[1], expect_upload={"piece_bytes_max": limit})
+            assert adapter.upload_breach(cfg, attrs) is None
+        assert adapter.upload_breach(
+            dict(bench[1], expect_upload={"piece_bytes_max": piece_bytes // 2}),
+            attrs) is not None
+
+    def test_a_fit_of_live_groups_gives_the_host_padded_layouts_factors(
+            self, monkeypatch):
+        """A whole fit on the grouped route, its layouts uploaded in
+        pieces of 32 groups: the same factors, bit for bit, as when the
+        bucket's pad groups go up from the host."""
+        monkeypatch.setattr(als_ops, "_GROUP_SIZES", (self.WIDTH,))
+        monkeypatch.setattr(table_mod, "_UPLOAD_PIECE_BYTES",
+                            4 * self.PIECE_ROWS * self.WIDTH * 4)
+        set_config(als_kernel="grouped")
+        x = _skewed(17)
+        live = _fit(x, 300, 500)
+        build = als_ops.build_grouped_edges
+
+        def padded_on_the_host(dst, src, conf, n_dst, p, *, groups=0, **kw):
+            layout = build(dst, src, conf, n_dst, p, groups=groups, **kw)
+            pad = [np.zeros((groups, p), a.dtype) for a in layout[:3]]
+            for full, a in zip(pad, layout):
+                full[:a.shape[0]] = a
+            return (*pad, layout[3])
+
+        monkeypatch.setattr(als_ops, "build_grouped_edges", padded_on_the_host)
+        padded = _fit(x, 300, 500)
+        up = [m.summary["timings"].root.node("table_convert/upload").attrs
+              for m in (live, padded)]
+        assert up[0]["device_bytes"] == up[1]["device_bytes"] == up[1]["bytes"]
+        assert up[0]["bytes"] < up[0]["device_bytes"] and up[0]["pieces"] > 8
+        for got, want in ((live.user_factors_, padded.user_factors_),
+                          (live.item_factors_, padded.item_factors_)):
+            assert got.tobytes() == want.tobytes()
 
 
 class TestTheDeploymentsShare:
