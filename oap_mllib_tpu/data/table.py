@@ -21,6 +21,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import dataclasses
+import itertools
 import os
 from typing import Optional
 
@@ -32,7 +33,6 @@ from oap_mllib_tpu.data.bucketing import bucket_rows
 from oap_mllib_tpu.parallel.mesh import data_sharding
 from oap_mllib_tpu.telemetry import spans
 from oap_mllib_tpu.utils import progcache
-from oap_mllib_tpu.utils.jax_compat import shard_map
 
 # rows are padded per shard to this multiple (cheap: padding is masked)
 _ROW_MULTIPLE = 256
@@ -85,7 +85,8 @@ def _stage_rows(x, multiple: int, dtype, shards: int = 0):
 # shards of an 8.6 GB table put all at once — by one ``device_put`` under
 # the row sharding or by four — land at 1.8-2.1 GB/s, their time spent
 # mapping DMA buffers; the same shards in pieces of 1.07 GB, one a device
-# in flight, at 25 GB/s, and in pieces of 268 MB at 24.  One chip
+# in flight, at 25 GB/s, and in pieces of 268 MB at 24 (written in place,
+# 256 MiB pieces read 0.405 s against 0.352 for 1 GiB).  One chip
 # (PERF.md section 6, PR 31): 8.6 GB in one ``device_put`` at 0.8-1.3
 # GB/s; one 1 GiB piece at a time at 9.8 (2.1 GB whole: 10.4).
 _UPLOAD_PIECE_BYTES = 1 << 30
@@ -96,6 +97,23 @@ _UPLOAD_PIECE_BYTES = 1 << 30
 # four chips with two or eight pieces each in flight read 21.7 GB/s
 # against 24.5 with one.
 _ONE_DEVICE_PIECES_IN_FLIGHT = 4
+
+
+def _geometry(devices: int, cast: bool, row_bytes: int):
+    """(rows a piece, pieces a row shard has in flight) of an upload of
+    rows of ``row_bytes`` onto ``devices`` devices: the one rule.  Rows
+    cast under the upload go in ``_CAST_BLOCK_BYTES`` blocks, a ring of
+    ``_CAST_RING_SLOTS`` a shard; rows as they are in
+    ``_UPLOAD_PIECE_BYTES`` a device, on one device as
+    ``_ONE_DEVICE_PIECES_IN_FLIGHT`` pieces in flight together."""
+    if cast:
+        piece, in_flight = _CAST_BLOCK_BYTES, _CAST_RING_SLOTS
+    elif devices == 1:
+        in_flight = _ONE_DEVICE_PIECES_IN_FLIGHT
+        piece = _UPLOAD_PIECE_BYTES // in_flight
+    else:
+        piece, in_flight = _UPLOAD_PIECE_BYTES, 1
+    return max(1, piece // max(row_bytes, 1)), in_flight
 
 
 def _put(host, to, send=None):
@@ -117,24 +135,6 @@ def _land(arrays):
         return jax.block_until_ready(arrays)
 
 
-def _join_pieces(sharding):
-    """The program that makes every device's row shard of the pieces it
-    holds (one ``concatenate`` a device, no traffic), kept in the program
-    registry: a fresh jit(shard_map) closure a table would recompile."""
-    return progcache.get_or_build(
-        "table.join_pieces",
-        (progcache.mesh_fingerprint(sharding.mesh), tuple(sharding.spec)),
-        lambda: jax.jit(
-            shard_map(
-                lambda pieces: jnp.concatenate(pieces, axis=0),
-                mesh=sharding.mesh,
-                in_specs=(sharding.spec,),
-                out_specs=sharding.spec,
-            )
-        ),
-    )
-
-
 def _write_piece():
     """The program that writes a piece into a table at a row offset IN
     PLACE: the table-sized buffer is donated, so the output is the
@@ -151,46 +151,95 @@ def _write_piece():
     )
 
 
-def _put_in_place(host: np.ndarray, sharding):
-    """``_put_rows`` on ONE device, where joined pieces would hold the
-    table twice: ``host`` goes up in row-block views of at most
-    ``_UPLOAD_PIECE_BYTES`` in flight, ``_ONE_DEVICE_PIECES_IN_FLIGHT``
-    of them together, each written into ONE table-sized buffer
-    (``_write_piece``) and dropped before the next is sent."""
-    step = max(
-        1,
-        _UPLOAD_PIECE_BYTES // _ONE_DEVICE_PIECES_IN_FLIGHT
-        * host.shape[0] // max(host.nbytes, 1),
+def _zeros(device):
+    """The program that makes ``jnp.zeros(shape, dtype)`` ON ``device``
+    (a device or a one-device sharding), kept in the program registry.
+    ``jnp.zeros(device=)`` fills on the default device and copies: on a
+    mesh the first device held the others' zeros too (9 GiB on the
+    four-chip cell's first chip: PERF.md section 6)."""
+    if not isinstance(device, jax.sharding.Sharding):
+        device = jax.sharding.SingleDeviceSharding(device)
+    return progcache.get_or_build(
+        "table.zeros", (progcache.backend_fingerprint(), device),
+        lambda: jax.jit(jnp.zeros, static_argnums=(0, 1), out_shardings=device),
     )
-    flying = collections.deque()  # (piece, its row offset), oldest first
+
+
+def _write(buffers, pieces, in_flight: int):
+    """THE upload writer: every table and layout goes up through it.
+
+    ``buffers``: (shape, dtype, device) of each device buffer to fill;
+    ``pieces``: an ordered stream of (host array, the buffers it goes
+    into — a shard's replicas on a model axis take one piece —, its row
+    offset there).  Each piece is put at once; at most ``in_flight`` are
+    outstanding, and when that many are, the oldest is written into its
+    buffers IN PLACE (``_write_piece``, donated) and landed before the
+    next is put.  A buffer starts as zeros made on its device, so rows
+    no piece covers never cross the link, unless it is exactly one
+    piece: that piece IS the buffer, no write.
+
+    Returns (the buffers, landed; the pieces each took; bytes sent)."""
     write = _write_piece()
-    table = None
+    out = [None] * len(buffers)
+    taken = [0] * len(buffers)
+    sent = 0
+    flying = collections.deque()  # (device pieces, their buffers, offset)
 
-    def write_oldest(table):
-        piece, lo = flying.popleft()
-        if table is None:
-            table = spans.launch(
-                jnp.zeros, host.shape, piece.dtype, device=sharding
-            )
-        return spans.launch(write, table, piece, np.int32(lo))
+    def zeros(k):
+        shape, dtype, device = buffers[k]
+        return spans.launch(_zeros(device), shape, np.dtype(dtype))
 
-    for lo in range(0, host.shape[0], step):
-        flying.append((_put(host[lo:lo + step], sharding), lo))
-        if len(flying) == _ONE_DEVICE_PIECES_IN_FLIGHT:
-            table = write_oldest(table)
+    def write_oldest():
+        parts, into, lo = flying.popleft()
+        for part, k in zip(parts, into):
+            if part.shape == buffers[k][0]:
+                out[k] = part
+            else:
+                out[k] = spans.launch(write, out[k], part, np.int32(lo))
+        return [out[k] for k in into]
+
+    for host, into, lo in pieces:
+        for k in into:
+            if out[k] is None and host.shape != buffers[k][0]:
+                # made under the first piece's transfer; made at the first
+                # write it cost the cast route 7 ms (PERF.md section 6)
+                out[k] = zeros(k)
+        flying.append(([_put(host, buffers[k][2]) for k in into], into, lo))
+        sent += host.nbytes
+        for k in into:
+            taken[k] += 1
+        if len(flying) == in_flight:
             # the oldest piece has landed, is written and is gone: room
             # for the next one, which goes while the others are in flight
-            _land(table)
+            _land(write_oldest())
     while flying:
-        table = write_oldest(table)
-    return table, -(-host.shape[0] // step)
+        write_oldest()
+    # a buffer no piece reached holds pad rows alone
+    out = [zeros(k) if b is None else b for k, b in enumerate(out)]
+    return _land(out), taken, sent
 
 
-def _piece_rows(row_bytes: int) -> int:
-    """Rows of one ``upload_arrays`` piece, for rows of ``row_bytes``."""
-    return max(
-        1, _UPLOAD_PIECE_BYTES // _ONE_DEVICE_PIECES_IN_FLIGHT // max(row_bytes, 1)
-    )
+def _row_walk(n: int, starts, shard_rows: int, step: int):
+    """(a shard's first row, a piece's first row in its shard, the
+    piece's rows) of every piece of ``step`` rows that holds some of the
+    first ``n`` rows of shards of ``shard_rows`` rows starting at
+    ``starts``: a piece of every shard in turn, so that the devices'
+    transfers overlap."""
+    for at in range(0, shard_rows, step):
+        for start in starts:
+            rows = min(step, shard_rows - at, n - start - at)
+            if rows > 0:
+                yield start, at, rows
+
+
+def _views(host: np.ndarray, shards, shard_rows: int, step: int):
+    """The pieces of an array that goes up as it is: row views of it,
+    nothing copied on the host (the array itself where one piece is all
+    of it)."""
+    for start, at, rows in _row_walk(host.shape[0], shards, shard_rows, step):
+        lo = start + at
+        view = host if rows == host.shape[0] else host[lo:lo + rows]
+        yield view, shards[start], at
 
 
 def held_rows(rows: int, live: int, row_bytes: int) -> int:
@@ -202,7 +251,7 @@ def held_rows(rows: int, live: int, row_bytes: int) -> int:
     ``rows`` where the whole array is under a piece (it goes up whole).
     Every piece of an array is then one piece long whatever ``live``
     is: a piece of another length would be another write program."""
-    return min(rows, max(live, _piece_rows(row_bytes)))
+    return min(rows, max(live, _geometry(1, False, row_bytes)[0]))
 
 
 def upload_arrays(hosts, sharding, rows=None):
@@ -211,69 +260,40 @@ def upload_arrays(hosts, sharding, rows=None):
     onto ONE device: together they are several GB, and handed to
     ``jnp.asarray`` one after another they are all in flight at once,
     which is the host link's slow path (``_UPLOAD_PIECE_BYTES``).  Here
-    they go up under ``_put_in_place``'s bounds, shared by all of them:
-    views of ``_UPLOAD_PIECE_BYTES`` / ``_ONE_DEVICE_PIECES_IN_FLIGHT``
-    along the first axis, that many in flight, each written in place
-    into its array's ONE buffer (``_write_piece``, donated), which
-    starts as zeros made on the device; an array of one piece that is
-    the whole of it is that piece.
+    they go through ``_write`` under one device's ``_geometry``, shared
+    by all of them, each into its array's ONE buffer.
 
     ``rows``: the first-axis length of each device array (None: the
     host array's).  A host array may hold fewer, its first rows
-    (``held_rows``): the rest of the device array is those zeros, and
-    is never sent.  Every piece is a whole piece: the last ends at the
-    held rows and overlaps the one before it, re-sending rows it wrote
-    with the same bytes, so the pieces of arrays of one shape and dtype
-    are one write program whatever rows the host holds.
+    (``held_rows``): the rest of the device array is zeros made there,
+    and is never sent.  Every piece is a whole piece: the last ends at
+    the held rows and overlaps the one before it, re-sending rows it
+    wrote with the same bytes, so the pieces of arrays of one shape and
+    dtype are one write program whatever rows the host holds.
 
     Returns the device arrays, landed.  ``attrs["bytes"]`` is what was
     sent (overlaps included), ``attrs["device_bytes"]`` what the device
     arrays hold, ``attrs["pieces"]`` in how many pieces, ``attrs
     ["arrays"]`` of how many arrays; the ``put`` / ``land`` / ``launch``
     leaves say what the host thread did meanwhile (``_upload``)."""
-    piece_bytes = _UPLOAD_PIECE_BYTES // _ONE_DEVICE_PIECES_IN_FLIGHT
     rows = [h.shape[0] for h in hosts] if rows is None else list(rows)
-    flying = collections.deque()  # (piece, its array, its offset or None)
-    write = _write_piece()
-    out = [None] * len(hosts)
 
-    def write_oldest():
-        piece, k, lo = flying.popleft()
-        if lo is None:
-            out[k] = piece
-        else:
-            if out[k] is None:
-                out[k] = spans.launch(
-                    jnp.zeros, (rows[k], *hosts[k].shape[1:]), piece.dtype,
-                    device=sharding,
-                )
-            out[k] = spans.launch(write, out[k], piece, np.int32(lo))
-        return out[k]
-
-    with spans.child("upload") as span:
-        pieces = sent = device_bytes = 0
+    def pieces():
         for k, host in enumerate(hosts):
             n = host.shape[0]
-            row_bytes = host.dtype.itemsize * int(np.prod(host.shape[1:]))
-            device_bytes += rows[k] * row_bytes
-            whole = n == rows[k] and host.nbytes <= piece_bytes
-            step = n if whole else min(n, _piece_rows(row_bytes))
-            for lo in range(0, max(n, 1), max(step, 1)):
+            step = max(1, min(n, _geometry(1, False, host[:1].nbytes)[0]))
+            for lo in range(0, max(n, 1), step):
                 lo = max(0, min(lo, n - step))
-                piece = host[lo:lo + step]
-                flying.append(
-                    (_put(piece, sharding), k, None if whole else lo)
-                )
-                sent += piece.nbytes
-                pieces += 1
-                if len(flying) == _ONE_DEVICE_PIECES_IN_FLIGHT:
-                    _land(write_oldest())
-        while flying:
-            write_oldest()
-        _land(out)
+                yield host[lo:lo + step], (k,), lo
+
+    with spans.child("upload") as span:
+        out, taken, sent = _write(
+            [((r, *h.shape[1:]), h.dtype, sharding) for h, r in zip(hosts, rows)],
+            pieces(), _geometry(1, False, 0)[1],
+        )
         span.attrs["bytes"] = sent
-        span.attrs["device_bytes"] = device_bytes
-        span.attrs["pieces"] = pieces
+        span.attrs["device_bytes"] = sum(a.nbytes for a in out)
+        span.attrs["pieces"] = sum(taken)
         span.attrs["arrays"] = len(hosts)
     return out
 
@@ -309,21 +329,18 @@ class _RowBlocks:
     """The caller's array where it cannot go up as it is (dtype differs,
     not C-contiguous, or rows off their bucket) and its padded shard is
     more than a device may have in flight: never made whole on the host.
-    ``put`` walks it in row blocks of ``_CAST_BLOCK_BYTES``, each cast /
-    un-strided into a staging buffer by host threads (NumPy's own cast
-    of the block, once: the table is bit for bit
-    ``np.pad(x.astype(dtype), ...)``) and handed to ``device_put``, which
-    returns at once, while earlier blocks are still in flight.  Every
-    device's row shard starts as zeros made on that device and each block
-    is written into it in place (``_write_piece``, donated): only the
-    valid rows cross the link, the pad is what was never written, and a
-    device holds its shard and the blocks in flight, never a shard twice.
-    The staging buffers are a ring of ``_CAST_RING_SLOTS`` a shard, which
-    is also what is in flight: far under ``_UPLOAD_PIECE_BYTES`` a
-    device.
+    ``pieces`` walks its valid rows in blocks of ``_CAST_BLOCK_BYTES``,
+    each cast / un-strided into a staging buffer by host threads
+    (NumPy's own cast of the block, once: the table is bit for bit
+    ``np.pad(x.astype(dtype), ...)``) and handed to ``_write``, which
+    writes it into shards that start as zeros on their devices: only the
+    valid rows cross the link, and the pad is what was never written.
+    The staging buffers are a ring as long as what ``_write`` keeps in
+    flight, so a buffer is cast into again only once the block that held
+    it has landed.
 
     ``shape`` is the padded table's, ``nbytes`` what crosses the link;
-    after ``put``: ``cast_bytes`` (what the block casts wrote).  The
+    after the upload: ``cast_bytes`` (what the block casts wrote).  The
     seconds the sender stood waiting for block casts, sending nothing
     (the blocks in flight go on landing meanwhile), are the upload's
     ``cast`` leaf."""
@@ -332,8 +349,7 @@ class _RowBlocks:
         self.x = x
         self.dtype = np.dtype(dtype)
         self.shape = (padded_rows, x.shape[1])
-        self.row_bytes = x.shape[1] * self.dtype.itemsize
-        self.nbytes = x.shape[0] * self.row_bytes
+        self.nbytes = x.shape[0] * x.shape[1] * self.dtype.itemsize
         self.cast_bytes = 0
         self.cast_threads = _cast_threads()
 
@@ -350,107 +366,87 @@ class _RowBlocks:
                 cast.result()
         self.cast_bytes += buf.nbytes
 
-    def put(self, sharding):
-        n, (rows, d) = self.x.shape[0], self.shape
-        index = sharding.addressable_devices_indices_map(self.shape)
-        # the devices of a model axis hold replicas of a row shard: one
-        # cast for them all
-        shards = collections.defaultdict(list)
-        for dev, idx in index.items():
-            shards[idx[0].indices(rows)[0]].append(dev)
-        shard_rows = rows // len(shards)
-        step = max(1, _CAST_BLOCK_BYTES // self.row_bytes)
-        # (index in its shard, the shard's first row, rows) of every
-        # block, a block of every shard in turn so that the devices'
-        # transfers overlap
-        blocks = sorted(
-            (at, start, min(step, start + shard_rows - lo, n - lo))
-            for start in shards
-            for at, lo in enumerate(range(start, min(start + shard_rows, n), step))
-        )
-        slots = _CAST_RING_SLOTS * len(shards)
+    def pieces(self, shards, shard_rows: int, step: int, slots: int):
+        """``_views`` of the cast table: its valid rows in blocks of
+        ``step`` rows, each cast into the next of ``slots`` staging
+        buffers — ``_write``'s ``in_flight``, which has landed the block
+        that last held a buffer before it asks for the block after."""
+        blocks = list(_row_walk(self.x.shape[0], shards, shard_rows, step))
         ring = [
-            np.empty((step, d), self.dtype) for _ in range(min(slots, len(blocks)))
+            np.empty((step, self.shape[1]), self.dtype)
+            for _ in range(min(slots, len(blocks)))
         ]
-        write = _write_piece()
-        table = {
-            dev: spans.launch(
-                jnp.zeros, (shard_rows, d), self.dtype, device=dev
-            )
-            for dev in index
-        }
-        flying = collections.deque()  # (device, piece, row in its shard)
-
-        def write_oldest():
-            dev, piece, at = flying.popleft()
-            table[dev] = spans.launch(write, table[dev], piece, np.int32(at))
-            return table[dev]
-
         with concurrent.futures.ThreadPoolExecutor(self.cast_threads) as pool:
-            for i, (at, start, height) in enumerate(blocks):
-                if i >= slots:
-                    # the block that had this staging buffer has landed
-                    # and is written: the buffer is free to be cast into
-                    _land(
-                        [write_oldest() for _ in shards[blocks[i - slots][1]]]
-                    )
-                buf = ring[i % slots][:height]
-                self._cast(pool, buf, start + at * step)
-                for dev in shards[start]:
-                    flying.append((dev, _put(buf, dev), at * step))
-        while flying:
-            write_oldest()
-        return (
-            jax.make_array_from_single_device_arrays(
-                self.shape, sharding, [table[dev] for dev in index]
-            ),
-            max(1, -(-min(n, shard_rows) // step)),
-        )
+            for i, (start, at, rows) in enumerate(blocks):
+                buf = ring[i % slots][:rows]
+                self._cast(pool, buf, start + at)
+                yield buf, shards[start], at
 
 
-def _put_rows(host: np.ndarray, sharding):
-    """(``jax.device_put(host, sharding)`` of a table every row of which
-    this process holds, the pieces a row shard went up in).  In a world
-    of several processes, and on one device for a table of at most
-    ``_UPLOAD_PIECE_BYTES``, just that.  Else no device has more than
-    ``_UPLOAD_PIECE_BYTES`` in flight, so that what is in flight does
-    not grow with the table, and the pieces are views of ``host``
-    (nothing is copied on the host).  One device: written in place into
-    one buffer (``_put_in_place``), table + 1 GiB live.  Several: every
-    device's row slice in pieces, one piece a device in flight; a shard
-    of several pieces is joined on its device, where it is held twice
-    until the pieces are dropped.  ``_RowBlocks`` (an array that needs a
-    cast, an un-striding or a pad under those same bounds) puts itself."""
-    if isinstance(host, _RowBlocks):
-        return host.put(sharding)
-    index = sharding.addressable_devices_indices_map(host.shape)
+def _put_whole(padded, mask, mesh, send=None):
+    """(table, mask, 1): one ``device_put`` each (or ``send`` in its
+    place), landed — the upload of a world of several processes."""
+    return (*_land((
+        _put(padded, data_sharding(mesh, 2), send),
+        _put(mask, data_sharding(mesh, 1), send),
+    )), 1)
+
+
+def _put_rows(padded, mask, mesh):
+    """(table, mask, the pieces a row shard of the table went up in) of a
+    table every row of which this process holds.  In a world of several
+    processes ``_put_whole``.  Else both go through ``_write``, the
+    table's row shards first, a piece of every shard in turn, then the
+    mask's, at the table's ``_geometry``: what is in flight does not
+    grow with the table, and every device's shard is a buffer of its
+    own, written in place — a device holds its shard and the pieces in
+    flight, never a shard twice.  The pieces are views of ``padded``
+    (nothing is copied on the host), or, for a ``_RowBlocks``, its cast
+    blocks."""
     if jax.process_count() > 1:
-        return _put(host, sharding), 1
-    if len(index) == 1:
-        if host.nbytes <= _UPLOAD_PIECE_BYTES:
-            return _put(host, sharding), 1
-        return _put_in_place(host, sharding)
-    slices = [(dev, host[idx]) for dev, idx in index.items()]
-    shard_rows = slices[0][1].shape[0]
-    step = max(1, _UPLOAD_PIECE_BYTES * host.shape[0] // max(host.nbytes, 1))
-    pieces = []  # one global array a wave: that piece of every shard
-    for lo in range(0, shard_rows, step):
-        parts = [_put(rows[lo:lo + step], dev) for dev, rows in slices]
-        _land(parts)
-        shape = (host.shape[0] // shard_rows * parts[0].shape[0], *host.shape[1:])
-        pieces.append(
-            jax.make_array_from_single_device_arrays(shape, sharding, parts)
-        )
-    table = (
-        pieces[0] if len(pieces) == 1
-        else spans.launch(_join_pieces(sharding), pieces)
+        return _put_whole(padded, mask, mesh)
+    table_sharding, mask_sharding = data_sharding(mesh, 2), data_sharding(mesh, 1)
+    index = table_sharding.addressable_devices_indices_map(padded.shape)
+    devices = len(index)
+    # {a shard's first row: its buffers} — the devices of a model axis
+    # hold replicas of one shard, which take one piece
+    starts = [idx[0].indices(padded.shape[0])[0] for idx in index.values()]
+    shards = {s: [k for k, t in enumerate(starts) if t == s] for s in starts}
+    marks = {s: [k + devices for k in ks] for s, ks in shards.items()}
+    shard_rows = padded.shape[0] // len(shards)
+    buffers = [
+        ((shard_rows, *a.shape[1:]), a.dtype, dev)
+        for a in (padded, mask) for dev in index
+    ]
+    cast = isinstance(padded, _RowBlocks)
+    step, in_flight = _geometry(
+        devices, cast, padded.shape[1] * padded.dtype.itemsize
     )
-    return table, len(pieces)
+    in_flight *= len(shards)
+    table = (
+        padded.pieces(shards, shard_rows, step, in_flight) if cast
+        else _views(padded, shards, shard_rows, step)
+    )
+    mask_step = _geometry(devices, False, mask.itemsize)[0]
+    out, taken, _ = _write(
+        buffers,
+        itertools.chain(table, _views(mask, marks, shard_rows, mask_step)),
+        in_flight,
+    )
+    return (
+        jax.make_array_from_single_device_arrays(
+            padded.shape, table_sharding, out[:devices]
+        ),
+        jax.make_array_from_single_device_arrays(
+            mask.shape, mask_sharding, out[devices:]
+        ),
+        max(1, *taken[:devices]),
+    )
 
 
 def _upload(put, padded, mask: np.ndarray, mesh, n_valid: int):
-    """The ``upload`` sub-span of both constructors: ``put(host array,
-    sharding)`` for the table and its mask, then the wait for both —
+    """The ``upload`` sub-span of both constructors: ``put(table, mask,
+    mesh)`` sends the table and its mask and waits for both —
     ``device_put`` returns before the bytes land, and without the wait
     the rest of the upload is booked to whichever phase first blocks on
     the table.  ``attrs["bytes"]`` is what this process sent,
@@ -467,14 +463,12 @@ def _upload(put, padded, mask: np.ndarray, mesh, n_valid: int):
     ``land`` blocked until bytes had landed or an in-place write had
     finished, ``cast`` blocked on a block's cast threads —
     ``attrs["cast_wait_s"]`` is that leaf's seconds, one clock reading
-    in two views —, ``launch`` inside the calls that start ``jnp.zeros``,
-    an in-place write or the join (a write returns after 2-6 ms while
-    pieces are in flight, 0.3 ms otherwise).  What is left is the span's
-    self time: Python."""
+    in two views —, ``launch`` inside the calls that start ``jnp.zeros``
+    or an in-place write (a write returns after 2-6 ms while pieces are
+    in flight, 0.3 ms otherwise).  What is left is the span's self time:
+    Python."""
     with spans.child("upload") as span:
-        data, pieces = put(padded, data_sharding(mesh, 2))
-        mask_dev, _ = put(mask, data_sharding(mesh, 1))
-        _land((data, mask_dev))
+        data, mask_dev, pieces = put(padded, mask, mesh)
         span.attrs["bytes"] = padded.nbytes + mask.nbytes
         span.attrs["shards"] = mesh.shape[mesh.axis_names[0]]
         span.attrs["pieces"] = pieces
@@ -486,6 +480,8 @@ def _upload(put, padded, mask: np.ndarray, mesh, n_valid: int):
             c.duration_s for c in span.children if c.name == spans.CAST
         )
     return data, mask_dev
+
+
 
 
 @dataclasses.dataclass
@@ -516,14 +512,14 @@ class DenseTable:
     array alone:
 
     - the table's dtype, C-contiguous, rows on their bucket: uploaded AS
-      IS, with no host copy (``copied_bytes`` 0, ``cast_bytes`` 0).  A
-      shard of more than 1 GiB goes up in row-block views of the array,
-      1 GiB a device in flight (``_put_rows``): what is in flight does
-      not grow with the table.  On one device the pieces are written in
-      place into the table's one buffer — it holds table + 1 GiB, never
-      the table twice; on a mesh a shard's pieces are joined on its
-      device.  The array must not be mutated until the constructor
-      returns; the program never writes into it;
+      IS, with no host copy (``copied_bytes`` 0, ``cast_bytes`` 0), in
+      row-block views of the array, 1 GiB a device in flight
+      (``_put_rows``): what is in flight does not grow with the table.
+      A shard of more than one piece (256 MiB on one device, 1 GiB a
+      device on a mesh) is written in place into its device's one
+      buffer — a device holds its shard + 1 GiB, never the shard twice.
+      The array must not be mutated until the constructor returns; the
+      program never writes into it;
     - another dtype (Spark's float64 rows), another layout, or rows off
       their bucket, and a padded shard of more than 1 GiB: cast,
       un-strided and padded UNDER the upload (``_RowBlocks``), 64 MiB
@@ -534,7 +530,8 @@ class DenseTable:
       device, and each value is rounded once, by NumPy's own cast: the
       table is ``np.pad(x.astype(dtype), ...)`` bit for bit;
     - the same, and a padded shard of at most 1 GiB: ONE pass of its own
-      into a padded host array (``copied_bytes`` = its size), put whole.
+      into a padded host array (``copied_bytes`` = its size), which
+      goes up as the first case's does.
       No device program's shape follows the valid rows there, so fits
       whose sizes share a bucket share every compiled program; the
       blocks route compiles one small in-place write for the block its
@@ -661,11 +658,9 @@ class DenseTable:
             mask_local = np.zeros((padded.shape[0],), dtype=padded.dtype)
             mask_local[:n_valid_local] = 1.0
         data, mask = _upload(
-            lambda host, sharding: (
-                _put(
-                    host, sharding,
-                    lambda h, s: jax.make_array_from_process_local_data(s, h),
-                ), 1
+            lambda padded, mask, mesh: _put_whole(
+                padded, mask, mesh,
+                lambda h, s: jax.make_array_from_process_local_data(s, h),
             ),
             padded, mask_local, mesh, n_valid_local,
         )

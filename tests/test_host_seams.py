@@ -28,7 +28,7 @@ PIECE_ROWS = 300  # several pieces a shard and an uneven last one
 ROUTES = {
     "whole": (1, np.float32, False),
     "in_place": (1, np.float32, True),
-    "waves_joined": (4, np.float32, True),
+    "mesh_in_place": (4, np.float32, True),
     "blocks_one_device": (1, np.float64, True),
     "blocks_mesh": (4, np.float64, True),
 }
@@ -131,13 +131,10 @@ class TestUploadLeaves:
         assert leaves["put"].count == len(made)
         assert leaves["put"].attrs["bytes"] == sum(made) == up.attrs["bytes"]
         assert up.attrs["pieces"] > 1 or route == "whole"
-        # one entry a program the upload starts: the join of the waves'
-        # pieces; else a jnp.zeros a device and an in-place write a piece
-        # (the mask goes up in one put a device and is written nowhere,
-        # so there are as many launches as puts)
-        if route == "waves_joined":
-            assert leaves["launch"].count == 1
-        elif route != "whole":
+        # one entry a program the upload starts: a jnp.zeros a device and
+        # an in-place write a piece (the mask goes up in one put a device
+        # and is written nowhere, so there are as many launches as puts)
+        if route != "whole":
             assert leaves["launch"].count == len(made)
         # the children are parts of the parent, on one clock
         assert sum(c.duration_s for c in up.children) <= up.duration_s

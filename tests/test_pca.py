@@ -422,9 +422,11 @@ class TestCovariancePhase:
         assert [w for w in waits if w[0].startswith("covariance")] == [
             ("covariance/fetch", [(8, 8)])
         ]
-        # and the staging phase's wait for table and mask, as before, in
-        # the upload's land leaf
-        assert ("table_convert/upload/land", [(2048, 8), (2048,)]) in waits
+        # and the staging phase's wait for table and mask — every one of
+        # the 8 devices' shards of each — in the upload's land leaf
+        assert (
+            "table_convert/upload/land", [(256, 8)] * 8 + [(256,)] * 8
+        ) in waits
 
 
 class TestBenchmarkCellAtRehearseSize:

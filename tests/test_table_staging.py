@@ -228,14 +228,13 @@ def test_fit_result_does_not_depend_on_the_layout(blobs, estimator):
 
 
 class TestUploadInPieces:
-    """A row shard of more than a device may have in flight goes up in
-    pieces of bounded size, views of the caller's array
-    (``data/table._put_rows``).  On a mesh of several devices: one piece
-    a device in flight, a shard's pieces joined on its device.  On ONE
-    device, where a join would hold the table twice: a few pieces in
-    flight together, each written IN PLACE into the one table-sized
-    buffer (``_put_in_place`` / ``_write_piece``).  Either way the same
-    array as the one ``device_put``."""
+    """A row shard of more than one piece goes up in pieces of bounded
+    size, views of the caller's array (``data/table._put_rows``), each
+    written IN PLACE into its device's one shard-sized buffer by the one
+    writer (``_write`` / ``_write_piece``).  On a mesh of several
+    devices: one piece a device in flight.  On ONE device: a few pieces
+    in flight together.  Either way the same array as the one
+    ``device_put``."""
 
     def _table(self, monkeypatch, x, piece_bytes, n_devices=4, in_flight=1):
         """(the table of ``x`` with pieces of ``piece_bytes`` — on one
@@ -296,13 +295,14 @@ class TestUploadInPieces:
         assert [s.data.shape for s in table.data.addressable_shards] == (
             [(shard_rows, blobs.shape[1])] * 4
         )
-        # every wave is one piece a device, waited for before the next
-        # goes; then the mask's waves (an item a row), then the upload
-        # span's own wait for table and mask
+        # one piece a device in flight, the table's then the mask's (an
+        # item a row), a device in turn: from the fourth piece on, each
+        # waits for the oldest before the next goes; then the writer's
+        # own wait for every device's table and mask
         waves = {1: 1, 2: 2, 2.5: 3, None: shard_rows}[pieces]
         mask_waves = -(-shard_rows // (piece // blobs.itemsize))
         waits = [n for what, n in events if what == "wait"]
-        assert waits == [4] * (waves + mask_waves) + [2]
+        assert waits == [1] * (4 * (waves + mask_waves) - 3) + [8]
 
     # on ONE device: whole, halves, a ragged tail twice over, one row each
     @pytest.mark.parametrize("in_flight", [1, 3])
@@ -328,11 +328,11 @@ class TestUploadInPieces:
     def test_one_device_goes_up_as_it_did(
         self, blobs, monkeypatch, pieces, in_flight
     ):
-        """No more than a device may have in flight: one ``device_put``
-        of the caller's array itself.  Over it: ``ceil(bytes / piece)``
-        puts, each a view of the caller's array and none larger than the
-        piece, never more in flight than allowed — a piece counts until
-        the wait for the table it was written into returns."""
+        """No more than one piece: one ``device_put`` of the caller's
+        array itself.  Over it: ``ceil(bytes / piece)`` puts, each a view
+        of the caller's array and none larger than the piece, never more
+        in flight than allowed — a piece counts until the wait for the
+        table it was written into returns."""
         piece = self._piece_bytes(blobs, blobs.shape[0], pieces)
         table, events = self._table(
             monkeypatch, blobs, piece, n_devices=1, in_flight=in_flight
@@ -340,13 +340,12 @@ class TestUploadInPieces:
         n = -(-blobs.nbytes // piece)
         assert n == math.ceil(pieces)
         sent = [v for what, v in events if what == "put" and v.ndim == 2]
-        if n <= in_flight:
-            assert len(sent) == 1 and sent[0] is blobs
-        else:
-            assert len(sent) == n
+        assert len(sent) == n
+        if n == 1:
+            assert sent[0] is blobs
         for part in sent:
             assert np.shares_memory(part, blobs) and part.flags.c_contiguous
-            assert part.nbytes <= piece * (in_flight if n <= in_flight else 1)
+            assert part.nbytes <= piece
         assert sum(part.shape[0] for part in sent) == blobs.shape[0]
         flying, most = 0, 0
         for what, v in events:
@@ -355,7 +354,7 @@ class TestUploadInPieces:
                 most = max(most, flying)
             elif what == "wait" and flying:
                 flying -= 1
-        assert most == (1 if n <= in_flight else in_flight)
+        assert most == min(n, in_flight)
         assert np.asarray(table.data).tobytes() == blobs.tobytes()
 
     def test_one_device_sends_four_pieces_together(self, blobs, monkeypatch):
@@ -411,8 +410,7 @@ class TestUploadInPieces:
         assert up.attrs == {
             "bytes": blobs.nbytes + blobs.shape[0] * blobs.itemsize,
             "shards": n_devices,
-            # a shard that may all be in flight at once goes up whole
-            "pieces": pieces if pieces > in_flight else 1,
+            "pieces": pieces,
             # the caller's array itself: nothing to cast, nothing padded
             "valid_rows": blobs.shape[0], "padded_rows": blobs.shape[0],
             "cast_bytes": 0, "cast_wait_s": 0, "cast_threads": 0,
@@ -451,12 +449,13 @@ class TestUploadInPieces:
         assert (
             pieced.cluster_centers_.tobytes() == whole.cluster_centers_.tobytes()
         )
-        # the join is one program a mesh, found again by the next table
-        joins = lambda: dict(progcache.stats()["by_algo"]["table.join_pieces"])
-        before = joins()
+        # every device's pieces are written in place by the one writer,
+        # found again by the next table
+        writes = lambda: dict(progcache.stats()["by_algo"]["table.write_piece"])
+        before = writes()
         KMeans(k=4, max_iter=3, seed=0).fit(blobs)
-        assert joins()["hits"] == before["hits"] + 1
-        assert joins()["misses"] == before["misses"]
+        assert writes()["hits"] == before["hits"] + 1
+        assert writes()["misses"] == before["misses"]
 
     @pytest.mark.parametrize("estimator", ["kmeans", "pca"])
     def test_a_one_device_fit_through_three_pieces(
@@ -677,3 +676,67 @@ class TestCastUnderTheUpload:
         gc.collect()
         new = [a for a in jax.live_arrays() if id(a) not in before]
         assert {id(a) for a in new} == {id(table.data), id(table.mask)}
+
+
+# -- every piece source through the one writer (``data/table._write``) -------
+
+PIECE_ROWS = 100  # pieces and cast blocks of 100 rows of W float32
+# source -> (devices, the caller's dtype): float32 goes up as it is in
+# views, float64 off its bucket is cast in blocks; None: several arrays
+# of which the host holds the first rows (``upload_arrays``)
+SOURCES = {
+    "as_is_one_device": (1, np.float32),
+    "as_is_mesh": (8, np.float32),
+    "cast_one_device": (1, np.float64),
+    "cast_mesh": (8, np.float64),
+    "held_rows": (1, None),
+}
+
+
+@pytest.mark.parametrize("source", sorted(SOURCES))
+def test_every_source_goes_through_the_one_writer(rng, monkeypatch, source):
+    """Views of an as-is array, cast blocks and arrays with held rows,
+    on one device and on the 8-device mesh: the device arrays are
+    ``np.pad(x.astype(dtype))`` byte for byte, and the upload span's
+    ``pieces`` and ``bytes`` are what the geometry of 100-row pieces
+    gives (a shard's pieces; the valid rows and the mask; for held rows
+    every piece of every array, the last overlapping the one before)."""
+    n_devices, src_dtype = SOURCES[source]
+    piece = PIECE_ROWS * W * 4
+    monkeypatch.setattr(
+        table_mod, "_UPLOAD_PIECE_BYTES",
+        piece * (table_mod._ONE_DEVICE_PIECES_IN_FLIGHT if n_devices == 1 else 1),
+    )
+    monkeypatch.setattr(table_mod, "_CAST_BLOCK_BYTES", piece)
+    timings = Timings("test.fit")
+    if src_dtype is None:
+        rows, held = 1000, 250  # three pieces, the last from row 150
+        hosts = [(rng.normal(size=(held, W)) * 100).astype(np.float32),
+                 rng.integers(0, 9, (held, W)).astype(np.int32)]
+        with phase_timer(timings, "table_convert"):
+            out = table_mod.upload_arrays(
+                hosts, jax.sharding.SingleDeviceSharding(jax.local_devices()[0]),
+                rows=[rows, rows],
+            )
+        got, want = out, [np.pad(h, ((0, rows - held), (0, 0))) for h in hosts]
+        pieces = 2 * 3
+        sent = 2 * 3 * piece
+    else:
+        bucket = n_devices * table_mod._ROW_MULTIPLE * 2
+        n = bucket if src_dtype == np.float32 else bucket - 137
+        x = (rng.normal(size=(n, W)) * 100).astype(src_dtype)
+        with phase_timer(timings, "table_convert"):
+            table = DenseTable.from_numpy(
+                x, get_mesh(n_devices=n_devices), np.float32
+            )
+        got = [table.data, table.mask]
+        want = [np.pad(x.astype(np.float32), ((0, bucket - n), (0, 0))),
+                (np.arange(bucket) < n).astype(np.float32)]
+        pieces = -(-min(n, bucket // n_devices) // PIECE_ROWS)
+        sent = n * W * 4 + bucket * 4
+    for dev, host in zip(got, want):
+        assert dev.dtype == host.dtype and dev.shape == host.shape
+        assert np.asarray(dev).tobytes() == host.tobytes()
+    up = timings.root.node("table_convert/upload")
+    assert up.attrs["pieces"] == pieces and up.attrs["bytes"] == sent
+    assert timings.root.node("table_convert/upload/put").attrs["bytes"] == sent

@@ -582,26 +582,44 @@ class TestDataParallelKMeans:
             assert collective not in text
         assert compiled.output_shardings.is_fully_replicated
 
-    def test_upload_joins_a_shards_pieces_on_its_device(self, mesh):
+    def test_upload_writes_a_shards_pieces_in_place(self, mesh):
+        """The cell's shard (2 GiB) goes up as two pieces of 1 GiB, one a
+        device in flight, each written into its device's one shard-sized
+        buffer, made as zeros there: the shard is donated, nothing
+        crosses to another chip, and the device holds one shard and one
+        piece."""
         from oap_mllib_tpu.data import table as table_mod
         from oap_mllib_tpu.utils import progcache
 
-        rows = NamedSharding(mesh, P("data", None))
-        progcache.clear()  # the registry may hold another mesh's program
-        # the cell's shard (2 GiB) goes up as two pieces of 1 GiB a device
-        piece = _s((self.ROWS // 2, self.D), rows)
-        compiled = table_mod._join_pieces(rows).lower([piece, piece]).compile()
+        shard_rows = self.ROWS // 4
+        step, in_flight = table_mod._geometry(4, False, self.D * 4)
+        assert (step, in_flight) == (shard_rows // 2, 1)
+        dev = SingleDeviceSharding(mesh.devices.flat[1])
+        progcache.clear()  # the registry may hold another backend's program
+        compiled = table_mod._write_piece().lower(
+            _s((shard_rows, self.D), dev), _s((step, self.D), dev),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=dev),
+        ).compile()
         text = compiled.as_text()
+        assert "input_output_alias={ {}: (0, {}" in text
         for collective in ("all-gather", "all-reduce", "collective-permute",
                            "all-to-all"):
-            assert collective not in text  # a local copy, no traffic
-        assert compiled.output_shardings == rows
+            assert collective not in text
         mem = compiled.memory_analysis()
-        shard = (self.ROWS // 4) * self.D * 4
-        # pieces + the joined shard: a device holds its rows twice, no more
+        shard, piece = shard_rows * self.D * 4, step * self.D * 4
+        assert mem.alias_size_in_bytes == shard == mem.output_size_in_bytes
         held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-                + mem.temp_size_in_bytes)
-        assert held <= 2 * shard + 2**20
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        assert held <= shard + piece + 2**20
+        # the shard's zeros are made on its own device, by one program
+        # that holds the shard alone
+        zeros = table_mod._zeros(dev).lower(
+            (shard_rows, self.D), np.dtype(np.float32)
+        ).compile()
+        assert zeros.output_shardings == dev
+        mem = zeros.memory_analysis()
+        assert mem.output_size_in_bytes == shard
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2**20
 
 
 class TestOneChipUpload:
