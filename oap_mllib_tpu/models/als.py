@@ -795,6 +795,27 @@ class ALS:
             plan = membudget.plan_als(
                 nnz, n_users, n_items, self.rank, world=1, **plan_kw
             )
+            if (plan.route == membudget.ROUTE_DST_BLOCKED
+                    and min(sizes) < als_ops.DST_LEAST_GROUP):
+                # that route multiplies a group as one tile of whole
+                # lanes: it takes the cheapest widths of whole lanes
+                with spans.child("group_edges"):
+                    sizes = als_ops.group_sizes_for(
+                        counts, self.rank, None, gather,
+                        least=als_ops.DST_LEAST_GROUP,
+                    )
+                    padded = [
+                        als_ops.padded_edges(c, p)
+                        for c, p in zip(counts, sizes)
+                    ]
+                    buckets = [
+                        als_ops.group_bucket(tot // p)
+                        for tot, p in zip(padded, sizes)
+                    ]
+                plan = membudget.plan_als(
+                    nnz, n_users, n_items, self.rank, world=1,
+                    grouped=list(zip(buckets, sizes)),
+                )
             planned_streamed = plan.route == membudget.ROUTE_STREAMED
             if planned_streamed and not grouped_ok:
                 # the planner routed streamed but the degree
@@ -808,6 +829,12 @@ class ALS:
                 )
                 planned_streamed = False
             stream_route = bool(degraded) or planned_streamed
+            # the resident layouts, solved a block of destinations at a
+            # time: where the grouped route's sheet does not fit
+            dst_route = (
+                grouped_ok and not stream_route
+                and plan.route == membudget.ROUTE_DST_BLOCKED
+            )
             if grouped_ok:
                 with spans.child("group_edges") as span:
                     by_user, by_item = (
@@ -831,8 +858,9 @@ class ALS:
                         ],
                         groups_user=by_user[3].shape[0],
                         groups_item=by_item[3].shape[0],
-                        # of the wider side; the streamed kernels hold none
-                        sheet_bytes=0 if stream_route
+                        # of the wider side; the streamed kernels and
+                        # the destination-blocked route hold none
+                        sheet_bytes=0 if stream_route or dst_route
                         else membudget.als_sheet_bytes(
                             max(buckets), self.rank
                         ),
@@ -878,7 +906,7 @@ class ALS:
                 iterations=int(self.max_iter), solve_kernel=solve_kernel,
                 rank=int(self.rank), implicit=bool(self.implicit_prefs),
             )
-            if grouped_ok:
+            if grouped_ok and not dst_route:
                 from oap_mllib_tpu.ops.pallas import als_gather
 
                 # the walk's packed tables, the user side's (items) first
@@ -890,7 +918,37 @@ class ALS:
                         for n_src in (n_items, n_users)
                     ],
                 )
-            if grouped_ok and stream_route:
+            if dst_route:
+                from oap_mllib_tpu.ops.pallas import als_dst
+
+                moments, solve = als_ops.resolve_dst_kernels()
+                blocks = [
+                    als_ops.dst_blocks(n, plan.dst_block)[1]
+                    for n in (n_users, n_items)
+                ]
+                span.attrs.update(
+                    route=plan.route, dst_block=int(plan.dst_block),
+                    blocks_user=blocks[0], blocks_item=blocks[1],
+                    r_pad=als_dst.r_pad(self.rank), gather="rows",
+                    moments=moments, solve=solve,
+                )
+
+                def run_iters(xa, ya, iters):
+                    return als_ops.als_run_dst_blocked(
+                        *dev, jnp.asarray(xa), jnp.asarray(ya),
+                        n_users, n_items, iters, self.reg_param,
+                        self.alpha, self.implicit_prefs,
+                        dst_block=plan.dst_block, timings=timings,
+                        policy=pol.name, moments=moments, solve=solve,
+                    )
+
+                if ckpt is None:
+                    x, y = run_iters(x0, y0, self.max_iter)
+                else:
+                    x, y = self._run_segmented(
+                        ckpt, x0, y0, run_iters, n_users, n_items
+                    )
+            elif grouped_ok and stream_route:
                 from oap_mllib_tpu.ops import als_stream
 
                 x, y = als_stream.als_run_streamed(
@@ -946,7 +1004,9 @@ class ALS:
                     )
             # the fit ends when both factor tables are back on the host
             x, y = spans.fetch(lambda: (np.asarray(x), np.asarray(y)))
-        timings.root.attrs["kernel"] = "grouped" if grouped_ok else "coo"
+        timings.root.attrs["kernel"] = (
+            "dst_blocked" if dst_route else "grouped" if grouped_ok else "coo"
+        )
         summary = {
             "timings": timings, "accelerated": True,
             "als_kernel": timings.root.attrs["kernel"],

@@ -177,6 +177,12 @@ def _chol_solve_unrolled(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.stack(w, axis=1)  # (B, r)
 
 
+# The largest rank the unrolled batch-wide solves take: the XLA one
+# (:func:`_chol_solve_unrolled`) and the fused Pallas one
+# (ops/pallas/als_kernel.py); larger ranks take the library routines.
+UNROLLED_RANK_MAX = 32
+
+
 def masked_solve(a: jax.Array, b: jax.Array, deg: jax.Array) -> jax.Array:
     """Batched SPD solve via Cholesky; rows with no (reg-counted) ratings
     get zero factors (fallback-path semantics).  Small static ranks (the
@@ -185,7 +191,7 @@ def masked_solve(a: jax.Array, b: jax.Array, deg: jax.Array) -> jax.Array:
     latency-bound lowering); larger ranks use the library routines.  A
     singular/non-SPD A (possible at reg=0) yields NaN either way, which
     nan_to_num + the degree mask absorb."""
-    if b.shape[-1] <= 32:
+    if b.shape[-1] <= UNROLLED_RANK_MAX:
         factors = _chol_solve_unrolled(a, b)
     else:
         import jax.scipy.linalg as jsl
@@ -296,8 +302,8 @@ def resolve_solve_kernel(r: int, dtype=None, cfg=None) -> str:
     block-parallel, streamed) resolves through, so two paths cannot
     route the same fit to different solve kernels.  "auto" takes the
     fused Pallas kernel on TPU with f32 factors in the unrolled-rank
-    regime (r <= 32); anything else — CPU tier-1 included — keeps the
-    XLA path.  A typo'd value raises on EVERY accelerated fit."""
+    regime (r <= :data:`UNROLLED_RANK_MAX`); anything else — CPU tier-1
+    included — keeps the XLA path.  A typo'd value raises on EVERY accelerated fit."""
     import numpy as np
 
     from oap_mllib_tpu.config import get_config
@@ -316,7 +322,7 @@ def resolve_solve_kernel(r: int, dtype=None, cfg=None) -> str:
     if (
         want
         and jax.default_backend() == "tpu"
-        and r <= 32
+        and r <= UNROLLED_RANK_MAX
         and (dtype is None or np.dtype(dtype) == np.float32)
     ):
         return "pallas"
@@ -486,7 +492,8 @@ _LANE_SLOT_NS = 0.53
 _BUCKET_GROUP_NS = 16.0
 
 
-def group_sizes_for(counts, r: int, room=None, gather: str = "xla"):
+def group_sizes_for(counts, r: int, room=None, gather: str = "xla",
+                    least: int = 0):
     """The group width ``P`` of each grouped side, from the degrees the
     fit has counted: ``counts`` holds one :func:`count_edges` a side.
     Every width of ``_GROUP_SIZES`` is priced from the groups it would
@@ -508,7 +515,9 @@ def group_sizes_for(counts, r: int, room=None, gather: str = "xla"):
     is no candidate — the sheet doubles with every halving of ``P``, and
     a row of fewer than 128 slots still takes 128 lanes — unless none
     fits, and then the streamed route, which holds neither, runs the
-    cheapest."""
+    cheapest.  ``least``: the narrowest width a side may take (the
+    destination-blocked route multiplies a group as one tile of whole
+    lanes, :data:`DST_LEAST_GROUP`)."""
     import itertools
 
     import numpy as np
@@ -520,7 +529,7 @@ def group_sizes_for(counts, r: int, room=None, gather: str = "xla"):
     for side in counts:
         per_dst = side.sum(axis=0, dtype=np.int64)
         options = []
-        for p in _GROUP_SIZES:
+        for p in (p for p in _GROUP_SIZES if p >= least):
             groups = int((-(per_dst // -p)).sum())
             bucket = group_bucket(groups)
             ns = groups * (
@@ -702,15 +711,7 @@ def grouped_block_moments(
     packed table (:func:`gather_factor_rows`)."""
     # (r, Gb, P) transposed gather
     ys = gather_factor_rows(src_factors, src_b, gather, table)
-    if implicit:
-        a_w = alpha * jnp.abs(conf_b) * valid_b
-        pos = (conf_b > 0).astype(conf_b.dtype) * valid_b
-        b_w = (1.0 + alpha * jnp.abs(conf_b)) * pos
-        n_w = pos
-    else:
-        a_w = valid_b
-        b_w = conf_b * valid_b
-        n_w = valid_b
+    a_w, b_w, n_w = slot_weights(conf_b, valid_b, alpha, implicit)
     lhs = jnp.concatenate(
         [ys, jnp.ones_like(conf_b)[None]], axis=0
     )  # (r+1, Gb, P)
@@ -722,6 +723,24 @@ def grouped_block_moments(
     )  # (Gb, r+1, r+2)  <- batched MXU, P-lane contraction; bf16 policy
     # casts the factor-carrying lhs/rhs tiles and accumulates f32 — the
     # per-destination moment tiles (and the solves they feed) stay f32
+
+
+def slot_weights(conf, valid, alpha, implicit: bool):
+    """``(a_w, b_w, n_w)`` of a grouped layout's slots: what a rating adds
+    to its destination's ``A`` (times ``y y^T``), ``b`` (times ``y``) and
+    regularization count — Spark's weighting of :func:`normal_eq_partials`
+    (implicit: ``alpha |r|``, ``(1 + alpha |r|)`` and 1 for ``r > 0``
+    only; explicit: 1, ``r``, 1); pad slots (``valid`` 0) add nothing."""
+    if implicit:
+        a_w = alpha * jnp.abs(conf) * valid
+        pos = (conf > 0).astype(conf.dtype) * valid
+        b_w = (1.0 + alpha * jnp.abs(conf)) * pos
+        n_w = pos
+    else:
+        a_w = valid
+        b_w = conf * valid
+        n_w = valid
+    return a_w, b_w, n_w
 
 
 def normal_eq_partials_grouped(
@@ -953,6 +972,244 @@ def als_run_grouped(
             i_src_g, i_conf_g, i_valid_g, i_group_dst,
             x0, y0, n_users, n_items, max_iter, reg, alpha, implicit,
             policy, solve_kernel, solve_geo, gram_geo, gather_kernel,
+        )
+
+
+# ---------------------------------------------------------------------------
+# Destination-blocked path: ranks whose sheet of group moments does not fit
+# ---------------------------------------------------------------------------
+# The grouped path keeps every group's (r+1)(r+2) moments and folds them by
+# destination at the end, and solves the whole side at once: at rank 100 on
+# the KDD-Cup'11 cell that sheet is 107 GB a side and the (n_dst, r, r)
+# equations 25 GB.  This path walks a side's destinations ``D`` at a time
+# (``membudget.plan_als`` sizes ``D`` from the memory the layouts leave).
+# The groups are sorted by destination, so a block's groups are one run of
+# the layout: its chunks of ``Gc`` groups are gathered as whole lane-dense
+# rows (ops/pallas/als_dst.lane_rows: at rank 100 a row pads 1.28x, where
+# the grouped path's transposed gather exists because rank 10 pads 12.8x),
+# their moments go straight into the block's per-destination tiles, and the
+# block is solved and written before the next one starts.  A destination
+# belongs to exactly one block; within a block its groups may span two
+# chunks, and the second chunk adds to the tile the first left.
+
+# bytes of gathered rows a chunk of groups holds
+DST_CHUNK_BYTES = 128 << 20
+# the narrowest group the path takes: a group's rows are one tile's lanes,
+# and a narrower group costs a whole tile all the same
+DST_LEAST_GROUP = 128
+
+
+def dst_chunk_groups(P: int, r: int) -> int:
+    """Groups of a chunk: about ``DST_CHUNK_BYTES`` of gathered rows, in
+    whole grid steps of the moments kernel."""
+    from oap_mllib_tpu.ops.pallas import als_dst
+
+    step = als_dst.STEP_GROUPS
+    want = DST_CHUNK_BYTES // (P * als_dst.r_pad(r) * 4)
+    return max(step, want // step * step)
+
+
+def dst_blocks(n_dst: int, dst_block: int) -> Tuple[int, int]:
+    """``(D, blocks)`` of one side: a block of at most ``dst_block``
+    destinations, whole solve steps of them, as many as cover ``n_dst``."""
+    from oap_mllib_tpu.ops.pallas.als_dst import SOLVE_LANES
+
+    d = min(dst_block, -(-max(n_dst, 1) // SOLVE_LANES) * SOLVE_LANES)
+    return d, -(-max(n_dst, 1) // d)
+
+
+def resolve_dst_kernels(backend: str = "") -> Tuple[str, str]:
+    """``(moments, solve)`` programs of the destination-blocked path:
+    the Pallas kernels of ops/pallas/als_dst.py on a TPU, their XLA
+    twins elsewhere (the CPU of tier-1); ``backend`` ("" =
+    ``jax.default_backend()``) is the test seam."""
+    if (backend or jax.default_backend()) == "tpu":
+        return "pallas", "pallas"
+    return "xla", "xla"
+
+
+def dst_block_starts(group_dst: jax.Array, live, blocks: int,
+                     D: int) -> jax.Array:
+    """``(blocks + 1,)`` int32: the first group of each block of ``D``
+    destinations, and behind the last the live groups' end (the pad
+    groups of a bucket, which name the last destination, belong to no
+    block)."""
+    bounds = jnp.arange(blocks + 1, dtype=jnp.int32) * D
+    starts = jnp.searchsorted(group_dst, bounds, side="left")
+    return jnp.minimum(starts.astype(jnp.int32), live)
+
+
+def dst_weights(conf: jax.Array, valid: jax.Array, alpha,
+                implicit: bool) -> jax.Array:
+    """``(Gc, 8, P)``: a chunk's :func:`slot_weights` as rows ``a_w``,
+    ``b_w``, ``n_w`` of the moments kernel's weight tile."""
+    w = jnp.stack(slot_weights(conf, valid, alpha, implicit), axis=1)
+    return jnp.pad(w.astype(jnp.float32), ((0, 0), (0, 5), (0, 0)))
+
+
+def dst_chunk_moments(ys, w, ldst, acc, r: int, mode: str,
+                      kernel: str) -> jax.Array:
+    """``acc`` with one chunk's tiles added: the ``als_dst_moments``
+    kernel on "pallas" ("pallas_interpret": the same in interpret mode),
+    or its XLA twin, a batched product and a scatter-add."""
+    from oap_mllib_tpu.ops.pallas import als_dst
+
+    if kernel.startswith("pallas"):
+        return als_dst.dst_moments(
+            ys, w, ldst, acc, r, mode, interpret=kernel == "pallas_interpret"
+        )
+    tiles = jax.vmap(lambda y, wt: als_dst.tile_of(y, wt, r, mode))(ys, w)
+    return acc.at[ldst].add(tiles, indices_are_sorted=True)
+
+
+def dst_block_solve(acc, gram, reg, r: int, kernel: str) -> jax.Array:
+    """``(D, r)`` factors of a block from its ``(D, R, R)`` tiles, the
+    Gram (None: explicit feedback) and ``reg`` x each row's count: the
+    ``als_block_cholesky`` kernel on "pallas" / "pallas_interpret", the
+    library Cholesky of :func:`regularized_solve` on "xla"."""
+    a, b, n = acc[:, :r, :r], acc[:, :r, r], acc[:, r, r + 1]
+    eye = jnp.eye(r, dtype=jnp.float32)
+    if not kernel.startswith("pallas"):
+        return regularized_solve(a, b, n, reg, eye, gram)
+    from oap_mllib_tpu.ops.pallas import als_dst
+
+    a = a + reg * n[:, None, None] * eye[None]
+    if gram is not None:
+        a = a + gram[None]
+    return als_dst.block_cholesky(
+        jnp.transpose(a, (1, 2, 0)), b.T, n[None, :],
+        interpret=kernel == "pallas_interpret",
+    ).T
+
+
+def dst_blocked_half(src_g, conf_g, valid_g, group_dst, starts, factors,
+                     n_dst: int, reg, alpha, implicit: bool, *,
+                     dst_block: int, chunk: int, mode: str = "highest",
+                     moments: str = "xla", solve: str = "xla") -> jax.Array:
+    """One half-update on the destination-blocked path: ``(n_dst, r)``
+    factors from the other side's ``factors``, ``starts`` from
+    :func:`dst_block_starts` at ``dst_block`` destinations a block."""
+    from oap_mllib_tpu.ops.pallas import als_dst
+
+    r = factors.shape[1]
+    G = src_g.shape[0]
+    R = als_dst.r_pad(r)
+    D, blocks = dst_blocks(n_dst, dst_block)
+    gc = min(chunk, G)
+    rows = als_dst.lane_rows(factors)
+    gram = _factor_gram(factors) if implicit else None
+
+    def block(k, out):
+        d0 = k * D
+        lo, hi = starts[k], starts[k + 1]
+
+        def add_chunk(c, acc):
+            g0 = lo + c * gc
+            s = jnp.minimum(g0, G - gc)  # the last chunk of the layout ends at G
+            src, conf, valid = (
+                lax.dynamic_slice_in_dim(a, s, gc) for a in (src_g, conf_g, valid_g)
+            )
+            gi = s + jnp.arange(gc, dtype=jnp.int32)
+            live = ((gi >= g0) & (gi < hi)).astype(jnp.float32)
+            # groups outside [g0, hi) add zeros: those before join the
+            # chunk's first destination, those after the block's last
+            first = group_dst[g0] - d0
+            ldst = jnp.clip(
+                lax.dynamic_slice_in_dim(group_dst, s, gc) - d0, first, D - 1
+            )
+            w = dst_weights(conf, valid * live[:, None], alpha, implicit)
+            return dst_chunk_moments(rows[src], w, ldst, acc, r, mode, moments)
+
+        acc = lax.fori_loop(
+            0, (hi - lo + gc - 1) // gc, add_chunk,
+            jnp.zeros((D, R, R), jnp.float32),
+        )
+        x = dst_block_solve(acc, gram, reg, r, solve)
+        return lax.dynamic_update_slice_in_dim(out, x.astype(out.dtype), d0, 0)
+
+    out = lax.fori_loop(
+        0, blocks, block, jnp.zeros((blocks * D, r), factors.dtype)
+    )
+    return out[:n_dst]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "n_users", "n_items", "max_iter", "implicit", "policy", "dst_block",
+        "chunks", "moments", "solve",
+    ),
+)
+def _als_run_dst_blocked_jit(
+    u_src_g, u_conf_g, u_valid_g, u_group_dst,
+    i_src_g, i_conf_g, i_valid_g, i_group_dst,
+    x0, y0, n_users: int, n_items: int, max_iter: int, reg, alpha,
+    implicit: bool, policy: str = "f32", dst_block: int = 16384,
+    chunks: Tuple[int, int] = (2048, 2048), moments: str = "xla",
+    solve: str = "xla",
+):
+    from oap_mllib_tpu.ops.pallas._tiers import check_mode
+
+    mode = check_mode(policy)
+    sides = []
+    for (src_g, conf_g, valid_g, group_dst), n_dst, gc in zip(
+        ((u_src_g, u_conf_g, u_valid_g, u_group_dst),
+         (i_src_g, i_conf_g, i_valid_g, i_group_dst)),
+        (n_users, n_items), chunks,
+    ):
+        D, blocks = dst_blocks(n_dst, dst_block)
+        # read once a program: where each block's groups start
+        starts = dst_block_starts(
+            group_dst, live_group_count(valid_g), blocks, D
+        )
+        sides.append(functools.partial(
+            dst_blocked_half, src_g, conf_g, valid_g, group_dst, starts,
+            n_dst=n_dst, reg=reg, alpha=alpha, implicit=implicit,
+            dst_block=dst_block, chunk=gc, mode=mode, moments=moments,
+            solve=solve,
+        ))
+
+    def body(carry, _):
+        x, y = carry
+        x = sides[0](y)
+        y = sides[1](x)
+        return (x, y), None
+
+    (x, y), _ = lax.scan(body, (x0, y0), None, length=max_iter)
+    return x, y
+
+
+def als_run_dst_blocked(
+    u_src_g, u_conf_g, u_valid_g, u_group_dst,
+    i_src_g, i_conf_g, i_valid_g, i_group_dst,
+    x0: jax.Array, y0: jax.Array, n_users: int, n_items: int,
+    max_iter: int, reg: float, alpha: float, implicit: bool, *,
+    dst_block: int, timings=None, phase: str = "als_iterations",
+    policy: str = "f32", moments: str = "", solve: str = "",
+) -> Tuple[jax.Array, jax.Array]:
+    """Full ALS loop on the destination-blocked path, over the same
+    device layouts as :func:`als_run_grouped`; ``dst_block``
+    destinations a block (``membudget.plan_als``).  ``moments`` /
+    ``solve``: "" resolves :func:`resolve_dst_kernels`; explicit values
+    are the test seams.  Registry-tracked like the grouped loop."""
+    auto = resolve_dst_kernels()
+    moments, solve = moments or auto[0], solve or auto[1]
+    r = x0.shape[1]
+    chunks = tuple(
+        dst_chunk_groups(g.shape[1], r) for g in (u_src_g, i_src_g)
+    )
+    key = (
+        progcache.backend_fingerprint(),
+        progcache.array_key(u_src_g, i_src_g, x0, y0),
+        n_users, n_items, max_iter, implicit, policy, dst_block, chunks,
+        moments, solve,
+    )
+    with progcache.launch("als.run_dst_blocked", key, timings, phase):
+        return _als_run_dst_blocked_jit(
+            u_src_g, u_conf_g, u_valid_g, u_group_dst,
+            i_src_g, i_conf_g, i_valid_g, i_group_dst,
+            x0, y0, n_users, n_items, max_iter, reg, alpha, implicit,
+            policy, dst_block, chunks, moments, solve,
         )
 
 

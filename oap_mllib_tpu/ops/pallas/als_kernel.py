@@ -29,9 +29,10 @@ split tiers).  The elimination sequence replicates
 the reference's masked upper-triangle work feeds only zeroed columns),
 so results are bit-identical to the XLA path on the same backend.
 
-Caller contract: rank r <= 32 (the unrolled-solve bound shared with
-masked_solve), batch pads to the 256-column tile with n_reg = 0 rows
-(masked to zero factors, sliced off by the wrapper).
+Caller contract: rank r <= MAX_RANK (``als_ops.UNROLLED_RANK_MAX``, the
+unrolled-solve bound shared with masked_solve), batch pads to the
+256-column tile with n_reg = 0 rows (masked to zero factors, sliced off
+by the wrapper).
 """
 
 from __future__ import annotations
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from oap_mllib_tpu.ops.als_ops import UNROLLED_RANK_MAX as MAX_RANK
 from oap_mllib_tpu.ops.pallas import _dbuf
 from oap_mllib_tpu.ops.pallas._tiers import (
     LANE,
@@ -58,7 +60,6 @@ from oap_mllib_tpu.utils import progcache
 
 _BATCH = 256  # solve batch tile (lane axis)
 _GRAM_BLOCK_ROWS = 512
-MAX_RANK = 32  # the unrolled-solve bound (als_ops.masked_solve contract)
 # double-buffered solve keeps the whole (r, n) factor sheet VMEM-resident;
 # past this element budget the walk falls back to the grid pipeline
 _DBUF_SOLVE_BUDGET = 1 << 22
@@ -452,6 +453,6 @@ def factor_gram_pallas(
 
 def pallas_solve_preferred(r: int) -> bool:
     """Shape rule for als_solve_kernel="auto": the fused assembly+solve
-    covers the unrolled-rank regime (r <= 32, Spark's default is 10);
+    covers the unrolled-rank regime (r <= MAX_RANK, Spark's default is 10);
     larger ranks keep the library Cholesky path."""
     return r <= MAX_RANK

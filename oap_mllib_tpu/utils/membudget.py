@@ -54,8 +54,10 @@ ROUTE_IN_MEMORY = "in-memory"
 ROUTE_CHUNKED = "chunked"
 ROUTE_STREAMED = "streamed"
 ROUTE_STREAMED_BLOCK = "streamed-block"
+# ALS's resident layouts, solved a block of destinations at a time
+ROUTE_DST_BLOCKED = "dst-blocked"
 ROUTES = (ROUTE_IN_MEMORY, ROUTE_CHUNKED, ROUTE_STREAMED,
-          ROUTE_STREAMED_BLOCK)
+          ROUTE_STREAMED_BLOCK, ROUTE_DST_BLOCKED)
 
 # planner fudge on analytic estimates: XLA temporaries, fusion buffers,
 # and allocator slack that no shape formula sees.  Streamed estimates
@@ -278,6 +280,7 @@ class RoutePlan:
         self.chunk_rows = chunk_rows  # suggested streamed chunk width
         self.over_budget = over_budget  # no candidate fit; loudest case
         self.forced = forced  # pin: override
+        self.dst_block = 0  # destinations a block (ALS dst-blocked route)
         self.downgrades: List[str] = []
         # what the planner priced one staged row at (chunk width x dtype
         # + the mask/weight columns that ride along) — record_plan
@@ -333,6 +336,8 @@ class RoutePlan:
         }
         if self.chunk_rows:
             out["chunk_rows"] = self.chunk_rows
+        if self.route == ROUTE_DST_BLOCKED:
+            out["dst_block"] = self.dst_block
         if self.over_budget:
             out["over_budget"] = True
         if self.forced:
@@ -348,8 +353,8 @@ def _scale_rank(route: str) -> int:
     """Higher = handles more data per resident byte.  A move to a LOWER
     rank is a scale downgrade (the thing strict mode forbids)."""
     return {
-        ROUTE_IN_MEMORY: 0, ROUTE_CHUNKED: 1, ROUTE_STREAMED: 2,
-        ROUTE_STREAMED_BLOCK: 3,
+        ROUTE_IN_MEMORY: 0, ROUTE_CHUNKED: 1, ROUTE_DST_BLOCKED: 1,
+        ROUTE_STREAMED: 2, ROUTE_STREAMED_BLOCK: 3,
     }[route]
 
 
@@ -635,11 +640,47 @@ def als_grouped_bytes(layouts: Sequence[Tuple[int, int]], rank: int) -> int:
     the program keeps a row-major copy of it of 2 GB, not 1; priced as
     if every array of a narrow side were held so) — and the sheet of the
     side with the most groups (one half-update runs at a time)."""
-    return sum(
-        g * max(p, 128) for g, p in layouts
-    ) * _ALS_EDGE_BYTES + als_sheet_bytes(
+    return als_layout_bytes(layouts) + als_sheet_bytes(
         max((g for g, _ in layouts), default=0), rank
     )
+
+
+def als_layout_bytes(layouts: Sequence[Tuple[int, int]]) -> int:
+    """Device bytes of the ``(G, P)`` grouped layouts alone, a row of
+    fewer than 128 slots taking 128 lanes (:func:`als_grouped_bytes`)."""
+    return sum(g * max(p, 128) for g, p in layouts) * _ALS_EDGE_BYTES
+
+
+def als_dst_block_bytes(dst_block: int, rank: int) -> int:
+    """What one block of the destination-blocked route holds
+    (``ops/als_ops.dst_blocked_half``): its ``(D, R, R)`` moment tiles
+    (``R``: the rank and two columns, in whole lanes), the ``(D, r, r)``
+    systems and their batch-last copy the solve reads."""
+    from oap_mllib_tpu.ops.pallas.als_dst import r_pad
+
+    return dst_block * (r_pad(rank) ** 2 + 2 * rank * rank) * 4
+
+
+def _als_dst_bytes(layouts, n_users: int, n_items: int, rank: int,
+                   dst_block: int) -> int:
+    """HBM of the destination-blocked route at ``dst_block``: the
+    layouts, three generations of factors, the lane-dense source rows
+    it gathers from, one chunk of gathered rows and one block."""
+    from oap_mllib_tpu.ops.als_ops import DST_CHUNK_BYTES
+    from oap_mllib_tpu.ops.pallas.als_dst import r_pad
+
+    factors, _ = _als_fixed_bytes(n_users, n_items, rank)
+    return int((
+        als_layout_bytes(layouts) + 3 * factors
+        + max(n_users, n_items) * r_pad(rank) * 4 + DST_CHUNK_BYTES
+        + als_dst_block_bytes(dst_block, rank) + _PROGRAM_BYTES
+    ) * _OVERHEAD)
+
+
+# the destination-blocked route's widest block: 1.07 GB of tiles at
+# rank 100; wider blocks only shorten a loop of a few dozen steps
+_DST_BLOCK_MAX = 16384
+_DST_BLOCK_MIN = 128  # one solve step
 
 
 def _als_fixed_bytes(n_users: int, n_items: int, rank: int):
@@ -677,7 +718,16 @@ def plan_als(nnz: int, n_users: int, n_items: int, rank: int, *,
     BOTH grouped sides where the fit has counted them already (the
     single-device fit, after its counting pass), priced with their sheet
     (:func:`als_grouped_bytes`); elsewhere the edges are priced at
-    ``_ALS_BLOWUP`` times the ratings a side."""
+    ``_ALS_BLOWUP`` times the ratings a side.
+
+    With ``grouped`` the destination-blocked route (``dst-blocked``,
+    ``ops/als_ops.als_run_dst_blocked``) is a candidate between the two:
+    the same resident layouts, no sheet and no whole-side equations, a
+    block of destinations at a time.  It is taken where the resident
+    grouped route does not fit — at rank 100 on the KDD-Cup'11 cell its
+    sheet alone is 107 GB a side — and its block (``plan.dst_block``
+    destinations) is the widest power of two up to ``_DST_BLOCK_MAX``
+    that the budget leaves room for."""
     factors, moments = _als_fixed_bytes(n_users, n_items, rank)
     if grouped is None:
         edges = host_layouts = int(2 * nnz * _ALS_EDGE_BYTES * _ALS_BLOWUP)
@@ -720,7 +770,20 @@ def plan_als(nnz: int, n_users: int, n_items: int, rank: int, *,
             [streamed, in_mem] if source_backing is not None
             else [in_mem, streamed]
         )
+        dst_block = 0
+        if grouped is not None:
+            hbm = Budgets.resolve().hbm
+            dst_block = _DST_BLOCK_MAX
+            while dst_block > _DST_BLOCK_MIN and hbm > 0 and _als_dst_bytes(
+                    grouped, n_users, n_items, rank, dst_block) > hbm:
+                dst_block //= 2
+            ests.insert(ests.index(streamed), RouteEstimate(
+                ROUTE_DST_BLOCKED,
+                _als_dst_bytes(grouped, n_users, n_items, rank, dst_block),
+                host_edges,
+            ))
         plan = choose("ALS", ests, natural)
+        plan.dst_block = dst_block
     # triples stage as width-3 f64 chunks on the streamed ingest path
     plan.est_row_bytes = 3 * 8
     return plan

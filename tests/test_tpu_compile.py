@@ -923,6 +923,120 @@ class TestALSCellOnOneChip:
                 + mem.output_size_in_bytes) < self.HBM
 
 
+class TestALSDstBlockedOnOneChip:
+    """``als_implicit_r100_kddcup11``: the KDD-Cup'11 user block at rank
+    100, on the destination-blocked route (``als_ops.als_run_dst_blocked``)
+    the plan takes there: its two kernels at the cell's sizes and the
+    whole five-iteration program for one described v5e, with what
+    ``memory_analysis`` says it holds — never a sheet of group moments
+    nor a side's ``(n_dst, r, r)`` equations."""
+
+    USERS, ITEMS, RANK, ITERS = 500495, 624961, 100, 5
+    RATINGS = 126_400_138
+    HBM = 15.75 * 2**30
+
+    @pytest.fixture(scope="class")
+    def plan(self):
+        """``(layouts [(P, G)], plan)``: the widths of whole lanes the
+        route takes for degrees drawn from the configuration's laws (as
+        ``TestALSCellOnOneChip.cell``), on their group buckets, planned
+        under the described chip's memory."""
+        import json
+        import os
+
+        from oap_mllib_tpu.config import set_config
+        from oap_mllib_tpu.ops import als_ops
+        from oap_mllib_tpu.utils import membudget
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(
+                root, "benchmarks", "configs", "als_implicit_r100_kddcup11.json")) as f:
+            cfg = json.load(f)
+        assert (cfg["users"], cfg["items"], cfg["rows_per_chip"], cfg["rank"]) == (
+            self.USERS, self.ITEMS, self.RATINGS, self.RANK)
+        law, rng = cfg["data"], np.random.default_rng(45)
+        w = rng.lognormal(0.0, law["degree_sigma"], self.USERS)
+        rest = self.RATINGS - law["degree_min"] * self.USERS
+        by_user = law["degree_min"] + np.floor(rest * w / w.sum())
+        edges = (np.arange(self.ITEMS + 1) + law["item_offset"]) ** (
+            1.0 - law["item_exponent"])
+        by_item = rng.multinomial(self.RATINGS, np.diff(edges) / (edges[-1] - edges[0]))
+        counts = [by_user.astype(np.int32)[None, :], by_item.astype(np.int32)[None, :]]
+        set_config(memory_budget_hbm=str(int(self.HBM)))
+        sizes = als_ops.group_sizes_for(counts, self.RANK, None,
+                                        least=als_ops.DST_LEAST_GROUP)
+        layouts = [(p, als_ops.group_bucket(als_ops.padded_edges(c, p) // p))
+                   for c, p in zip(counts, sizes)]
+        plan = membudget.plan_als(self.RATINGS, self.USERS, self.ITEMS, self.RANK,
+                                  grouped=[(g, p) for p, g in layouts])
+        return layouts, plan
+
+    def test_the_plan_takes_the_route(self, plan):
+        from oap_mllib_tpu.utils import membudget
+
+        layouts, plan = plan
+        assert layouts == [(128, 1 << 21), (128, 1 << 21)]
+        assert plan.route == membudget.ROUTE_DST_BLOCKED and plan.dst_block == 16384
+
+    def test_the_moments_kernel(self, one_chip):
+        from oap_mllib_tpu.ops.pallas import als_dst
+
+        gc, p, lanes, d = 2048, 128, als_dst.r_pad(self.RANK), 16384
+        compiled = _compile(
+            lambda ys, w, ldst, acc: als_dst.dst_moments(ys, w, ldst, acc, self.RANK),
+            _s((gc, p, lanes), one_chip), _s((gc, 8, p), one_chip),
+            jax.ShapeDtypeStruct((gc,), jnp.int32, sharding=one_chip),
+            _s((d, lanes, lanes), one_chip),
+        )
+        # the block's tiles are written in place: no copy of them
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * MiB
+
+    def test_the_block_cholesky(self, one_chip):
+        from oap_mllib_tpu.ops.pallas import als_dst
+
+        r, d = self.RANK, 16384
+        compiled = _compile(
+            als_dst.block_cholesky, _s((r, r, d), one_chip), _s((r, d), one_chip),
+            _s((1, d), one_chip),
+        )
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * MiB
+
+    def test_the_whole_program(self, one_chip, plan):
+        from oap_mllib_tpu.ops import als_ops
+        from oap_mllib_tpu.utils import membudget
+
+        layouts, plan = plan
+        r = self.RANK
+
+        def side(p, g):
+            i32 = jax.ShapeDtypeStruct((g, p), jnp.int32, sharding=one_chip)
+            return (i32, _s((g, p), one_chip), _s((g, p), one_chip),
+                    jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one_chip))
+
+        compiled = als_ops._als_run_dst_blocked_jit.lower(
+            *side(*layouts[0]), *side(*layouts[1]),
+            _s((self.USERS, r), one_chip), _s((self.ITEMS, r), one_chip),
+            n_users=self.USERS, n_items=self.ITEMS, max_iter=self.ITERS,
+            reg=0.1, alpha=40.0, implicit=True, policy="f32",
+            dst_block=plan.dst_block,
+            chunks=tuple(als_ops.dst_chunk_groups(p, r) for p, _ in layouts),
+            moments="pallas", solve="pallas",
+        ).compile()
+        text = compiled.as_text()
+        assert "als_dst_moments" in text and "als_block_cholesky" in text
+        mem = compiled.memory_analysis()
+        layout_bytes = sum(g * p * 12 + g * 4 for p, g in layouts)
+        assert mem.argument_size_in_bytes < 1.02 * layout_bytes + 0.5e9
+        # one block's tiles and systems, a chunk of gathered rows and the
+        # lane-dense source rows: a few GB, where one side's (n_dst, r, r)
+        # equations would be 25 GB and the grouped sheet 107 GB
+        block = membudget.als_dst_block_bytes(plan.dst_block, r)
+        assert mem.temp_size_in_bytes < 2.5 * block
+        assert membudget.als_sheet_bytes(1 << 21, r) > 100e9
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes) < self.HBM
+
+
 class TestALSGatherWalk:
     """The factor-row gather of the grouped moments as the Pallas walk over
     the packed table held whole in VMEM (``ops/pallas/als_gather.py``): at
