@@ -926,11 +926,28 @@ class ALS:
                     als_ops.dst_blocks(n, plan.dst_block)[1]
                     for n in (n_users, n_items)
                 ]
+                # the slots a fit's chunks hold, and the rows its moments
+                # read: the XLA twin gathers every slot, the kernel copies
+                # every slot too and, at each chunk's last grid step, the
+                # step's rows once more
+                walked = fetched = 0
+                for g, pad, p, n in zip((by_user, by_item), padded, sizes,
+                                        (n_users, n_items)):
+                    chunk = als_ops.dst_chunk_groups(p, self.rank)
+                    chunks = int(self.max_iter) * als_ops.dst_walked_chunks(
+                        g[3], pad // p, n, plan.dst_block, chunk
+                    )
+                    gc = min(chunk, g[3].shape[0])
+                    walked += chunks * gc * p
+                    fetched += chunks * (gc + als_dst.STEP_GROUPS) * p
+                dma = moments.startswith("pallas")
                 span.attrs.update(
                     route=plan.route, dst_block=int(plan.dst_block),
                     blocks_user=blocks[0], blocks_item=blocks[1],
-                    r_pad=als_dst.r_pad(self.rank), gather="rows",
-                    moments=moments, solve=solve,
+                    r_pad=als_dst.r_pad(self.rank),
+                    gather="dma" if dma else "rows",
+                    rows_fetched=fetched if dma else walked,
+                    slots_walked=walked, moments=moments, solve=solve,
                 )
 
                 def run_iters(xa, ya, iters):

@@ -984,15 +984,18 @@ def als_run_grouped(
 # equations 25 GB.  This path walks a side's destinations ``D`` at a time
 # (``membudget.plan_als`` sizes ``D`` from the memory the layouts leave).
 # The groups are sorted by destination, so a block's groups are one run of
-# the layout: its chunks of ``Gc`` groups are gathered as whole lane-dense
-# rows (ops/pallas/als_dst.lane_rows: at rank 100 a row pads 1.28x, where
-# the grouped path's transposed gather exists because rank 10 pads 12.8x),
-# their moments go straight into the block's per-destination tiles, and the
-# block is solved and written before the next one starts.  A destination
+# the layout: its chunks of ``Gc`` groups read their source rows whole and
+# lane-dense (ops/pallas/als_dst.lane_rows: at rank 100 a row pads 1.28x,
+# where the grouped path's transposed gather exists because rank 10 pads
+# 12.8x) — the moments kernel copies them from HBM into VMEM itself, the
+# XLA twin gathers them —, their moments go straight into the block's
+# per-destination tiles, and the block is solved and written before the
+# next one starts.  A destination
 # belongs to exactly one block; within a block its groups may span two
 # chunks, and the second chunk adds to the tile the first left.
 
-# bytes of gathered rows a chunk of groups holds
+# bytes of source rows a chunk of groups names, all of them gathered on
+# the XLA twin (the kernel holds a step's alone): sizes the chunk
 DST_CHUNK_BYTES = 128 << 20
 # the narrowest group the path takes: a group's rows are one tile's lanes,
 # and a narrower group costs a whole tile all the same
@@ -1000,7 +1003,7 @@ DST_LEAST_GROUP = 128
 
 
 def dst_chunk_groups(P: int, r: int) -> int:
-    """Groups of a chunk: about ``DST_CHUNK_BYTES`` of gathered rows, in
+    """Groups of a chunk: about ``DST_CHUNK_BYTES`` of source rows, in
     whole grid steps of the moments kernel."""
     from oap_mllib_tpu.ops.pallas import als_dst
 
@@ -1016,6 +1019,22 @@ def dst_blocks(n_dst: int, dst_block: int) -> Tuple[int, int]:
 
     d = min(dst_block, -(-max(n_dst, 1) // SOLVE_LANES) * SOLVE_LANES)
     return d, -(-max(n_dst, 1) // d)
+
+
+def dst_walked_chunks(group_dst, live: int, n_dst: int, dst_block: int,
+                      chunk: int) -> int:
+    """Chunks of ``min(chunk, G)`` groups one half-update's moments walk
+    on the destination-blocked route, each block's groups in whole
+    chunks; reckoned on the host from the side's built ``group_dst``
+    ``(G,)`` and its ``live`` groups (:func:`dst_block_starts`, as the
+    program reads them)."""
+    import numpy as np
+
+    D, blocks = dst_blocks(n_dst, dst_block)
+    gc = min(chunk, group_dst.shape[0])
+    bounds = np.arange(blocks + 1, dtype=np.int64) * D
+    starts = np.minimum(np.searchsorted(group_dst, bounds, side="left"), live)
+    return int(np.sum(-(-np.diff(starts) // gc)))
 
 
 def resolve_dst_kernels(backend: str = "") -> Tuple[str, str]:
@@ -1047,18 +1066,21 @@ def dst_weights(conf: jax.Array, valid: jax.Array, alpha,
     return jnp.pad(w.astype(jnp.float32), ((0, 0), (0, 5), (0, 0)))
 
 
-def dst_chunk_moments(ys, w, ldst, acc, r: int, mode: str,
+def dst_chunk_moments(rows, src, w, ldst, acc, r: int, mode: str,
                       kernel: str) -> jax.Array:
     """``acc`` with one chunk's tiles added: the ``als_dst_moments``
     kernel on "pallas" ("pallas_interpret": the same in interpret mode),
-    or its XLA twin, a batched product and a scatter-add."""
+    which fetches the chunk's source rows from ``rows`` itself, or its
+    XLA twin, the rows gathered whole, a batched product and a
+    scatter-add."""
     from oap_mllib_tpu.ops.pallas import als_dst
 
     if kernel.startswith("pallas"):
         return als_dst.dst_moments(
-            ys, w, ldst, acc, r, mode, interpret=kernel == "pallas_interpret"
+            rows, src, w, ldst, acc, r, mode,
+            interpret=kernel == "pallas_interpret",
         )
-    tiles = jax.vmap(lambda y, wt: als_dst.tile_of(y, wt, r, mode))(ys, w)
+    tiles = jax.vmap(lambda y, wt: als_dst.tile_of(y, wt, r, mode))(rows[src], w)
     return acc.at[ldst].add(tiles, indices_are_sorted=True)
 
 
@@ -1118,7 +1140,7 @@ def dst_blocked_half(src_g, conf_g, valid_g, group_dst, starts, factors,
                 lax.dynamic_slice_in_dim(group_dst, s, gc) - d0, first, D - 1
             )
             w = dst_weights(conf, valid * live[:, None], alpha, implicit)
-            return dst_chunk_moments(rows[src], w, ldst, acc, r, mode, moments)
+            return dst_chunk_moments(rows, src, w, ldst, acc, r, mode, moments)
 
         acc = lax.fori_loop(
             0, (hi - lo + gc - 1) // gc, add_chunk,

@@ -11,12 +11,20 @@ block's tiles, solves them and writes the block's factors.
 
 - **Moments** (:func:`dst_moments`, ``pallas_call`` name
   ``als_dst_moments``): one call takes a chunk of ``Gc`` consecutive
-  groups of the destination-sorted layout, their gathered source rows
-  ``(Gc, P, R)`` (``R`` = ``r_pad``: the rank rounded up to whole lanes
-  with room for two more columns; column ``r`` of every gathered row is
-  1, see :func:`lane_rows`), their weights ``(Gc, 8, P)`` (rows
-  ``a_w``, ``b_w``, ``n_w``) and each group's destination within the
-  block.  For each group it forms the ``(R, R)`` tile
+  groups of the destination-sorted layout — their source ids ``(Gc, P)``,
+  their weights ``(Gc, 8, P)`` (rows ``a_w``, ``b_w``, ``n_w``) and
+  each group's destination within the block — and the source table
+  ``(n_src, R)`` (``R`` = ``r_pad``: the rank rounded up to whole lanes
+  with room for two more columns; column ``r`` of every row is 1, see
+  :func:`lane_rows`), which stays in HBM.  The kernel fetches the rows
+  itself: one 512-byte DMA a slot into a sublane of a double-buffered
+  ``(2, K·P, R)`` VMEM buffer, a step's copies issued during the step
+  before, as straight-line code beside its products (copies issued from
+  a loop are not overlapped with the MXU, and cost more than a gather
+  by XLA), and waited for in one wait a step.  Every slot is fetched,
+  pad slots too (source 0, weight 0): skipping them takes a
+  data-dependent trip count, which costs more than it saves.  For each
+  group it forms the ``(R, R)`` tile
   ``[Ys | 1]^T [a_w Ys | b_w | n_w]`` on the MXU — ``A`` in
   ``[:r, :r]``, ``b`` in column ``r`` and the count at ``[r, r+1]`` —
   and adds it to a VMEM tile that stays put while the destination does.
@@ -38,6 +46,8 @@ block's tiles, solves them and writes the block's factors.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -54,8 +64,8 @@ from oap_mllib_tpu.ops.pallas._tiers import (
     tiered_dot,
 )
 
-# groups a moments grid step takes (its (K, P, R) rows block: 512 KiB at
-# P = R = 128)
+# groups a moments grid step takes (its (K * P, R) half of the rows
+# buffer: 512 KiB at P = R = 128)
 STEP_GROUPS = 8
 # systems a solve grid step takes, on the lanes
 SOLVE_LANES = 128
@@ -71,7 +81,7 @@ def r_pad(r: int) -> int:
 
 def lane_rows(factors: jax.Array) -> jax.Array:
     """``(n_src, r)`` factors -> the ``(n_src, r_pad)`` rows the route
-    gathers: the factors, then a column of ones (the ``[Ys | 1]`` of
+    reads: the factors, then a column of ones (the ``[Ys | 1]`` of
     every tile), then zeros."""
     n_src, r = factors.shape
     return (
@@ -82,9 +92,9 @@ def lane_rows(factors: jax.Array) -> jax.Array:
 
 
 def tile_of(rows, w, r: int, mode: str):
-    """One group's ``(R, R)`` moment tile from its gathered ``(P, R)``
-    rows and ``(8, P)`` weights — the kernel's product, and the XLA
-    route's (``als_ops``) through the same rows."""
+    """One group's ``(R, R)`` moment tile from its ``(P, R)`` source rows
+    and ``(8, P)`` weights — the kernel's product, and the XLA route's
+    (``als_ops``) through the same rows."""
     yt = rows.T  # (R, P): the slots on the lanes
     row = lax.broadcasted_iota(jnp.int32, yt.shape, 0)
     rhs = jnp.where(
@@ -93,10 +103,12 @@ def tile_of(rows, w, r: int, mode: str):
     return tiered_dot(yt, rhs, (((1,), (1,)), ((), ())), mode)
 
 
-def _make_moments_kernel(r: int, steps: int, mode: str):
+def _make_moments_kernel(r: int, p: int, steps: int, mode: str,
+                         interpret: bool):
     K = STEP_GROUPS
 
-    def _kernel(ldst, ys_ref, w_ref, acc_in, acc_out, tile, state, sem):
+    def _kernel(ldst, first_ids, next_ids, rows, w_ref, acc_in, acc_out,
+                ys, tile, state, sem):
         del acc_in  # the same buffer as acc_out
         s = pl.program_id(0)
 
@@ -105,8 +117,38 @@ def _make_moments_kernel(r: int, steps: int, mode: str):
                 tile.at[slot], acc_out.at[d], sem.at[slot]
             )
 
+        def fetch(b, k, ids):
+            """Start the copies of group ``k``'s rows (``ids`` holds its
+            step's source ids) into buffer ``b``, one DMA a row; the
+            copies of a buffer share its semaphore.  Straight-line code
+            on the chip; the interpreter takes the same copies in a loop,
+            which it traces in seconds where the unrolled ones take
+            minutes."""
+            def start(j):
+                pltpu.make_async_copy(
+                    rows.at[pl.ds(ids[j], 1)], ys.at[b, pl.ds(j, 1)],
+                    sem.at[3 + b],
+                ).start()
+
+            if interpret:
+                def turn(j, c):
+                    start(j)
+                    return c
+
+                lax.fori_loop(k * p, (k + 1) * p, turn, 0)
+            else:
+                for j in range(k * p, (k + 1) * p):
+                    start(j)
+
+        def landed(b):
+            """Wait for a step's rows in buffer ``b``: a DMA semaphore
+            counts bytes, so one wait the buffer's size takes them all."""
+            pltpu.make_async_copy(ys.at[b], ys.at[b], sem.at[3 + b]).wait()
+
         @pl.when(s == 0)
         def _start():
+            for k in range(K):
+                fetch(0, k, first_ids)
             d = ldst[0]
             load = pltpu.make_async_copy(acc_out.at[d], tile.at[0], sem.at[2])
             load.start()
@@ -116,7 +158,7 @@ def _make_moments_kernel(r: int, steps: int, mode: str):
             state[2] = 0  # slot 0 has a write in flight
             state[3] = 0  # slot 1 has a write in flight
 
-        for k in range(K):
+        def accumulate(k, group_rows):
             d = ldst[s * K + k]
 
             @pl.when(d != state[0])
@@ -136,7 +178,27 @@ def _make_moments_kernel(r: int, steps: int, mode: str):
                 state[1] = other
 
             slot = state[1]
-            tile[slot] = tile[slot] + tile_of(ys_ref[k], w_ref[k], r, mode)
+            tile[slot] = tile[slot] + tile_of(group_rows, w_ref[k], r, mode)
+
+        def step(b):
+            """A step whose rows are in buffer ``b`` (a static index: the
+            copies into the other buffer and the products from this one
+            are one block of code, which the scheduler interleaves): the
+            next step's rows go into the other buffer a group at a time
+            while this step's groups are multiplied.  The last step
+            fetches its own rows again, so that every step is the same
+            code, and waits for them."""
+            landed(b)
+            for k in range(K):
+                fetch(1 - b, k, next_ids)
+                accumulate(k, ys[b, k * p:(k + 1) * p])
+
+            @pl.when(s == steps - 1)
+            def _():
+                landed(1 - b)
+
+        for b in range(2):
+            pl.when(lax.rem(s, 2) == b)(functools.partial(step, b))
 
         @pl.when(s == steps - 1)
         def _end():
@@ -152,46 +214,63 @@ def _make_moments_kernel(r: int, steps: int, mode: str):
     return _kernel
 
 
-def dst_moments(ys, w, ldst, acc, r: int, mode: str = "highest",
+def dst_moments(rows, src, w, ldst, acc, r: int, mode: str = "highest",
                 interpret: bool = False):
     """``acc`` (``(D, R, R)`` f32, the block's tiles) with the moments of
-    one chunk of groups added: ``ys`` ``(Gc, P, R)`` gathered rows
-    (:func:`lane_rows`), ``w`` ``(Gc, 8, P)`` weights, ``ldst`` ``(Gc,)``
-    int32 destinations within the block, non-decreasing.  ``Gc`` is a
-    multiple of :data:`STEP_GROUPS`.  A group of zero weights adds exact
-    zeros wherever it points, but a run of them may only point at its
-    neighbours' destinations or at one no group of the block names (the
-    caller clamps).  Traced inside the ALS programs (no jit of its own);
-    ``acc`` is updated in place."""
-    gc, p, lanes = ys.shape
+    one chunk of groups added.  ``rows`` ``(n_src, R)``: the source
+    table (:func:`lane_rows`), read where it lies in HBM; ``src``
+    ``(Gc, P)`` int32 source ids; ``w`` ``(Gc, 8, P)`` weights, 0 on
+    every pad slot; ``ldst`` ``(Gc,)`` int32 destinations within the
+    block, non-decreasing.  ``Gc`` is a multiple of :data:`STEP_GROUPS`.
+    A grid step copies the rows of the next step's groups, one DMA a
+    row, into the other half of a double buffer while it multiplies its
+    own.  A group of zero weights adds exact zeros wherever it points,
+    but a run of them may only point at its neighbours' destinations or
+    at one no group of the block names (the caller clamps).  Traced
+    inside the ALS programs (no jit of its own); ``acc`` is updated in
+    place."""
+    gc, p = src.shape
+    lanes = rows.shape[1]
     steps = gc // STEP_GROUPS
+    ids_block = (STEP_GROUPS * p,)
     note_emitted("als.dst_moments")
+    ids = src.reshape(gc * p).astype(jnp.int32)
     return pl.pallas_call(
-        _make_moments_kernel(r, steps, mode),
+        _make_moments_kernel(r, p, steps, mode, bool(interpret)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(steps,),
             in_specs=[
-                pl.BlockSpec((STEP_GROUPS, p, lanes), lambda s, ld: (s, 0, 0)),
+                # the first step's ids, and the next step's: a step
+                # starts the copies of the one after it
+                pl.BlockSpec(ids_block, lambda s, ld: (0,),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(ids_block,
+                             lambda s, ld: (jnp.minimum(s + 1, steps - 1),),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec((STEP_GROUPS, WEIGHT_ROWS, p),
                              lambda s, ld: (s, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
+                pltpu.VMEM((2, STEP_GROUPS * p, lanes), jnp.float32),
                 pltpu.VMEM((2, lanes, lanes), jnp.float32),
                 pltpu.SMEM((4,), jnp.int32),
-                pltpu.SemaphoreType.DMA((3,)),
+                # the tiles' two writes, the first tile's load, the two
+                # rows buffers
+                pltpu.SemaphoreType.DMA((5,)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
-        # operands count the scalar-prefetch one: acc is the fourth
-        input_output_aliases={3: 0},
+        # operands count the scalar-prefetch one: acc is the sixth
+        input_output_aliases={5: 0},
         interpret=interpret,
         name="als_dst_moments",
         # a destination's tile stays open across steps: they run in order
         **compiled_kwargs(interpret, dimension_semantics=("arbitrary",)),
-    )(ldst.astype(jnp.int32), ys, w, acc)
+    )(ldst.astype(jnp.int32), ids, ids, rows, w, acc)
 
 
 def _make_solve_kernel(r: int):

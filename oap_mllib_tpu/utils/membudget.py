@@ -665,7 +665,8 @@ def _als_dst_bytes(layouts, n_users: int, n_items: int, rank: int,
                    dst_block: int) -> int:
     """HBM of the destination-blocked route at ``dst_block``: the
     layouts, three generations of factors, the lane-dense source rows
-    it gathers from, one chunk of gathered rows and one block."""
+    it reads, one chunk of gathered rows (the XLA twin's; the Pallas
+    kernel fetches its rows into VMEM) and one block."""
     from oap_mllib_tpu.ops.als_ops import DST_CHUNK_BYTES
     from oap_mllib_tpu.ops.pallas.als_dst import r_pad
 

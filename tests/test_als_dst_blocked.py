@@ -10,6 +10,7 @@ the interpreter.  What Mosaic makes of them is compiled in
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from oap_mllib_tpu import ALS
@@ -146,9 +147,33 @@ class TestBlocksAndChunks:
         assert blocks == -(-N_ITEMS // D) and D % 128 == 0
 
 
+def _parents_form(rows, src, w, ldst, acc, r):
+    """The moments as the route added them before the kernel fetched its
+    own rows: every slot's row gathered whole (``rows[src]``), then each
+    group's tile added to its destination's, in the groups' order."""
+    tiles = jax.vmap(lambda y, wt: als_dst.tile_of(y, wt, r, "highest"))(
+        rows[src], w)
+    out = jnp.asarray(acc)
+    for g, d in enumerate(np.asarray(ldst)):
+        out = out.at[d].set(out[d] + tiles[g])
+    return np.asarray(out)
+
+
+def _chunk(rng, gc, p, n_src, live):
+    """``(src, conf, valid)`` of ``gc`` groups whose slots fill from the
+    first, ``live[g]`` of them; the pad slots name source 0, as the
+    grouped build leaves them."""
+    valid = (np.arange(p)[None, :] < np.asarray(live)[:, None]).astype(np.float32)
+    src = np.where(valid > 0, rng.integers(0, n_src, (gc, p)), 0).astype(np.int32)
+    conf = (rng.integers(0, 5, (gc, p)) * 10).astype(np.float32) * valid
+    return src, conf, valid
+
+
 class TestTheKernels:
     """``als_dst_moments`` and ``als_block_cholesky`` under the
-    interpreter against ``jnp.einsum`` and ``numpy.linalg.cholesky``."""
+    interpreter against ``jnp.einsum`` and ``numpy.linalg.cholesky``, and
+    the moments kernel, which fetches its own source rows, against the
+    gathered rows it used to be handed."""
 
     @pytest.mark.parametrize("r, p", [(10, 128), (40, 64), (100, 128)])
     def test_the_moments_kernel_is_the_einsum(self, r, p):
@@ -165,9 +190,9 @@ class TestTheKernels:
         acc0 = np.zeros((d, als_dst.r_pad(r), als_dst.r_pad(r)), np.float32)
         acc0[ldst[0], :r, :r] = 1.5
         w = als_ops.dst_weights(jnp.asarray(conf), jnp.asarray(valid), 40.0, True)
-        ys = als_dst.lane_rows(f)[src]
-        got = als_dst.dst_moments(ys, w, jnp.asarray(ldst), jnp.asarray(acc0),
-                                  r, interpret=True)
+        rows = als_dst.lane_rows(f)
+        got = als_dst.dst_moments(rows, jnp.asarray(src), w, jnp.asarray(ldst),
+                                  jnp.asarray(acc0), r, interpret=True)
         y = np.asarray(f)[src]
         a_w = 40.0 * np.abs(conf) * valid
         pos = (conf > 0) * valid
@@ -180,10 +205,71 @@ class TestTheKernels:
             want[ldst[g], :r + 1, :r + 2] += np.asarray(jnp.einsum(
                 "pa,pb->ab", lhs, rhs, precision="highest"))
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=1e-2)
-        xla = als_ops.dst_chunk_moments(ys, w, jnp.asarray(ldst),
-                                        jnp.asarray(acc0), r, "highest", "xla")
+        xla = als_ops.dst_chunk_moments(rows, jnp.asarray(src), w,
+                                        jnp.asarray(ldst), jnp.asarray(acc0),
+                                        r, "highest", "xla")
         np.testing.assert_allclose(np.asarray(got), np.asarray(xla),
                                    rtol=1e-5, atol=1e-3)
+
+    @pytest.mark.parametrize("case", [
+        "pad_at_group_tails", "empty_groups_at_the_end", "ids_at_both_ends",
+        "one_step",
+    ])
+    def test_the_fetch_gives_the_gathered_rows_tiles(self, case):
+        """Bit for bit the parent's form: the rows the kernel copies are
+        the rows ``rows[src]`` gathered, pad slots (source 0, weight 0)
+        included."""
+        r, p, d, n_src = 100, 128, 9, 50
+        gc = 8 if case == "one_step" else 24
+        rng = np.random.default_rng(len(case))
+        live = rng.integers(1, p + 1, gc)
+        if case == "pad_at_group_tails":
+            live[::3] = p  # full groups beside ones that end early
+            live[1], live[2] = 1, p - 1
+        elif case == "empty_groups_at_the_end":
+            # the groups past the chunk's owner: no rows, no weights
+            live[-11:] = 0
+        src, conf, valid = _chunk(rng, gc, p, n_src, live)
+        if case == "ids_at_both_ends":
+            src[0, :live[0]] = 0
+            src[5, :live[5]] = n_src - 1
+            src[7, 0], src[7, live[7] - 1] = n_src - 1, 0
+        f = jnp.asarray(rng.standard_normal((n_src, r)).astype(np.float32))
+        rows = als_dst.lane_rows(f)
+        ldst = np.sort(rng.integers(0, d, gc)).astype(np.int32)
+        acc0 = np.zeros((d, 128, 128), np.float32)
+        acc0[ldst[0], :r, :r] = 1.5
+        w = als_ops.dst_weights(jnp.asarray(conf), jnp.asarray(valid), 40.0, True)
+        got = als_dst.dst_moments(rows, jnp.asarray(src), w, jnp.asarray(ldst),
+                                  jnp.asarray(acc0), r, interpret=True)
+        want = _parents_form(rows, jnp.asarray(src), w, ldst, acc0, r)
+        assert np.array_equal(np.asarray(got), want)
+
+    def test_a_destination_across_steps_and_chunks(self):
+        """One destination's groups run over two grid steps of the first
+        chunk and on into the second: its tile is carried across both."""
+        r, p, gc, d, n_src = 100, 128, 16, 5, 70
+        rng = np.random.default_rng(46)
+        f = jnp.asarray(rng.standard_normal((n_src, r)).astype(np.float32))
+        rows = als_dst.lane_rows(f)
+        # destination 2 holds groups 5 ... 20 of 32: steps 0 and 1 of the
+        # first chunk, step 0 of the second
+        ldst = np.array([0] * 3 + [1] * 2 + [2] * 16 + [3] * 6 + [4] * 5,
+                        np.int32)
+        live = rng.integers(1, p + 1, 2 * gc)
+        src, conf, valid = _chunk(rng, 2 * gc, p, n_src, live)
+        w = als_ops.dst_weights(jnp.asarray(conf), jnp.asarray(valid), 40.0, True)
+        acc = want = np.zeros((d, 128, 128), np.float32)
+        for c in range(2):
+            part = slice(c * gc, (c + 1) * gc)
+            acc = als_dst.dst_moments(
+                rows, jnp.asarray(src[part]), w[part], jnp.asarray(ldst[part]),
+                jnp.asarray(acc), r, interpret=True)
+            want = _parents_form(rows, jnp.asarray(src[part]), w[part],
+                                 ldst[part], want, r)
+        assert np.array_equal(np.asarray(acc), want)
+        # its count: the preferred ratings of its sixteen groups
+        assert np.asarray(acc)[2, r, r + 1] == ((conf > 0) * valid)[5:21].sum()
 
     @pytest.mark.parametrize("r, b", [(10, 128), (40, 200), (100, 130)])
     def test_the_block_cholesky_solves_what_numpy_factors(self, r, b):
@@ -247,16 +333,47 @@ class TestThePlan:
         assert als_ops.resolve_dst_kernels("tpu") == ("pallas", "pallas")
 
 
-def test_the_fit_books_the_route_on_its_span(table):
+@pytest.mark.parametrize("moments", ["xla", "pallas_interpret"])
+def test_the_fit_books_the_route_on_its_span(table, monkeypatch, moments):
+    if moments != "xla":
+        monkeypatch.setattr(als_ops, "resolve_dst_kernels",
+                            lambda backend="": (moments, "xla"))
     model, _ = _fit(table, 40, max_iter=1)
     attrs = model.summary["timings"].root.node("als_iterations").attrs
     D, blocks_u = als_ops.dst_blocks(N_USERS, 16384)
+    build = model.summary["timings"].root.node("table_convert/group_edges").attrs
+    assert build["group_size"] == [128, 128]
+    # one chunk a side: every bucket holds fewer groups than a chunk
+    walked = (build["groups_user"] + build["groups_item"]) * 128
     assert attrs == {
         "iterations": 1, "solve_kernel": "xla", "rank": 40, "implicit": True,
         "route": membudget.ROUTE_DST_BLOCKED, "dst_block": 16384,
         "blocks_user": blocks_u, "blocks_item": 1, "r_pad": 128,
-        "gather": "rows", "moments": "xla", "solve": "xla",
+        # the XLA twin gathers every slot of its chunks; the kernel copies
+        # them too, and its last grid step's rows once more a chunk
+        "gather": "rows" if moments == "xla" else "dma",
+        "rows_fetched": walked if moments == "xla"
+        else walked + 2 * als_dst.STEP_GROUPS * 128,
+        "slots_walked": walked, "moments": moments, "solve": "xla",
     }
-    build = model.summary["timings"].root.node("table_convert/group_edges").attrs
     assert build["sheet_bytes"] == 0
     assert model.summary["timings"].root.attrs["kernel"] == "dst_blocked"
+    if moments != "xla":
+        twin, _ = _fit(table, 40, max_iter=1)
+        for got, want in ((model.user_factors_, twin.user_factors_),
+                          (model.item_factors_, twin.item_factors_)):
+            assert _rel(got, np.asarray(want, np.float64)) < 1e-6
+
+
+def test_the_walk_is_reckoned_in_whole_chunks_a_block():
+    """``dst_walked_chunks``: 300 destinations in blocks of 128, eleven
+    live groups of a 16-group bucket."""
+    group_dst = np.array([0, 0, 5, 127, 128, 130, 200, 255, 256, 299, 299]
+                         + [299] * 5, np.int32)
+    # blocks hold groups [0, 4), [4, 8), [8, 11): chunks of 4 take one
+    # each, chunks of 3 two, two and one
+    assert als_ops.dst_walked_chunks(group_dst, 11, 300, 128, 4) == 3
+    assert als_ops.dst_walked_chunks(group_dst, 11, 300, 128, 3) == 5
+    # one block of 384 destinations, its 11 groups in one chunk of the
+    # bucket's 16 (a chunk is never longer than the layout)
+    assert als_ops.dst_walked_chunks(group_dst, 11, 300, 16384, 2048) == 1

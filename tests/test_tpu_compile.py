@@ -979,17 +979,22 @@ class TestALSDstBlockedOnOneChip:
         assert plan.route == membudget.ROUTE_DST_BLOCKED and plan.dst_block == 16384
 
     def test_the_moments_kernel(self, one_chip):
+        """The kernel fetches a chunk's rows itself, one DMA a row into a
+        sublane of its VMEM buffer, from the item side's table in HBM."""
         from oap_mllib_tpu.ops.pallas import als_dst
 
         gc, p, lanes, d = 2048, 128, als_dst.r_pad(self.RANK), 16384
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
         compiled = _compile(
-            lambda ys, w, ldst, acc: als_dst.dst_moments(ys, w, ldst, acc, self.RANK),
-            _s((gc, p, lanes), one_chip), _s((gc, 8, p), one_chip),
-            jax.ShapeDtypeStruct((gc,), jnp.int32, sharding=one_chip),
-            _s((d, lanes, lanes), one_chip),
+            lambda rows, src, w, ldst, acc: als_dst.dst_moments(
+                rows, src, w, ldst, acc, self.RANK),
+            _s((self.ITEMS, lanes), one_chip), i32(gc, p),
+            _s((gc, 8, p), one_chip), i32(gc), _s((d, lanes, lanes), one_chip),
         )
-        # the block's tiles are written in place: no copy of them
-        assert compiled.memory_analysis().temp_size_in_bytes < 64 * MiB
+        assert "als_dst_moments" in compiled.as_text()
+        # the block's tiles are written in place and the rows read where
+        # they lie: no copy of either, no gathered chunk
+        assert compiled.memory_analysis().temp_size_in_bytes < MiB
 
     def test_the_block_cholesky(self, one_chip):
         from oap_mllib_tpu.ops.pallas import als_dst
@@ -1024,14 +1029,20 @@ class TestALSDstBlockedOnOneChip:
         ).compile()
         text = compiled.as_text()
         assert "als_dst_moments" in text and "als_block_cholesky" in text
+        # the kernel reads the source rows itself: XLA gathers no chunk of
+        # them, f32[262144,128] (2048 groups of 128 slots)
+        from oap_mllib_tpu.ops.pallas.als_dst import r_pad
+
+        gc = als_ops.dst_chunk_groups(128, r)
+        assert f"f32[{gc * 128},{r_pad(r)}]" not in text
         mem = compiled.memory_analysis()
         layout_bytes = sum(g * p * 12 + g * 4 for p, g in layouts)
         assert mem.argument_size_in_bytes < 1.02 * layout_bytes + 0.5e9
-        # one block's tiles and systems, a chunk of gathered rows and the
-        # lane-dense source rows: a few GB, where one side's (n_dst, r, r)
-        # equations would be 25 GB and the grouped sheet 107 GB
+        # one block's tiles and systems and the lane-dense source rows: a
+        # few GB, where one side's (n_dst, r, r) equations would be 25 GB
+        # and the grouped sheet 107 GB; and no chunk of gathered rows
         block = membudget.als_dst_block_bytes(plan.dst_block, r)
-        assert mem.temp_size_in_bytes < 2.5 * block
+        assert mem.temp_size_in_bytes < 2.5 * block - als_ops.DST_CHUNK_BYTES
         assert membudget.als_sheet_bytes(1 << 21, r) > 100e9
         assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 + mem.output_size_in_bytes) < self.HBM
